@@ -72,9 +72,9 @@ def main() -> int:
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}",
           flush=True)
 
-    cfg = CONFIGS[args.config]
-    if jax.default_backend() != "tpu":
-        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    cfg = dataclasses.replace(CONFIGS[args.config],
+                              dtype=rt.platform_dtype())
+    print(rt.device_line(cfg.dtype), flush=True)
     seq = min(args.seq_len, cfg.max_seq)
 
     params = shard_pytree(B.init_params(jax.random.PRNGKey(0), cfg),

@@ -17,11 +17,12 @@ import sys
 import time
 
 import jax
-import jax.numpy as jnp
 
+import tony_tpu.runtime as rt
 from tony_tpu.models import transformer as T
 from tony_tpu.models.checkpoint import CheckpointManager
 from tony_tpu.models.decode import generate
+from tony_tpu.runtime import compile_cache
 
 
 def main() -> int:
@@ -42,9 +43,10 @@ def main() -> int:
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args()
 
-    on_tpu = jax.default_backend() == "tpu"
-    cfg = T.PRESETS[args.preset].scaled(
-        dtype=jnp.bfloat16 if on_tpu else jnp.float32, remat=False)
+    compile_cache.enable()
+    dtype = rt.platform_dtype()
+    print(rt.device_line(dtype), flush=True)
+    cfg = T.PRESETS[args.preset].scaled(dtype=dtype, remat=False)
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     if args.ckpt_dir:
         with CheckpointManager(args.ckpt_dir) as mgr:

@@ -80,17 +80,33 @@ the green-field serving stack end to end.
 from __future__ import annotations
 
 import argparse
+import signal
 import sys
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+import tony_tpu.runtime as rt
 from tony_tpu.models import transformer as T
 from tony_tpu.models.checkpoint import CheckpointManager
 from tony_tpu.models.serve import (ContinuousBatcher,
                                    SpeculativeContinuousBatcher)
+from tony_tpu.runtime import compile_cache
+
+
+def _sleep_until_stopped() -> None:
+    """Block until SIGINT or SIGTERM. Both handlers are installed here:
+    a process started in the background of a non-interactive shell
+    inherits SIGINT ignored, and a supervisor stops a replica with
+    SIGTERM — either must reach the drain, not kill mid-request."""
+    for sig in (signal.SIGINT, signal.SIGTERM):
+        signal.signal(sig, signal.default_int_handler)
+    try:
+        while True:
+            time.sleep(3600)
+    except KeyboardInterrupt:
+        pass
 
 
 def _parse_addr(addr: str) -> tuple[str, int]:
@@ -150,12 +166,10 @@ def _run_server(args, batcher) -> int:
         "sampled" if args.temperature > 0 else "greedy")
     print(f"serving {args.preset} ({mode}) on {host}:{bound} with "
           f"{args.slots} slots — ^C drains and exits", flush=True)
-    try:
-        while True:
-            time.sleep(3600)
-    except KeyboardInterrupt:
-        print("draining in-flight requests ...", flush=True)
-        server.stop(drain=True)
+    _sleep_until_stopped()
+    print("draining in-flight requests ...", flush=True)
+    server.stop(drain=True)
+    print(compile_cache.stats(), flush=True)
     return 0
 
 
@@ -181,11 +195,8 @@ def _run_router(args) -> int:
              if decodes else f"{len(replicas)} replicas")
     print(f"routing on {host}:{bound} over {shape} — ^C exits",
           flush=True)
-    try:
-        while True:
-            time.sleep(3600)
-    except KeyboardInterrupt:
-        router.stop()
+    _sleep_until_stopped()
+    router.stop()
     return 0
 
 
@@ -208,11 +219,8 @@ def _run_prefill(args, params, cfg) -> int:
         _install_and_publish(args, server)
     print(f"prefill tier ({args.preset}) on {host}:{bound} "
           f"({args.slots}-row waves) — ^C exits", flush=True)
-    try:
-        while True:
-            time.sleep(3600)
-    except KeyboardInterrupt:
-        server.stop()
+    _sleep_until_stopped()
+    server.stop()
     return 0
 
 
@@ -229,12 +237,10 @@ def _run_decode(args, batcher) -> int:
     print(f"decode tier ({args.preset}, {mode}) on {host}:{bound} with "
           f"{args.slots} slots; kv channel on :{server.hub.port} — ^C "
           f"drains and exits", flush=True)
-    try:
-        while True:
-            time.sleep(3600)
-    except KeyboardInterrupt:
-        print("draining in-flight requests ...", flush=True)
-        server.stop(drain=True)
+    _sleep_until_stopped()
+    print("draining in-flight requests ...", flush=True)
+    server.stop(drain=True)
+    print(compile_cache.stats(), flush=True)
     return 0
 
 
@@ -585,9 +591,11 @@ def main() -> int:
     if args.role and not args.listen:
         parser.error("--role requires --listen")
 
-    on_tpu = jax.default_backend() == "tpu"
+    compile_cache.enable()
+    dtype = rt.platform_dtype()
+    print(rt.device_line(dtype), flush=True)
     cfg = T.PRESETS[args.preset].scaled(
-        dtype=jnp.bfloat16 if on_tpu else jnp.float32, remat=False,
+        dtype=dtype, remat=False,
         kv_cache_dtype=args.kv_cache_dtype,
         attn_window=args.attn_window,
         kv_cache_capacity=args.kv_cache_capacity)

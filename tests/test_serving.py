@@ -913,7 +913,7 @@ class TestStreamingBenchArm:
         """The tentpole acceptance, deterministically: at a 50 ms
         injected round trip the streamed wall sits within 1.15x of the
         zero-delay wall (the round trip is paid once) while the
-        request/response tunnel pays it per chunk + per admission —
+        request/response wire pays it per chunk + per admission —
         stream-vs-rr >= 2. The plug keeps the streamed sync schedule
         identical across runs (asserted)."""
         import bench
@@ -937,7 +937,7 @@ class TestStreamingBenchRealistic:
     def test_realistic_compute_still_streams_past_rr(self):
         """No injected fetch floor — real (tiny-model) chunk compute
         only, so the 50 ms round trip dominates: streaming must beat
-        the per-chunk tunnel by well over 2x."""
+        the per-chunk wire by well over 2x."""
         import bench
 
         res = bench._streaming_arm(fetch_floor_s=0.0, budget=96)

@@ -214,7 +214,7 @@ class TestTpuBackendE2E:
         assert gang_ops("create", "-s0") == 1
 
     def test_topology_instances_mismatch_rejected_at_submit(self, tmp_path):
-        """VERDICT #6: instances=4 on a v5e 2x2 slice (1 host) must fail
+        """Review finding 6: instances=4 on a v5e 2x2 slice (1 host) must fail
         in the SUBMITTING process with an actionable message — before any
         coordinator launch, not as a late opaque ssh error."""
         conf = tpu_conf(tmp_path, {"tony.worker.instances": "4",

@@ -746,6 +746,40 @@ class TestCompileCache:
         assert store.get(digest) == good        # a compile-cache hit
         assert reg.counter("tony_compile_cache_hits_total").value == 1
 
+    def test_one_cache_placed_from_outside(self, tmp_path, monkeypatch):
+        """runtime/compile_cache.py is the tree's one writer of
+        ``jax_compilation_cache_dir``: with JAX_COMPILATION_CACHE_DIR set
+        it sets nothing (whoever launched the process placed the cache);
+        otherwise the checkout's own ``.jax_cache`` — derived from the
+        package, not from the working directory — or the dir a shipped
+        artifact names."""
+        import jax
+        from tony_tpu.runtime import compile_cache
+        from tony_tpu.serving.weightstore import attach_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.chdir(tmp_path)
+        try:
+            monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "env"))
+            assert compile_cache.enable() == str(tmp_path / "env")
+            assert attach_compile_cache(str(tmp_path / "x")) \
+                == str(tmp_path / "env")
+            assert jax.config.jax_compilation_cache_dir == before
+            monkeypatch.delenv(compile_cache.ENV_VAR)
+            repo = os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))
+            assert compile_cache.enable() == os.path.join(repo,
+                                                          ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir \
+                == os.path.join(repo, ".jax_cache")
+            assert attach_compile_cache(str(tmp_path / "x")) \
+                == str(tmp_path / "x")
+            assert jax.config.jax_compilation_cache_dir \
+                == str(tmp_path / "x")
+            assert attach_compile_cache("") == ""       # none configured
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        assert compile_cache.stats().startswith("compile cache: ")
+
 
 # ---------------------------------------------------------------------------
 # Bench pins

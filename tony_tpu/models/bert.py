@@ -10,15 +10,18 @@ post-LN transformer encoder, GELU MLP, weight-tied MLM head.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from tony_tpu.ops import mosaic
 from tony_tpu.ops.attention import flash_attention, reference_attention
 from tony_tpu.ops.norms import layer_norm_reference
-from tony_tpu.parallel.sharding import DEFAULT_RULES, constrain
+from tony_tpu.parallel.sharding import (DEFAULT_RULES, constrain,
+                                        shard_attention)
 from tony_tpu.models.train import masked_cross_entropy
 
 
@@ -103,10 +106,12 @@ def logical_axes(cfg: BertConfig) -> dict:
     }
 
 
-def _attention(q, k, v):
-    if jax.default_backend() == "tpu":
-        return flash_attention(q, k, v, causal=False)
-    return reference_attention(q, k, v, causal=False)
+def _attention(q, k, v, mesh):
+    # per device inside shard_map on a multi-device mesh — see
+    # transformer._attention
+    arm = reference_attention if mosaic.interpret() else flash_attention
+    return shard_attention(functools.partial(arm, causal=False),
+                           q, k, v, mesh)
 
 
 def _block(x, p, cfg: BertConfig, mesh, rules):
@@ -114,7 +119,7 @@ def _block(x, p, cfg: BertConfig, mesh, rules):
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, p["wv"])
-    o = _attention(q, k, v)
+    o = _attention(q, k, v, mesh)
     attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     x = layer_norm_reference(x + attn, p["attn_ln"]["scale"],
                              p["attn_ln"]["bias"])   # post-LN (original BERT)

@@ -127,16 +127,25 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array] | None,
                            "step": new_state["step"]}
 
     jitted = jax.jit(step, donate_argnums=(0,) if donate else ())
-    if mesh is None:
-        return _instrument_step(jitted)
 
-    def sharded_step(state, batch):
-        # set_mesh must wrap the CALL, not the traced body: the ambient mesh
-        # is what lets bare-PartitionSpec sharding constraints resolve.
-        with jax.set_mesh(mesh):
-            return jitted(state, batch)
+    def under_mesh(fn):
+        if mesh is None:
+            return fn
 
-    return _instrument_step(sharded_step)
+        def sharded(state, batch):
+            # set_mesh must wrap the CALL, not the traced body: the ambient
+            # mesh is what lets bare-PartitionSpec sharding constraints
+            # resolve.
+            with jax.set_mesh(mesh):
+                return fn(state, batch)
+        return sharded
+
+    run = _instrument_step(under_mesh(jitted))
+    # AOT handle on the SAME program (arrays or ShapeDtypeStructs in): what
+    # lets a caller read the step's text — is the flash kernel in it? — or
+    # compile it for a described topology, without running it
+    run.lower = under_mesh(jitted.lower)
+    return run
 
 
 def _instrument_step(step_fn: Callable) -> Callable:

@@ -31,11 +31,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from tony_tpu.ops import mosaic
 from tony_tpu.ops.attention import flash_attention, reference_attention
 from tony_tpu.ops.norms import rms_norm_reference
 from tony_tpu.parallel.moe import moe_ffn
 from tony_tpu.parallel.ring_attention import ring_attention
-from tony_tpu.parallel.sharding import DEFAULT_RULES, constrain
+from tony_tpu.parallel.sharding import (DEFAULT_RULES, constrain,
+                                        shard_attention)
 from tony_tpu.models.train import masked_cross_entropy
 
 
@@ -343,10 +345,14 @@ def _attention(q, k, v, mesh: Mesh | None, cp_strategy: str = "ring",
         # ring rides GQA K/V unexpanded: the rotation payload (the ring's
         # whole inter-chip cost) shrinks by n_heads/n_kv_heads
         return ring_attention(q, k, v, mesh, causal=True)
-    # flash and reference both consume GQA K/V natively (fewer kv heads)
-    if jax.default_backend() == "tpu":
-        return flash_attention(q, k, v, causal=True, window=window)
-    return reference_attention(q, k, v, causal=True, window=window)
+    # flash and reference both consume GQA K/V natively (fewer kv heads).
+    # On a multi-device mesh the arm runs per device inside shard_map
+    # (batch over dp/fsdp, heads over tp): a Mosaic kernel cannot be
+    # partitioned by the compiler, and the dense arm rides the same
+    # island so the CPU mesh tests pin the wrapper the chip runs.
+    arm = reference_attention if mosaic.interpret() else flash_attention
+    return shard_attention(
+        functools.partial(arm, causal=True, window=window), q, k, v, mesh)
 
 
 def _remat_policy(cfg: TransformerConfig):

@@ -74,6 +74,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tony_tpu.ops import mosaic
+
 _NEG_INF = -1.0e30
 _LANES = 128
 # The online softmax runs in BASE-2 (flash-2-style transcendental
@@ -90,10 +92,6 @@ _LN2 = 0.6931471805599453
 
 def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pick_group(bh: int, block_h: int) -> int:
@@ -288,7 +286,7 @@ def _flash_forward(q, k, v, *, causal, g, bq, bk, band, window=None):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
     )(q, k, v)
     return o, lse
 
@@ -455,7 +453,7 @@ def _flash_backward_fused(q, k, v, o, lse, do, dlse, *, causal, g,
     # forward's: the backward holds 2× f32 kv-block scratch per head,
     # so the forward's g=16 short-kv choice blows its VMEM (any g=16
     # implies 8 | bh, so the clamp always divides).
-    if not _interpret():
+    if not mosaic.interpret():
         g = min(g, 8)
         if sq % _BWD_BQ == 0 and band % _BWD_BQ == 0:
             bq = _BWD_BQ
@@ -509,7 +507,7 @@ def _flash_backward_fused(q, k, v, o, lse, do, dlse, *, causal, g,
                         pltpu.VMEM((g, bk, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
     )(*operands)
     if nk == 1:
         return dqp[0], dk, dv
@@ -626,7 +624,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse=None, *, causal, g,
     # dQ partials are [nk, bh, sq, d] at the blocks the fused path will
     # actually pick — mirror its clamp chain exactly.
     bk_eff = bk
-    if not _interpret():
+    if not mosaic.interpret():
         if sk % _BWD_BK == 0:
             bk_eff = _BWD_BK
         elif bk > 256 and sk % 256 == 0:
@@ -641,7 +639,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse=None, *, causal, g,
     # two-pass kernels inside the ~16 MB VMEM budget (long sequences have
     # hundreds of grid steps either way). Same independent head-group
     # clamp as the fused path (the forward may have picked g=16).
-    if not _interpret():
+    if not mosaic.interpret():
         g = min(g, 8)
     if bq > 256 and sq % 256 == 0 and band % 256 == 0:
         bq = 256
@@ -677,7 +675,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse=None, *, causal, g,
         scratch_shapes=[pltpu.VMEM((g, bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
     )(q, k, v, do, lse2, delta)
 
     band_nq = _cdiv(band, bq)
@@ -709,7 +707,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse=None, *, causal, g,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
     )(q, k, v, do, lse2, delta)
     return dq, dk, dv
 
@@ -850,7 +848,7 @@ def _sub_tile(q, block_q: int) -> bool:
     sub-128 lanes are an untested Mosaic regime (interpret mode — the CPU
     test path — keeps small blocks so the kernels stay bit-testable).
     Callers fall back to the dense arm, which has no tiling demands."""
-    if _interpret():
+    if mosaic.interpret():
         return False
     return min(block_q, q.shape[1]) % _LANES != 0
 
@@ -877,13 +875,13 @@ def _prep_flat(q, k, v, scale, block_q: int, block_k: int, block_h: int):
         raise ValueError(f"seq lengths ({sq}, {sk}) must divide into blocks")
     bq = min(block_q, sq)
     bk = min(block_k, sk)
-    if not _interpret() and bk == sk and bq < bk and sk % 256 == 0 \
-            and sk >= 1024:
+    on_chip = not mosaic.interpret()
+    if on_chip and bk == sk and bq < bk and sk % 256 == 0 and sk >= 1024:
         # single-kv-block grids at wide bk lose the revolving-buffer
         # VMEM reuse and blow the ~16 MB budget by a hair (measured:
         # [256, 1024] at nk=1 is 68 KB over); two kv blocks fit.
         bk = sk // 2
-    if not _interpret() and bk <= 512 and bq > 128 and sq % 128 == 0:
+    if on_chip and bk <= 512 and bq > 128 and sq % 128 == 0:
         # short-kv regime (the wide-kv choice above didn't engage): the
         # v5e sweep at seq 1k picked 128-row q blocks with a DOUBLE head
         # group (2.00 ms vs 2.44 for 256×512 g8, vs 2.08 for the old

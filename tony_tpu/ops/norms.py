@@ -19,9 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from tony_tpu.ops import mosaic
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -55,7 +53,7 @@ def _row_call(kernel, x, *params, block_rows: int = 256):
                  [pl.BlockSpec((d,), lambda i: (0,))] * len(params),
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
     )(x2, *params)
     return out.reshape(shape)
 

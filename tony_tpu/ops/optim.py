@@ -29,12 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tony_tpu.ops import mosaic
+
 _LANES = 128
 _BLOCK_ROWS = 2048          # (2048, 128) f32×5 + bf16×2 ≈ 5.5 MB of VMEM
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _adamw_kernel(sc_ref, p_ref, g_ref, m_ref, v_ref,
@@ -111,7 +109,7 @@ def _fused_leaf_update(p: jax.Array, g: jax.Array, m: jax.Array,
                    jax.ShapeDtypeStruct(v2.shape, v.dtype)],
         # alias p/m/v through: the update is in-place under donation
         input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
     )(scalars, p2, g2, m2, v2)
     new_p, new_m, new_v = out
     return (new_p.reshape(p.shape), new_m.reshape(p.shape),

@@ -55,26 +55,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def _axis_size(axis_name: str):
-    """lax.axis_size across jax versions (0.4.x predates it): the size
-    of a named mesh axis from inside a shard_map body."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: the public alias (with
-    ``check_vma``) landed after 0.4.x, where the same entry point lives
-    in jax.experimental.shard_map with the flag named ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def _pipeline_local(stage_params: Any, microbatches: jax.Array, *,
                     stage_fn: Callable[[Any, jax.Array], Any],
                     axis_name: str, with_aux: bool,
@@ -89,7 +69,7 @@ def _pipeline_local(stage_params: Any, microbatches: jax.Array, *,
     garbage state whose aux must not count), summed over stages, and
     averaged over the batch axes.
     """
-    s = _axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     params = jax.tree.map(lambda x: x[0], stage_params)
     m = microbatches.shape[0]
@@ -172,7 +152,7 @@ def _pipeline_1f1b_local(stage_params: Any, head_params: Any,
     yet reduced: loss_sum/head_grads live on the last stage, dxs on stage
     0, stage_grads on their own stage.
     """
-    s_count = _axis_size(axis_name)
+    s_count = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     params = jax.tree.map(lambda v: v[0], stage_params)
     m = microbatches.shape[0]
@@ -250,7 +230,7 @@ def _pipeline_1f1b_local(stage_params: Any, head_params: Any,
                 # weight via the vjp's aux output
                 denom = 1
                 for _ax in head_reduce_axes:
-                    denom = denom * _axis_size(_ax)
+                    denom = denom * lax.axis_size(_ax)
                 w_eff = aux_weight / denom
 
                 def last_fn(p, hp, x):
@@ -368,7 +348,7 @@ def _pipeline_1f1b_local(stage_params: Any, head_params: Any,
     # cotangent of THIS shard's tokens — scaled, not summed
     d_total = 1
     for a in batch_axes:
-        d_total *= _axis_size(a)
+        d_total *= lax.axis_size(a)
         loss = lax.pmean(loss, a)
         grads = jax.tree.map(lambda g, _a=a: lax.pmean(g, _a), grads)
         hgrads = jax.tree.map(lambda g, _a=a: lax.pmean(g, _a), hgrads)
@@ -477,7 +457,7 @@ def pipeline_value_and_grad(stage_fn: Callable[[Any, jax.Array], jax.Array],
                            stage_specs=param_specs,
                            head_reduce_axes=head_reduce_axes,
                            with_aux=with_aux, aux_weight=aux_weight)
-    loss, g_sp, g_hp, g_xs = _shard_map(
+    loss, g_sp, g_hp, g_xs = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(param_specs, head_specs, data_spec, data_spec),
         out_specs=(P(), param_specs, head_specs, data_spec),
@@ -543,7 +523,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], Any],
     fn = functools.partial(_pipeline_local, stage_fn=stage_fn,
                            axis_name=axis_name, with_aux=with_aux,
                            batch_axes=live)
-    out = _shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(param_specs, data_spec),
         out_specs=(data_spec, P()) if with_aux else data_spec,

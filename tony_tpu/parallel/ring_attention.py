@@ -20,10 +20,12 @@ from __future__ import annotations
 import functools
 from typing import Sequence
 
-import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
+
+from tony_tpu.ops import mosaic
+from tony_tpu.parallel.sharding import shard_attention
 
 _NEG_INF = -1.0e30
 
@@ -83,8 +85,7 @@ _USE_FLASH_CHUNKS: bool | None = None
 def _flash_chunks() -> bool:
     if _USE_FLASH_CHUNKS is not None:
         return _USE_FLASH_CHUNKS
-    import jax
-    return jax.default_backend() == "tpu"
+    return not mosaic.interpret()
 
 
 def ring_attention_local(q, k, v, *, axis_name: str = "cp",
@@ -144,9 +145,8 @@ def _flash_block(s_loc: int) -> int | None:
     and per-grid-step overhead makes flash slower than the dense arm
     there anyway. Interpret mode (the CPU test path) keeps the small
     blocks so the flash-chunk arm stays bit-testable at tiny shapes."""
-    import jax
-    blocks = ((512, 256, 128) if jax.default_backend() == "tpu"
-              else (512, 256, 128, 64, 32, 16, 8))
+    blocks = ((512, 256, 128, 64, 32, 16, 8) if mosaic.interpret()
+              else (512, 256, 128))
     for b in blocks:
         if s_loc % b == 0:
             return b
@@ -247,26 +247,14 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = True,
     they divide it; otherwise (H_kv < tp) K/V expand to full width
     first — correctness over the payload saving.
     """
-    from tony_tpu.parallel.sharding import attention_spec
-    spec, s_spec = attention_spec(mesh, batch_axes, seq_axis, head_axis)
-    h, hk = q.shape[2], k.shape[2]
-    if hk != h and (hk <= 0 or h % hk):
-        raise ValueError(f"kv heads ({hk}) must divide heads ({h})")
-    if hk != h:
-        tp = mesh.shape.get(head_axis, 1) if head_axis else 1
-        if hk % max(tp, 1):
-            rep = h // hk
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-
-    if s_spec is None:
-        # no cp axis: plain (still blockwise/online-softmax) local attention
-        fn = functools.partial(_single_chunk, causal=causal, scale=scale)
-    else:
+    if mesh.shape.get(seq_axis, 1) > 1:
         fn = functools.partial(ring_attention_local, axis_name=seq_axis,
                                causal=causal, scale=scale)
-    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    else:
+        # no cp axis: plain (still blockwise/online-softmax) local attention
+        fn = functools.partial(_single_chunk, causal=causal, scale=scale)
+    return shard_attention(fn, q, k, v, mesh, batch_axes=batch_axes,
+                           seq_axis=seq_axis, head_axis=head_axis)
 
 
 def _single_chunk(q, k, v, *, causal, scale):

@@ -11,6 +11,7 @@ shardings, let XLA insert the collectives.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Sequence
 
 import jax
@@ -155,18 +156,75 @@ def param_shardings(logical_tree: Any, mesh: Mesh,
         logical_tree, is_leaf=_is_axes_leaf)
 
 
+_BATCH_AXES = dict(DEFAULT_RULES)["batch"]      # ("dp", "fsdp")
+_HEAD_AXIS = dict(DEFAULT_RULES)["heads"]       # "tp"
+
+
 def attention_spec(mesh: Mesh, batch_axes, seq_axis: str | None,
                    head_axis: str | None):
     """PartitionSpec for [B, S, H, D] attention operands under shard_map:
     batch over the live subset of ``batch_axes``, sequence over ``seq_axis``,
-    heads over ``head_axis``; axes missing from the mesh (or size 1) are
-    dropped. Returns (spec, seq_axis_live: str | None) — shared by the
-    context-parallel attention wrappers (ring / ulysses)."""
-    from jax.sharding import PartitionSpec as P
-    live = lambda a: a is not None and a in mesh.shape and mesh.shape[a] > 1
+    heads over ``head_axis``; axes missing from the mesh (or size 1, or
+    already Manual — an enclosing shard_map owns those) are dropped.
+    Returns (spec, seq_axis_live: str | None)."""
+    auto = _auto_axes(mesh)
+    live = lambda a: (a is not None and a in auto
+                      and mesh.shape.get(a, 1) > 1)
     b_spec = tuple(a for a in batch_axes if live(a)) or None
     if isinstance(b_spec, tuple) and len(b_spec) == 1:
         b_spec = b_spec[0]
     s_spec = seq_axis if live(seq_axis) else None
     h_spec = head_axis if live(head_axis) else None
     return P(b_spec, s_spec, h_spec, None), s_spec
+
+
+def shard_attention(local_fn, q, k, v, mesh: Mesh | None, *,
+                    batch_axes: Sequence[str] = _BATCH_AXES,
+                    seq_axis: str | None = None,
+                    head_axis: str | None = _HEAD_AXIS,
+                    kv_split: int = 1):
+    """Run a per-device attention ``local_fn(q, k, v)`` over global
+    [B, S, H, D] operands as ONE ``shard_map`` island: batch over the
+    mesh axes the rules give "batch", heads over the "heads" axis,
+    sequence over ``seq_axis`` when the caller's body does its own
+    cross-chunk collectives (ring / Ulysses). The flash kernels are
+    Mosaic custom calls, which the SPMD partitioner cannot split ("Mosaic
+    kernels cannot be automatically partitioned") — so every attention
+    arm on a multi-device mesh goes through here, not only the
+    context-parallel ones.
+
+    ``mesh=None`` takes the ambient mesh (``jax.set_mesh``); with no mesh,
+    one device, or every axis already Manual (a pipeline stage body) the
+    call is ``local_fn(q, k, v)`` as is.
+
+    GQA K/V (fewer heads than Q) stay UNEXPANDED when the kv heads
+    survive the head sharding — ``tp · kv_split`` divides them, where
+    ``kv_split`` is a further split the body makes itself (Ulysses'
+    all-to-all over cp) — because the local arms pair local query head j
+    with local kv head j // rep, which is the global pairing only when
+    K/V heads shard like Q's. Otherwise they expand to full width first:
+    correctness over the payload saving."""
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty or mesh.size == 1 \
+            or not _auto_axes(mesh):
+        return local_fn(q, k, v)
+    # an axis that does not divide its dim is dropped (the operand
+    # replicates over it and the devices repeat the work) — what the
+    # partitioner does with a constraint it cannot honour evenly, e.g. a
+    # serving batch of 2 under a dp=4 mesh
+    batch_axes = tuple(a for a in batch_axes if a in mesh.shape)
+    while q.shape[0] % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = batch_axes[:-1]
+    if head_axis in mesh.shape and q.shape[2] % mesh.shape[head_axis]:
+        head_axis = None
+    spec, _ = attention_spec(mesh, batch_axes, seq_axis, head_axis)
+    h, hk = q.shape[2], k.shape[2]
+    if hk != h:
+        if hk <= 0 or h % hk:
+            raise ValueError(f"kv heads ({hk}) must divide heads ({h})")
+        tp = mesh.shape[spec[2]] if spec[2] is not None else 1
+        if hk % (tp * kv_split):
+            k, v = (jax.numpy.repeat(x, h // hk, axis=2) for x in (k, v))
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

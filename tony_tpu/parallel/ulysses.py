@@ -24,11 +24,11 @@ from __future__ import annotations
 import functools
 from typing import Sequence
 
-import jax
 from jax import lax
 from jax.sharding import Mesh
 
 from tony_tpu.parallel.ring_attention import _single_chunk
+from tony_tpu.parallel.sharding import shard_attention
 
 
 def ulysses_attention_local(q, k, v, *, axis_name: str = "cp",
@@ -105,28 +105,15 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, causal: bool = True,
     payload shrinks by H/H_kv, the same discipline as the ring's
     unexpanded rotation. Otherwise (H_kv < tp·cp granularity) K/V expand
     to full width first — correctness over the payload saving."""
-    import jax.numpy as jnp
-
-    from tony_tpu.parallel.sharding import attention_spec
-    spec, s_spec = attention_spec(mesh, batch_axes, seq_axis, head_axis)
-    h, hk = q.shape[2], k.shape[2]
-    if hk != h and (hk <= 0 or h % hk):
-        raise ValueError(f"kv heads ({hk}) must divide heads ({h})")
-    if hk != h:
-        tp = mesh.shape.get(head_axis, 1) if head_axis else 1
-        cp = mesh.shape.get(seq_axis, 1) if seq_axis else 1
-        # the kv-head dim must survive the tp shard AND the local
-        # all-to-all split: hk % (tp·cp) == 0 keeps every rank's local
-        # kv heads aligned with its query-head groups
-        if hk % max(tp, 1) or (hk // max(tp, 1)) % max(cp, 1):
-            rep = h // hk
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-
-    if s_spec is None:
-        fn = functools.partial(_single_chunk, causal=causal, scale=scale)
-    else:
+    cp = mesh.shape.get(seq_axis, 1)
+    if cp > 1:
         fn = functools.partial(ulysses_attention_local, axis_name=seq_axis,
                                causal=causal, scale=scale)
-    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    else:
+        fn = functools.partial(_single_chunk, causal=causal, scale=scale)
+    # the kv-head dim must survive the tp shard AND the local all-to-all
+    # split: hk % (tp·cp) == 0 keeps every rank's local kv heads aligned
+    # with its query-head groups
+    return shard_attention(fn, q, k, v, mesh, batch_axes=batch_axes,
+                           seq_axis=seq_axis, head_axis=head_axis,
+                           kv_split=cp)

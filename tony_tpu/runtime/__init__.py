@@ -83,10 +83,34 @@ def initialize() -> TaskInfo:
             coordinator_address=info.coordinator_address,
             num_processes=info.num_processes,
             process_id=info.process_id)
-    from tony_tpu.runtime import profiler
+    from tony_tpu.runtime import compile_cache, profiler
+    compile_cache.enable()
     profiler.maybe_start()
     _initialized = True
     return info
+
+
+def platform_dtype():
+    """The dtype the example entry points compute in: bfloat16 on a TPU
+    (the MXU's native input), float32 elsewhere (the CPU tests compare
+    against float32 references). One definition for all of them — and
+    :func:`device_line` is how the choice reaches the log."""
+    import jax
+    import jax.numpy as jnp
+    return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+
+
+def device_line(dtype) -> str:
+    """``platform tpu kind 'TPU v5 lite' devices 1 dtype bfloat16`` — what
+    an entry point prints FIRST: dtype (and, in the ops, Mosaic or the
+    interpreter) follows the platform, so a run that landed on the CPU
+    must say so in its log rather than exit 0 looking like a chip run.
+    Initialises the backend."""
+    import jax
+    import numpy as np
+    d = jax.devices()
+    return (f"platform {d[0].platform} kind {d[0].device_kind!r} "
+            f"devices {len(d)} dtype {np.dtype(dtype).name}")
 
 
 def mesh_axes() -> dict[str, int]:

@@ -3,8 +3,7 @@
 One persistent TCP connection multiplexes many in-flight requests in
 BOTH directions — the client pushes admissions and cancels, the server
 pushes token deltas the moment the engine consumes them — replacing a
-request/response round trip per chunk (the pre-streaming tunnel paid
-~70-100 ms of transport per chunk AND per admission; see
+request/response round trip per chunk AND per admission (see
 docs/serving.md "Streaming serving"). The framing keeps the
 self-describing discipline of the TONY1 record format
 (``tony_tpu/io/framed.py``): a magic preamble so a stray peer fails

@@ -384,25 +384,20 @@ def install_compile_cache(blob: bytes, cache_dir: str) -> dict:
     return meta
 
 
-def attach_compile_cache(cache_dir: str | None = None) -> bool:
+def attach_compile_cache(cache_dir: str | None = None) -> str:
     """Point JAX's persistent compilation cache at ``cache_dir`` (so a
     landed artifact's traces are HITS, and local traces accrete into
-    the next artifact). ``None`` takes the
-    ``tony.weights.compile-cache-dir`` config default; empty means
-    no cache is configured. Best-effort: returns False when jax is
-    absent or too old to configure — pre-tracing is an optimization,
-    never a boot dependency."""
+    the next artifact) and return the directory in force. ``None``
+    takes the ``tony.weights.compile-cache-dir`` config default; empty
+    means no shippable cache is configured (returns ""). When
+    ``JAX_COMPILATION_CACHE_DIR`` is set it wins — whoever launched the
+    process placed the cache, and artifacts must be landed THERE
+    (:func:`tony_tpu.runtime.compile_cache.enable`, the tree's one
+    writer of the setting)."""
+    from tony_tpu.runtime import compile_cache
     if cache_dir is None:
         cache_dir = DEFAULTS[WEIGHTS_COMPILE_CACHE_DIR_KEY]
-    if not cache_dir:
-        return False
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        return True
-    except Exception as e:                  # noqa: BLE001 — optional
-        log.warning("compile cache not attached (%s)", e)
-        return False
+    return compile_cache.enable(cache_dir) if cache_dir else ""
 
 
 # ---------------------------------------------------------------------------
