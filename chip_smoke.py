@@ -181,7 +181,7 @@ def phase_train(chips: int, mesh: str) -> None:
         sys.stdout.write(out[-3000:])
         _fail(f"{name}: no task log with a step program under {staging}")
     _echo(name, logs, r"platform \S+ kind|mesh=|step program|^step \d+ loss"
-                      r"|compile cache|done: final loss")
+                      r"|compile cache|compile seconds|done: final loss")
     dev = _device_of(logs, name)
     if (dev["platform"], dev["count"], dev["dtype"]) != (
             PLATFORM, chips, "bfloat16"):
@@ -290,7 +290,7 @@ def phase_serve() -> None:
     if rc != 0 or "draining" not in log_text():
         _fail(f"serve: replica exit code {rc} on drain", log_path)
     _echo("serve", log_text(), r"platform \S+ kind|serving |draining"
-                               r"|compile cache")
+                               r"|compile cache|compile seconds")
     print(f"[serve] ok: drained, exit 0, {time.perf_counter() - t0:.1f} s "
           f"in all", flush=True)
 
@@ -299,7 +299,7 @@ def phase_compare(chips: int) -> None:
     out = _run("compare", _self("compare", "--chips", str(chips)),
                timeout=900)
     _echo("compare", out, r"^(platform|device \d|param|unsharded|sharded"
-                          r"|compile cache)")
+                          r"|compile cache|compile seconds)")
 
 
 # ------------------------------------------- children that hold the chip
@@ -389,6 +389,7 @@ def child_compare(chips: int) -> None:
     _assert_close("dp×tp train-step grad norm", gnorm, ref_gnorm,
                   _GNORM_RTOL)
     print(compile_cache.stats(), flush=True)
+    print(compile_cache.seconds_line(), flush=True)
 
 
 def main() -> int:

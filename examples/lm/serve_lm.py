@@ -170,6 +170,7 @@ def _run_server(args, batcher) -> int:
     print("draining in-flight requests ...", flush=True)
     server.stop(drain=True)
     print(compile_cache.stats(), flush=True)
+    print(compile_cache.seconds_line(), flush=True)
     return 0
 
 
@@ -241,6 +242,7 @@ def _run_decode(args, batcher) -> int:
     print("draining in-flight requests ...", flush=True)
     server.stop(drain=True)
     print(compile_cache.stats(), flush=True)
+    print(compile_cache.seconds_line(), flush=True)
     return 0
 
 

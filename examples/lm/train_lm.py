@@ -262,6 +262,7 @@ def main() -> int:
     loss = float(metrics["loss"]) if metrics else float("nan")
     ok = jnp.isfinite(loss)
     print(compile_cache.stats(), flush=True)
+    print(compile_cache.seconds_line(), flush=True)
     print(f"done: final loss {loss:.4f}", flush=True)
     return 0 if ok else 1
 

@@ -523,24 +523,31 @@ def _decode_block(x, layer_params, bufs, li, pos, cfg, rope,
     # pre-update buffer has no later consumer)
     pos = jnp.asarray(pos)
     cap = _ring_capacity(cfg)
+    # named sections (metadata only): a decode profile is read by them
     if cap:
         # rolling cache: the write position wraps modulo the capacity
         # (single-token chunks only — _blocks_forward enforces it), and
         # the read masks rows by their ring offset from each query
-        bufs = {n: _write_kv_chunk(bufs[n], c, li, pos % cap, None)
-                for n, c in _kv_writes(bufs, k, v).items()}
+        with jax.named_scope("cache_write"):
+            bufs = {n: _write_kv_chunk(bufs[n], c, li, pos % cap, None)
+                    for n, c in _kv_writes(bufs, k, v).items()}
         q_pos = (jnp.broadcast_to(pos, (x.shape[0],))
                  if pos.ndim == 0 else pos)
-        o = _ring_cached_attention(q, bufs, li, q_pos, cfg.attn_window)
+        with jax.named_scope("cached_attention"):
+            o = _ring_cached_attention(q, bufs, li, q_pos,
+                                       cfg.attn_window)
     else:
-        bufs = {n: _write_kv_chunk(bufs[n], c, li, pos, window)
-                for n, c in _kv_writes(bufs, k, v).items()}
-        o = _cached_attention(q, bufs, li, pos,
-                              attn_window=cfg.attn_window or None)
+        with jax.named_scope("cache_write"):
+            bufs = {n: _write_kv_chunk(bufs[n], c, li, pos, window)
+                    for n, c in _kv_writes(bufs, k, v).items()}
+        with jax.named_scope("cached_attention"):
+            o = _cached_attention(q, bufs, li, pos,
+                                  attn_window=cfg.attn_window or None)
     x = x + _weinsum("bshk,hkd->bsd", o, p["wo"])
 
-    h = rms_norm_reference(x, p["mlp_norm"])
-    mlp_out = _mlp(h, p, cfg)
+    with jax.named_scope("mlp"):
+        h = rms_norm_reference(x, p["mlp_norm"])
+        mlp_out = _mlp(h, p, cfg)
     return x + mlp_out, bufs
 
 
@@ -607,10 +614,11 @@ def extend_step(params: dict, tokens: jax.Array, cache: dict, pos,
     the K/V writes through the bounded-window path —
     :func:`_window_write`."""
     x, new_cache = _blocks_forward(params, tokens, cache, pos, cfg, window)
-    x = rms_norm_reference(x, params["final_norm"])
-    logits = _weinsum("bsd,dv->bsv", x, params["lm_head"],
-                      pet=jnp.float32)
-    logits = logits.astype(cfg.logits_storage_dtype)
+    with jax.named_scope("lm_head"):
+        x = rms_norm_reference(x, params["final_norm"])
+        logits = _weinsum("bsd,dv->bsv", x, params["lm_head"],
+                          pet=jnp.float32)
+        logits = logits.astype(cfg.logits_storage_dtype)
     return logits, new_cache
 
 
@@ -825,6 +833,7 @@ def _filter_logits(logits, temperature: float, top_k: int, top_p: float):
     return scaled
 
 
+@jax.named_scope("sample")
 def _sample(logits, rng, temperature: float, top_k: int,
             top_p: float = 0.0):
     """logits [B, V] → (token [B], logprob [B]). Math in f32 whatever the

@@ -135,20 +135,26 @@ def run_training(step_fn: Callable[[Any, Any], tuple[Any, dict]],
                 step_hook(step)
             # Per-step trace (head-sampled via tony.trace.sample-rate):
             # the step root with its phases as children — the causal
-            # view behind the tony_data_wait/step-wall aggregates.
-            with tracer.span("train.step", step=step) as step_span:
-                t0 = time.perf_counter()
+            # view behind the tony_data_wait/step-wall aggregates. Every
+            # span is also a row of a running profiler capture: the step
+            # root as xprof's step ``tony.train``, the phases as
+            # ``tony.train.<phase>`` inside it, on the device's clock.
+            with tracer.span("train.step", step=step, step_num=step):
                 try:
-                    with ledger.enter("data_wait"):
-                        batch = next(it)
+                    with tracer.span("train.data_wait"):
+                        # the clock reads sit INSIDE the span and around
+                        # the ledger, as they always have: the histogram
+                        # is the block on next(), not the span's own
+                        # storing (a spool write when sampled)
+                        t0 = time.perf_counter()
+                        with ledger.enter("data_wait"):
+                            batch = next(it)
+                        wait = time.perf_counter() - t0
                 except StopIteration:
                     log.warning("data exhausted at step %d (wanted %d); "
                                 "stopping early", step, steps)
                     break
-                wait = time.perf_counter() - t0
                 wait_hist.observe(wait)
-                tracer.record_span("train.data_wait", wait,
-                                   parent=step_span)
                 try:
                     with tracer.span("train.dispatch"), \
                             ledger.enter("step"):

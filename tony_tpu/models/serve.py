@@ -118,6 +118,22 @@ stream position reproduce the sampled stream exactly, so disaggregated
 outputs match the colocated engine bit-for-bit (greedy AND sampled;
 test-pinned end-to-end across two processes).
 
+Where the engine thread's wall goes is named from inside
+(``batcher.phase_times``, a :class:`~tony_tpu.runtime.profiler
+.PhaseTimes` that also enters each phase as the profiler row
+``tony.engine.<phase>``): ``dispatch`` / ``fetch`` / ``admit`` /
+``retire`` in the batcher; in the engine ``consume`` (all of
+:meth:`ServeEngine._consume`) with ``emit`` NESTED in it (the
+``on_delta`` / ``on_retired`` callbacks: frame packing and socket sends
+on the engine thread — ``consume``'s self time is ``consume − emit``),
+``admit_pick`` (the admission sweep outside the device dispatch: lock,
+class-priority pop, preemption) and ``wait`` (blocked on an empty
+queue). Two request WAITS ride the same accumulator through
+``observe``: ``queue_wait`` (entering the wait queue → slot admission,
+once per admission) and ``first_token`` (admission → first consumed
+delta, once per request that produced a token). They are waits, not
+loop phases: they overlap across requests and do not sum to the wall.
+
 ``TRACE_COUNTS`` records one entry per (program, static shape) TRACE —
 a Python side effect inside the jitted bodies, executed at trace time
 only — so tests (and the conftest retrace guard) can pin "bucketed
@@ -155,6 +171,10 @@ from tony_tpu.runtime.profiler import PhaseTimes
 #: ``retrace_guard`` fixture assert on deltas of this counter.
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
+#: name prefix of the host loop's phases: each ``phase_times.phase(x)``
+#: is also the row ``tony.engine.<x>`` of a running profiler capture
+ENGINE_PHASES = tracing.PROFILER_PREFIX + "engine"
+
 #: smallest bucketed-admission pad length — prompts shorter than this
 #: share one program rather than compiling 16 tiny variants
 _MIN_ADMIT_BUCKET = 16
@@ -189,6 +209,7 @@ def bucket_for(n: int, cap: int,
     return min(b, cap)
 
 
+@jax.named_scope("sample")
 def _row_samples(logits, keys, temperature, top_k, top_p):
     """One sampling decision per row from PER-ROW keys [B, 2] — argmax
     at ``temperature == 0`` (keys unused; pass None), otherwise the same
@@ -888,7 +909,7 @@ class ContinuousBatcher:
                                 cfg.logits_storage_dtype)
         self.steps_executed = 0
         self.rounds_executed = 0
-        self.phase_times = PhaseTimes()
+        self.phase_times = PhaseTimes(ENGINE_PHASES)
         # seams usable standalone (no serve() call required); serve()
         # re-seeds for per-workload reproducibility
         self._reset_streams()
@@ -1647,8 +1668,8 @@ class _EngineRequest:
 
     __slots__ = ("rid", "prompt", "budget", "stream", "rng_skip",
                  "emitted", "done", "reason", "t_submit", "t_last",
-                 "span", "queued_span", "first_span", "cls", "history",
-                 "requeued")
+                 "t_queued", "t_admit", "span", "queued_span",
+                 "first_span", "cls", "history", "requeued")
 
     def __init__(self, rid, prompt, budget: int, stream: int,
                  t_submit: float, rng_skip: int = 0,
@@ -1666,6 +1687,11 @@ class _EngineRequest:
         self.reason: str | None = None
         self.t_submit = t_submit
         self.t_last = t_submit
+        #: when this record entered the wait queue (the submit, or the
+        #: requeue of a preempted stream) and when a slot admitted it:
+        #: the ends of the ``queue_wait`` / ``first_token`` waits
+        self.t_queued = t_submit
+        self.t_admit = t_submit
         #: QoS tier (one of :data:`QOS_CLASSES`)
         self.cls = cls
         #: emitted token VALUES, tracked only for evictable rows (batch
@@ -1798,7 +1824,7 @@ class ServeEngine:
         # per-call reset moved here
         batcher.steps_executed = 0
         batcher.rounds_executed = 0
-        batcher.phase_times = PhaseTimes()
+        batcher.phase_times = PhaseTimes(ENGINE_PHASES)
         batcher._reset_streams()
         # Registry instrumentation: a handful of locked increments per
         # host SYNC (token counts batch into one inc per consume; the
@@ -2160,7 +2186,8 @@ class ServeEngine:
                     return True
                 if self._draining:
                     return False
-                with goodput_mod.get_ledger().enter("idle"):
+                with self.b.phase_times.phase("wait"), \
+                        goodput_mod.get_ledger().enter("idle"):
                     self._work.wait()
 
     def _pop_admissible_locked(self, free: int, occ: dict):
@@ -2236,6 +2263,7 @@ class ServeEngine:
                                      cls="batch")
                 new.emitted = old.emitted  # resume deltas are ITL
                 new.t_last = old.t_last
+                new.t_queued = time.perf_counter()   # waits anew
                 new.history = list(old.history)
                 new.span = old.span        # same logical request
                 old.span = tracing.NOOP_SPAN
@@ -2256,7 +2284,30 @@ class ServeEngine:
         floors, then preempt batch rows for any interactive admissions
         left waiting. The device dispatch runs OUTSIDE the lock; a
         request cancelled between marking and dispatch is discarded at
-        its first consume."""
+        its first consume. Everything up to the dispatch is the phase
+        ``admit_pick``; the dispatch is the batcher's ``admit``."""
+        with self.b.phase_times.phase("admit_pick"):
+            pairs, prompts, admitted = self._pick_admissions()
+        if not admitted:
+            return
+        b = self.b
+        before = (b.prefill_forward_tokens, b.prefix_copied_tokens,
+                  b.prefix_admits)
+        b._admit_batch(pairs, prompts)
+        self._admitted_c.inc(len(admitted))
+        # fold the batcher's host-side prefill accounting into the
+        # registry (the batcher itself is registry-unaware)
+        if b.prefill_forward_tokens > before[0]:
+            self._prefill_tok_c.inc(b.prefill_forward_tokens - before[0])
+        if b.prefix_copied_tokens > before[1]:
+            self._prefix_tok_c.inc(b.prefix_copied_tokens - before[1])
+        if b.prefix_admits > before[2]:
+            self._prefix_admits_c.inc(b.prefix_admits - before[2])
+
+    def _pick_admissions(self):
+        """The host half of an admission sweep: pop, preempt, and close
+        each admitted request's wait. Returns ``(pairs, prompts,
+        admitted)`` for the device dispatch."""
         with self._lock:
             pairs, prompts, admitted = [], {}, []
             occ = {c: 0 for c in QOS_CLASSES}
@@ -2295,32 +2346,25 @@ class ServeEngine:
                 self._emit_retired(old)
         if admitted:
             tr = tracing.get_tracer()
+            now = time.perf_counter()
             for req in admitted:
+                # the wait for a slot ends here — counted (a wait, not
+                # a loop phase: waits of different requests overlap and
+                # do not sum to the thread's wall) at the same instant
+                # the per-request span ends
+                self.b.phase_times.observe("queue_wait",
+                                           now - req.t_queued)
+                req.t_admit = now
                 req.queued_span.end()
                 if req.span.recording:
                     # admit → first consumed delta: the prefill+decode
                     # share of TTFT, next to engine.queued's queue share
                     req.first_span = tr.start_span("engine.first_token",
                                                    parent=req.span)
-            b = self.b
-            before = (b.prefill_forward_tokens, b.prefix_copied_tokens,
-                      b.prefix_admits)
-            for req in admitted:
                 if req.rng_skip:
                     # consumed by _rebind_streams at this admission
-                    b._stream_skip[req.stream] = req.rng_skip
-            b._admit_batch(pairs, prompts)
-            self._admitted_c.inc(len(admitted))
-            # fold the batcher's host-side prefill accounting into the
-            # registry (the batcher itself is registry-unaware)
-            if b.prefill_forward_tokens > before[0]:
-                self._prefill_tok_c.inc(b.prefill_forward_tokens
-                                        - before[0])
-            if b.prefix_copied_tokens > before[1]:
-                self._prefix_tok_c.inc(b.prefix_copied_tokens
-                                       - before[1])
-            if b.prefix_admits > before[2]:
-                self._prefix_admits_c.inc(b.prefix_admits - before[2])
+                    self.b._stream_skip[req.stream] = req.rng_skip
+        return pairs, prompts, admitted
 
     def _consume(self, host_toks, snap) -> None:
         """Apply one fetched chunk under the occupancy it was ISSUED
@@ -2328,7 +2372,12 @@ class ServeEngine:
         deltas. Rows whose snapshot request already finished (a
         speculatively issued chunk crossed the completion, or a cancel
         landed mid-flight) carry garbage and are discarded — the same
-        discard as idle-slot garbage."""
+        discard as idle-slot garbage. The whole of it is the phase
+        ``consume``; the callbacks inside it are ``emit``."""
+        with self.b.phase_times.phase("consume"):
+            self._consume_chunk(host_toks, snap)
+
+    def _consume_chunk(self, host_toks, snap) -> None:
         deltas, retired = [], []
         eos = self.b.eos_id
         with self._lock:
@@ -2370,24 +2419,35 @@ class ServeEngine:
             if req.emitted == len(new):      # this is the first delta
                 self._ttft_h.observe(now - req.t_submit)
                 self._ttft_by_cls[req.cls].observe(now - req.t_submit)
+                # admission → first delta, counted where it ends (a
+                # wait like queue_wait: overlapping, not a loop phase)
+                self.b.phase_times.observe("first_token",
+                                           now - req.t_admit)
                 req.first_span.end()
             else:
                 gap = (now - req.t_last) / len(new)
                 self._itl_h.observe(gap)
                 self._itl_by_cls[req.cls].observe(gap)
             req.t_last = now
+        if appended:
+            self._tokens_c.inc(appended)
+        if retired:
+            self._retired_c.inc(len(retired))
+        if not deltas:
+            return
+        # what the transport does with the chunk, ON this thread: the
+        # frame server packs and sends each delta from these callbacks
+        with self.b.phase_times.phase("emit"):
             # a retiring request's FINAL delta rides its retirement
             # callback instead of on_delta, so transports can emit the
             # two atomically (a replica killed between a final TOKENS
             # frame and its RETIRED would otherwise leave a router
             # believing the stream is unfinished and re-admitting PAST
             # an already-streamed eos)
-            if id(req) not in finals and self.on_delta is not None:
-                self.on_delta(req.rid, new)
-        if appended:
-            self._tokens_c.inc(appended)
-        if retired:
-            self._retired_c.inc(len(retired))
+            if self.on_delta is not None:
+                for req, new in deltas:
+                    if id(req) not in finals:
+                        self.on_delta(req.rid, new)
             for req in retired:
                 req.first_span.end()     # eos on the very first delta
                 req.span.end(reason=req.reason, tokens=req.emitted)

@@ -111,16 +111,17 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array] | None,
     def step(state: TrainState, batch: Any):
         _count_trace("train_step", batch)   # trace-time only: counts compiles
         loss, grads = vag(state["params"], batch)
-        if fused:
-            # single-pass update (ops/optim.py): params change inside the
-            # kernel, no separate apply_updates traversal
-            params, opt_state, gnorm = optimizer.fused_apply(
-                grads, state["opt_state"], state["params"])
-        else:
-            updates, opt_state = optimizer.update(grads, state["opt_state"],
-                                                  state["params"])
-            params = optax.apply_updates(state["params"], updates)
-            gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            if fused:
+                # single-pass update (ops/optim.py): params change inside
+                # the kernel, no separate apply_updates traversal
+                params, opt_state, gnorm = optimizer.fused_apply(
+                    grads, state["opt_state"], state["params"])
+            else:
+                updates, opt_state = optimizer.update(
+                    grads, state["opt_state"], state["params"])
+                params = optax.apply_updates(state["params"], updates)
+                gnorm = optax.global_norm(grads)
         new_state = {"params": params, "opt_state": opt_state,
                      "step": state["step"] + 1}
         return new_state, {"loss": loss, "grad_norm": gnorm,

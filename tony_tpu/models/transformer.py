@@ -386,51 +386,55 @@ def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
         rope = rope_tables(positions, cfg.head_dim)
     cos, sin = rope
 
-    h = rms_norm_reference(x, p["attn_norm"])
-    h = constrain(h, ("batch", "seq", "embed"), mesh, rules)
-    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, p["wv"])
-    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    # GQA K/V stay at kv_heads width — the flash kernels read each shared
-    # head once per query group in-kernel (ops/attention.py "GQA-native");
-    # only the cp wrappers expand (inside _attention). KV heads replicate
-    # under TP when h_kv < h (Llama-style), mirroring logical_axes.
-    kv_head_axis = "heads" if cfg.kv_heads == cfg.n_heads else None
-    q = constrain(q, ("batch", "seq", "heads", "kv"), mesh, rules)
-    k = constrain(k, ("batch", "seq", kv_head_axis, "kv"), mesh, rules)
-    v = constrain(v, ("batch", "seq", kv_head_axis, "kv"), mesh, rules)
-    o = _attention(q, k, v, mesh, cfg.cp_strategy,
-                   cfg.attn_window or None)
-    attn_out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    x = x + constrain(attn_out, ("batch", "seq", "embed"), mesh, rules)
+    # named sections (metadata only): a profile is read by these scopes
+    with jax.named_scope("attn"):
+        h = rms_norm_reference(x, p["attn_norm"])
+        h = constrain(h, ("batch", "seq", "embed"), mesh, rules)
+        q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, p["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", h, p["wv"])
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        # GQA K/V stay at kv_heads width — the flash kernels read each
+        # shared head once per query group in-kernel (ops/attention.py
+        # "GQA-native"); only the cp wrappers expand (inside _attention).
+        # KV heads replicate under TP when h_kv < h (Llama-style),
+        # mirroring logical_axes.
+        kv_head_axis = "heads" if cfg.kv_heads == cfg.n_heads else None
+        q = constrain(q, ("batch", "seq", "heads", "kv"), mesh, rules)
+        k = constrain(k, ("batch", "seq", kv_head_axis, "kv"), mesh, rules)
+        v = constrain(v, ("batch", "seq", kv_head_axis, "kv"), mesh, rules)
+        o = _attention(q, k, v, mesh, cfg.cp_strategy,
+                       cfg.attn_window or None)
+        attn_out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        x = x + constrain(attn_out, ("batch", "seq", "embed"), mesh, rules)
 
-    h = rms_norm_reference(x, p["mlp_norm"])
-    h = constrain(h, ("batch", "seq", "embed"), mesh, rules)
-    if "router" in p:
-        if ep_axis is not None:
-            from tony_tpu.parallel.moe import moe_ffn_manual
-            moe_out, metrics = moe_ffn_manual(
-                h, p["router"], p["w_gate"], p["w_down"],
-                axis_name=ep_axis, num_experts=cfg.num_experts,
-                top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                activation=jax.nn.silu)
+    with jax.named_scope("mlp"):
+        h = rms_norm_reference(x, p["mlp_norm"])
+        h = constrain(h, ("batch", "seq", "embed"), mesh, rules)
+        if "router" in p:
+            if ep_axis is not None:
+                from tony_tpu.parallel.moe import moe_ffn_manual
+                moe_out, metrics = moe_ffn_manual(
+                    h, p["router"], p["w_gate"], p["w_down"],
+                    axis_name=ep_axis, num_experts=cfg.num_experts,
+                    top_k=cfg.moe_top_k,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    activation=jax.nn.silu)
+            else:
+                moe_out, metrics = moe_ffn(
+                    h, p["router"], p["w_gate"], p["w_down"],
+                    top_k=cfg.moe_top_k,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    activation=jax.nn.silu)
+            aux = metrics.aux_loss
+            mlp_out = moe_out
         else:
-            moe_out, metrics = moe_ffn(
-                h, p["router"], p["w_gate"], p["w_down"],
-                top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                activation=jax.nn.silu)
-        aux = metrics.aux_loss
-        mlp_out = moe_out
-    else:
-        gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"])
-        up = jnp.einsum("bsd,df->bsf", h, p["w_up"])
-        inner = jax.nn.silu(gate) * up
-        inner = constrain(inner, ("batch", "seq", "mlp"), mesh, rules)
-        mlp_out = jnp.einsum("bsf,fd->bsd", inner, p["w_down"])
-        aux = jnp.zeros((), jnp.float32)
+            gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"])
+            up = jnp.einsum("bsd,df->bsf", h, p["w_up"])
+            inner = jax.nn.silu(gate) * up
+            inner = constrain(inner, ("batch", "seq", "mlp"), mesh, rules)
+            mlp_out = jnp.einsum("bsf,fd->bsd", inner, p["w_down"])
+            aux = jnp.zeros((), jnp.float32)
     x = x + constrain(mlp_out, ("batch", "seq", "embed"), mesh, rules)
     return x, aux
 
@@ -438,13 +442,15 @@ def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
 def _lm_head(params: dict, x: jax.Array, cfg: TransformerConfig,
              mesh, rules) -> jax.Array:
     """final_norm + lm_head on block output x [B, S, D] → logits."""
-    x = rms_norm_reference(x, params["final_norm"])
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    # The cast fuses into the matmul epilogue, so with bf16 logits_dtype
-    # the f32 array never reaches HBM (see TransformerConfig.logits_dtype).
-    logits = logits.astype(cfg.logits_storage_dtype)
-    return constrain(logits, ("batch", "seq", "vocab"), mesh, rules)
+    with jax.named_scope("lm_head"):
+        x = rms_norm_reference(x, params["final_norm"])
+        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
+                            preferred_element_type=jnp.float32)
+        # The cast fuses into the matmul epilogue, so with bf16
+        # logits_dtype the f32 array never reaches HBM (see
+        # TransformerConfig.logits_dtype).
+        logits = logits.astype(cfg.logits_storage_dtype)
+        return constrain(logits, ("batch", "seq", "vocab"), mesh, rules)
 
 
 def _pp_layout(cfg: TransformerConfig, mesh: Mesh, batch: int):
@@ -607,7 +613,9 @@ def lm_loss(params: dict, batch: dict, cfg: TransformerConfig,
     else:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     logits, aux = forward(params, inputs, cfg, mesh, rules)
-    return masked_cross_entropy(logits, targets) + cfg.moe_aux_weight * aux
+    with jax.named_scope("loss"):
+        return (masked_cross_entropy(logits, targets)
+                + cfg.moe_aux_weight * aux)
 
 
 def lm_value_and_grad(params: dict, batch: dict, cfg: TransformerConfig,

@@ -287,6 +287,12 @@ def _flash_forward(q, k, v, *, causal, g, bq, bk, band, window=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=mosaic.interpret(),
+        # the four launches carry stable names: ``name=`` becomes the HLO
+        # instruction's name (``%tony_flash_fwd.N``, what a device trace
+        # is read by — XLA's own ``checkpoint.N`` / ``closed_call.N``
+        # renumber on any refactor) and opens a ``jax.named_scope`` of
+        # the same name around the launch
+        name="tony_flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -508,6 +514,7 @@ def _flash_backward_fused(q, k, v, o, lse, do, dlse, *, causal, g,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=mosaic.interpret(),
+        name="tony_flash_bwd_fused",
     )(*operands)
     if nk == 1:
         return dqp[0], dk, dv
@@ -676,6 +683,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse=None, *, causal, g,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=mosaic.interpret(),
+        name="tony_flash_bwd_dq",
     )(q, k, v, do, lse2, delta)
 
     band_nq = _cdiv(band, bq)
@@ -708,6 +716,7 @@ def _flash_backward(q, k, v, o, lse, do, dlse=None, *, causal, g,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=mosaic.interpret(),
+        name="tony_flash_bwd_dkv",
     )(q, k, v, do, lse2, delta)
     return dq, dk, dv
 

@@ -19,7 +19,10 @@ calls for — per-host ``jax.profiler`` / xprof capture as a framework feature:
   host-driven loop (the serving batchers record ``dispatch``/``fetch``/
   ``admit``/``retire`` per :meth:`serve` call) — xprof sees device work,
   but the serving question is usually about the HOST side: how much of
-  the wall went to transport syncs vs dispatch vs admission.
+  the wall went to transport syncs vs dispatch vs admission. Given a
+  name prefix (``PhaseTimes("tony.engine")``) every phase is also a row
+  of a running profiler capture, ``tony.engine.<phase>``, on the same
+  clock as the device ops (``tracing.profiler_annotation``).
 
 User scripts get all of it through ``tony_tpu.runtime.initialize()``, which
 calls :func:`maybe_start` after the jax.distributed bootstrap.
@@ -33,6 +36,7 @@ import os
 import time
 
 from tony_tpu import constants
+from tony_tpu.runtime import tracing
 
 log = logging.getLogger(__name__)
 
@@ -44,11 +48,12 @@ class PhaseTimes:
 
     Usage::
 
-        times = PhaseTimes()
-        with times.phase("dispatch"):
+        times = PhaseTimes("tony.engine")
+        with times.phase("dispatch"):   # profiler row tony.engine.dispatch
             handle = issue_chunk()
         with times.phase("fetch"):
             host = np.asarray(handle)
+        times.observe("queue_wait", now - t_queued)   # not a with block
         times.total("fetch")        # seconds
         times.summary()             # {"fetch": {"total_s", "count",
                                     #            "mean_ms"}, ...}
@@ -58,22 +63,36 @@ class PhaseTimes:
     (building + enqueueing a device chunk — async, no device sync),
     ``fetch`` (blocking on a chunk's tokens: device compute remaining +
     the transport round trip — the time the pipelined loop overlaps with
-    the next chunk), ``admit`` (admission dispatches), and ``retire``.
-    Pure host timing: no jax import, no device sync of its own."""
+    the next chunk), ``admit`` (admission dispatches), and ``retire``;
+    the open-loop engine adds ``consume``/``emit``/``admit_pick``/
+    ``wait`` and the two request waits it records through
+    :meth:`observe` (``ServeEngine``). Pure host timing: no jax import,
+    no device sync of its own. With a ``prefix`` each phase also enters
+    the profiler row ``<prefix>.<name>``; without one it only
+    accumulates."""
 
-    def __init__(self) -> None:
+    def __init__(self, prefix: str = "") -> None:
+        self.prefix = prefix
         self._total: dict[str, float] = {}
         self._count: dict[str, int] = {}
 
     @contextlib.contextmanager
     def phase(self, name: str):
+        row = (tracing.profiler_annotation(f"{self.prefix}.{name}")
+               if self.prefix else tracing.NO_ANNOTATION)
         t0 = time.perf_counter()
         try:
-            yield
+            with row:
+                yield
         finally:
-            dt = time.perf_counter() - t0
-            self._total[name] = self._total.get(name, 0.0) + dt
-            self._count[name] = self._count.get(name, 0) + 1
+            self.observe(name, time.perf_counter() - t0)
+
+    def observe(self, name: str, seconds: float) -> None:
+        """Add one interval that was not a ``with`` block (a wait that
+        began on another thread, or ends where no block can enclose it):
+        total and count move as for :meth:`phase`; no profiler row."""
+        self._total[name] = self._total.get(name, 0.0) + seconds
+        self._count[name] = self._count.get(name, 0) + 1
 
     def total(self, name: str) -> float:
         """Accumulated seconds in ``name`` (0.0 if never entered)."""
@@ -143,6 +162,18 @@ def _reset_server_state_for_tests() -> None:
     _server_started = False
 
 
+def _start_trace(logdir: str) -> None:
+    """Start a capture with the Python call tracer OFF. JAX's default
+    records every Python call: it swamps the file and slows the host
+    that is being measured; the program's phases reach the capture as
+    annotations (``tracing.profiler_annotation``), not as call frames."""
+    import jax
+    os.makedirs(logdir, exist_ok=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+
+
 @contextlib.contextmanager
 def trace(logdir: str | None = None):
     """Capture a jax trace for the enclosed block (xprof/TensorBoard
@@ -152,8 +183,7 @@ def trace(logdir: str | None = None):
     if logdir is None:
         yield
         return
-    os.makedirs(logdir, exist_ok=True)
-    jax.profiler.start_trace(logdir)
+    _start_trace(logdir)
     try:
         yield
     finally:
@@ -183,8 +213,7 @@ class StepTracer:
             return
         import jax
         if not self._active and self.start <= step < self.stop:
-            os.makedirs(self.logdir, exist_ok=True)
-            jax.profiler.start_trace(self.logdir)
+            _start_trace(self.logdir)
             self._active = True
         elif self._active and step >= self.stop:
             jax.profiler.stop_trace()

@@ -38,6 +38,16 @@ dumps to a JSON file under the job dir — a postmortem artifact instead
 of only an exit code — and the executor ships the tail of its ring on
 its final heartbeat so the coordinator can attach it to the incident's
 jhist event.
+
+The third leg puts the program's own phases on the PROFILER's clock:
+:func:`profiler_annotation` is the one place in the tree that enters a
+``jax.profiler.TraceAnnotation``. Both host timers — :meth:`Tracer.span`
+here and ``PhaseTimes.phase`` (``runtime/profiler.py``) — enter it under
+the name ``tony.<layer>.<phase>``, so an xprof capture of a job shows
+its engine and train-loop phases as rows beside the device's ops. It
+never imports JAX (a process that has not loaded JAX has no profiler to
+write to and gets a shared null context), and with no capture running an
+annotation is one atomic load in the profiler's C++.
 """
 
 from __future__ import annotations
@@ -51,6 +61,7 @@ import math
 import os
 import random
 import re
+import sys
 import tempfile
 import threading
 import time
@@ -108,6 +119,31 @@ _id_rng = random.SystemRandom()
 # instance — unaffected by user seeding; a theoretical thread race only
 # skews one sampling decision, never an id.
 _sample_rng = random.Random()
+
+
+#: what a process without JAX (or without a profiler) enters instead
+NO_ANNOTATION = contextlib.nullcontext()
+
+#: every profiler row of the program starts with this: ``tony.<layer>.<phase>``
+PROFILER_PREFIX = "tony."
+
+
+def profiler_annotation(name: str, step_num: int | None = None, **attrs):
+    """A context manager that shows the enclosed block as the row
+    ``name`` on the host timeline of a ``jax.profiler`` capture — the
+    same clock as the device planes. With ``step_num`` the row is a STEP
+    root (``jax.profiler.StepTraceAnnotation``): xprof's step view groups
+    the device ops under it. ``attrs`` ride as the event's metadata.
+
+    JAX is looked up in ``sys.modules`` and never imported: the serving
+    client, the router and the executor use this module too and must
+    stay JAX-free — they get a shared null context."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    if prof is None:
+        return NO_ANNOTATION
+    if step_num is not None:
+        return prof.StepTraceAnnotation(name, step_num=step_num, **attrs)
+    return prof.TraceAnnotation(name, **attrs)
 
 
 def new_trace_id() -> str:
@@ -300,9 +336,16 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, *, ctx: dict | None = None,
-             coarse: bool = False, **attrs):
+             coarse: bool = False, step_num: int | None = None, **attrs):
         """Context-manager span, parented on (and installed as) the
-        ambient current span for the duration."""
+        ambient current span for the duration.
+
+        The block is also a row of a running profiler capture
+        (:func:`profiler_annotation`): ``tony.<name>`` — or, with
+        ``step_num``, the step root ``tony.<layer>`` (``name`` up to its
+        first dot: ``train.step`` → ``tony.train``). Sampled or not:
+        sampling governs what the tracing plane STORES, not what the
+        profiler shows."""
         # the span itself (recording or NOOP) becomes the ambient
         # parent: an UNSAMPLED span must suppress its children too (a
         # None here would let nested spans re-roll the sampling dice as
@@ -310,8 +353,11 @@ class Tracer:
         # invariant)
         sp = self.start_span(name, ctx=ctx, coarse=coarse, **attrs)
         token = _current_span.set(sp)
+        row = PROFILER_PREFIX + (name if step_num is None
+                                 else name.partition(".")[0])
         try:
-            yield sp
+            with profiler_annotation(row, step_num):
+                yield sp
         finally:
             _current_span.reset(token)
             sp.end()
