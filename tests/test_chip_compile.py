@@ -188,3 +188,66 @@ def test_large_lm_grad_compiles_on_2x2_mesh(chip):
     batch = {"inputs": tokens, "targets": tokens}
     chip.compile(jax.value_and_grad(
         lambda p, b: T.lm_loss(p, b, cfg, chip.mesh)), params, batch)
+
+
+# (d_model, heads, kv heads, d_ff, vocab, slots, cache rows) of the
+# benchmark's serving widths; depth is cut to 4 (a case under 10 s)
+STEP_ROWS_CASES = {
+    "phi3mini-6slots": (3072, 32, 32, 8192, 32064, 6, 1280),
+    "phi3mini-8slots": (3072, 32, 32, 8192, 32064, 8, 1280),
+    "mistral7b-6slots": (4096, 32, 8, 14336, 32000, 6, 8192),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STEP_ROWS_CASES))
+def test_step_rows_never_copies_the_cache(chip, name):
+    """The decode chunk (``serve.step_rows``, ``n=8``, per-row frontiers)
+    holds the K/V cache in ONE layout from its arguments through the
+    ``while`` to its results: no instruction copies a cache-sized
+    buffer, and the program's temporaries are smaller than one cache
+    buffer. With a trailing [.., KV, hd] (the parent of PR 26) the
+    Phi-3-mini cases fail: head_dim 96 pads to 128 lanes in the loop's
+    layout and not in the arguments', so the program begins and ends
+    with 4 transposing copies of the whole cache (temporaries 0.51 GB
+    at this depth and 6 slots, against 0.19 GB a buffer)."""
+    import re
+
+    from tony_tpu.models import decode as D
+    from tony_tpu.models import serve as S
+    from tony_tpu.models import transformer as T
+    d_model, heads, kv, d_ff, vocab, slots, rows = STEP_ROWS_CASES[name]
+    cfg = T.TransformerConfig(
+        vocab_size=vocab, d_model=d_model, n_layers=4, n_heads=heads,
+        n_kv_heads=kv, d_ff=d_ff, max_seq=rows, dtype=jnp.bfloat16)
+    params = chip.place(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(lambda: D.init_kv_cache(cfg, slots, rows))
+    cache = chip.place(dict(cache, length=chip.shape((slots,), jnp.int32)))
+    compiled = S.step_rows.lower(
+        params, cache, chip.shape((slots, vocab), cfg.logits_storage_dtype),
+        chip.shape((slots, 2), jnp.uint32), chip.shape((slots,), jnp.int32),
+        n=8, cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step_rows")
+
+    buf = cache["k"]
+    dims = ",".join(str(d) for d in buf.shape)
+    cache_sized = [
+        m.group(0) for m in re.finditer(
+            r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", text)
+        if np.prod([int(d) for d in m.group(1).split(",")]) == buf.size]
+    assert not cache_sized, cache_sized
+    # entry arguments, the while's carry, the results: one layout
+    layouts = set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})", text))
+    assert len(layouts) == 1, layouts
+    carried = [ln for ln in text.splitlines()
+               if " while(" in ln and f"bf16[{dims}]" in ln]
+    assert carried, "no while carries the cache"
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < buf.size * buf.dtype.itemsize, temporaries
+    # nor is a layer's slice of a stacked projection weight made a buffer
+    # of its own (``_decode_block``'s barrier on v: without it the flatten
+    # folds into the v projection and every step copies all of wv)
+    sliced = re.findall(r"copy-start[.\d]* = \(bf16\[1," + str(d_model)
+                        + r",\d+,\d+\]", text)
+    assert not sliced, sliced

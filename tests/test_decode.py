@@ -134,8 +134,9 @@ class TestDecode:
 
     def test_cache_shapes(self):
         cache = init_kv_cache(CFG, batch=2, max_len=32)
-        assert cache["k"].shape == (CFG.n_layers, 2, 32, CFG.n_heads,
-                                    CFG.head_dim)
+        # heads and head_dim are stored merged (init_kv_cache)
+        assert cache["k"].shape == (CFG.n_layers, 2, 32,
+                                    CFG.n_heads * CFG.head_dim)
         assert cache["k"].dtype == CFG.dtype
 
     def test_flash_safe_len_boundaries(self):
@@ -325,7 +326,8 @@ class TestBlockwiseCachedAttention:
         if q_start + n_q > 640:
             pytest.skip("positions exceed cache")
         got = D._cached_attention_blockwise(
-            q, {"k": k_cache[None], "v": v_cache[None]}, 0,
+            q, {"k": D._kv_flat(k_cache)[None],
+                "v": D._kv_flat(v_cache)[None]}, 0,
             jnp.asarray(q_start))
         b, nq, h, d = q.shape
         kv = k_cache.shape[2]
@@ -346,7 +348,8 @@ class TestBlockwiseCachedAttention:
         from tony_tpu.models import decode as D
         q, k_cache, v_cache = self._rand(7, 2, 768, 2, 8, 16, 3)  # group=4
         got = D._cached_attention_blockwise(
-            q, {"k": k_cache[None], "v": v_cache[None]}, 0,
+            q, {"k": D._kv_flat(k_cache)[None],
+                "v": D._kv_flat(v_cache)[None]}, 0,
             jnp.asarray(500))
         b, nq, h, d = q.shape
         kv, group = 2, 4
@@ -421,8 +424,8 @@ class TestGQA:
 
     def test_cache_stores_kv_heads_only(self):
         cache = init_kv_cache(self.GCFG, batch=2, max_len=32)
-        assert cache["k"].shape == (self.GCFG.n_layers, 2, 32, 2,
-                                    self.GCFG.head_dim)
+        assert cache["k"].shape == (self.GCFG.n_layers, 2, 32,
+                                    2 * self.GCFG.head_dim)
 
     def test_greedy_generate_equals_full_forward(self):
         gparams = T.init_params(jax.random.PRNGKey(4), self.GCFG)
@@ -832,7 +835,8 @@ class TestQuantizedKVCache:
         c = D.init_kv_cache(self.QCFG, 2, 64)
         kv, hd = self.QCFG.kv_heads, self.QCFG.head_dim
         assert c["k"].dtype == jnp.int8 and c["v"].dtype == jnp.int8
-        assert c["k_scale"].shape == (self.QCFG.n_layers, 2, 64, kv, 1)
+        assert c["k"].shape == (self.QCFG.n_layers, 2, 64, kv * hd)
+        assert c["k_scale"].shape == (self.QCFG.n_layers, 2, 64, kv)
         assert c["k_scale"].dtype == jnp.float32
 
     def test_quantize_roundtrip_error_bounded(self):
@@ -853,9 +857,10 @@ class TestQuantizedKVCache:
         v = jax.random.normal(ks[1], (1, b, max_len, kv, d), jnp.float32)
         kq, ksc = D._kv_quantize(k)
         vq, vsc = D._kv_quantize(v)
-        return ({"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc},
-                {"k": kq.astype(jnp.float32) * ksc,
-                 "v": vq.astype(jnp.float32) * vsc})
+        return (D.kv_from_wire({"k": kq, "v": vq, "k_scale": ksc,
+                                "v_scale": vsc}),
+                D.kv_from_wire({"k": kq.astype(jnp.float32) * ksc,
+                                "v": vq.astype(jnp.float32) * vsc}))
 
     @pytest.mark.parametrize("max_len,q_start,n_q", [(192, 150, 1),
                                                      (1024, 700, 3)])
@@ -882,9 +887,11 @@ class TestQuantizedKVCache:
         q = jax.random.normal(ks[2], (2, 1, 4, 32), jnp.float32)
         kq, ksc = D._kv_quantize(k)
         vq, vsc = D._kv_quantize(v)
-        of = D._cached_attention(q, {"k": k, "v": v}, 0, jnp.asarray(150))
+        of = D._cached_attention(q, D.kv_from_wire({"k": k, "v": v}), 0,
+                                 jnp.asarray(150))
         oq = D._cached_attention(
-            q, {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}, 0,
+            q, D.kv_from_wire({"k": kq, "v": vq, "k_scale": ksc,
+                               "v_scale": vsc}), 0,
             jnp.asarray(150))
         rel = float(jnp.max(jnp.abs(of - oq)) / jnp.max(jnp.abs(of)))
         assert rel < 0.05, rel
@@ -925,9 +932,11 @@ class TestQuantizedKVCache:
         # (bit-equality only holds at layer 0 — deeper layers' K/V
         # inputs inherit shape-dependent dot rounding from the layers
         # below, which can move a value across a rounding boundary)
+        w1 = D.kv_to_wire(D._kv_bufs(c1), self.QCFG)
+        w2 = D.kv_to_wire(D._kv_bufs(c2), self.QCFG)
         for kn, sn in (("k", "k_scale"), ("v", "v_scale")):
-            d1 = np.asarray(c1[kn], np.float32) * np.asarray(c1[sn])
-            d2 = np.asarray(c2[kn], np.float32) * np.asarray(c2[sn])
+            d1 = np.asarray(w1[kn], np.float32) * np.asarray(w1[sn])
+            d2 = np.asarray(w2[kn], np.float32) * np.asarray(w2[sn])
             np.testing.assert_allclose(d1, d2, atol=1e-3)
         np.testing.assert_array_equal(np.asarray(c1["k"][0]),
                                       np.asarray(c2["k"][0]))
@@ -1001,8 +1010,8 @@ class TestSlidingWindowDecode:
         k_cache = jax.random.normal(ks[1], (2, max_len, kv, d), jnp.float32)
         v_cache = jax.random.normal(ks[2], (2, max_len, kv, d), jnp.float32)
         got = D._cached_attention_blockwise(
-            q, {"k": k_cache[None], "v": v_cache[None]}, 0,
-            jnp.asarray(q_start), attn_window=w)
+            q, D.kv_from_wire({"k": k_cache[None], "v": v_cache[None]}),
+            0, jnp.asarray(q_start), attn_window=w)
         # dense masked oracle
         q_pos = q_start + jnp.arange(n_q)
         k_pos = jnp.arange(max_len)
@@ -1022,7 +1031,7 @@ class TestSlidingWindowDecode:
             kc = k_cache.at[:, :q_start - w].set(1e4)
             vc = v_cache.at[:, :q_start - w].set(-1e4)
             got2 = D._cached_attention_blockwise(
-                q, {"k": kc[None], "v": vc[None]}, 0,
+                q, D.kv_from_wire({"k": kc[None], "v": vc[None]}), 0,
                 jnp.asarray(q_start), attn_window=w)
             np.testing.assert_array_equal(np.asarray(got),
                                           np.asarray(got2))
@@ -1043,11 +1052,12 @@ class TestSlidingWindowDecode:
         kq, ksc = D._kv_quantize(k_c[None])
         vq, vsc = D._kv_quantize(v_c[None])
         got = D._cached_attention_blockwise(
-            q, {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}, 0,
+            q, D.kv_from_wire({"k": kq, "v": vq, "k_scale": ksc,
+                               "v_scale": vsc}), 0,
             jnp.asarray(700), attn_window=w)
         want = D._cached_attention_blockwise(
-            q, {"k": kq.astype(jnp.float32) * ksc,
-                "v": vq.astype(jnp.float32) * vsc}, 0,
+            q, D.kv_from_wire({"k": kq.astype(jnp.float32) * ksc,
+                               "v": vq.astype(jnp.float32) * vsc}), 0,
             jnp.asarray(700), attn_window=w)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-2)
@@ -1243,3 +1253,4 @@ class TestWindowCombinations:
         # non-vacuity: at least one request's windowed output differs
         # from full attention
         assert diverged
+

@@ -26,7 +26,8 @@ import numpy as np
 import pytest
 
 from tony_tpu.models import transformer as T
-from tony_tpu.models.decode import extract_kv_rows, generate, init_kv_cache
+from tony_tpu.models.decode import (extract_kv_rows, generate,
+                                    init_kv_cache, kv_from_wire)
 from tony_tpu.models.serve import (ContinuousBatcher,
                                    SpeculativeContinuousBatcher,
                                    land_kv_rows, prefill_ship_row,
@@ -135,7 +136,9 @@ class TestKVWireCodec:
                 p, jnp.asarray(toks, jnp.int32),
                 jnp.asarray([len(prompt), 1], np.int32), cfg)
             width = len(prompt)
-        bufs = extract_kv_rows(mini, [width])[0]
+        bufs = extract_kv_rows(mini, [width], cfg)[0]
+        # the wire form is 5-D whatever the cache stores
+        assert bufs["k"].shape[3:] == (cfg.kv_heads, cfg.head_dim)
         return bufs, np.asarray(lg)[0], len(prompt), width, mini
 
     @pytest.mark.parametrize("layout", sorted(LAYOUTS))
@@ -186,9 +189,10 @@ class TestKVWireCodec:
         keys = jnp.zeros((batch, 2), jnp.uint32)
         rows = np.asarray([slot, batch, batch + 1], np.int32)
         s_b = bufs2["k"].shape[2]
+        stored2 = kv_from_wire(bufs2)       # the host reshape at landing
         mini = {n: np.zeros((a2.shape[0], batch, s_b) + a2.shape[3:],
-                            a2.dtype) for n, a2 in bufs2.items()}
-        for n, a2 in bufs2.items():
+                            a2.dtype) for n, a2 in stored2.items()}
+        for n, a2 in stored2.items():
             mini[n][:, 0:1] = a2
         lens = np.asarray([length, 0, 0], np.int32)
         lgs = np.zeros((batch, cfg.vocab_size), lg2.dtype)
@@ -201,7 +205,7 @@ class TestKVWireCodec:
             jnp.asarray(lens), jnp.asarray(lgs), keys,
             jnp.asarray(kmat))
         assert int(cache["length"][slot]) == length
-        for n, a2 in bufs2.items():
+        for n, a2 in stored2.items():
             landed = np.asarray(cache[n][:, slot:slot + 1, :s_b])
             assert (landed == a2).all(), n
         assert (np.asarray(logits[slot]) == lg2).all()
@@ -584,7 +588,7 @@ def _package_blob(params, cfg, rid, budget, prompt=(3, 1, 4, 1, 5),
     lg, mini = prefill_ship_rows(
         params, jnp.asarray(toks, jnp.int32),
         jnp.asarray([len(prompt), 1], np.int32), cfg)
-    bufs = extract_kv_rows(mini, [len(prompt)])[0]
+    bufs = extract_kv_rows(mini, [len(prompt)], cfg)[0]
     key = np.asarray(jax.random.fold_in(jax.random.PRNGKey(0), 0),
                      np.uint32)
     meta = kvship.pack_kv_meta(rid, budget, len(prompt), key, rng_off=0)

@@ -4,9 +4,10 @@ The inference half of the model stack (the reference delegates all compute,
 so this — like training — is green-field per SURVEY.md §2.3). TPU-first
 choices:
 
-- **Static shapes everywhere**: the cache is a fixed [L, B, max_len, KV, D]
+- **Static shapes everywhere**: the cache is a fixed [L, B, max_len, KV·hd]
   buffer (KV = cfg.kv_heads — n_heads/n_kv_heads× smaller under
-  grouped-query attention) updated with ``lax.dynamic_update_slice``; the
+  grouped-query attention; heads and head_dim stored MERGED, see
+  :func:`init_kv_cache`) updated with ``lax.dynamic_update_slice``; the
   decode loop is a ``lax.scan`` over step index — one compiled program
   regardless of prompt or generation length.
 - **Prefill/decode split**: the prompt is processed in one batched forward
@@ -131,18 +132,49 @@ def _ring_capacity(cfg: T.TransformerConfig) -> int:
     return cfg.kv_cache_capacity
 
 
+def _kv_spec(cfg: T.TransformerConfig) -> dict:
+    """name → (per-head width, dtype) of each position buffer: k/v hold
+    ``head_dim`` values per (token, kv-head); an int8 cache adds one f32
+    absmax scale per (token, kv-head) beside each."""
+    if cfg.kv_quant:
+        return {"k": (cfg.head_dim, jnp.int8), "v": (cfg.head_dim, jnp.int8),
+                "k_scale": (1, jnp.float32), "v_scale": (1, jnp.float32)}
+    return {"k": (cfg.head_dim, cfg.dtype), "v": (cfg.head_dim, cfg.dtype)}
+
+
 def init_kv_cache(cfg: T.TransformerConfig, batch: int,
                   max_len: int) -> dict:
-    """Zeroed cache pytree: k/v of shape [L, B, max_len, KV, hd] — KV is
+    """Zeroed cache pytree: k/v of shape [L, B, max_len, KV·hd] — KV is
     cfg.kv_heads, so grouped-query configs carry an n_heads/n_kv_heads×
     smaller cache (the main GQA payoff at long max_len).
 
+    Heads and head_dim are stored MERGED in one trailing axis, for every
+    configuration, so that the layout the device gives the buffer at
+    allocation is the layout the per-row write updates in place and the
+    blockwise read slices. With a trailing [.., KV, hd] the TPU's default
+    layout for ``bf16[24,6,1280,32,96]`` is ``{2,4,3,1,0:T(8,128)}`` —
+    the ROWS minor, because a minor axis of 96 would pad to 128 lanes —
+    while the write inside ``step_rows``' loop wants head_dim minor
+    (padded: 1.51 GB a buffer instead of 1.13). Every decode chunk then
+    began and ended with a transposing copy of the whole K and V cache
+    (4 copies, 3.03 GB of temporaries; at 8 slots the cache was re-laid
+    out inside every step), and an admission wrote its rows as strided
+    partial tiles. Merged, the minor axis is KV·hd (3,072 = 24 × 128
+    lanes: nothing padded), the default layout ``{3,2,1,0}`` is also the
+    loop's, and no program that holds the cache copies it
+    (described-chip compile at 24 layers × 6 and × 8 slots × 1,280
+    rows: 0 cache-sized copies, temporaries 0.01 GB;
+    ``tests/test_chip_compile.py`` holds it). Only :func:`_kv_flat`
+    (writes) and :func:`_spread_queries` / :func:`_head_values` (reads)
+    know the trailing shape; what crosses a process boundary keeps the
+    5-D wire form (:func:`kv_to_wire`).
+
     ``cfg.kv_cache_dtype == "int8"`` stores k/v as int8 with per-token,
     per-kv-head absmax scales in parallel ``k_scale``/``v_scale`` buffers
-    of shape [L, B, max_len, KV, 1] (f32) — the SAME rank and leading
+    of shape [L, B, max_len, KV] (f32) — the SAME rank and leading
     dims as k/v, so every cache write path (contiguous slice, bounded
     window, per-row scatter) applies to the scale buffers unchanged with
-    a trailing dim of 1. Cache memory and read traffic halve vs bf16
+    a per-head width of 1. Cache memory and read traffic halve vs bf16
     (each of k and v costs 1 + 4/hd bytes per element ≈ 1.06 at hd=64,
     vs 2 bf16); see :func:`_kv_quantize` for the numerics.
 
@@ -163,17 +195,9 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
             f"attn_window={cfg.attn_window}: ring-cache attention reads "
             "every capacity row per token (O(capacity), not O(window)) — "
             "size the capacity near the window", stacklevel=2)
-    shape = (cfg.n_layers, batch, rows, cfg.kv_heads, cfg.head_dim)
-    if cfg.kv_quant:
-        sshape = shape[:-1] + (1,)
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(sshape, jnp.float32),
-                "v_scale": jnp.zeros(sshape, jnp.float32),
-                "length": jnp.zeros((), jnp.int32)}
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "length": jnp.zeros((), jnp.int32)}
+    cache = {n: jnp.zeros((cfg.n_layers, batch, rows, cfg.kv_heads * w), dt)
+             for n, (w, dt) in _kv_spec(cfg).items()}
+    return dict(cache, length=jnp.zeros((), jnp.int32))
 
 
 #: cache keys that hold per-position buffers (and so follow every write/
@@ -185,6 +209,105 @@ def _kv_bufs(cache: dict) -> dict:
     """The cache's position-indexed buffers (k/v + scales when present),
     without the length field."""
     return {n: cache[n] for n in _KV_BUFS if n in cache}
+
+
+def _kv_flat(chunk):
+    """[.., KV, w] → [.., KV·w]: a chunk (or a wire buffer) as the cache
+    stores it. The WRITE side's one statement of the stored trailing
+    shape; :func:`_spread_queries` / :func:`_head_values` are the read
+    side's."""
+    return chunk.reshape(chunk.shape[:-2] + (-1,))
+
+
+def _kv_rows(buf, li, start=None, n=None):
+    """Layer ``li``'s rows of a stored buffer [L, B, rows, KV·w], as
+    stored: [B, n, KV·w] — all rows, or the ``n`` from traced ``start``.
+    The block is sliced from the STACKED buffer directly: slicing the
+    layer first (``buf[li]``) reads loop-invariant in the blockwise
+    ``fori_loop``, so XLA hoists and MATERIALIZES the whole padded
+    per-layer cache before the loop, re-paying exactly the O(max_len)
+    traffic that path exists to avoid (measured: 5x decode slowdown at
+    max_len 8192)."""
+    if start is None:
+        return buf[li]
+    return jax.lax.dynamic_slice(
+        buf, (li, 0, start, 0), (1, buf.shape[1], n, buf.shape[3]))[0]
+
+
+def _spread_queries(q, kv):
+    """q [B, Q, H, hd] laid out block-diagonally over the ``kv`` K/V
+    heads, to meet stored rows whole: ``qx[(k, d), k', g, q] =
+    q[q, k, g, d]·[k == k']`` as [B, KV·hd, KV, G, Q] (G = H/KV query
+    heads share each K/V head). Loop-invariant: the blockwise path
+    builds it once, outside its ``fori_loop``.
+
+    Why: the stored rows are NOT split into heads. With hd = 96 that
+    split falls off the 128-lane tiles, and the compiler answers it with
+    a transposition of every block read (measured on the v5e: 0.28 ms a
+    layer and step against 0.18 with the padded 5-D cache, PR 26).
+    Against ``qx`` ONE matmul contracts the whole stored row
+    (:func:`_head_scores`): the other heads' columns meet exact zeros,
+    so each score is the same bf16 products accumulated in f32 as the
+    per-head einsum's. The MXU does KV times the needed work, on an
+    operand it would otherwise wait on HBM for."""
+    b, n_q, h, d = q.shape
+    qg = q.reshape(b, n_q, kv, h // kv, d)
+    qx = jnp.einsum("bqkgd,kK->bkdKgq", qg, jnp.eye(kv, dtype=q.dtype))
+    return qx.reshape(b, kv * d, kv, h // kv, n_q)
+
+
+def _head_scores(qx, k_rows):
+    """Per-head q·k over stored rows. ``qx``: :func:`_spread_queries`;
+    ``k_rows``: [B, S, KV·hd] as :func:`_kv_rows` returns them. Returns
+    f32 scores [B, KV, G, Q, S]."""
+    b, f = qx.shape[:2]
+    s = jnp.einsum("bsf,bfn->bns", k_rows, qx.reshape(b, f, -1),
+                   preferred_element_type=jnp.float32)
+    return s.reshape((b,) + qx.shape[2:] + k_rows.shape[1:2])
+
+
+def _head_values(p, v_rows):
+    """Per-head p·v over stored rows, the counterpart of
+    :func:`_head_scores`. p: [B, KV, G, Q, S] (the rows' dtype);
+    ``v_rows``: [B, S, KV·hd]. Returns f32 [B, KV, G, Q, hd]: one
+    matmul ``p @ v_rows`` gives every (query head, stored column) pair,
+    and each KV head keeps its own hd columns (a select, no
+    arithmetic)."""
+    b, kv, g, n_q, n_s = p.shape
+    r = jnp.einsum("bns,bsf->bnf", p.reshape(b, -1, n_s), v_rows,
+                   preferred_element_type=jnp.float32)
+    r = r.reshape(b, kv, g, n_q, kv, -1)
+    own = jnp.eye(kv, dtype=bool)[None, :, None, None, :, None]
+    return jnp.where(own, r, 0.0).sum(axis=4)
+
+
+def _merge_query_heads(o, dtype):
+    """Attention output [B, KV, G, Q, hd] → [B, Q, H, hd] in ``dtype``."""
+    b, kv, g, n_q, d = o.shape
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, n_q, kv * g, d).astype(dtype)
+
+
+def kv_wire_layout(cfg: T.TransformerConfig) -> dict:
+    """name → ``ShapeDtypeStruct`` of ONE position of every buffer in the
+    WIRE form [L, 1, 1, KV, w]: what a shipped ``KVPackage`` or prefix
+    template is validated against (layers, heads, head_dim, dtype)."""
+    return {n: jax.ShapeDtypeStruct(
+                (cfg.n_layers, 1, 1, cfg.kv_heads, w), dt)
+            for n, (w, dt) in _kv_spec(cfg).items()}
+
+
+def kv_to_wire(bufs: dict, cfg: T.TransformerConfig) -> dict:
+    """Stored buffers [L, B, S, KV·w] → the wire form [L, B, S, KV, w]
+    that ``KVPackage`` rows and prefix-template blobs have always had, so
+    replicas of either cache representation land each other's ships."""
+    return {n: a.reshape(a.shape[:3] + (cfg.kv_heads, -1))
+            for n, a in bufs.items()}
+
+
+def kv_from_wire(bufs: dict) -> dict:
+    """Wire buffers [L, B, S, KV, w] → the stored form (a host reshape:
+    no copy)."""
+    return {n: _kv_flat(a) for n, a in bufs.items()}
 
 
 def _kv_quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -235,12 +358,11 @@ def _cached_attention_blockwise(q, bufs, li, q_start,
     compiled program is static-shape (one [block]-row slice per step)
     while the executed cost follows the live length.
 
-    Takes the STACKED caches [L, B, max_len, KV, hd] plus this layer's
-    static index ``li`` and slices each block 5-D directly — slicing the
-    layer first (``k_all[li]``) reads loop-invariant in the fori_loop, so
-    XLA hoists and MATERIALIZES the full padded per-layer cache before
-    the loop, re-paying exactly the O(max_len) traffic this path exists
-    to avoid (measured: 5x decode slowdown at max_len 8192).
+    Takes the STACKED caches [L, B, max_len, KV·hd] plus this layer's
+    static index ``li``; each block is one :func:`_kv_rows` slice of the
+    stacked buffer (never of a per-layer slice — see there), contracted
+    as stored (:func:`_head_scores`, :func:`_head_values`): nothing is
+    re-laid-out, block-sized or cache-sized.
 
     Same contract as the dense path: q [B, K, H, hd] at positions
     q_start..q_start+K-1 (GQA reads its shared K/V head unexpanded),
@@ -263,11 +385,11 @@ def _cached_attention_blockwise(q, bufs, li, q_start,
     quant = "k_scale" in bufs
     b, n_q, h, d = q.shape
     max_len = k_all.shape[2]
-    kv = k_all.shape[3]
+    kv = k_all.shape[3] // d
     group = h // kv
     scale = d ** -0.5
     q_pos = _q_positions(q_start, b, n_q)                       # [B, Q]
-    qg = q.reshape(b, n_q, kv, group, d)
+    qx = _spread_queries(q, kv)
     n_active = (jnp.max(q_pos) + block) // block                # traced
     # sliding window: blocks entirely older than every row's window are
     # never read — the loop STARTS at the window's first block, so
@@ -282,10 +404,8 @@ def _cached_attention_blockwise(q, bufs, li, q_start,
     def body(i, carry):
         m, l, acc = carry
         start = jnp.minimum(i * block, max_len - block)
-        kb = jax.lax.dynamic_slice(
-            k_all, (li, 0, start, 0, 0), (1, b, block, kv, d))[0]
-        vb = jax.lax.dynamic_slice(
-            v_all, (li, 0, start, 0, 0), (1, b, block, kv, d))[0]
+        kb = _kv_rows(k_all, li, start, block)              # [B, S, KV·hd]
+        vb = _kv_rows(v_all, li, start, block)
         if quant:
             kb, vb = kb.astype(q.dtype), vb.astype(q.dtype)
         k_pos = start + jnp.arange(block)                       # [S]
@@ -295,12 +415,9 @@ def _cached_attention_blockwise(q, bufs, li, q_start,
         if attn_window is not None:
             mask = mask & (q_pos[:, :, None] - k_pos[None, None, :]
                            < attn_window)
-        s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kb,
-                       preferred_element_type=jnp.float32) * scale
+        s = _head_scores(qx, kb) * scale
         if quant:
-            ksb = jax.lax.dynamic_slice(
-                bufs["k_scale"], (li, 0, start, 0, 0),
-                (1, b, block, kv, 1))[0, ..., 0]                # [B, S, KV]
+            ksb = _kv_rows(bufs["k_scale"], li, start, block)   # [B, S, KV]
             s = s * ksb.transpose(0, 2, 1)[:, :, None, None, :]
         s = jnp.where(mask[:, None, None], s, -jnp.inf)
         new_m = jnp.maximum(m, s.max(axis=-1))
@@ -311,26 +428,22 @@ def _cached_attention_blockwise(q, bufs, li, q_start,
         p = jnp.exp(s - safe_m[..., None])
         l = l * alpha + p.sum(axis=-1)
         if quant:
-            vsb = jax.lax.dynamic_slice(
-                bufs["v_scale"], (li, 0, start, 0, 0),
-                (1, b, block, kv, 1))[0, ..., 0]                # [B, S, KV]
+            vsb = _kv_rows(bufs["v_scale"], li, start, block)   # [B, S, KV]
             p_eff = p * vsb.transpose(0, 2, 1)[:, :, None, None, :]
         else:
             p_eff = p
-        pv = jnp.einsum("bkgqs,bskd->bkgqd", p_eff.astype(vb.dtype), vb,
-                        preferred_element_type=jnp.float32)
+        pv = _head_values(p_eff.astype(vb.dtype), vb)
         acc = acc * alpha[..., None] + pv
         return new_m, l, acc
 
     m, l, acc = jax.lax.fori_loop(lo, n_active, body, (m0, l0, acc0))
-    o = acc / l[..., None]          # l > 0: every query attends itself
-    o = o.transpose(0, 3, 1, 2, 4).reshape(b, n_q, h, d)
-    return o.astype(q.dtype)
+    # l > 0: every query attends itself
+    return _merge_query_heads(acc / l[..., None], q.dtype)
 
 
 def _cached_attention(q, bufs, li, q_start, attn_window=None):
     """q: [B, K, H, hd] holding positions q_start..q_start+K-1; ``bufs``:
-    the cache's stacked [L, B, max_len, KV, hd] k/v buffers (plus
+    the cache's stacked [L, B, max_len, KV·hd] k/v buffers (plus
     ``k_scale``/``v_scale`` for int8 caches) with ``li`` this layer's
     static index (KV = H for MHA; KV < H for grouped-query, where each
     query group reads its shared K/V head WITHOUT materializing a
@@ -350,13 +463,11 @@ def _cached_attention(q, bufs, li, q_start, attn_window=None):
         return _cached_attention_blockwise(q, bufs, li, q_start,
                                            attn_window=attn_window)
     quant = "k_scale" in bufs
-    k_cache, v_cache = k_all[li], v_all[li]
+    b, n_q, h, d = q.shape
+    k_cache, v_cache = _kv_rows(k_all, li), _kv_rows(v_all, li)
     if quant:
         k_cache, v_cache = (k_cache.astype(q.dtype),
                             v_cache.astype(q.dtype))
-    b, n_q, h, d = q.shape
-    kv = k_cache.shape[2]
-    group = h // kv                                  # 1 = plain MHA
     scale = d ** -0.5
     q_pos = _q_positions(q_start, b, n_q)                       # [B, Q]
     k_pos = jnp.arange(max_len)                                 # [S]
@@ -364,26 +475,24 @@ def _cached_attention(q, bufs, li, q_start, attn_window=None):
     if attn_window is not None:
         mask = mask & (q_pos[:, :, None] - k_pos[None, None, :]
                        < attn_window)
-    qg = q.reshape(b, n_q, kv, group, d)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
-                        preferred_element_type=jnp.float32) * scale
+    qx = _spread_queries(q, k_cache.shape[-1] // d)
+    scores = _head_scores(qx, k_cache) * scale      # [B, KV, G, Q, S]
     if quant:
         # per-token K scale is constant along the contracted hd — apply
         # it on the scores instead of dequantizing the cache
-        ks = bufs["k_scale"][li, ..., 0].transpose(0, 2, 1)     # [B, KV, S]
+        ks = _kv_rows(bufs["k_scale"], li).transpose(0, 2, 1)   # [B, KV, S]
         scores = scores * ks[:, :, None, None, :]
     scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)                     # f32
     if quant:
-        vs = bufs["v_scale"][li, ..., 0].transpose(0, 2, 1)     # [B, KV, S]
+        vs = _kv_rows(bufs["v_scale"], li).transpose(0, 2, 1)   # [B, KV, S]
         probs = probs * vs[:, :, None, None, :]
-    o = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v_cache.dtype),
-                   v_cache, preferred_element_type=jnp.float32)
-    return o.reshape(b, n_q, h, d).astype(q.dtype)
+    return _merge_query_heads(
+        _head_values(probs.astype(v_cache.dtype), v_cache), q.dtype)
 
 
 def _ring_cached_attention(q, bufs, li, q_pos, attn_window: int):
-    """Cached attention over a ROLLING cache [L, B, C, KV, hd]: writes
+    """Cached attention over a ROLLING cache [L, B, C, KV·hd]: writes
     wrapped modulo C, so ring row ``r`` holds the most recent absolute
     position congruent to r — ``q_pos - ((q_pos - r) mod C)``. A query
     at ``q_pos`` attends exactly the rows whose offset
@@ -401,29 +510,25 @@ def _ring_cached_attention(q, bufs, li, q_pos, attn_window: int):
     quant = "k_scale" in bufs
     b, n_q, h, d = q.shape
     c = k_all.shape[2]
-    k_cache, v_cache = k_all[li], v_all[li]
+    k_cache, v_cache = _kv_rows(k_all, li), _kv_rows(v_all, li)
     if quant:
         k_cache, v_cache = (k_cache.astype(q.dtype),
                             v_cache.astype(q.dtype))
-    kv = k_cache.shape[2]
-    group = h // kv
     scale = d ** -0.5
     offset = jnp.mod(q_pos[:, None] - jnp.arange(c)[None, :], c)  # [B, C]
     mask = offset < jnp.minimum(attn_window, q_pos[:, None] + 1)
-    qg = q.reshape(b, n_q, kv, group, d)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
-                        preferred_element_type=jnp.float32) * scale
+    qx = _spread_queries(q, k_cache.shape[-1] // d)
+    scores = _head_scores(qx, k_cache) * scale      # [B, KV, G, Q, C]
     if quant:
-        ks = bufs["k_scale"][li, ..., 0].transpose(0, 2, 1)     # [B, KV, C]
+        ks = _kv_rows(bufs["k_scale"], li).transpose(0, 2, 1)   # [B, KV, C]
         scores = scores * ks[:, :, None, None, :]
     scores = jnp.where(mask[:, None, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)                     # f32
     if quant:
-        vs = bufs["v_scale"][li, ..., 0].transpose(0, 2, 1)
+        vs = _kv_rows(bufs["v_scale"], li).transpose(0, 2, 1)
         probs = probs * vs[:, :, None, None, :]
-    o = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v_cache.dtype),
-                   v_cache, preferred_element_type=jnp.float32)
-    return o.reshape(b, n_q, h, d).astype(q.dtype)
+    return _merge_query_heads(
+        _head_values(probs.astype(v_cache.dtype), v_cache), q.dtype)
 
 
 def _window_write(buf_all, chunk, li, pos, window):
@@ -434,13 +539,14 @@ def _window_write(buf_all, chunk, li, pos, window):
 
     One contiguous ``window``-wide slice of the stacked cache is read,
     each row's K-token chunk lands at its own offset via a one-hot
-    einsum (an MXU-shaped [B,W,K]x[B,K,KV*hd] contraction instead of a
+    einsum (an MXU-shaped [B,W,K]x[B,K,KV·hd] contraction instead of a
     serialized gather/scatter), and the window is written back with one
     ``dynamic_update_slice``. Traffic is O(B * window) contiguous rows —
     independent of max_len and free of scatter lowering. Measured ~25%
     faster per speculative round than the global-cache scatter at the
-    bench shapes (see docs/performance.md, round 5)."""
-    b, n_k, kv, d = chunk.shape
+    bench shapes (see docs/performance.md, round 5). ``chunk`` arrives
+    flattened ([B, K, KV·w]) from :func:`_write_kv_chunk`."""
+    b, n_k, f = chunk.shape
     max_len = buf_all.shape[2]
     # clamp base the way dynamic_slice clamps its start (start <=
     # max_len - window), so `off` stays relative to where the slice
@@ -460,12 +566,11 @@ def _window_write(buf_all, chunk, li, pos, window):
     sel = (w_idx[None, :, None]
            == off[:, None, None] + jnp.arange(n_k)[None, None, :])
     win = jax.lax.dynamic_slice(
-        buf_all, (li, 0, base, 0, 0),
-        (1, b, window, kv, d))[0]                               # [B, W, KV, hd]
-    upd = jnp.einsum("bwj,bjkd->bwkd", sel.astype(chunk.dtype), chunk)
-    win = jnp.where(sel.any(-1)[..., None, None], upd, win)
+        buf_all, (li, 0, base, 0), (1, b, window, f))[0]        # [B, W, KV·w]
+    upd = jnp.einsum("bwj,bjf->bwf", sel.astype(chunk.dtype), chunk)
+    win = jnp.where(sel.any(-1)[..., None], upd, win)
     return jax.lax.dynamic_update_slice(buf_all, win[None],
-                                        (li, 0, base, 0, 0))
+                                        (li, 0, base, 0))
 
 
 def _kv_writes(bufs: dict, k: jax.Array, v: jax.Array) -> dict:
@@ -481,14 +586,16 @@ def _kv_writes(bufs: dict, k: jax.Array, v: jax.Array) -> dict:
 
 
 def _write_kv_chunk(buf, chunk, li, pos, window):
-    """Write a K-token chunk [B, K, KV, d] into the stacked cache buffer
-    [L, B, max_len, KV, d] at layer ``li``, positions ``pos``. The three
-    write modes (scalar contiguous slice / bounded window / per-row
-    unique scatter) are dtype- and trailing-dim-agnostic, so int8 caches
-    route their [.., KV, 1] scale buffers through the same path as k/v."""
+    """Write a K-token chunk [B, K, KV, w] into the stacked cache buffer
+    [L, B, max_len, KV·w] at layer ``li``, positions ``pos`` — flattened
+    here, once, to the stored row (:func:`_kv_flat`). The three write
+    modes (scalar contiguous slice / bounded window / per-row unique
+    scatter) are dtype- and width-agnostic, so int8 caches route their
+    [.., KV, 1] scale chunks through the same path as k/v."""
+    chunk = _kv_flat(chunk)
     if pos.ndim == 0:                   # uniform frontier: contiguous slice
         return jax.lax.dynamic_update_slice(buf, chunk[None],
-                                            (li, 0, pos, 0, 0))
+                                            (li, 0, pos, 0))
     if window is not None:              # bounded divergence: window write
         return _window_write(buf, chunk, li, pos, window)
     # per-row frontiers: unique scatter
@@ -500,7 +607,7 @@ def _write_kv_chunk(buf, chunk, li, pos, window):
 def _decode_block(x, layer_params, bufs, li, pos, cfg, rope,
                   window=None):
     """Chunked decoder block. x: [B, K, D] at positions pos..pos+K-1;
-    ``bufs``: the FULL stacked cache buffers [L, B, max_len, KV, hd]
+    ``bufs``: the FULL stacked cache buffers [L, B, max_len, KV·hd]
     (k/v, plus scales for int8 caches); ``li``: this layer's static
     index; ``rope``: (cos, sin) tables precomputed once per chunk
     (position-only, so layer-invariant — same hoisting as the training
@@ -519,8 +626,18 @@ def _decode_block(x, layer_params, bufs, li, pos, cfg, rope,
     k = _weinsum("bsd,dhk->bshk", h, p["wk"])
     v = _weinsum("bsd,dhk->bshk", h, p["wv"])
     q, k = T.apply_rope(q, cos, sin), T.apply_rope(k, cos, sin)
-    # write this chunk into the stacked cache (in place under jit: the
-    # pre-update buffer has no later consumer)
+    # v goes straight to the cache write, which flattens its heads; left
+    # alone, XLA folds that reshape into the projection, needs wv[li] as
+    # a 2-D matrix, and MATERIALIZES every layer's slice of the stacked
+    # weight in each step to get one (453 MB a step at Phi-3-mini
+    # widths, 7% of the decode chunk on the v5e — PR 26). The barrier
+    # keeps the projection in the [.., KV, hd] form q and k have anyway.
+    v = jax.lax.optimization_barrier(v)
+    # write this chunk into the stacked cache — in place under jit, and
+    # with the merged trailing axis (init_kv_cache) in place for the
+    # WHOLE program: the buffer's layout at entry is the one this write
+    # and the read below want, so nothing re-lays-out the cache
+    # (tests/test_chip_compile.py holds that for step_rows)
     pos = jnp.asarray(pos)
     cap = _ring_capacity(cfg)
     # named sections (metadata only): a decode profile is read by them
@@ -720,7 +837,8 @@ def _prompt_forward(params, tokens, cfg, bufs, s):
             s0 = max(s - cap, 0)
             idx = jnp.arange(s0, s) % cap
             for n, c in _kv_writes(bufs, k[:, s0:s], v[:, s0:s]).items():
-                layer = bufs[n][li].at[:, idx].set(c, unique_indices=True)
+                layer = bufs[n][li].at[:, idx].set(_kv_flat(c),
+                                                   unique_indices=True)
                 bufs[n] = bufs[n].at[li].set(layer)
         else:
             for n, c in _kv_writes(bufs, k[:, :s], v[:, :s]).items():
@@ -781,7 +899,8 @@ def place_rows(cache: dict, mini: dict, rows: jax.Array,
         lengths.astype(jnp.int32), mode="drop", unique_indices=True))
 
 
-def extract_kv_rows(mini: dict, widths) -> list[dict]:
+def extract_kv_rows(mini: dict, widths,
+                    cfg: T.TransformerConfig) -> list[dict]:
     """Per-row HOST copies of a K-row mini cache's buffers — the
     extraction half of disaggregated serving's KV shipment
     (:func:`place_rows` is the landing half). Row ``i`` ships its first
@@ -793,13 +912,14 @@ def extract_kv_rows(mini: dict, widths) -> list[dict]:
     short prompt for nothing — and for rolling (ring) caches the full
     capacity, whose positional wrap only a whole-slot landing
     preserves. Returns one ``{name: np [L, 1, w, KV, hd]}`` dict per
-    row (every layer's slice in one device fetch per row)."""
+    row — the wire form (:func:`kv_to_wire`, a free host reshape) —
+    every layer's slice in one device fetch per row."""
     bufs = _kv_bufs(mini)
     out = []
     for i, w in enumerate(widths):
         w = int(w)
-        out.append(jax.device_get(
-            {n: b[:, i:i + 1, :w] for n, b in bufs.items()}))
+        out.append(kv_to_wire(jax.device_get(
+            {n: b[:, i:i + 1, :w] for n, b in bufs.items()}), cfg))
     return out
 
 
@@ -1433,7 +1553,7 @@ def beam_search(params: dict, prompt: jax.Array, cfg: T.TransformerConfig,
     static-shape discipline as :func:`generate`.
 
     Mechanics: the prompt prefills once per row and its cache tiles
-    across beams ([L, B, S, KV, hd] → [L, B·W, S, KV, hd] — beams share
+    across beams ([L, B, S, KV·hd] → [L, B·W, S, KV·hd] — beams share
     history until they diverge); each step feeds every beam's last token
     (writing its K/V), forms the [B, W·V] successor scores, takes the
     top W, and GATHERS the cache along the beam axis by parent index —

@@ -158,8 +158,9 @@ from tony_tpu.models.decode import (_check_draft_vocab, _check_no_ring,
                                     _propose_and_verify,
                                     _propose_and_verify_sampled,
                                     decode_step, extend_step,
-                                    init_kv_cache, place_rows, prefill,
-                                    prefill_rows)
+                                    init_kv_cache, kv_from_wire,
+                                    kv_to_wire, kv_wire_layout, place_rows,
+                                    prefill, prefill_rows)
 from tony_tpu.runtime import goodput as goodput_mod
 from tony_tpu.runtime import metrics as metrics_mod
 from tony_tpu.runtime import tracing
@@ -230,8 +231,8 @@ def _place_prefill(cache, mini, row, s_p):
     contiguous ``dynamic_update_slice`` per buffer — k/v plus int8
     scales when the cache is quantized) and set the row's frontier to
     the prompt length."""
-    placed = {n: jax.lax.dynamic_update_slice(cache[n], mini[n],
-                                              (0, row, 0, 0, 0))
+    placed = {n: jax.lax.dynamic_update_slice(
+                  cache[n], mini[n], (0, row) + (0,) * (mini[n].ndim - 2))
               for n in _kv_bufs(mini)}
     return dict(placed, length=cache["length"].at[row].set(s_p))
 
@@ -303,8 +304,9 @@ def admit_rows(params, cache, logits, rows, prompts, lengths, cfg):
 
 def prefix_template(params, prefix, cfg):
     """Prefill a SHARED PREFIX once (a system prompt every request
-    continues from); returns the [L, 1, P, KV, hd] K/V template
-    :func:`prefix_admit_rows` copies into each admitted slot. prefix:
+    continues from); returns the [L, 1, P, KV·hd] K/V template (the
+    cache's own stored form) :func:`prefix_admit_rows` copies into each
+    admitted slot. prefix:
     [P] ints. Rolling caches are rejected up front: a ring-shaped
     buffer's shape[2] is the capacity, which the template consumers
     would misread as the prefix length and build a corrupt cache."""
@@ -318,7 +320,7 @@ class PrefixEntry:
     """One RESIDENT shared prefix in a batcher's prefix store: the
     token sequence (for matching and suffix splitting) plus its
     precomputed K/V ``template`` (:func:`prefix_template` shape —
-    ``[L, 1, P, KV, hd]`` per buffer). ``draft_template`` is the
+    ``[L, 1, P, KV·hd]`` per buffer). ``draft_template`` is the
     speculative batcher's draft-model template (computed locally at
     install — template ships carry only the target's K/V)."""
 
@@ -347,13 +349,15 @@ class _PrefixHit:
 
 
 def validate_template_bufs(proto: dict, tokens, bufs: dict) -> dict:
-    """Validate a (possibly shipped) prefix template against a
-    reference cache's buffer layout ``proto`` (``_kv_bufs`` of any
-    cache built from the serving config): buffer-name set, dtypes,
-    layer count, and trailing head dims must match, and the sequence
-    extent must equal the prefix length. Raises ``ValueError`` naming
-    the mismatch — request-scoped at the install path, exactly like a
-    mismatched KV row shipment. Returns the buffers as device arrays."""
+    """Validate a (possibly shipped) prefix template — wire form,
+    ``[L, 1, P, KV, hd]`` per buffer — against the serving config's
+    wire layout ``proto`` (:func:`~tony_tpu.models.decode.
+    kv_wire_layout`): buffer-name set, dtypes, layer count, and
+    trailing head dims must match, and the sequence extent must equal
+    the prefix length. Raises ``ValueError`` naming the mismatch —
+    request-scoped at the install path, exactly like a mismatched KV
+    row shipment. Returns the buffers as device arrays in the cache's
+    stored form."""
     p_len = len(tokens)
     if set(bufs) != set(proto):
         raise ValueError(
@@ -365,21 +369,21 @@ def validate_template_bufs(proto: dict, tokens, bufs: dict) -> dict:
         if a.dtype != c.dtype:
             raise ValueError(f"template buffer {n!r} dtype {a.dtype} "
                              f"!= cache dtype {c.dtype}")
-        if a.ndim != c.ndim or a.shape[0] != c.shape[0]:
-            layers = a.shape[0] if a.ndim else 0
+        if a.ndim == c.ndim and a.shape[0] != c.shape[0]:
             raise ValueError(
-                f"template buffer {n!r} carries {layers} layers; this "
-                f"model has {c.shape[0]} (layer mismatch between "
+                f"template buffer {n!r} carries {a.shape[0]} layers; "
+                f"this model has {c.shape[0]} (layer mismatch between "
                 f"producer and installer?)")
-        if a.shape[1] != 1 or a.shape[3:] != c.shape[3:]:
+        if (a.ndim != c.ndim or a.shape[1] != 1
+                or a.shape[3:] != c.shape[3:]):
             raise ValueError(f"template buffer {n!r} shape "
-                             f"{list(a.shape)} does not fit cache "
-                             f"{list(c.shape)}")
+                             f"{list(a.shape)} does not fit this "
+                             f"cache's wire layout {list(c.shape)}")
         if a.shape[2] != p_len:
             raise ValueError(f"template buffer {n!r} holds {a.shape[2]} "
                              f"positions for a {p_len}-token prefix")
-        out[n] = jnp.asarray(a)
-    return out
+        out[n] = a
+    return {n: jnp.asarray(a) for n, a in kv_from_wire(out).items()}
 
 
 def _extend_from_template(model_params, template, suffix, model_cfg):
@@ -977,7 +981,7 @@ class ContinuousBatcher:
             template = prefix_template(self.params, tokens, self.cfg)
             self.prefill_forward_tokens += len(tokens)
         else:
-            template = validate_template_bufs(_kv_bufs(self.cache),
+            template = validate_template_bufs(kv_wire_layout(self.cfg),
                                               tokens, template)
         self._prefix_store[str(prefix_id)] = self._build_entry(
             str(prefix_id), tokens, template)
@@ -1023,7 +1027,8 @@ class ContinuousBatcher:
             raise ValueError(f"prefix {prefix_id!r} is not resident")
         return kvship.pack_template(
             entry.id, entry.tokens,
-            {n: np.asarray(a) for n, a in entry.template.items()},
+            kv_to_wire({n: np.asarray(a)
+                        for n, a in entry.template.items()}, self.cfg),
             self.cfg.vocab_size)
 
     def _resolve_prefix(self, prefix_id, prompt) -> PrefixEntry | None:
@@ -1150,15 +1155,17 @@ class ContinuousBatcher:
         lgs = np.zeros((b, self.cfg.vocab_size),
                        pkgs[grp[0][1]].logits.dtype)
         keys = np.zeros((b, 2), np.uint32)
+        # packages arrive in the wire form; the staging buffers are in
+        # the cache's stored form (a host reshape per row, no copy)
         mini = {n: np.zeros((a.shape[0], b, s_b) + a.shape[3:], a.dtype)
-                for n, a in proto.items()}
+                for n, a in kv_from_wire(proto).items()}
         for i, (row, req) in enumerate(grp):
             pkg = pkgs[req]
             rows[i] = row
             lens[i] = pkg.length
             lgs[i] = pkg.logits
             keys[i] = pkg.rng_key
-            for n, a in pkg.bufs.items():
+            for n, a in kv_from_wire(pkg.bufs).items():
                 mini[n][:, i:i + 1, :a.shape[2]] = a
         self.cache, self.logits, self._row_keys = land_kv_rows(
             self.cache, self.logits, jnp.asarray(rows),
@@ -1189,7 +1196,7 @@ class ContinuousBatcher:
                 f"package logits shape {list(lg.shape)} != "
                 f"[{self.cfg.vocab_size}] (vocab mismatch between the "
                 f"prefill and decode gangs?)")
-        want = _kv_bufs(self.cache)
+        want = kv_wire_layout(self.cfg)
         if set(pkg.bufs) != set(want):
             raise ValueError(
                 f"package buffers {sorted(pkg.bufs)} do not match this "
@@ -1204,7 +1211,8 @@ class ContinuousBatcher:
             if (a.shape[0] != c.shape[0] or a.shape[1] != 1
                     or a.shape[3:] != c.shape[3:]):
                 raise ValueError(f"package buffer {n!r} shape {a.shape} "
-                                 f"does not fit cache {c.shape}")
+                                 f"does not fit this cache's wire "
+                                 f"layout {c.shape}")
             if a.shape[2] > rows:
                 raise ValueError(f"package width {a.shape[2]} exceeds "
                                  f"the cache's {rows} rows")

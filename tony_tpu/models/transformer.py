@@ -118,8 +118,8 @@ class TransformerConfig:
     moe_aux_weight: float = 0.01
     # KV-cache storage for the decode/serving path: "model" stores K/V in
     # ``dtype`` (exact); "int8" quantizes each written token per kv-head
-    # (absmax/127 scale carried in a parallel [.., KV, 1] f32 buffer) —
-    # the cache's HBM footprint and read traffic halve vs bf16, so a
+    # (absmax/127 scale carried in a parallel [L, B, rows, KV] f32
+    # buffer) — the cache's HBM footprint and read traffic halve vs bf16, so a
     # serving host fits ~2x the slots (or 2x max_len) in the same memory.
     # Decode logits shift by the ~0.4% relative rounding of K/V; training
     # and prefill math are untouched (quantization happens only at the

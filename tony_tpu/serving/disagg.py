@@ -230,7 +230,6 @@ class PrefillServer(WeightHost, PrefixHost, FrameServerBase):
         #: only; entries immutable — lock-free reads at wave time.
         self._prefix_store: dict[str, tuple] = {}
         self._ring_prefix_warned = False
-        self._proto_bufs = None          # lazy layout prototype
         self._init_prefix_host(reg)
         # weights lane shares the prefix hub port (kind-tagged blobs)
         self._init_weight_host(
@@ -263,6 +262,7 @@ class PrefillServer(WeightHost, PrefixHost, FrameServerBase):
         return str(pid)
 
     def install_prefix_template(self, meta, bufs) -> str:
+        from tony_tpu.models.decode import kv_wire_layout
         from tony_tpu.models.serve import validate_template_bufs
 
         if int(meta["vocab"]) != self.cfg.vocab_size:
@@ -282,10 +282,8 @@ class PrefillServer(WeightHost, PrefixHost, FrameServerBase):
             raise ValueError(
                 f"prefix of {len(tokens)} tokens leaves no room for a "
                 f"suffix + generation under max_len {self.max_len}")
-        if self._proto_bufs is None:
-            from tony_tpu.models.decode import _kv_bufs, init_kv_cache
-            self._proto_bufs = _kv_bufs(init_kv_cache(self.cfg, 1, 1))
-        template = validate_template_bufs(self._proto_bufs, tokens, bufs)
+        template = validate_template_bufs(kv_wire_layout(self.cfg),
+                                          tokens, bufs)
         pid = str(meta["id"])
         self._prefix_store[pid] = (tokens, template)
         return pid
@@ -297,10 +295,13 @@ class PrefillServer(WeightHost, PrefixHost, FrameServerBase):
         entry = self._prefix_store.get(str(prefix_id))
         if entry is None:
             raise ValueError(f"prefix {prefix_id!r} is not resident")
+        from tony_tpu.models.decode import kv_to_wire
+
         tokens, template = entry
         return kvship.pack_template(
             str(prefix_id), tokens,
-            {n: np.asarray(a) for n, a in template.items()},
+            kv_to_wire({n: np.asarray(a) for n, a in template.items()},
+                       self.cfg),
             self.cfg.vocab_size)
 
     def _resolve_item(self, item: _PrefillItem):
@@ -644,7 +645,7 @@ class PrefillServer(WeightHost, PrefixHost, FrameServerBase):
                 widths = [len(item.prompt) for item in grp]
                 lengths = widths
                 fwd = sum(widths)
-            rows = extract_kv_rows(mini, widths)
+            rows = extract_kv_rows(mini, widths, self.cfg)
             lg_host = jax.device_get(lg)
             self._fwd_tok_c.inc(fwd)
         except Exception as e:            # device failure: request-scoped
