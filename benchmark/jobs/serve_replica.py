@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.lib import modelcfg, reference, traffic, weights, xplane
+from benchmark.lib import modelcfg, reference, traffic, xplane
 
 
 def say(**obj) -> None:
@@ -70,6 +70,7 @@ class Replica:
     def __init__(self, args) -> None:
         self.args = args
         self.c = modelcfg.load(args.config)
+        self.family = modelcfg.family(self.c)
         self.mix = traffic.load(args.traffic)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         import tony_tpu.runtime as rt
@@ -91,12 +92,11 @@ class Replica:
 
     # what a run serves: the published widths in the program's own types
     def config(self):
-        from tony_tpu.models import transformer as T
-        return T.TransformerConfig(**modelcfg.program_kwargs(self.c),
-                                   dtype=self.dtype, remat=False)
+        return self.family.program_config(self.c, dtype=self.dtype,
+                                          remat=False)
 
     def params(self, seed: int):
-        return weights.make_params(seed, self.c, self.dtype)
+        return self.family.make_params(seed, self.c, self.dtype)
 
     def start(self, seed: int) -> int:
         """Seeded weights -> ``ContinuousBatcher`` -> ``ServingServer``;

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.lib import modelcfg, reference, traffic, weights, xplane
+from benchmark.lib import modelcfg, reference, traffic, xplane
 
 
 def _cache_counts() -> tuple[int, int]:
@@ -40,27 +40,6 @@ def _cache_counts() -> tuple[int, int]:
     hits, requests = (int(x) for x in re.findall(
         r"\d+", compile_cache.stats()))
     return hits, requests
-
-
-def _leaf_norms(tree: dict, minus: dict | None = None) -> dict:
-    """{"blocks/wq/3": norm, "embed": norm, ...} of a params-shaped tree
-    (less ``minus``), one jitted program that materialises no difference."""
-    @jax.jit
-    def norms(t, m):
-        if m is not None:
-            t = jax.tree.map(lambda a, b: a.astype(jnp.float32)
-                             - b.astype(jnp.float32), t, m)
-        sq = lambda x, ax: jnp.sqrt(jnp.sum(                # noqa: E731
-            jnp.square(x.astype(jnp.float32)), axis=ax))
-        return {"blocks": {n: sq(x, tuple(range(1, x.ndim)))
-                           for n, x in t["blocks"].items()},
-                **{n: sq(x, None) for n, x in t.items() if n != "blocks"}}
-    out = jax.device_get(norms(tree, minus))
-    flat = {n: float(v) for n, v in out.items() if n != "blocks"}
-    for n, per_layer in out["blocks"].items():
-        flat.update({f"blocks/{n}/{li}": float(v)
-                     for li, v in enumerate(per_layer)})
-    return flat
 
 
 def _adam_mu(opt_state):
@@ -84,6 +63,7 @@ def main() -> int:
     args = ap.parse_args()
 
     c = modelcfg.load(args.config)
+    fam = modelcfg.family(c)
     mix = traffic.load(args.traffic)
     # every program, however quick to compile, is served from the cache on
     # the next run (the program's own entry points keep JAX's 1 s default)
@@ -106,13 +86,13 @@ def main() -> int:
     dtype = jnp.bfloat16 if args.platform == "tpu" else jnp.float32
     print(rt.device_line(dtype), flush=True)
     mesh = rt.mesh()
-    cfg = T.TransformerConfig(**modelcfg.program_kwargs(c), dtype=dtype)
+    cfg = fam.program_config(c, dtype=dtype)
     batch, seq = mix["batch_per_process"], mix["seq_len"]
     warm, lag = mix["warm_steps"], mix["sync_lag_steps"]
     seed = args.seed
 
     def seeded_params():
-        return weights.make_params(
+        return fam.make_params(
             seed, c, dtype, param_shardings(T.logical_axes(cfg), mesh))
 
     params = seeded_params()
@@ -165,12 +145,12 @@ def main() -> int:
     def hook(i: int) -> None:
         """Runs first in every iteration: ``seen`` holds step i-1's output."""
         if i == 1:
-            seen["checks"]["mu"] = _leaf_norms(_adam_mu(
+            seen["checks"]["mu"] = fam.leaf_norms(_adam_mu(
                 seen["state"]["opt_state"]))
         if i == 2:
             p0 = seeded_params()
-            seen["checks"]["delta"] = _leaf_norms(seen["state"]["params"],
-                                                  p0)
+            seen["checks"]["delta"] = fam.leaf_norms(
+                seen["state"]["params"], p0)
             del p0
         if i == warm:
             jax.block_until_ready(seen["state"])

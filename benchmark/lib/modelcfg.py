@@ -1,11 +1,20 @@
 """A configuration file (``benchmark/configs/<name>.json``) as the benchmark
-reads it: the published ``config.json`` keys, as run. No JAX here — the
-parent process reads sizes from it too."""
+reads it: the published ``config.json`` keys, as run, and ``family``, the
+module that knows what those keys mean (``benchmark/families/<family>.py``:
+its check, its counts, its seeded weights, its plain reference — the list
+is in ``families/dense_decoder.py``'s docstring). Everything else in the
+harness reaches a model through :func:`family`. No JAX here, and none in
+the half of a family the parent process reads.
+"""
 
 from __future__ import annotations
 
+import importlib
+import importlib.util
 import json
 import os
+import re
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: the program's RMSNorm epsilon (``tony_tpu/ops/norms.py``): a constant
@@ -15,41 +24,34 @@ PROGRAM_RMS_EPS = 1e-6
 
 
 def load(name: str) -> dict:
-    """``name`` of a file in ``configs/``, or a path ending in .json."""
+    """``name`` of a file in ``configs/``, or a path ending in .json. The
+    file names its family — a module of ``benchmark/families/``, or a path
+    ending in .py beside the configuration file — and that family checks
+    that its program block can express the configuration."""
     path = name if name.endswith(".json") else os.path.join(
         BENCH_DIR, "configs", f"{name}.json")
     with open(path) as f:
         c = json.load(f)
-    heads, d = c["num_attention_heads"], c["hidden_size"]
-    if c.get("head_dim", d // heads) * heads != d:
-        raise ValueError(f"{name}: head_dim x heads != hidden_size — the "
-                         f"program derives head_dim = d_model / n_heads")
-    if c["rope_theta"] != 10000.0 or c["hidden_act"] != "silu" \
-            or c["tie_word_embeddings"]:
-        raise ValueError(f"{name}: the program's block is RoPE base 10000, "
-                         f"SwiGLU, untied head")
+    if not isinstance(c.get("family"), str):
+        raise ValueError(f"{name}: no \"family\" key — a configuration "
+                         f"names the module under benchmark/families/ that "
+                         f"reads it")
+    if c["family"].endswith(".py"):
+        c["family"] = os.path.join(os.path.dirname(os.path.abspath(path)),
+                                   c["family"])
+    family(c).check(c, name)
     return c
 
 
-def program_kwargs(c: dict) -> dict:
-    """Keyword arguments of ``tony_tpu.models.transformer.TransformerConfig``
-    for this configuration (dtype and remat are the job script's)."""
-    return dict(vocab_size=c["vocab_size"], d_model=c["hidden_size"],
-                n_layers=c["num_hidden_layers"],
-                n_heads=c["num_attention_heads"],
-                n_kv_heads=c["num_key_value_heads"],
-                d_ff=c["intermediate_size"],
-                max_seq=c["max_position_embeddings"],
-                attn_window=c.get("sliding_window") or 0)
-
-
-def layer_params(c: dict) -> int:
-    d, f = c["hidden_size"], c["intermediate_size"]
-    kvw = c["num_key_value_heads"] * (d // c["num_attention_heads"])
-    return 2 * d * d + 2 * d * kvw + 3 * d * f + 2 * d
-
-
-def param_count(c: dict) -> int:
-    d = c["hidden_size"]
-    return (c["num_hidden_layers"] * layer_params(c)
-            + 2 * c["vocab_size"] * d + d)
+def family(c: dict):
+    """The module of a loaded configuration's family."""
+    name = c["family"]
+    if not name.endswith(".py"):
+        return importlib.import_module(f"benchmark.families.{name}")
+    modname = "bench_family_" + re.sub(r"\W", "_", name[:-3])
+    if modname not in sys.modules:
+        spec = importlib.util.spec_from_file_location(modname, name)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[modname] = mod
+    return sys.modules[modname]

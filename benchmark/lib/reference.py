@@ -1,21 +1,23 @@
-"""The plain reference: the decoder in straightforward float32 ``jax.numpy``
+"""The plain reference: a model in straightforward float32 ``jax.numpy``
 at ``default_matmul_precision("highest")`` — no kernels, no cache, no
 batching tricks, nothing imported from the program and nothing the program
-has made. It regenerates the seeded weights (``weights.py``) layer by
-layer, upcasts them, and runs a few sequences at a time, so it fits
-beside nothing.
-
-Departures from the published models, shared with the program and noted
-in each configuration file: RMSNorm epsilon 1e-6 (the program's constant),
-separate q/k/v and gate/up matrices (Phi-3 publishes them fused: same
-mathematics), sliding window = "query i sees keys j with 0 <= i-j < W".
+has made. This module is the DRIVER: embedding lookup -> the layers of the
+configuration's family, one jitted program per layer KIND with the layer
+index traced -> the family's head; the loss, its gradient one row at a
+time, the optimizer's first two steps, the served tokens' gaps. What one
+layer computes, which leaves it has and how they are seeded is the
+family's (``benchmark/families/<name>.py``). The driver regenerates the
+seeded weights layer by layer, upcasts them, and runs a few sequences at
+a time, so it fits beside nothing. What deals rows over devices (the
+four-chip cell) belongs here too, not in a family.
 
 ``mode`` recomputes the same mathematics with the matmul weights rounded
-to a lower precision — ``"int8"`` (per-output-channel absmax, the scheme
-of the program's own ``models/quantize.py``) or ``"fp8"`` (e4m3 after the
-same scaling). That is the CONTROL of the training cells, whose step has
-no lower precision of its own: it must come out as not correct. (The
-serving cells' control is the program's own int8 weights and int8 cache,
+to a lower precision over the contraction axes the family names —
+``"int8"`` (per-output-channel absmax, the scheme of the program's own
+``models/quantize.py``) or ``"fp8"`` (e4m3 after the same scaling). That
+is the CONTROL of the training cells, whose step has no lower precision of
+its own: it must come out as not correct. (The serving cells' control is
+the program's own int8 weights and int8 cache,
 ``tools/control_serve.py``.) ``None`` is the reference itself.
 """
 
@@ -24,16 +26,10 @@ from __future__ import annotations
 import functools
 import math
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from . import weights
-from .modelcfg import PROGRAM_RMS_EPS
-
-_CONTRACT = {"wq": (0,), "wk": (0,), "wv": (0,), "wo": (0, 1),
-             "w_gate": (0,), "w_up": (0,), "w_down": (0,), "lm_head": (0,)}
-_NEG = -1e30
+from .lazyjax import jax, jnp
+from .modelcfg import PROGRAM_RMS_EPS, family
 
 
 def _lower(w, mode, axes):
@@ -51,72 +47,10 @@ def _lower(w, mode, axes):
     raise ValueError(f"unknown control precision {mode!r}")
 
 
-def _f32(tree, mode):
-    return {n: _lower(w.astype(jnp.float32), mode, _CONTRACT[n])
-            if n in _CONTRACT else w.astype(jnp.float32)
-            for n, w in tree.items()}
-
-
 def rms_norm(x, w):
     rms = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
                         + PROGRAM_RMS_EPS)
     return x * rms * w
-
-
-def rope(x, positions):
-    """[B, S, H, D] rotated by position, halves convention, base 10000."""
-    half = x.shape[-1] // 2
-    freqs = jnp.exp(-jnp.arange(half, dtype=jnp.float32)
-                    * (math.log(10000.0) / half))
-    ang = positions[:, :, None, None].astype(jnp.float32) * freqs
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * jnp.cos(ang) - x2 * jnp.sin(ang),
-                            x1 * jnp.sin(ang) + x2 * jnp.cos(ang)], -1)
-
-
-def attention(q, k, v, window: int):
-    """Causal (and windowed) softmax attention, grouped-query aware, over
-    blocks of query rows so the score matrix never exceeds ~1 GiB. Each
-    block is rematerialised in the backward pass."""
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    g = h // kv
-    bq = s
-    while b * h * bq * s * 4 > (1 << 30) and bq % 2 == 0 and bq > 128:
-        bq //= 2
-    q = q.reshape(b, s // bq, bq, kv, g, d)
-    kpos = jnp.arange(s)
-
-    @jax.checkpoint
-    def block(args):
-        qb, i0 = args                                 # [b, bq, kv, g, d]
-        sc = jnp.einsum("bqkgd,bskd->bkgqs", qb, k) * (d ** -0.5)
-        qpos = i0 + jnp.arange(bq)
-        mask = qpos[:, None] >= kpos[None, :]
-        if window:
-            mask = mask & (qpos[:, None] - kpos[None, :] < window)
-        p = jax.nn.softmax(jnp.where(mask, sc, _NEG), axis=-1)
-        return jnp.einsum("bkgqs,bskd->bqkgd", p, v)
-
-    out = jax.lax.map(block, (jnp.moveaxis(q, 1, 0),
-                              jnp.arange(s // bq) * bq))
-    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
-
-
-def block_forward(x, p, window: int):
-    """One decoder block on [B, S, d] float32."""
-    b, s, _ = x.shape
-    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-    hdn = rms_norm(x, p["attn_norm"])
-    q = rope(jnp.einsum("bsd,dhk->bshk", hdn, p["wq"]), pos)
-    k = rope(jnp.einsum("bsd,dhk->bshk", hdn, p["wk"]), pos)
-    v = jnp.einsum("bsd,dhk->bshk", hdn, p["wv"])
-    o = attention(q, k, v, window)
-    x = x + jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    hdn = rms_norm(x, p["mlp_norm"])
-    inner = (jax.nn.silu(jnp.einsum("bsd,df->bsf", hdn, p["w_gate"]))
-             * jnp.einsum("bsd,df->bsf", hdn, p["w_up"]))
-    return x + jnp.einsum("bsf,fd->bsd", inner, p["w_down"])
 
 
 def cross_entropy_sum(logits, targets):
@@ -128,48 +62,61 @@ def cross_entropy_sum(logits, targets):
 class Reference:
     """The reference for one configuration and seed. Every method is one
     jitted program that makes the weights it needs from the seed inside
-    itself; ``li`` is traced, so all layers share one compilation."""
+    itself; ``li`` is traced, so all layers of one kind share one
+    compilation."""
 
     def __init__(self, c: dict, seed: int, mode: str | None = None,
-                 weight_dtype=jnp.bfloat16) -> None:
+                 weight_dtype="bfloat16") -> None:
+        fam = family(c)
         self.seed = np.uint32(seed % (1 << 32))
-        self.L = c["num_hidden_layers"]
-        window = c.get("sliding_window") or 0
+        self.kinds = fam.layer_kinds(c)
+        #: gradient name -> (layer, leaf) of a layer's leaf; an outer leaf
+        #: goes by its own name and is not here
+        self.where: dict[str, tuple[int, str]] = {}
+        self._family = fam
         hi = functools.partial(jax.default_matmul_precision, "highest")
 
-        def layer_p(seed, li):
-            return _f32(weights.layer(seed, li, c, weight_dtype), mode)
+        def f32(tree):
+            return {n: _lower(w.astype(jnp.float32), mode, fam.CONTRACT[n])
+                    if n in fam.CONTRACT else w.astype(jnp.float32)
+                    for n, w in tree.items()}
+
+        def layer_p(seed, li, kind):
+            return f32(fam.layer_weights(seed, li, c, weight_dtype, kind))
 
         def outer_p(seed):
-            return _f32(weights.outer(seed, c, weight_dtype), mode)
+            return f32(fam.outer_weights(seed, c, weight_dtype))
 
         @jax.jit
         def embed(seed, tokens):
             return outer_p(seed)["embed"][tokens]
 
-        @jax.jit
-        def layer_fwd(seed, li, x):
-            with hi():
-                return block_forward(x, layer_p(seed, li), window)
+        def layer_programs(kind):
+            @jax.jit
+            def layer_fwd(seed, li, x):
+                with hi():
+                    return fam.layer_forward(x, layer_p(seed, li, kind), c,
+                                             kind)
 
-        @jax.jit
-        def layer_bwd(seed, li, x, dy):
-            with hi():
-                _, vjp = jax.vjp(
-                    lambda p, x: block_forward(x, p, window),
-                    layer_p(seed, li), x)
-                return vjp(dy)
+            @jax.jit
+            def layer_bwd(seed, li, x, dy):
+                with hi():
+                    _, vjp = jax.vjp(
+                        lambda p, x: fam.layer_forward(x, p, c, kind),
+                        layer_p(seed, li, kind), x)
+                    return vjp(dy)
+
+            return layer_fwd, layer_bwd
 
         def head(o, x):
-            return jnp.einsum("bsd,dv->bsv", rms_norm(x, o["final_norm"]),
-                              o["lm_head"])
+            return fam.head(o, x, c)
 
         @jax.jit
         def head_loss(seed, x, targets, count):
             """(sum CE) / count for these rows, with its gradients."""
             with hi():
                 o = outer_p(seed)
-                o = {"final_norm": o["final_norm"], "lm_head": o["lm_head"]}
+                o = {n: o[n] for n in fam.HEAD_LEAVES}
                 loss, (go, dx) = jax.value_and_grad(
                     lambda o, x: cross_entropy_sum(head(o, x), targets)
                     / count, argnums=(0, 1))(o, x)
@@ -188,14 +135,16 @@ class Reference:
             picked = jnp.take_along_axis(lg, nxt[..., None], -1)[..., 0]
             return jnp.max(lg, axis=-1) - picked
 
+        v, d = jax.eval_shape(outer_p, self.seed)["embed"].shape
+
         @jax.jit
         def embed_grad(tokens, dx):
-            v, d = c["vocab_size"], c["hidden_size"]
             return jnp.zeros((v, d), jnp.float32).at[tokens.reshape(-1)].add(
                 dx.reshape(-1, d))
 
-        self._embed, self._layer_fwd, self._layer_bwd = (embed, layer_fwd,
-                                                         layer_bwd)
+        self._embed = embed
+        self._layer = {kind: layer_programs(kind)
+                       for kind in dict.fromkeys(self.kinds)}
         self._head_loss, self._head_logits = head_loss, head_logits
         self._head_gaps = head_gaps
         self._embed_grad = embed_grad
@@ -206,8 +155,8 @@ class Reference:
         hidden state, or every layer's input when ``keep``."""
         x = self._embed(self.seed, tokens)
         xs = [x]
-        for li in range(self.L):
-            x = self._layer_fwd(self.seed, np.int32(li), x)
+        for li, kind in enumerate(self.kinds):
+            x = self._layer[kind][0](self.seed, np.int32(li), x)
             if keep:
                 xs.append(x)
         return xs if keep else x
@@ -218,7 +167,8 @@ class Reference:
     # ------------------------------------------------------- one gradient
     def loss_and_grads(self, inputs: np.ndarray, targets: np.ndarray):
         """Mean next-token loss of one batch and its gradient, one row at
-        a time. Returns (loss, {"leaf/li" or "leaf": array})."""
+        a time. Returns (loss, {name: array}): a layer's leaf under the
+        family's ``leaf_name(li, leaf)``, an outer leaf under its own."""
         count = np.float32(inputs.size)
         rows = [{"tok": inputs[r:r + 1], "tgt": targets[r:r + 1],
                  "xs": self.hidden(inputs[r:r + 1], keep=True)}
@@ -232,14 +182,16 @@ class Reference:
             ls, go, row["dx"] = self._head_loss(
                 self.seed, row["xs"].pop(), row["tgt"], count)
             loss = loss + float(ls)
-            add("final_norm", go["final_norm"])
-            add("lm_head", go["lm_head"])
-        for li in reversed(range(self.L)):
+            for n in self._family.HEAD_LEAVES:
+                add(n, go[n])
+        for li in reversed(range(len(self.kinds))):
             for row in rows:
-                gp, row["dx"] = self._layer_bwd(
+                gp, row["dx"] = self._layer[self.kinds[li]][1](
                     self.seed, np.int32(li), row["xs"].pop(), row["dx"])
                 for n, g in gp.items():
-                    add(f"blocks/{n}/{li}", g)
+                    name = self._family.leaf_name(li, n)
+                    self.where[name] = (li, n)
+                    add(name, g)
         for row in rows:
             add("embed", self._embed_grad(row["tok"], row["dx"]))
         return loss, grads
@@ -251,7 +203,7 @@ def _sumsq(tree) -> float:
 
 def train_two_steps(c: dict, seed: int, batches, opt: dict,
                     mode: str | None = None,
-                    weight_dtype=jnp.bfloat16) -> dict:
+                    weight_dtype="bfloat16") -> dict:
     """Follow the program's first two optimizer steps (optax
     ``clip_by_global_norm(1) -> adamw`` under linear warm-up from 0, as
     ``tony_tpu.models.train.default_optimizer`` builds it) in float32.
@@ -259,6 +211,7 @@ def train_two_steps(c: dict, seed: int, batches, opt: dict,
     seeded weights and the parameters' change after two steps is step 1's
     update. Returns the two losses, each leaf's norm of the first clipped
     gradient and of the change."""
+    fam = family(c)
     ref = Reference(c, seed, mode, weight_dtype)
     (l0, g0), (l1, g1) = (ref.loss_and_grads(i, t) for i, t in batches[:2])
     b1, b2, eps = 0.9, 0.999, 1e-8
@@ -279,14 +232,14 @@ def train_two_steps(c: dict, seed: int, batches, opt: dict,
            "global_grad_norm": [math.sqrt(_sumsq(g)) for g in (g0, g1)]}
     outer_w = None
     for name in g0:
-        parts = name.split("/")
-        if parts[0] == "blocks":
+        if name in ref.where:
             # the change is of the TRUE weights, whatever ``mode``
-            p = weights.layer(ref.seed, np.int32(int(parts[2])), c,
-                              weight_dtype)[parts[1]]
+            li, leaf = ref.where[name]
+            p = fam.layer_weights(ref.seed, np.int32(li), c, weight_dtype,
+                                  ref.kinds[li])[leaf]
         else:
             if outer_w is None:
-                outer_w = weights.outer(ref.seed, c, weight_dtype)
+                outer_w = fam.outer_weights(ref.seed, c, weight_dtype)
             p = outer_w[name]
         gn, dn = finish(g0[name], g1[name], p.astype(jnp.float32),
                         np.float32(clip[0]), np.float32(clip[1]))
@@ -309,7 +262,7 @@ def worst_leaf_gap(program: dict, reference: dict) -> tuple[float, str]:
 
 
 def served_token_gaps(c: dict, seed: int, samples, widths, rows: int = 8,
-                      weight_dtype=jnp.bfloat16) -> list[np.ndarray]:
+                      weight_dtype="bfloat16") -> list[np.ndarray]:
     """For served requests ``[(prompt, tokens), ...]``: run the reference
     once over each prompt with its served tokens and return, per request,
     the gaps by which each served token's logit lies below the reference's

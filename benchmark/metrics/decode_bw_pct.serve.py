@@ -1,9 +1,10 @@
 """Model step (models/decode.py): the bytes a decode step must read — the
-weights once plus the live cache rows, from shapes (``lib/flops.py``) —
-over peak HBM bytes/s, over the step's device time: the decode chunk
-program's traced device time divided by its steps."""
+weights once plus the live cache rows, counted from shapes by the
+configuration's family (``families/<name>.py``) — over peak HBM bytes/s,
+over the step's device time: the decode chunk program's traced device
+time divided by its steps."""
 
-from benchmark.lib import flops, xplane
+from benchmark.lib import modelcfg, xplane
 
 
 def read(ctx):
@@ -12,5 +13,6 @@ def read(ctx):
     if not chunks or ctx["peaks"] is None:
         return None
     step_s = sum(e[2] for e in chunks) / 1e9 / (len(chunks) * k["chunk"])
-    need = flops.decode_step_bytes(ctx["c"], k["mean_live_rows"])
+    need = modelcfg.family(ctx["c"]).decode_step_bytes(
+        ctx["c"], k["mean_live_rows"], ctx)
     return 100.0 * need / ctx["peaks"]["hbm_bytes_per_s"] / step_s

@@ -6,7 +6,7 @@ that names nothing (the parent) reads as None, never as an error."""
 import pytest
 
 from benchmark import run as bench_run
-from benchmark.lib import flash_kernels, flops, modelcfg
+from benchmark.lib import flash_kernels, modelcfg
 
 
 def _read(name, ctx):
@@ -47,8 +47,9 @@ def test_forward_and_backward_sum_to_the_step_count():
     for config, batch, seq in (("mistral-7b-l4", 2, 8192),
                                ("phi-3-mini-4k-l24", 4, 1024)):
         c = modelcfg.load(config)
-        part = flash_kernels.layer_flops_bytes(c, batch, seq)
-        fl, by = flops.flash_train_flops_bytes(c, batch, seq)
+        fam = modelcfg.family(c)
+        part = fam.flash_layer_flops_bytes(c, batch, seq)
+        fl, by = fam.flash_train_flops_bytes(c, batch, seq)
         layers = c["num_hidden_layers"]
         assert layers * (part["fwd"][0] + part["bwd"][0]) == \
             pytest.approx(fl, rel=1e-12)
@@ -57,7 +58,7 @@ def test_forward_and_backward_sum_to_the_step_count():
 
 def test_mistral_layer_hand_count():
     c = modelcfg.load("mistral-7b-l4")
-    part = flash_kernels.layer_flops_bytes(c, 2, 8192)
+    part = modelcfg.family(c).flash_layer_flops_bytes(c, 2, 8192)
     # 3072.25 keys attended on average at 8,192 with window 4,096
     pairs = 2 * 8192 * 3072.25
     assert part["fwd"][0] == pytest.approx(4 * pairs * 4096)
@@ -79,7 +80,7 @@ def _train_ctx(ops):
 
 def test_kernel_rooflines_per_call():
     c = modelcfg.load("mistral-7b-l4")
-    part = flash_kernels.layer_flops_bytes(c, 2, 8192)
+    part = modelcfg.family(c).flash_layer_flops_bytes(c, 2, 8192)
     least_f = part["fwd"][0] / 197e12            # FLOP-bound, both
     least_b = part["bwd"][0] / 197e12
     assert least_f > part["fwd"][1] / 819e9

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.lib import modelcfg, reference, traffic, weights
+from benchmark.lib import modelcfg, reference, traffic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SEED = 2**31 + 11
@@ -20,11 +20,10 @@ OPT = {"lr": 3e-4, "weight_decay": 0.01, "warmup_steps": 1,
 
 @pytest.fixture(scope="module")
 def tiny():
-    from tony_tpu.models import transformer as T
     c = modelcfg.load(os.path.join(HERE, "tiny.json"))
-    cfg = T.TransformerConfig(**modelcfg.program_kwargs(c),
-                              dtype=jnp.float32)
-    return c, cfg, weights.make_params(SEED, c, jnp.float32)
+    fam = modelcfg.family(c)
+    return (c, fam.program_config(c, dtype=jnp.float32),
+            fam.make_params(SEED, c, jnp.float32))
 
 
 def _batches(c, b=2, s=64):
@@ -36,7 +35,8 @@ def _batches(c, b=2, s=64):
 def test_weights_are_the_same_stacked_and_layer_by_layer(tiny):
     c, _, params = tiny
     for li in range(c["num_hidden_layers"]):
-        one = weights.layer(np.uint32(SEED), np.int32(li), c, jnp.float32)
+        one = modelcfg.family(c).layer_weights(
+            np.uint32(SEED), np.int32(li), c, jnp.float32)
         for name, w in one.items():
             # the same draws; XLA's fusion may differ by one float32 ulp
             np.testing.assert_allclose(w, params["blocks"][name][li],

@@ -20,11 +20,12 @@ def test_flops_mistral_hand_count():
     # ((4096*4097/2 + 4096*4096)/8192); head 2*4096*32000
     layer = (2 * 2 * 4096 * 4096 + 2 * 2 * 4096 * 1024
              + 3 * 2 * 4096 * 14336 + 4 * 3072.25 * 4096)
-    assert flops.forward_flops_per_token(c, 8192) == pytest.approx(
+    fam = modelcfg.family(c)
+    assert fam.forward_flops_per_token(c, 8192) == pytest.approx(
         4 * layer + 2 * 4096 * 32000, rel=1e-12)
     assert flops.train_flops_per_token(c, 8192) / 1e9 == pytest.approx(
         6.625, abs=0.001)
-    assert modelcfg.param_count(c) == 4 * 218_112_000 + 2 * 131_072_000 + 4096
+    assert fam.param_count(c) == 4 * 218_112_000 + 2 * 131_072_000 + 4096
 
 
 def test_flops_phi3_hand_count():
@@ -32,18 +33,19 @@ def test_flops_phi3_hand_count():
     # MHA: four 3072x3072 projections; MLP 3 x 3072x8192; full causal at
     # 1,024 (window 2,047 never binds): 512.5 keys on average
     layer = 4 * 2 * 3072 * 3072 + 3 * 2 * 3072 * 8192 + 4 * 512.5 * 3072
-    assert flops.forward_flops_per_token(c, 1024) == pytest.approx(
+    fam = modelcfg.family(c)
+    assert fam.forward_flops_per_token(c, 1024) == pytest.approx(
         24 * layer + 2 * 3072 * 32064, rel=1e-12)
-    assert modelcfg.param_count(c) == pytest.approx(2.915e9, rel=1e-3)
+    assert fam.param_count(c) == pytest.approx(2.915e9, rel=1e-3)
     # a decode step reads every matmul weight once and the live cache:
     # 294,912 bytes a token at 24 layers of 32 x 96 K and V in bf16
-    one = flops.decode_step_bytes(c, 1.0) - flops.decode_step_bytes(c, 0.0)
+    one = fam.decode_step_bytes(c, 1.0) - fam.decode_step_bytes(c, 0.0)
     assert one == 294_912
 
 
 def test_flash_shape_function():
     c = modelcfg.load("mistral-7b-l4")
-    fl, by = flops.flash_train_flops_bytes(c, 2, 8192)
+    fl, by = modelcfg.family(c).flash_train_flops_bytes(c, 2, 8192)
     pairs = 2 * 8192 * 3072.25
     assert fl == pytest.approx(4 * 14 * pairs * 4096)
     # fwd q,o (4096 wide) + k,v (1024 wide); bwd q,o,do,dq + k,v,dk,dv
