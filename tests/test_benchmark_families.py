@@ -1,0 +1,72 @@
+"""Tier 1 runs the benchmark's own cases of the seam between the harness
+and a model family (``benchmark/tests/test_families.py``: imported, not
+copied), which ``python -m pytest benchmark/tests`` alone collected.
+
+One of them is written for a dense family: ``test_counts_are_the_trees``
+ends by holding a token's forward FLOPs above twice EVERY parameter and a
+decode step's bytes at two a parameter. A sparse family cannot meet
+either (a token meets 8 x 12 / 384 of the routed experts here, and the
+router is float32), and the file is the benchmark's, which this
+repository's program PRs may not edit. So ``python -m pytest
+benchmark/tests`` is RED for a sparse configuration until a ``benchmark``
+PR makes that inequality the family's own (PERF.md section 7), and here
+the imported case is an expected failure for it — on that one statement:
+an assertion that fails earlier (the tree equalities) fails this test,
+and so does the case passing. The same tree equalities stand as positive
+assertions in ``test_a_sparse_family_counts_its_trees`` below and, with
+the published numbers, in ``tests/test_latent_moe.py``.
+"""
+
+import sys
+import traceback
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.lib import modelcfg
+from benchmark.tests import test_families as cases
+from benchmark.tests.test_families import (  # noqa: F401 — collected here
+    test_dense_weights_are_the_parents_bit_for_bit,
+    test_family_provides_the_whole_list,
+    test_refused_with_the_reason,
+    test_the_parent_of_a_run_never_imports_jax)
+
+
+#: first line of the statement that only a dense family can meet
+DENSE_ONLY = "assert fam.forward_flops_per_token(c, 1024) > 2 * fam.param_count(c)"
+SPARSE = [n for n in cases.CONFIGS
+          if modelcfg.load(n)["family"] != "dense_decoder"]
+
+
+@pytest.mark.parametrize("config", cases.CONFIGS)
+def test_counts_are_the_trees(config):
+    if config not in SPARSE:
+        return cases.test_counts_are_the_trees(config)
+    try:
+        cases.test_counts_are_the_trees(config)
+    except AssertionError:
+        at = traceback.extract_tb(sys.exc_info()[2])[-1]
+        if not at.line.startswith(DENSE_ONLY):
+            raise                   # a real miscount, not the known one
+        pytest.xfail("benchmark/tests/test_families.py::"
+                     "test_counts_are_the_trees holds forward FLOPs a token "
+                     "above 2 x every parameter: dense-only, the "
+                     "benchmark's to make family-aware")
+    pytest.fail("the dense-only inequality holds for a sparse family now: "
+                "run the imported case whole")
+
+
+@pytest.mark.parametrize("config", SPARSE)
+def test_a_sparse_family_counts_its_trees(config):
+    """What the imported case asserts before its dense-only end."""
+    from tony_tpu.models import transformer as T
+    c = modelcfg.load(config)
+    fam = modelcfg.family(c)
+    made = jax.eval_shape(lambda: fam.make_params(7, c, jnp.bfloat16))
+    own = jax.eval_shape(lambda: T.init_params(
+        jax.random.PRNGKey(0), fam.program_config(c, dtype=jnp.bfloat16)))
+    assert fam.param_count(c) == cases._size(made) == cases._size(own)
+    assert jax.tree.map(lambda x: (x.shape, x.dtype), made) == \
+        jax.tree.map(lambda x: (x.shape, x.dtype), own)
+    assert len(fam.layer_kinds(c)) == c["num_hidden_layers"]

@@ -251,3 +251,107 @@ def test_step_rows_never_copies_the_cache(chip, name):
     sliced = re.findall(r"copy-start[.\d]* = \(bf16\[1," + str(d_model)
                         + r",\d+,\d+\]", text)
     assert not sliced, sliced
+
+
+# ---------------------------------------------------------------------------
+# Latent attention + sparse experts at Kimi-K2.5's widths (PR 28)
+# ---------------------------------------------------------------------------
+
+def _kimi_cfg(expert_layers: int):
+    """Kimi-K2.5's widths with ONE dense layer and ``expert_layers`` of
+    12 held experts (of 384), 20,480 vocabulary rows: the cell's
+    configuration at a depth that compiles in seconds."""
+    from tony_tpu.models import transformer as T
+    return T.TransformerConfig(
+        vocab_size=20480, d_model=7168, n_layers=1 + expert_layers,
+        n_heads=64, d_ff=18432, dtype=jnp.bfloat16, remat=False,
+        rms_eps=1e-5, rope_base=50000.0,
+        rope_scaling=T.RopeYarn(64.0, 32.0, 1.0, 4096, 1.0, 1.0),
+        layer_kinds=("dense",) + ("moe",) * expert_layers,
+        latent=T.LatentAttention(1536, 512, 128, 64, 128),
+        experts=T.SparseExperts(384, 8, 2048, 2.827, 0, 12))
+
+
+@pytest.mark.parametrize("rows,tm,k,n", [
+    (448, 16, 7168, 2048), (448, 16, 2048, 7168),       # a decode step
+    (2048, 256, 7168, 2048), (2048, 256, 2048, 7168)])  # a prefill chunk
+def test_grouped_matmul_compiles_for_v5e(chip, rows, tm, k, n):
+    """The routed-expert product (``tony_moe_gmm``) at the published
+    expert shapes, 5 layers x 12 experts stacked: scalar-prefetched tile
+    groups, skipped tiles, blocks inside VMEM."""
+    from tony_tpu.ops.grouped_matmul import grouped_matmul
+    text = chip.compile(
+        lambda lhs, rhs, tg, nt: grouped_matmul(
+            lhs, rhs, tg, nt, tm=tm, out_dtype=jnp.float32),
+        chip.shape((rows, k)), chip.shape((60, k, n)),
+        chip.shape((rows // tm,), jnp.int32), chip.shape((), jnp.int32))
+    assert "tony_moe_gmm" in text
+
+
+def test_latent_prompt_attention_fits_vmem(chip):
+    """The expanded prefill at 32 slots x the 512 bucket: 64 heads of
+    192 (v padded from 128) through the flash kernel at the narrower
+    blocks ``_latent_prompt_attention`` asks for — the defaults are 23 MB
+    of the 16 MB of VMEM at this head width."""
+    from tony_tpu.models import decode as D
+    cfg = _kimi_cfg(1)
+    la = cfg.latent
+    text = chip.compile(
+        lambda q_n, q_r, row, wkv_b: D._latent_prompt_attention(
+            q_n, q_r, row, {"wkv_b": wkv_b}, cfg),
+        chip.shape((32, 512, 64, la.nope_dim)),
+        chip.shape((32, 512, 64, la.rope_dim)),
+        chip.shape((32, 512, 1, la.stored_row)),
+        chip.shape((la.kv_rank, 64, la.nope_dim + la.v_dim)))
+    assert "tony_flash_fwd" in text
+
+
+def test_latent_step_rows_never_copies_the_cache_or_an_expert_layer(chip):
+    """The decode chunk at the cell's 32 slots x 2,048 rows: the latent
+    buffer ([L, B, rows, 640]: 576 stored in whole lane tiles) keeps ONE
+    layout from the arguments through the read loops to the results — at
+    576 minor the compiler kept the rows minor inside the loop and copied
+    the whole cache around every layer's write — and the routed experts
+    reach their kernel stacked: no layer's 1 GB of expert weights is
+    copied out for the call (the temporaries stay under ONE expert's
+    three matrices)."""
+    import re
+
+    from tony_tpu.models import decode as D
+    from tony_tpu.models import serve as S
+    from tony_tpu.models import transformer as T
+    cfg = _kimi_cfg(2)
+    slots, rows = 32, 2048
+    params = chip.place(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(lambda: D.init_kv_cache(cfg, slots, rows))
+    cache = chip.place(dict(cache, length=chip.shape((slots,), jnp.int32)))
+    compiled = S.step_rows.lower(
+        params, cache,
+        chip.shape((slots, cfg.vocab_size), cfg.logits_storage_dtype),
+        chip.shape((slots, 2), jnp.uint32), chip.shape((slots,), jnp.int32),
+        n=8, cfg=cfg).compile()
+    text = compiled.as_text()
+    buf = cache["ckv"]
+    assert buf.shape == (3, slots, rows, 640)
+    dims = ",".join(str(d) for d in buf.shape)
+    assert not re.findall(r"bf16\[" + dims + r"\]\{[^}]*\} copy\(", text)
+    assert len(set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})",
+                              text))) == 1
+    assert text.count("tony_moe_gmm") >= 6        # 2 layers x gate/up/down
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 7168 * 2048 * 2
+
+
+def test_latent_prompt_attention_takes_an_unaligned_short_prompt(chip):
+    """A prompt of at most 256 that is no multiple of 128 (``prefill``
+    leaves it unpadded) takes the dense arm instead of failing the
+    narrower q block's divisibility (found on the chip, PR 28)."""
+    from tony_tpu.models import decode as D
+    cfg = _kimi_cfg(1)
+    la = cfg.latent
+    jax.jit(lambda q_n, q_r, row, wkv_b: D._latent_prompt_attention(
+        q_n, q_r, row, {"wkv_b": wkv_b}, cfg)).lower(
+        chip.shape((2, 255, 64, la.nope_dim)),
+        chip.shape((2, 255, 64, la.rope_dim)),
+        chip.shape((2, 255, 1, la.stored_row)),
+        chip.shape((la.kv_rank, 64, la.nope_dim + la.v_dim))).compile()

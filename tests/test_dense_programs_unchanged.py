@@ -1,0 +1,81 @@
+"""The dense programs do not change when a model with more than one kind
+of layer arrives beside them: the lowered text of ``step_rows``,
+``admit_rows`` and the gradient of ``lm_loss`` for the two dense
+configurations' shapes of layer (MHA of 96 full-causal; GQA 4/2 with a
+window) at toy width is, hash for hash, the text of the commit before
+latent attention and sparse experts (06229a9, PR 27): no new operand, no
+new output (``serve._device_stats`` is an EMPTY pytree there), no
+reordered op. A later PR that means to change a dense program replaces
+the hash it changed, and says so; run this file as a script with a
+checkout's root to print that checkout's hashes.
+"""
+
+import hashlib
+import os
+import sys
+
+import pytest
+
+#: sha256[:16] of the lowered text at 06229a9 (PR 27), CPU backend
+PARENT = {
+    "mistral.admit_rows": "e950ef452b1d16c7",
+    "mistral.grad": "b30bf7cc7e203f12",
+    "mistral.step_rows": "e71b707f3a28fcb1",
+    "phi.admit_rows": "32ad1762b4f0f5e8",
+    "phi.grad": "05a842aa6f92e610",
+    "phi.step_rows": "51240cd8f81fe970",
+}
+
+
+def lowered(which: str) -> str:
+    import jax
+    import jax.numpy as jnp
+    from tony_tpu.models import decode as D
+    from tony_tpu.models import serve as S
+    from tony_tpu.models import transformer as T
+    model, program = which.split(".")
+    cfg = {
+        "phi": T.TransformerConfig(
+            vocab_size=512, d_model=192, n_layers=2, n_heads=2, d_ff=256,
+            max_seq=1024, dtype=jnp.bfloat16, remat=False),
+        "mistral": T.TransformerConfig(
+            vocab_size=512, d_model=128, n_layers=2, n_heads=4,
+            n_kv_heads=2, d_ff=256, max_seq=1024, attn_window=64,
+            dtype=jnp.bfloat16),
+    }[model]
+    slots = 4
+    sds = jax.ShapeDtypeStruct
+    params = jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    if program == "grad":
+        return jax.jit(jax.value_and_grad(
+            lambda p, b: T.lm_loss(p, b, cfg))).lower(
+                params, {"tokens": sds((2, 129), jnp.int32)}).as_text()
+    cache = jax.eval_shape(lambda: dict(
+        D.init_kv_cache(cfg, slots, 640),
+        length=jnp.zeros((slots,), jnp.int32)))
+    logits = sds((slots, cfg.vocab_size), cfg.logits_storage_dtype)
+    rows = sds((slots,), jnp.int32)
+    if program == "step_rows":
+        return S.step_rows.lower(params, cache, logits,
+                                 sds((slots, 2), jnp.uint32), rows, 8,
+                                 cfg).as_text()
+    return S.admit_rows.lower(params, cache, logits, rows,
+                              sds((slots, 64), jnp.int32), rows,
+                              cfg).as_text()
+
+
+def digest(which: str) -> str:
+    return hashlib.sha256(lowered(which).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("which", sorted(PARENT))
+def test_lowered_text_is_the_parents(which):
+    assert digest(which) == PARENT[which]
+
+
+if __name__ == "__main__":
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    sys.path.insert(0, os.path.abspath(sys.argv[1]))
+    for name in sorted(PARENT):
+        print(name, digest(name))

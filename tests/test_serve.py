@@ -890,3 +890,25 @@ class TestPipelinedServingSmoke:
                                chunk=4, temperature=0.8, top_k=30,
                                seed=1, eos_id=eos)
         assert b3.serve(prompts, budgets) == b2.serve(prompts, budgets)
+
+
+def test_wide_wave_admits_in_dispatches_of_at_most_eight_rows(params,
+                                                               retrace_guard):
+    """12 slots filled at once: a bucket's admissions go through in
+    dispatches of at most ``_ADMIT_ROWS_MAX`` rows (one program a bucket
+    at that width, not at the slot count), every request still equals
+    its solo greedy generate, and a batcher of up to 8 slots keeps the
+    full-width dispatch it always had."""
+    from tony_tpu.models import serve as S
+    rng = np.random.RandomState(11)
+    prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
+               for n in (5, 3, 7, 4, 6, 3, 9, 12, 5, 8, 11, 4, 6, 13)]
+    batcher = ContinuousBatcher(params, CFG, batch=12, max_len=32, chunk=4)
+    assert batcher._admit_width == S._ADMIT_ROWS_MAX == 8
+    outs = batcher.serve(prompts, max_new_tokens=5)
+    for i, p in enumerate(prompts):
+        assert outs[i] == _reference(params, p, 5), f"request {i}"
+    shapes = retrace_guard.new_traces("admit_rows")
+    assert shapes and all(shape[0] == 8 for shape in shapes), shapes
+    assert ContinuousBatcher(params, CFG, batch=6,
+                             max_len=32)._admit_width == 6

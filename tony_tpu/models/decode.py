@@ -45,7 +45,8 @@ from tony_tpu.models import transformer as T
 from tony_tpu.models.quantize import QuantizedWeight
 from tony_tpu.ops import mosaic
 from tony_tpu.ops.norms import rms_norm_reference
-from tony_tpu.parallel.moe import moe_ffn
+from tony_tpu.parallel.moe import (HeldExperts, held_experts_ffn, moe_ffn,
+                                   sigmoid_route)
 
 
 #: TOKEN POSITIONS (the sequence axis, NOT batch x seq) STRICTLY ABOVE
@@ -136,10 +137,19 @@ def _kv_spec(cfg: T.TransformerConfig) -> dict:
     """name → (per-head width, dtype) of each position buffer: k/v hold
     ``head_dim`` values per (token, kv-head); an int8 cache adds one f32
     absmax scale per (token, kv-head) beside each."""
+    if cfg.kinded:
+        # latent attention: ONE compressed row a token, [c_kv; k_rope],
+        # shared by every head (kv heads = 1) — see _latent_qkv
+        return {"ckv": (cfg.latent.stored_row, cfg.dtype)}
     if cfg.kv_quant:
         return {"k": (cfg.head_dim, jnp.int8), "v": (cfg.head_dim, jnp.int8),
                 "k_scale": (1, jnp.float32), "v_scale": (1, jnp.float32)}
     return {"k": (cfg.head_dim, cfg.dtype), "v": (cfg.head_dim, cfg.dtype)}
+
+
+def _kv_heads(cfg: T.TransformerConfig) -> int:
+    """Heads a stored row holds: the latent row is one for all heads."""
+    return 1 if cfg.kinded else cfg.kv_heads
 
 
 def init_kv_cache(cfg: T.TransformerConfig, batch: int,
@@ -195,14 +205,35 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
             f"attn_window={cfg.attn_window}: ring-cache attention reads "
             "every capacity row per token (O(capacity), not O(window)) — "
             "size the capacity near the window", stacklevel=2)
-    cache = {n: jnp.zeros((cfg.n_layers, batch, rows, cfg.kv_heads * w), dt)
+    cache = {n: jnp.zeros((cfg.n_layers, batch, rows, _kv_heads(cfg) * w),
+                          dt)
              for n, (w, dt) in _kv_spec(cfg).items()}
+    if cfg.experts is not None:
+        # what the expert layers counted since the holding program began
+        # (assignments landed on held experts, held experts touched):
+        # state the 'moe' layers own, riding the cache through the layer
+        # loop; serve.step_rows / admit_rows hand it out with the tokens
+        cache[MOE_COUNTS] = jnp.zeros((2,), jnp.int32)
     return dict(cache, length=jnp.zeros((), jnp.int32))
 
 
 #: cache keys that hold per-position buffers (and so follow every write/
-#: gather/tile path together); "length" is the only non-buffer key
-_KV_BUFS = ("k", "v", "k_scale", "v_scale")
+#: gather/tile path together); "length" and the expert layers' counters
+#: (``MOE_COUNTS``) are the only non-buffer keys
+_KV_BUFS = ("k", "v", "k_scale", "v_scale", "ckv")
+MOE_COUNTS = "moe_counts"
+
+
+def _kv_state(cache: dict) -> dict:
+    """Everything a layer loop threads: the buffers, and the expert
+    layers' counters where the model has them. For the dense decoder
+    this is :func:`_kv_bufs`."""
+    return {n: a for n, a in cache.items() if n != "length"}
+
+
+def cache_rows(cache: dict) -> int:
+    """Positions a cache (or mini cache, or template) holds per slot."""
+    return next(iter(_kv_bufs(cache).values())).shape[2]
 
 
 def _kv_bufs(cache: dict) -> dict:
@@ -291,6 +322,8 @@ def kv_wire_layout(cfg: T.TransformerConfig) -> dict:
     """name → ``ShapeDtypeStruct`` of ONE position of every buffer in the
     WIRE form [L, 1, 1, KV, w]: what a shipped ``KVPackage`` or prefix
     template is validated against (layers, heads, head_dim, dtype)."""
+    cfg.refuse("KV shipping and prefix templates (the wire form is K and "
+               "V rows per head)")
     return {n: jax.ShapeDtypeStruct(
                 (cfg.n_layers, 1, 1, cfg.kv_heads, w), dt)
             for n, (w, dt) in _kv_spec(cfg).items()}
@@ -621,7 +654,7 @@ def _decode_block(x, layer_params, bufs, li, pos, cfg, rope,
     p = layer_params
     cos, sin = rope
 
-    h = rms_norm_reference(x, p["attn_norm"])
+    h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
     q = _weinsum("bsd,dhk->bshk", h, p["wq"])
     k = _weinsum("bsd,dhk->bshk", h, p["wk"])
     v = _weinsum("bsd,dhk->bshk", h, p["wv"])
@@ -663,7 +696,7 @@ def _decode_block(x, layer_params, bufs, li, pos, cfg, rope,
     x = x + _weinsum("bshk,hkd->bsd", o, p["wo"])
 
     with jax.named_scope("mlp"):
-        h = rms_norm_reference(x, p["mlp_norm"])
+        h = rms_norm_reference(x, p["mlp_norm"], cfg.rms_eps)
         mlp_out = _mlp(h, p, cfg)
     return x + mlp_out, bufs
 
@@ -689,6 +722,244 @@ def _mlp(h, p, cfg):
     return _weinsum("bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"])
 
 
+def _rope_tables(positions, cfg: T.TransformerConfig):
+    """(cos, sin) for a chunk's positions, once per chunk: over the head
+    dim, or over the rotary slice of a latent head."""
+    d = cfg.latent.rope_dim if cfg.kinded else cfg.head_dim
+    return T.rope_tables(positions, d, cfg.rope_base, cfg.rope_scaling)
+
+
+#: the routed experts' leaves, [layers, held, ...] each
+_ROUTED = ("w_gate", "w_up", "w_down")
+
+
+def _layer_params(params: dict, cfg: T.TransformerConfig, li: int) -> dict:
+    """Layer ``li``'s leaves, unstacked: from the one stacked group of
+    the dense decoder, or from its KIND's group."""
+    if not cfg.kinded:
+        return jax.tree.map(lambda a: a[li], params["blocks"])
+    kind, i = cfg.kind_index(li)
+    group = params["blocks"][kind]
+    if kind != "moe":
+        return jax.tree.map(lambda a: a[i], group)
+    # the routed experts go to their kernel STACKED, with the layer's
+    # index: a Mosaic call takes whole buffers, so a sliced layer would
+    # be copied out (1 GB a matrix at 12 x 7168 x 2048) in every step
+    p = {n: jax.tree.map(lambda a: a[i], a) for n, a in group.items()
+         if n not in _ROUTED}
+    return dict(p, routed=tuple(group[n] for n in _ROUTED), routed_layer=i)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention + sparse experts: the layers of a model with layer_kinds
+# ---------------------------------------------------------------------------
+
+def _latent_scale(cfg: T.TransformerConfig) -> float:
+    scale = cfg.latent.qk_dim ** -0.5
+    if cfg.rope_scaling is not None:
+        scale *= cfg.rope_scaling.softmax_scale
+    return scale
+
+
+def _latent_qkv(h, p, cfg: T.TransformerConfig, rope):
+    """Projections of latent attention on normed h [B, S, D]. Returns
+    (q_n [B, S, H, nope], q_r [B, S, H, rope] rotated, row [B, S, 1,
+    stored_row]): ``row`` is what the cache stores, the normed
+    compressed c_kv beside the ONE rotated key head every query head
+    shares, then zeros up to whole lane tiles
+    (``LatentAttention.stored_row``)."""
+    la = cfg.latent
+    cos, sin = rope
+    cq = rms_norm_reference(_weinsum("bsd,dr->bsr", h, p["wq_a"]),
+                            p["q_norm"], cfg.rms_eps)
+    q = _weinsum("bsr,rhk->bshk", cq, p["wq_b"])
+    q_n = q[..., :la.nope_dim]
+    q_r = T.apply_rope(q[..., la.nope_dim:], cos, sin)
+    kv = _weinsum("bsd,dr->bsr", h, p["wkv_a"])
+    c = rms_norm_reference(kv[..., :la.kv_rank], p["kv_norm"], cfg.rms_eps)
+    k_r = T.apply_rope(kv[:, :, None, la.kv_rank:], cos, sin)
+    tail = jnp.zeros(k_r.shape[:-1] + (la.stored_row - la.row,), k_r.dtype)
+    return q_n, q_r, jnp.concatenate([c[:, :, None, :], k_r, tail], axis=-1)
+
+
+def _latent_prompt_attention(q_n, q_r, row, p, cfg: T.TransformerConfig):
+    """The EXPANDED form, for a prompt: per-head keys ``[k_n; k_r]`` and
+    values from ``c_kv @ wkv_b``, causal flash attention. The kernels
+    take one head width for q, k and v, so v (``v_dim``) is zero-padded
+    to the q·k width and the output cut back: at 192 against 128 that
+    is a fifth more attention FLOPs, on a prompt whose matmuls are a
+    hundred times its attention."""
+    la = cfg.latent
+    b, s, heads, _ = q_n.shape
+    kvb = _weinsum("bsc,chk->bshk", row[:, :, 0, :la.kv_rank], p["wkv_b"])
+    k = jnp.concatenate(
+        [kvb[..., :la.nope_dim],
+         jnp.broadcast_to(row[..., la.kv_rank:la.row],
+                          (b, s, heads, la.rope_dim))],
+        axis=-1)
+    v = kvb[..., la.nope_dim:]
+    if la.v_dim < la.qk_dim:
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, la.qk_dim - la.v_dim),))
+    q = jnp.concatenate([q_n, q_r], axis=-1)
+    if mosaic.interpret():
+        o = T.reference_attention(q, k, v, causal=True,
+                                  scale=_latent_scale(cfg))
+    else:
+        # narrower blocks than the kernels' defaults: those were swept at
+        # head_dim 64-128, and a 192-wide head (256 lanes) at 16 heads x
+        # 512 keys a step is 23 MB of the 16 MB of VMEM (described-chip
+        # compile, PR 28). A prompt of at most 256 that is no multiple
+        # of 128 (_flash_safe_len leaves it unpadded) keeps the default
+        # q block, which clamps to the prompt and takes the dense arm.
+        o = T.flash_attention(q, k, v, causal=True,
+                              scale=_latent_scale(cfg),
+                              block_q=128 if s % 128 == 0 else 256,
+                              block_k=256)
+    return o[..., :la.v_dim]
+
+
+def _latent_cached_attention(q_n, q_r, buf, li, q_start, p,
+                             cfg: T.TransformerConfig,
+                             block: int = DECODE_BLOCK):
+    """The ABSORBED form, for decode: the key up-projection moves onto the
+    query (``q~ = q_n @ W_uk^T``, kv_rank wide) and the value
+    up-projection behind the softmax, so every head attends the STORED
+    rows ``[c_kv; k_r]`` as they are — multi-query attention whose
+    value is the row's first kv_rank columns:
+
+        scores = (q~ . c_kv + q_r . k_r) * scale
+        o      = (softmax(scores) @ c_kv) @ W_uv
+
+    The same mathematics as :func:`_latent_prompt_attention`, and the
+    cache holds kv_rank + rope values a token in place of heads x
+    (qk_dim + v_dim). Online softmax over the live blocks only, as
+    :func:`_cached_attention_blockwise` (which this sits beside): cost
+    follows the live length. q_n, q_r: [B, Q, H, .] at positions
+    q_start..q_start+Q-1; ``buf``: the stacked [L, B, max_len, row]
+    buffer. Returns [B, Q, H, v_dim]."""
+    la = cfg.latent
+    b, n_q, heads, _ = q_n.shape
+    max_len = buf.shape[2]
+    block = min(block, max_len)
+    w_uk = p["wkv_b"][..., :la.nope_dim]              # [c, H, nope]
+    w_uv = p["wkv_b"][..., la.nope_dim:]              # [c, H, v]
+    qc = jnp.einsum("bqhk,chk->bqhc", q_n, w_uk)
+    # [B, row, Q·H], built once outside the loop: the stored rows then
+    # meet it as the LEFT operand, contracted over their minor axis as
+    # stored (the same orientation as _head_scores) — as a right operand
+    # the compiler re-lays-out the whole cache to put rows minor
+    tail = jnp.zeros(q_r.shape[:-1] + (la.stored_row - la.row,), q_r.dtype)
+    qx = jnp.concatenate([qc, q_r, tail], axis=-1).reshape(
+        b, n_q * heads, la.stored_row).transpose(0, 2, 1)
+    q_pos = _q_positions(q_start, b, n_q)             # [B, Q]
+    row_pos = jnp.repeat(q_pos, heads, axis=1)        # [B, Q·H]
+    n_active = (jnp.max(q_pos) + block) // block
+    scale = _latent_scale(cfg)
+
+    m0 = jnp.full((b, n_q * heads), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((b, n_q * heads), jnp.float32)
+    acc0 = jnp.zeros((b, n_q * heads, la.stored_row), jnp.float32)
+
+    def body(i, carry):
+        m, l, acc = carry
+        start = jnp.minimum(i * block, max_len - block)
+        rows = _kv_rows(buf, li, start, block)                  # [B, S, row]
+        k_pos = start + jnp.arange(block)
+        mask = ((k_pos[None, None, :] >= i * block)
+                & (k_pos[None, None, :] <= row_pos[:, :, None]))
+        sc = jnp.einsum("bsf,bfn->bns", rows, qx,
+                        preferred_element_type=jnp.float32) * scale
+        sc = jnp.where(mask, sc, -jnp.inf)
+        new_m = jnp.maximum(m, sc.max(axis=-1))
+        safe_m = jnp.where(jnp.isneginf(new_m), 0.0, new_m)
+        alpha = jnp.exp(m - safe_m)
+        pr = jnp.exp(sc - safe_m[..., None])
+        l = l * alpha + pr.sum(axis=-1)
+        # p @ the whole stored row: the rotary columns and the tail ride
+        # along and are cut after the loop (no slice per block)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bns,bsf->bnf", pr.astype(rows.dtype), rows,
+            preferred_element_type=jnp.float32)
+        return new_m, l, acc
+
+    m, l, acc = jax.lax.fori_loop(0, n_active, body, (m0, l0, acc0))
+    ctx = (acc[..., :la.kv_rank] / l[..., None]).astype(q_n.dtype)
+    return jnp.einsum("bqhc,chk->bqhk",
+                      ctx.reshape(b, n_q, heads, la.kv_rank), w_uv)
+
+
+def _sparse_mlp(h, p, cfg: T.TransformerConfig, live=None):
+    """Sigmoid-routed experts beside a shared one, on [B, S, D]: the
+    held experts' part of the routed sum (dropless,
+    :func:`tony_tpu.parallel.moe.held_experts_ffn`) plus the whole shared
+    SwiGLU. ``live`` [B, S] bool: positions that hold a real token — a
+    prompt's padding is not routed (its output is never read, and every
+    padded position carries the same token, so on a seed whose token 0
+    picks held experts a 32 x 512 prefill would land 16k rows on them).
+    Returns (out, counts [2] int32: assignments landed here, held
+    experts touched)."""
+    e = cfg.experts
+    b, s, d = h.shape
+    flat = h.reshape(b * s, d)
+    with jax.named_scope("moe_route"):
+        picks, w = sigmoid_route(flat, p["router"], p["router_bias"],
+                                 e.top_k, e.scale)
+    routed, landed, touched = held_experts_ffn(
+        flat, picks, w, *p["routed"], p["routed_layer"],
+        HeldExperts(e.first, e.n_held, e.total),
+        live=None if live is None else live.reshape(b * s))
+    with jax.named_scope("moe_shared"):
+        gate = _weinsum("bsd,df->bsf", h, p["shared_gate"])
+        up = _weinsum("bsd,df->bsf", h, p["shared_up"])
+        shared = _weinsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                          p["shared_down"], pet=jnp.float32)
+    out = (routed.reshape(b, s, d) + shared).astype(h.dtype)
+    return out, jnp.stack([landed, touched])
+
+
+def _latent_mlp(x, p, cfg: T.TransformerConfig, bufs: dict, live=None):
+    """The feed-forward half of a layer with layer_kinds: the dense
+    SwiGLU, or the sparse experts, whose counts join ``bufs``."""
+    with jax.named_scope("mlp"):
+        h = rms_norm_reference(x, p["mlp_norm"], cfg.rms_eps)
+        if "router" not in p:
+            return x + _mlp(h, p, cfg), bufs
+        out, counts = _sparse_mlp(h, p, cfg, live)
+        return x + out, dict(bufs, **{MOE_COUNTS: bufs[MOE_COUNTS] + counts})
+
+
+def _latent_decode_block(x, p, bufs, li, pos, cfg, rope, window=None):
+    """:func:`_decode_block` for a layer of a model with layer_kinds:
+    the chunk's compressed rows go through the SAME cache write paths,
+    the read is the absorbed form. Returns (x, bufs)."""
+    h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
+    with jax.named_scope("mla_attention"):
+        q_n, q_r, row = _latent_qkv(h, p, cfg, rope)
+        pos = jnp.asarray(pos)
+        with jax.named_scope("cache_write"):
+            bufs = dict(bufs, ckv=_write_kv_chunk(bufs["ckv"], row, li, pos,
+                                                  window))
+        with jax.named_scope("cached_attention"):
+            o = _latent_cached_attention(q_n, q_r, bufs["ckv"], li, pos, p,
+                                         cfg)
+        x = x + _weinsum("bshk,hkd->bsd", o, p["wo"])
+    return _latent_mlp(x, p, cfg, bufs)
+
+
+def _latent_prompt_block(x, p, bufs, li, cfg, rope, s, live):
+    """One layer of :func:`_prompt_forward` for a model with layer_kinds:
+    expanded attention over the (padded) prompt, rows [0, s) written to
+    the latent cache; only the ``live`` positions are routed."""
+    h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
+    with jax.named_scope("mla_attention"):
+        q_n, q_r, row = _latent_qkv(h, p, cfg, rope)
+        o = _latent_prompt_attention(q_n, q_r, row, p, cfg)
+        x = x + _weinsum("bshk,hkd->bsd", o, p["wo"])
+    x, bufs = _latent_mlp(x, p, cfg, bufs, live)
+    return x, dict(bufs, ckv=_write_kv_chunk(
+        bufs["ckv"], row[:, :s], li, jnp.asarray(0, jnp.int32), None))
+
+
 def _blocks_forward(params: dict, tokens: jax.Array, cache: dict, pos,
                     cfg: T.TransformerConfig,
                     window: int | None = None) -> tuple[jax.Array, dict]:
@@ -705,16 +976,16 @@ def _blocks_forward(params: dict, tokens: jax.Array, cache: dict, pos,
             "decode steps only — chunked verify (speculative decoding) "
             "needs the linear cache")
     positions = _q_positions(pos, b, n_q)           # scalar or per-row pos
-    rope = T.rope_tables(positions, cfg.head_dim)   # once, not per layer
+    rope = _rope_tables(positions, cfg)             # once, not per layer
 
     # Unrolled layer loop with static per-layer indices — NOT a lax.scan
     # with the caches as xs/ys (see _decode_block: scan forces whole-cache
     # copies every step)
-    bufs = _kv_bufs(cache)
+    bufs = _kv_state(cache)
+    block = _latent_decode_block if cfg.kinded else _decode_block
     for li in range(cfg.n_layers):
-        layer_params = jax.tree.map(lambda a: a[li], params["blocks"])
-        x, bufs = _decode_block(
-            x, layer_params, bufs, li, pos, cfg, rope, window)
+        x, bufs = block(x, _layer_params(params, cfg, li), bufs, li, pos,
+                        cfg, rope, window)
     return x, dict(bufs, length=pos + tokens.shape[1])
 
 
@@ -732,7 +1003,7 @@ def extend_step(params: dict, tokens: jax.Array, cache: dict, pos,
     :func:`_window_write`."""
     x, new_cache = _blocks_forward(params, tokens, cache, pos, cfg, window)
     with jax.named_scope("lm_head"):
-        x = rms_norm_reference(x, params["final_norm"])
+        x = rms_norm_reference(x, params["final_norm"], cfg.rms_eps)
         logits = _weinsum("bsd,dv->bsv", x, params["lm_head"],
                           pet=jnp.float32)
         logits = logits.astype(cfg.logits_storage_dtype)
@@ -789,35 +1060,44 @@ def prefill(params: dict, tokens: jax.Array, cfg: T.TransformerConfig,
     behavior at low capacity factors."""
     b, s = tokens.shape
     cache = init_kv_cache(cfg, b, max_len)
-    x, bufs = _prompt_forward(params, tokens, cfg, _kv_bufs(cache), s)
+    x, bufs = _prompt_forward(params, tokens, cfg, _kv_state(cache), s)
     logits = _weinsum("bd,dv->bv", x[:, s - 1], params["lm_head"],
                       pet=jnp.float32)
     logits = logits.astype(cfg.logits_storage_dtype)
     return logits, dict(bufs, length=jnp.asarray(s, jnp.int32))
 
 
-def _prompt_forward(params, tokens, cfg, bufs, s):
+def _prompt_forward(params, tokens, cfg, bufs, s, lengths=None):
     """The prompt forward shared by :func:`prefill` and
     :func:`prefill_rows`: right-pads ``tokens`` [B, s] to a flash-safe
     length when the kernels need it, runs the unrolled layer loop writing
     positions [0, s) of K/V into ``bufs``, and returns the final-norm'd
     activations [B, s_padded, D] plus the filled buffers — each caller
     does its own lm_head projection (last position for prefill, per-row
-    true last positions for the bucketed variant)."""
+    true last positions for the bucketed variant). ``lengths`` [B]: the
+    rows' true lengths where they differ (the bucketed variant); a model
+    with experts routes only the positions below them."""
     b = tokens.shape[0]
     sp = _flash_safe_len(s) if _pad_prompts() else s
     if sp != s:
         tokens = jnp.pad(tokens, ((0, 0), (0, sp - s)))
     x = params["embed"][tokens].astype(cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(sp), (b, sp))
-    cos, sin = T.rope_tables(positions, cfg.head_dim)   # once, not per layer
+    rope = _rope_tables(positions, cfg)                 # once, not per layer
+    cos, sin = rope
+    live = (positions < (s if lengths is None else lengths[:, None])
+            if cfg.kinded else None)
 
     # Unrolled layers, prompt K/V written straight into the stacked cache
     # (same no-scan rationale as extend_step; int8 caches quantize at the
     # write — the prefill forward itself runs full-precision)
     for li in range(cfg.n_layers):
-        p = jax.tree.map(lambda a: a[li], params["blocks"])
-        h = rms_norm_reference(x, p["attn_norm"])
+        p = _layer_params(params, cfg, li)
+        if cfg.kinded:
+            x, bufs = _latent_prompt_block(x, p, bufs, li, cfg, rope, s,
+                                           live)
+            continue
+        h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
         q = _weinsum("bsd,dhk->bshk", h, p["wq"])
         k = _weinsum("bsd,dhk->bshk", h, p["wk"])
         v = _weinsum("bsd,dhk->bshk", h, p["wv"])
@@ -826,7 +1106,7 @@ def _prompt_forward(params, tokens, cfg, bufs, s):
         # kv_heads-wide K/V natively; no-op distinction for MHA)
         o = T._attention(q, k, v, None, window=cfg.attn_window or None)
         x = x + _weinsum("bshk,hkd->bsd", o, p["wo"])
-        h = rms_norm_reference(x, p["mlp_norm"])
+        h = rms_norm_reference(x, p["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, p, cfg)
         cap = _ring_capacity(cfg)
         if cap:
@@ -844,7 +1124,7 @@ def _prompt_forward(params, tokens, cfg, bufs, s):
             for n, c in _kv_writes(bufs, k[:, :s], v[:, :s]).items():
                 bufs[n] = _write_kv_chunk(bufs[n], c, li,
                                           jnp.asarray(0, jnp.int32), None)
-    return rms_norm_reference(x, params["final_norm"]), bufs
+    return rms_norm_reference(x, params["final_norm"], cfg.rms_eps), bufs
 
 
 def prefill_rows(params: dict, tokens: jax.Array, lengths: jax.Array,
@@ -873,7 +1153,8 @@ def prefill_rows(params: dict, tokens: jax.Array, lengths: jax.Array,
     _check_no_ring(cfg, "bucketed prefill")
     k_rows, s = tokens.shape
     cache = init_kv_cache(cfg, k_rows, s)
-    x, bufs = _prompt_forward(params, tokens, cfg, _kv_bufs(cache), s)
+    x, bufs = _prompt_forward(params, tokens, cfg, _kv_state(cache), s,
+                              lengths)
     xl = x[jnp.arange(k_rows), lengths - 1]                   # [K, D]
     logits = _weinsum("bd,dv->bv", xl, params["lm_head"],
                       pet=jnp.float32)
@@ -891,11 +1172,11 @@ def place_rows(cache: dict, mini: dict, rows: jax.Array,
     scatter semantics) — the batched admission path pads its row vector
     with distinct out-of-range sentinels, so a partial admission batch
     writes exactly its real rows."""
-    s_b = mini["k"].shape[2]
+    s_b = cache_rows(mini)
     placed = {n: cache[n].at[:, rows, :s_b].set(
                   mini[n], mode="drop", unique_indices=True)
               for n in _kv_bufs(mini)}
-    return dict(placed, length=cache["length"].at[rows].set(
+    return dict(cache, **placed, length=cache["length"].at[rows].set(
         lengths.astype(jnp.int32), mode="drop", unique_indices=True))
 
 
@@ -989,7 +1270,11 @@ def _check_draft_vocab(cfg, draft_cfg):
     two models must share a vocabulary. A mismatch is silent corruption
     in greedy mode (target ids past the draft's vocab clamp in its
     embedding gather, producing garbage proposals) and a shape error in
-    sampled mode — reject it up front."""
+    sampled mode — reject it up front. (Every speculative entry point
+    passes here, so this is also where a model with layer_kinds is
+    refused.)"""
+    for c in (cfg, draft_cfg):
+        c.refuse("speculative decoding")
     if draft_cfg.vocab_size != cfg.vocab_size:
         raise ValueError(
             f"draft vocab_size {draft_cfg.vocab_size} != target "
@@ -1579,6 +1864,7 @@ def beam_search(params: dict, prompt: jax.Array, cfg: T.TransformerConfig,
     if max_new_tokens < 1:
         raise ValueError("max_new_tokens must be >= 1")
     _check_no_ring(cfg, "beam search")
+    cfg.refuse("beam search")
     v = cfg.vocab_size
     max_len = s + max_new_tokens
     logits, cache = prefill(params, prompt, cfg, max_len)

@@ -104,11 +104,32 @@ _BLOCK_AXES = {
 }
 
 
+#: the same for a layer of a model with ``layer_kinds`` (leading dim 0:
+#: the kind's stacked layers): the projections that go through
+#: ``decode._weinsum``. ``wkv_b`` stays full precision (the absorbed
+#: decode form slices it per use), as do the router and the routed
+#: experts (the grouped product reads plain arrays).
+_KINDED_AXES = {
+    "wq_a": (1,), "wq_b": (1,), "wkv_a": (1,), "wo": (1, 2),
+    "shared_gate": (1,), "shared_up": (1,), "shared_down": (1,),
+}
+
+
 def quantize_weights_int8(params: dict) -> dict:
     """Serving snapshot: the dense matmul weights → :class:`QuantizedWeight`
     (per-output-channel int8); everything else (embed, norms, MoE
     experts/router) passes through unchanged. The returned pytree is a
     drop-in ``params`` for every ``tony_tpu.models.decode`` entry point."""
+    if all(isinstance(g, dict) for g in params["blocks"].values()):
+        blocks = {}
+        for kind, group in params["blocks"].items():
+            axes = dict(_KINDED_AXES)
+            if "router" not in group:       # the dense SwiGLU
+                axes.update(w_gate=(1,), w_up=(1,), w_down=(1,))
+            blocks[kind] = {n: _quantize(w, axes[n]) if n in axes else w
+                            for n, w in group.items()}
+        return dict(params, blocks=blocks,
+                    lm_head=_quantize(params["lm_head"], (0,)))
     blocks = dict(params["blocks"])
     moe = "router" in blocks
     for name, axes in _BLOCK_AXES.items():

@@ -153,8 +153,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from tony_tpu.models import transformer as T
-from tony_tpu.models.decode import (_check_draft_vocab, _check_no_ring,
-                                    _filter_logits, _kv_bufs,
+from tony_tpu.models.decode import (MOE_COUNTS, _check_draft_vocab,
+                                    _check_no_ring, _filter_logits, _kv_bufs,
                                     _propose_and_verify,
                                     _propose_and_verify_sampled,
                                     decode_step, extend_step,
@@ -179,6 +179,20 @@ ENGINE_PHASES = tracing.PROFILER_PREFIX + "engine"
 #: smallest bucketed-admission pad length — prompts shorter than this
 #: share one program rather than compiling 16 tiny variants
 _MIN_ADMIT_BUCKET = 16
+
+#: most rows one bucketed admission dispatch prefills. The dispatch is
+#: padded to a fixed row count so each bucket compiles one program. With
+#: that count = the slot count, a wave that freed one or two of 32 slots
+#: prefilled 32 x bucket positions: a fifth of a saturated window at
+#: Kimi-K2.5's widths, and how many such dispatches a run happened to
+#: make moved its tokens per second by up to 5% from run to run (my chip
+#: runs, PR 28). A wider wave goes through in several dispatches. Any
+#: batcher of more than 8 slots dispatches differently for it; up to 8
+#: (the Phi-3 cells' 6) the width is the slot count as before. Only 32
+#: and 8 were read on the chip, and only at those widths: 8 is the
+#: benchmark's largest slot count that keeps its old dispatch, not the
+#: best of a sweep (PERF.md section 7).
+_ADMIT_ROWS_MAX = 8
 
 #: rng-stream id for rows with no occupant (their draws are garbage the
 #: host discards; any fixed stream works)
@@ -295,11 +309,26 @@ def admit_rows(params, cache, logits, rows, prompts, lengths, cfg):
     (:func:`~tony_tpu.models.decode.prefill_rows`), each slot's K/V land
     via one batch-axis scatter per buffer
     (:func:`~tony_tpu.models.decode.place_rows`), and each slot's
-    next-step logits seed from its true last prompt position."""
+    next-step logits seed from its true last prompt position. Returns
+    (cache, logits, stats): ``stats`` is :func:`_device_stats` of the
+    prefill — empty for a model without experts, which gains no
+    output."""
     _count_trace("admit_rows", prompts.shape)
     lg, mini = prefill_rows(params, prompts, lengths, cfg)
     return (place_rows(cache, mini, rows, lengths),
-            logits.at[rows].set(lg, mode="drop", unique_indices=True))
+            logits.at[rows].set(lg, mode="drop", unique_indices=True),
+            _device_stats(mini))
+
+
+def _device_stats(cache: dict) -> dict:
+    """What the program counted on the device, as it leaves with the
+    tokens: the expert layers' ``[assignments landed on held experts,
+    held experts touched]`` (summed over layers; a decode chunk's idle
+    slots included — they are routed like any other — a prompt's padding
+    not) for a model with experts,
+    NOTHING for one without: an empty pytree adds no output, so the dense
+    programs lower to the HLO they always had."""
+    return {MOE_COUNTS: cache[MOE_COUNTS]} if MOE_COUNTS in cache else {}
 
 
 def prefix_template(params, prefix, cfg):
@@ -311,6 +340,7 @@ def prefix_template(params, prefix, cfg):
     buffer's shape[2] is the capacity, which the template consumers
     would misread as the prefix length and build a corrupt cache."""
     _check_no_ring(cfg, "prefix templates")
+    cfg.refuse("prefix templates")
     _, mini = prefill(params, jnp.asarray(prefix, jnp.int32)[None], cfg,
                       max_len=len(prefix))
     return _kv_bufs(mini)
@@ -477,10 +507,15 @@ def step_rows(params, cache, logits, keys, offsets, n, cfg,
     samples are a function of its own stream position alone, independent
     of batch composition or admission timing (what lets the pipelined
     loop shift admissions without changing outputs). Returns (tokens
-    [B, n], cache, logits). Idle rows decode garbage that the host
-    discards — uniform batch math keeps this one compiled program
-    regardless of which rows are live."""
-    _count_trace("step_rows", (cache["k"].shape, n))
+    [B, n], cache, logits, stats — :func:`_device_stats` of this chunk).
+    Idle rows decode garbage that the host discards — uniform batch math
+    keeps this one compiled program regardless of which rows are
+    live."""
+    _count_trace("step_rows",
+                 (next(iter(_kv_bufs(cache).values())).shape, n))
+    if MOE_COUNTS in cache:             # count this chunk alone
+        cache = dict(cache, **{MOE_COUNTS: jnp.zeros_like(
+            cache[MOE_COUNTS])})
 
     def body(carry, j):
         lg, c = carry
@@ -492,7 +527,7 @@ def step_rows(params, cache, logits, keys, offsets, n, cfg,
 
     (lg, cache), toks = jax.lax.scan(body, (logits, cache),
                                      jnp.arange(n))
-    return toks.T, cache, lg
+    return toks.T, cache, lg, _device_stats(cache)
 
 
 @functools.partial(jax.jit, donate_argnames=("cache",))
@@ -841,8 +876,15 @@ class ContinuousBatcher:
         self.params = params
         self.cfg = cfg
         self.batch = batch
+        #: rows a bucketed admission dispatch carries (see _ADMIT_ROWS_MAX)
+        self._admit_width = min(batch, _ADMIT_ROWS_MAX)
         self.max_len = max_len
         self.eos_id = eos_id
+        if shared_prefix is not None:
+            cfg.refuse("shared-prefix caching")
+        if not bucketed_admission:
+            cfg.refuse("batch-1 (unbucketed) admission, whose programs "
+                       "do not carry the expert layers' counters,")
         #: shared-prefix caching: when set (a token sequence, e.g. a
         #: system prompt), every request's prompt is interpreted as a
         #: CONTINUATION of it — the prefix prefills once into a K/V
@@ -913,6 +955,16 @@ class ContinuousBatcher:
                                 cfg.logits_storage_dtype)
         self.steps_executed = 0
         self.rounds_executed = 0
+        #: the expert layers' device counters, folded per program kind
+        #: ("decode": step_rows chunks, "admit": admit_rows prefills) as
+        #: each chunk's tokens are fetched: (token, pick) assignments
+        #: that landed on held experts, and (layer, held expert) pairs
+        #: with at least one — the weights a step had to read. A decode
+        #: chunk's idle slots count (the device routes them like any
+        #: other); a prompt's padding is not routed.
+        self.moe_assignments = {"decode": 0, "admit": 0}
+        self.moe_touches = {"decode": 0, "admit": 0}
+        self._device_stats: collections.deque = collections.deque()
         self.phase_times = PhaseTimes(ENGINE_PHASES)
         # seams usable standalone (no serve() call required); serve()
         # re-seeds for per-workload reproducibility
@@ -956,6 +1008,7 @@ class ContinuousBatcher:
         Raises ``ValueError`` for an unusable request (empty tokens,
         no room for a suffix, legacy ``shared_prefix`` mode, or a
         mismatched shipped template)."""
+        self.cfg.refuse("resident prefix templates")
         tokens = [int(t) for t in tokens]
         if not tokens:
             raise ValueError("prefix tokens must be non-empty")
@@ -1071,17 +1124,19 @@ class ContinuousBatcher:
                               if self.shared_prefix else 0)
         return bucket_for(n, cap, self.admission_buckets)
 
-    def _marshal_wave(self, pairs):
-        """THE home of the sentinel scheme: ([batch] row targets, [batch,
+    def _marshal_wave(self, pairs, width: int | None = None):
+        """THE home of the sentinel scheme: ([width] row targets, [width,
         2] per-request base rng keys) for a set of admitted (row,
-        request) pairs, padded to the full slot count — unused entries
+        request) pairs, padded to ``width`` (the full slot count unless
+        given) — unused entries
         get DISTINCT out-of-range row sentinels (their scatters drop)
         and the idle rng stream. One marshalling shared by prompt
         placement, stream rebinding, and the speculative seed draws, so
         the scheme cannot drift apart between paths; the keys come from
         ONE vmapped fold_in per wave, not one dispatch per row."""
-        rows = self.batch + np.arange(self.batch, dtype=np.int32)
-        req_ids = [_IDLE_STREAM] * self.batch
+        width = self.batch if width is None else width
+        rows = self.batch + np.arange(width, dtype=np.int32)
+        req_ids = [_IDLE_STREAM] * width
         for i, (row, req) in enumerate(pairs):
             rows[i] = row
             req_ids[i] = req
@@ -1095,14 +1150,14 @@ class ContinuousBatcher:
         return payload.suffix if isinstance(payload, _PrefixHit) \
             else payload
 
-    def _pad_prompts_to(self, grp, prompts, bucket):
-        """[batch, bucket] right-padded prompt matrix plus [batch] true
+    def _pad_prompts_to(self, grp, prompts, bucket, width: int):
+        """[width, bucket] right-padded prompt matrix plus [width] true
         lengths for one bucket group (entries past the group are inert —
         their scatter targets are :meth:`_marshal_wave`'s out-of-range
         sentinels). Prefix hits pad their SUFFIX (the only tokens that
         run a forward)."""
-        toks = np.zeros((self.batch, bucket), np.int64)
-        lens = np.ones((self.batch,), np.int32)
+        toks = np.zeros((width, bucket), np.int64)
+        lens = np.ones((width,), np.int32)
         for i, (_, req) in enumerate(grp):
             p = self._seq_of(prompts[req])
             toks[i, :len(p)] = p
@@ -1255,15 +1310,19 @@ class ContinuousBatcher:
                 for pid, bucket in sorted(groups,
                                           key=lambda k: (k[0] or "",
                                                          k[1])):
-                    grp = groups[(pid, bucket)]
-                    entry = (prompts[grp[0][1]].entry if pid is not None
+                    whole = groups[(pid, bucket)]
+                    entry = (prompts[whole[0][1]].entry if pid is not None
                              else None)
-                    rows, keys = self._marshal_wave(grp)
-                    toks, lens = self._pad_prompts_to(grp, prompts,
-                                                      bucket)
-                    self._admit_rows(rows, toks, lens, keys, entry=entry)
-                    self._rebind_streams(grp, rows, keys)
-                    self._count_admission(grp, prompts)
+                    w = self._admit_width
+                    for i in range(0, len(whole), w):
+                        grp = whole[i:i + w]
+                        rows, keys = self._marshal_wave(grp, w)
+                        toks, lens = self._pad_prompts_to(grp, prompts,
+                                                          bucket, w)
+                        self._admit_rows(rows, toks, lens, keys,
+                                         entry=entry)
+                        self._rebind_streams(grp, rows, keys)
+                        self._count_admission(grp, prompts)
             else:
                 for row, req in pairs:
                     self._admit_legacy(row, req, prompts)
@@ -1308,9 +1367,11 @@ class ContinuousBatcher:
                 self.params, self.cache, self.logits, rows,
                 self._prefix_template, toks, lens, self.cfg)
         else:
-            self.cache, self.logits = admit_rows(
+            self.cache, self.logits, stats = admit_rows(
                 self.params, self.cache, self.logits, rows, toks, lens,
                 self.cfg)
+            if stats:
+                self._device_stats.append(("admit", stats))
 
     def _admit_legacy(self, row, req, prompts) -> None:
         p = prompts[req]
@@ -1352,10 +1413,12 @@ class ContinuousBatcher:
         loop issues chunk N+1 here before fetching chunk N."""
         with self.phase_times.phase("dispatch"):
             offs = jnp.asarray(self._row_off, jnp.int32)
-            toks, self.cache, self.logits = step_rows(
+            toks, self.cache, self.logits, stats = step_rows(
                 self.params, self.cache, self.logits, self._row_keys,
                 offs, self.chunk, self.cfg, self.temperature, self.top_k,
                 self.top_p)
+            if stats:
+                self._device_stats.append(("decode", stats))
         self.steps_executed += self.chunk
         for r in range(self.batch):
             self._row_off[r] += self.chunk
@@ -1367,7 +1430,25 @@ class ContinuousBatcher:
         overlaps with the NEXT chunk. Returns per-row sequences of newly
         generated tokens."""
         with self.phase_times.phase("fetch"):
-            return np.asarray(handle)
+            toks = np.asarray(handle)
+            self._collect_device_stats()
+            return toks
+
+    def _collect_device_stats(self) -> None:
+        """Fold what the device counted, up to and including the OLDEST
+        outstanding decode chunk — the one whose tokens were just
+        fetched, so its counters (outputs of the same program) and those
+        of every admission dispatched before it are already computed: no
+        new device sync. Later entries wait for their own chunk's
+        fetch."""
+        while self._device_stats:
+            where, stats = self._device_stats.popleft()
+            landed, touched = (int(v) for v in
+                               np.asarray(stats[MOE_COUNTS]))
+            self.moe_assignments[where] += landed
+            self.moe_touches[where] += touched
+            if where == "decode":
+                break
 
     def _retire(self, mask) -> None:
         self.cache = retire_rows(self.cache, jnp.asarray(mask))
@@ -1516,6 +1597,8 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
                  seed: int = 0, shared_prefix=None,
                  pipeline: bool = True, bucketed_admission: bool = True,
                  admission_buckets: Sequence[int] | None = None) -> None:
+        for c in (cfg, draft_cfg):
+            c.refuse("speculative decoding")
         super().__init__(params, cfg, batch, max_len, eos_id=eos_id,
                          chunk=chunk, temperature=temperature,
                          top_k=top_k, top_p=top_p, seed=seed,
@@ -1902,6 +1985,21 @@ class ServeEngine:
             "tony_serve_prefix_admits_total",
             help="admissions that went through a resident prefix "
                  "template (only suffix tokens ran the model)")
+        # a model with sparse experts: what its expert layers counted on
+        # the device, per program kind (the batcher folds them as each
+        # chunk's tokens are fetched, _collect_device_stats)
+        self._moe_c = {
+            (what, program): reg.counter(
+                f"tony_moe_{what}_total", help=text, program=program)
+            for program in ("decode", "admit")
+            for what, text in (
+                ("assignments", "(token, pick) assignments that landed "
+                                "on experts held here"),
+                ("expert_touches", "(layer, held expert) pairs with at "
+                                   "least one assignment: the expert "
+                                   "weights a program had to read"))
+        } if batcher.cfg.experts is not None else {}
+        self._moe_seen = {k: 0 for k in self._moe_c}
         self._qdepth_g.set(0)
         for g in self._qdepth_by_cls.values():
             g.set(0)
@@ -2123,6 +2221,11 @@ class ServeEngine:
                 "prefill_tokens": self.b.prefill_forward_tokens,
                 "prefix_tokens": self.b.prefix_copied_tokens,
                 "prefix_admits": self.b.prefix_admits,
+                # the expert layers' device counters per program kind
+                # (zeros for a model without experts)
+                "moe_assignments": dict(self.b.moe_assignments),
+                "moe_expert_touches": dict(self.b.moe_touches),
+                "steps_executed": self.b.steps_executed,
             }
 
     # --- the loop (one driving thread) ---
@@ -2384,6 +2487,11 @@ class ServeEngine:
         ``consume``; the callbacks inside it are ``emit``."""
         with self.b.phase_times.phase("consume"):
             self._consume_chunk(host_toks, snap)
+        for (what, program), c in self._moe_c.items():
+            total = (self.b.moe_assignments if what == "assignments"
+                     else self.b.moe_touches)[program]
+            c.inc(total - self._moe_seen[(what, program)])
+            self._moe_seen[(what, program)] = total
 
     def _consume_chunk(self, host_toks, snap) -> None:
         deltas, retired = [], []

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -39,6 +40,99 @@ from tony_tpu.parallel.ring_attention import ring_attention
 from tony_tpu.parallel.sharding import (DEFAULT_RULES, constrain,
                                         shard_attention)
 from tony_tpu.models.train import masked_cross_entropy
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeYarn:
+    """YaRN scaling of the rotary frequencies (Peng et al. 2023, as the
+    DeepSeek-V3 family configures it): each frequency blends the
+    unscaled ``theta_i`` and ``theta_i / factor`` by a linear ramp between
+    the dimensions at which ``beta_fast`` and ``beta_slow`` rotations fit
+    in ``original_max`` positions. ``mscale`` / ``mscale_all_dim``: the
+    cos/sin tables carry ``m(mscale) / m(mscale_all_dim)`` and the
+    attention scale ``m(mscale_all_dim)**2``, ``m(x) = 0.1 x ln(factor)
+    + 1``."""
+    factor: float
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max: int = 4096
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def m(self, x: float) -> float:
+        if self.factor <= 1.0 or not x:
+            return 1.0
+        return 0.1 * x * math.log(self.factor) + 1.0
+
+    @property
+    def table_scale(self) -> float:
+        return self.m(self.mscale) / self.m(self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.m(self.mscale_all_dim) ** 2
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """Latent attention (DeepSeek-V2's MLA): queries through a normed
+    low-rank bottleneck (``q_rank``), keys and values from ONE compressed
+    row a token — ``[c_kv (kv_rank, normed); k_rope (rope_dim)]``, the
+    only thing the cache stores. A head's query is ``[nope_dim;
+    rope_dim]`` wide and its value ``v_dim``; the rotary part of the key
+    is one head shared by all."""
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+    @property
+    def row(self) -> int:
+        """Values a token stores per layer."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def stored_row(self) -> int:
+        """Width of the cache buffer's row: ``row`` rounded up to whole
+        128-lane tiles, the tail zeros. With 576 minor the device pads to
+        640 anyway, and — offered a layout with padding — the compiler
+        chose to keep the ROWS minor inside the read loop and re-laid-out
+        the whole cache around every layer's write (8 cache-sized copies
+        a decode step; described-chip compile, PR 28)."""
+        return -(-self.row // 128) * 128
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseExperts:
+    """Sigmoid-routed SwiGLU experts beside a shared one: every token
+    scores all ``total`` experts, picks ``top_k`` by score + selection
+    bias, and weighs them by score normalised over the pick x ``scale``.
+    THIS program holds experts ``[first, first + held)`` — one rank's
+    share of an expert-parallel layer — and computes their part
+    (:func:`tony_tpu.parallel.moe.held_experts_ffn`); ``held == total``
+    is the whole layer. ``d_expert``: width of one routed expert and of
+    the shared one."""
+    total: int
+    top_k: int
+    d_expert: int
+    scale: float = 1.0
+    first: int = 0
+    held: int | None = None
+
+    @property
+    def n_held(self) -> int:
+        return self.total if self.held is None else self.held
+
+
+#: layer kinds of a model with a ``layer_kinds`` list: both attend
+#: through the latent cache; ``dense`` has a SwiGLU of ``d_ff``,
+#: ``moe`` the sparse experts
+LAYER_KINDS = ("dense", "moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,6 +219,20 @@ class TransformerConfig:
     # and prefill math are untouched (quantization happens only at the
     # cache write). See models/decode.py.
     kv_cache_dtype: str = "model"
+    # RMSNorm epsilon and rotary base as the model publishes them
+    rms_eps: float = 1e-6
+    rope_base: float = 10000.0
+    rope_scaling: RopeYarn | None = None
+    # A model with MORE THAN ONE KIND of layer: the kind of each layer in
+    # order (see LAYER_KINDS), parameters stacked per kind under
+    # params["blocks"][kind], and each kind owning the cache buffers it
+    # writes. None = the dense decoder: one kind, today's layout. Such a
+    # model attends through ``latent`` and routes through ``experts``;
+    # it is SERVED (models/decode.py, models/serve.py) — the train step
+    # and the serving features listed in ``unsupported`` refuse it.
+    layer_kinds: tuple[str, ...] | None = None
+    latent: LatentAttention | None = None
+    experts: SparseExperts | None = None
 
     def __post_init__(self):
         # fail where the config was written, not at first trace
@@ -157,6 +265,63 @@ class TransformerConfig:
                     f"kv_cache_capacity ({self.kv_cache_capacity}) must "
                     f"be >= attn_window ({self.attn_window}): a decode "
                     f"step reads its window's rows from the ring")
+        if self.layer_kinds is not None:
+            if len(self.layer_kinds) != self.n_layers or any(
+                    k not in LAYER_KINDS for k in self.layer_kinds):
+                raise ValueError(
+                    f"layer_kinds must name one of {LAYER_KINDS} for each "
+                    f"of the {self.n_layers} layers, got {self.layer_kinds}")
+            if self.latent is None or ("moe" in self.layer_kinds
+                                       and self.experts is None):
+                raise ValueError(
+                    "a model with layer_kinds attends through `latent` "
+                    "(LatentAttention) and its 'moe' layers route through "
+                    "`experts` (SparseExperts): set both")
+            if self.latent.rope_dim % 2:
+                raise ValueError("latent.rope_dim must be even")
+            e = self.experts
+            if e is not None and not (0 <= e.first and 0 < e.n_held
+                                      and e.first + e.n_held <= e.total
+                                      and 0 < e.top_k <= e.total):
+                raise ValueError(
+                    f"experts [{e.first}, {e.first + e.n_held}) must lie "
+                    f"inside the {e.total} the router scores, top_k "
+                    f"{e.top_k} among them")
+            for what, on in (("kv_cache_dtype='int8'", self.kv_quant),
+                             ("kv_cache_capacity (ring cache)",
+                              self.kv_cache_capacity),
+                             ("attn_window", self.attn_window),
+                             ("num_experts (the gshard block)",
+                              self.num_experts)):
+                if on:
+                    raise ValueError(
+                        f"{what} is not supported with layer_kinds: the "
+                        f"latent cache is one {self.latent.row}-wide row "
+                        f"a token in the model's dtype, full causal")
+        elif self.latent is not None or self.experts is not None:
+            raise ValueError("`latent` / `experts` describe the layers of "
+                             "a model with `layer_kinds`: set it")
+
+    @property
+    def kinded(self) -> bool:
+        """More than one kind of layer (``layer_kinds`` set)."""
+        return self.layer_kinds is not None
+
+    def kind_index(self, li: int) -> tuple[str, int]:
+        """(kind of layer ``li``, its index within that kind's stack)."""
+        kind = self.layer_kinds[li]
+        return kind, self.layer_kinds[:li].count(kind)
+
+    def refuse(self, what: str) -> None:
+        """Raise where ``what`` (a serving or training feature) cannot
+        take a model with ``layer_kinds`` yet — at construction, with the
+        reason, never a wrong answer later."""
+        if self.kinded:
+            raise NotImplementedError(
+                f"{what} is not supported for a model with layer_kinds "
+                f"(latent attention + sparse experts) yet: it is written "
+                f"against K and V rows of one head width and one stacked "
+                f"block group (ROADMAP.md, Reach A)")
 
     @property
     def head_dim(self) -> int:
@@ -194,7 +359,9 @@ PRESETS = {
 
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
-    """Initialize the parameter pytree. Layer params are stacked [L, ...]."""
+    """Initialize the parameter pytree. Layer params are stacked [L, ...]
+    (a model with ``layer_kinds``: one stacked group per kind, see
+    :func:`_init_kinded_blocks`)."""
     k_emb, k_blocks, k_out = jax.random.split(rng, 3)
     d, h, hd, f, L = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
                       cfg.n_layers)
@@ -203,6 +370,14 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dt)
+
+    if cfg.kinded:
+        return {
+            "embed": dense(k_emb, (cfg.vocab_size, d), d),
+            "blocks": _init_kinded_blocks(k_blocks, cfg, dense),
+            "final_norm": jnp.ones((d,), dt),
+            "lm_head": dense(k_out, (d, cfg.vocab_size), d),
+        }
 
     ks = jax.random.split(k_blocks, 8)
     kv = cfg.kv_heads
@@ -238,9 +413,80 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     }
 
 
+def kinded_block_shapes(cfg: TransformerConfig, kind: str) -> dict:
+    """leaf -> (shape of ONE layer, fan-in or None for a norm / the
+    bias, logical axes) of a ``kind`` layer of a model with
+    ``layer_kinds``. The one statement of that layout: ``init_params``
+    and ``logical_axes`` stack it per kind."""
+    d, h, la = cfg.d_model, cfg.n_heads, cfg.latent
+    leaves = {
+        "attn_norm": ((d,), None, ("norm",)),
+        "wq_a": ((d, la.q_rank), d, ("embed", None)),
+        "q_norm": ((la.q_rank,), None, ("norm",)),
+        "wq_b": ((la.q_rank, h, la.qk_dim), la.q_rank,
+                 (None, "heads", "kv")),
+        "wkv_a": ((d, la.row), d, ("embed", None)),
+        "kv_norm": ((la.kv_rank,), None, ("norm",)),
+        "wkv_b": ((la.kv_rank, h, la.nope_dim + la.v_dim), la.kv_rank,
+                  (None, "heads", "kv")),
+        "wo": ((h, la.v_dim, d), h * la.v_dim, ("heads", "kv", "embed")),
+        "mlp_norm": ((d,), None, ("norm",)),
+    }
+    if kind == "dense":
+        f = cfg.d_ff
+        leaves.update({"w_gate": ((d, f), d, ("embed", "mlp")),
+                       "w_up": ((d, f), d, ("embed", "mlp")),
+                       "w_down": ((f, d), f, ("mlp", "embed"))})
+        return leaves
+    e, f = cfg.experts, cfg.experts.d_expert
+    leaves.update({
+        # router and selection bias stay float32: a pick compares scores
+        "router": ((d, e.total), d, ("embed", None)),
+        "router_bias": ((e.total,), None, (None,)),
+        "w_gate": ((e.n_held, d, f), d, ("expert", "embed", "mlp")),
+        "w_up": ((e.n_held, d, f), d, ("expert", "embed", "mlp")),
+        "w_down": ((e.n_held, f, d), f, ("expert", "mlp", "embed")),
+        "shared_gate": ((d, f), d, ("embed", "mlp")),
+        "shared_up": ((d, f), d, ("embed", "mlp")),
+        "shared_down": ((f, d), f, ("mlp", "embed")),
+    })
+    return leaves
+
+
+def _init_kinded_blocks(rng, cfg: TransformerConfig, dense) -> dict:
+    """{kind: {leaf: [layers of that kind, ...]}}: norms ones, the
+    selection bias zeros, router float32."""
+    blocks = {}
+    for ki, kind in enumerate(dict.fromkeys(cfg.layer_kinds)):
+        n = cfg.layer_kinds.count(kind)
+        shapes = kinded_block_shapes(cfg, kind)
+        keys = jax.random.split(jax.random.fold_in(rng, ki), len(shapes))
+        group = {}
+        for key, (leaf, (shape, fan_in, _)) in zip(keys, shapes.items()):
+            if leaf == "router_bias":
+                group[leaf] = jnp.zeros((n,) + shape, jnp.float32)
+            elif fan_in is None:
+                group[leaf] = jnp.ones((n,) + shape, cfg.dtype)
+            else:
+                w = dense(key, (n,) + shape, fan_in)
+                group[leaf] = (w.astype(jnp.float32) if leaf == "router"
+                               else w)
+        blocks[kind] = group
+    return blocks
+
+
 def logical_axes(cfg: TransformerConfig) -> dict:
     """Logical-axis pytree matching init_params (leading axis = "stage" so
     the same layout drives FSDP sharding and pipeline stage assignment)."""
+    if cfg.kinded:
+        return {
+            "embed": ("vocab", "embed"),
+            "blocks": {kind: {leaf: ("stage",) + axes for leaf, (_, _, axes)
+                              in kinded_block_shapes(cfg, kind).items()}
+                       for kind in dict.fromkeys(cfg.layer_kinds)},
+            "final_norm": ("norm",),
+            "lm_head": ("embed", "vocab"),
+        }
     # Under GQA the K/V head count can be smaller than any tp axis, so
     # those params replicate instead of claiming the "heads" rule (they are
     # n_heads/n_kv_heads× smaller than MHA's to begin with; Llama-style TP
@@ -278,15 +524,41 @@ def logical_axes(cfg: TransformerConfig) -> dict:
 # Forward
 # ---------------------------------------------------------------------------
 
-def rope_tables(positions: jax.Array, d: int) -> tuple[jax.Array, jax.Array]:
+def rope_tables(positions: jax.Array, d: int, base: float = 10000.0,
+                scaling: RopeYarn | None = None
+                ) -> tuple[jax.Array, jax.Array]:
     """(cos, sin) tables [B, S, 1, d/2] for head dim ``d``. Position-only,
     so callers hoist them OUT of the layer scan — recomputing the trig per
-    layer cost ~2.3 ms/step at 8 layers × 16×1024 on one v5e."""
+    layer cost ~2.3 ms/step at 8 layers × 16×1024 on one v5e. ``base``
+    and ``scaling`` (YaRN, :class:`RopeYarn`) as the model publishes
+    them."""
     half = d // 2
     freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
-                    * (jnp.log(10000.0) / half))
+                    * (jnp.log(base) / half))
+    if scaling is not None:
+        freqs = _yarn_frequencies(freqs, d, base, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs   # [B, S, half]
-    return jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    if scaling is not None and scaling.table_scale != 1.0:
+        cos, sin = cos * scaling.table_scale, sin * scaling.table_scale
+    return cos, sin
+
+
+def _yarn_frequencies(freqs, d: int, base: float, y: RopeYarn):
+    """Blend each rotary frequency with its ``1/factor`` interpolation:
+    dimensions that turn more than ``beta_fast`` times in the original
+    context keep their frequency, those that turn fewer than
+    ``beta_slow`` times are interpolated, a linear ramp between."""
+    def dim_of(rotations: float) -> float:
+        return (d * math.log(y.original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+    low = max(math.floor(dim_of(y.beta_fast)), 0)
+    high = min(math.ceil(dim_of(y.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / y.factor * ramp + freqs * (1.0 - ramp)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -383,12 +655,13 @@ def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
     b, s, d = x.shape
     if rope is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        rope = rope_tables(positions, cfg.head_dim)
+        rope = rope_tables(positions, cfg.head_dim, cfg.rope_base,
+                           cfg.rope_scaling)
     cos, sin = rope
 
     # named sections (metadata only): a profile is read by these scopes
     with jax.named_scope("attn"):
-        h = rms_norm_reference(x, p["attn_norm"])
+        h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
         h = constrain(h, ("batch", "seq", "embed"), mesh, rules)
         q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
         k = jnp.einsum("bsd,dhk->bshk", h, p["wk"])
@@ -409,7 +682,7 @@ def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
         x = x + constrain(attn_out, ("batch", "seq", "embed"), mesh, rules)
 
     with jax.named_scope("mlp"):
-        h = rms_norm_reference(x, p["mlp_norm"])
+        h = rms_norm_reference(x, p["mlp_norm"], cfg.rms_eps)
         h = constrain(h, ("batch", "seq", "embed"), mesh, rules)
         if "router" in p:
             if ep_axis is not None:
@@ -443,7 +716,7 @@ def _lm_head(params: dict, x: jax.Array, cfg: TransformerConfig,
              mesh, rules) -> jax.Array:
     """final_norm + lm_head on block output x [B, S, D] → logits."""
     with jax.named_scope("lm_head"):
-        x = rms_norm_reference(x, params["final_norm"])
+        x = rms_norm_reference(x, params["final_norm"], cfg.rms_eps)
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                             preferred_element_type=jnp.float32)
         # The cast fuses into the matmul epilogue, so with bf16
@@ -510,7 +783,8 @@ def _forward_pp(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         # axes — expert weights arrive pre-sliced via param_specs below
         hb, hs = h.shape[0], h.shape[1]
         positions = jnp.broadcast_to(jnp.arange(hs), (hb, hs))
-        rope = rope_tables(positions, cfg.head_dim)
+        rope = rope_tables(positions, cfg.head_dim, cfg.rope_base,
+                           cfg.rope_scaling)
         block_fn = functools.partial(_block, cfg=cfg, mesh=None, rules=rules,
                                      ep_axis=ep_axis)
         if cfg.remat:
@@ -551,13 +825,16 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     With a mesh whose ``pp`` axis is >1 the blocks run as a GPipe pipeline
     (:func:`_forward_pp`) — pipelining is a mesh change, not a model change.
     """
+    cfg.refuse("the training forward (transformer.forward, lm_loss, the "
+               "train step)")
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         return _forward_pp(params, tokens, cfg, mesh, rules)
     x = params["embed"][tokens].astype(cfg.dtype)
     x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    rope = rope_tables(positions, cfg.head_dim)   # hoisted out of the scan
+    rope = rope_tables(positions, cfg.head_dim, cfg.rope_base,
+                       cfg.rope_scaling)      # hoisted out of the scan
 
     block_fn = functools.partial(_block, cfg=cfg, mesh=mesh, rules=rules)
     if cfg.remat:
@@ -637,6 +914,7 @@ def lm_value_and_grad(params: dict, batch: dict, cfg: TransformerConfig,
     from tony_tpu.models.train import masked_cross_entropy as _mxe
     from tony_tpu.parallel.pipeline import pipeline_value_and_grad
 
+    cfg.refuse("the 1F1B pipeline train step")
     if "inputs" in batch:
         inputs, targets = batch["inputs"], batch["targets"]
     else:
@@ -661,7 +939,8 @@ def lm_value_and_grad(params: dict, batch: dict, cfg: TransformerConfig,
     def stage_fn(stage_params, h):
         hb, hs = h.shape[0], h.shape[1]
         positions = jnp.broadcast_to(jnp.arange(hs), (hb, hs))
-        rope = rope_tables(positions, cfg.head_dim)
+        rope = rope_tables(positions, cfg.head_dim, cfg.rope_base,
+                           cfg.rope_scaling)
         block_fn = functools.partial(_block, cfg=cfg, mesh=None,
                                      rules=rules)
         if cfg.remat:
@@ -690,7 +969,7 @@ def lm_value_and_grad(params: dict, batch: dict, cfg: TransformerConfig,
         from jax.sharding import PartitionSpec as _P
 
         def loss_head(hp, out_mb, tgt_mb):
-            h = rms_norm_reference(out_mb, hp["final_norm"])
+            h = rms_norm_reference(out_mb, hp["final_norm"], cfg.rms_eps)
             logits = jnp.einsum("bsd,dv->bsv", h, hp["lm_head"],
                                 preferred_element_type=jnp.float32)
             # same storage rounding as _lm_head, math back in f32

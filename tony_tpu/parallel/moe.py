@@ -143,3 +143,148 @@ def moe_ffn_manual(x: jax.Array, router_w: jax.Array, w_in_local: jax.Array,
     out = lax.psum(jnp.einsum("bsec,ebcd->bsd", c_loc, expert_out),
                    axis_name)
     return out, metrics
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over one chip's share of the experts
+# ---------------------------------------------------------------------------
+
+class HeldExperts(NamedTuple):
+    """Which of a layer's routed experts live here: ``[first, first +
+    held)`` of ``total``. The router keeps its ``total`` outputs."""
+    first: int
+    held: int
+    total: int
+
+
+def sigmoid_route(h: jax.Array, router_w: jax.Array, bias: jax.Array,
+                  top_k: int, scale: float):
+    """Sigmoid routing with a selection bias, over ALL experts.
+    h: [T, D]; router_w: [D, E] and bias: [E], both float32. Returns
+    (picks [T, k] int32, weights [T, k] float32): the ``top_k`` largest of
+    ``z + bias`` with ``z = sigmoid(h @ router_w)`` — the bias steers the
+    pick and nothing else — weighted by ``z`` normalised over the picked
+    and scaled. The product and the scores are float32: a pick is a
+    comparison of near-equal scores, and bf16 would flip it."""
+    z = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, picks = lax.top_k(z + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(z, picks, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    return picks.astype(jnp.int32), w
+
+
+#: rows of the sorted layout one trip of the expert loop takes: bounds
+#: the gathered activations ([rows, D]) whatever the prefill's size
+_EXPERT_CHUNK_ROWS = 2048
+#: tokens up to which gather and combine are one-hot matmuls (a decode
+#: step); above it (a prefill) a row gather and a scatter-add
+_ONE_HOT_MAX_TOKENS = 256
+
+
+def held_experts_ffn(h: jax.Array, picks: jax.Array, weights: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     layer: int, share: HeldExperts, live=None):
+    """The routed part of a sparse SwiGLU layer that THIS chip's experts
+    give: ``sum over picked e in [first, first + held) of w_e *
+    SwiGLU_e(h)``; what the absent experts would add is left out.
+    Dropless: every assignment that lands on a held expert is computed,
+    none is padded to a capacity and none is dropped.
+
+    h: [T, D]; picks, weights: [T, k] (:func:`sigmoid_route`);
+    w_gate, w_up: [layers, held, D, F]; w_down: [layers, held, F, D] —
+    the STACKED leaves with ``layer`` (static) naming the one to use: the
+    kernel indexes the layer itself, because a sliced layer handed to a
+    Mosaic call would first be copied out whole. ``live`` [T] bool: the
+    tokens to route at all (None: every one) — a prompt's padding lands
+    nowhere. Returns (out [T, D]
+    float32, assignments, touched): the count of (token, pick) pairs that
+    landed here and of held experts with at least one.
+
+    The assignments are sorted by expert and each expert's rows padded to
+    whole tiles (:mod:`tony_tpu.ops.grouped_matmul`), so the products cost
+    what landed: a decode step reads the weights of the experts it
+    touched and no others. The sorted rows go through in chunks of
+    ``_EXPERT_CHUNK_ROWS`` under a loop whose trip count follows the rows
+    that exist, so a 16k-token prefill gathers 2,048 rows at a time, not
+    tokens x k."""
+    from tony_tpu.ops import mosaic
+    from tony_tpu.ops.grouped_matmul import grouped_matmul
+
+    t, d = h.shape
+    k = picks.shape[1]
+    n = t * k
+    held = share.held
+    # row tile: a decode step's few rows pad to 16 (bf16 sublanes), a
+    # prefill's hundreds per expert to 256 (MXU-sized)
+    tm = 16 if n <= 1024 else 256
+    local = picks.reshape(n) - share.first
+    on = (local >= 0) & (local < held)
+    if live is not None:
+        on = on & jnp.repeat(live, k)
+    key = jnp.where(on, local, held)
+    order = jnp.argsort(key, stable=True)                       # [N]
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
+                     axis=0, dtype=jnp.int32)                   # [held]
+    padded = (counts + tm - 1) // tm * tm
+    pad_end = jnp.cumsum(padded)
+    pad_start = pad_end - padded
+    start = jnp.cumsum(counts) - counts
+    m_pad = -(-n // tm) * tm + held * tm        # bound of pad_end[-1]
+    chunk = min(m_pad, _EXPERT_CHUNK_ROWS)
+    m_pad = -(-m_pad // chunk) * chunk
+    tiles = chunk // tm
+    flat_w = weights.reshape(n)
+    one_hot = t <= _ONE_HOT_MAX_TOKENS
+
+    def rows_of(c):
+        """Chunk ``c`` of the padded layout: (token of each row, its
+        weight — 0 for padding —, the group of each tile)."""
+        r = c * chunk + jnp.arange(chunk)
+        g = jnp.sum(r[:, None] >= pad_end[None, :], axis=1)     # [chunk]
+        gc = jnp.minimum(g, held - 1)
+        j = r - pad_start[gc]
+        valid = (g < held) & (j < counts[gc])
+        src = order[jnp.where(valid, start[gc] + j, 0)]
+        return (src // k, jnp.where(valid, flat_w[src], 0.0), valid,
+                gc[::tm])
+
+    def products(xb, tile_group, live_tiles):
+        if mosaic.interpret():
+            sizes = jnp.zeros((held,), jnp.int32).at[tile_group].add(
+                jnp.where(jnp.arange(tiles) < live_tiles, tm, 0))
+            mm = lambda a, w, dt: lax.ragged_dot(                 # noqa: E731
+                a, w[layer], sizes, preferred_element_type=dt)
+        else:
+            # [layers, held, ..] -> [layers x held, ..]: merging leading
+            # axes moves nothing
+            mm = lambda a, w, dt: grouped_matmul(                 # noqa: E731
+                a, w.reshape((-1,) + w.shape[2:]),
+                tile_group + layer * held, live_tiles, tm=tm,
+                out_dtype=dt)
+        inner = (jax.nn.silu(mm(xb, w_gate, h.dtype))
+                 * mm(xb, w_up, h.dtype))
+        return mm(inner, w_down, jnp.float32)
+
+    def body(c, out):
+        tok, w, valid, tile_group = rows_of(c)
+        live_tiles = jnp.clip(pad_end[-1] // tm - c * tiles, 0, tiles)
+        if one_hot:
+            sel = (tok[:, None] == jnp.arange(t)[None, :]) & valid[:, None]
+            xb = jnp.einsum("rt,td->rd", sel.astype(h.dtype), h)
+        else:
+            xb = h[tok]
+        y = products(xb, tile_group, live_tiles)
+        y = jnp.where(valid[:, None], y * w[:, None], 0.0)
+        if one_hot:
+            return out + jnp.einsum(
+                "rt,rd->td", sel.astype(jnp.float32), y,
+                precision=lax.Precision.HIGHEST)
+        return out.at[tok].add(y)
+
+    with jax.named_scope("moe_experts"):
+        n_chunks = (pad_end[-1] + chunk - 1) // chunk
+        out = lax.fori_loop(0, n_chunks, body,
+                            jnp.zeros((t, d), jnp.float32))
+    return out, on.sum(dtype=jnp.int32), (counts > 0).sum(dtype=jnp.int32)
