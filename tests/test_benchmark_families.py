@@ -26,6 +26,9 @@ import pytest
 
 from benchmark.lib import modelcfg
 from benchmark.tests import test_families as cases
+from benchmark.tests.test_admit_readers import (  # noqa: F401 — PR 29's
+    test_admissions_per_call_and_over_the_window,
+    test_no_admission_reads_none)
 from benchmark.tests.test_families import (  # noqa: F401 — collected here
     test_dense_weights_are_the_parents_bit_for_bit,
     test_family_provides_the_whole_list,

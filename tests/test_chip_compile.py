@@ -199,6 +199,31 @@ STEP_ROWS_CASES = {
 }
 
 
+def _dense_serving(chip, name):
+    """(cfg, params, cache) of ``STEP_ROWS_CASES[name]`` at depth 4, as
+    shapes on the described chip, the cache with per-row frontiers."""
+    from tony_tpu.models import decode as D
+    from tony_tpu.models import transformer as T
+    d_model, heads, kv, d_ff, vocab, slots, rows = STEP_ROWS_CASES[name]
+    cfg = T.TransformerConfig(
+        vocab_size=vocab, d_model=d_model, n_layers=4, n_heads=heads,
+        n_kv_heads=kv, d_ff=d_ff, max_seq=rows, dtype=jnp.bfloat16)
+    params = chip.place(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(lambda: D.init_kv_cache(cfg, slots, rows))
+    return cfg, params, chip.place(
+        dict(cache, length=chip.shape((slots,), jnp.int32)))
+
+
+def _cache_sized_copies(text, buf):
+    """The ``copy`` instructions of a compiled program's text whose
+    result has as many elements as the cache buffer ``buf``."""
+    import re
+    return [m.group(0) for m in re.finditer(
+                r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", text)
+            if np.prod([int(d) for d in m.group(1).split(",")]) == buf.size]
+
+
 @pytest.mark.parametrize("name", sorted(STEP_ROWS_CASES))
 def test_step_rows_never_copies_the_cache(chip, name):
     """The decode chunk (``serve.step_rows``, ``n=8``, per-row frontiers)
@@ -212,17 +237,9 @@ def test_step_rows_never_copies_the_cache(chip, name):
     at this depth and 6 slots, against 0.19 GB a buffer)."""
     import re
 
-    from tony_tpu.models import decode as D
     from tony_tpu.models import serve as S
-    from tony_tpu.models import transformer as T
-    d_model, heads, kv, d_ff, vocab, slots, rows = STEP_ROWS_CASES[name]
-    cfg = T.TransformerConfig(
-        vocab_size=vocab, d_model=d_model, n_layers=4, n_heads=heads,
-        n_kv_heads=kv, d_ff=d_ff, max_seq=rows, dtype=jnp.bfloat16)
-    params = chip.place(jax.eval_shape(
-        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
-    cache = jax.eval_shape(lambda: D.init_kv_cache(cfg, slots, rows))
-    cache = chip.place(dict(cache, length=chip.shape((slots,), jnp.int32)))
+    d_model, _, _, _, vocab, slots, _ = STEP_ROWS_CASES[name]
+    cfg, params, cache = _dense_serving(chip, name)
     compiled = S.step_rows.lower(
         params, cache, chip.shape((slots, vocab), cfg.logits_storage_dtype),
         chip.shape((slots, 2), jnp.uint32), chip.shape((slots,), jnp.int32),
@@ -232,11 +249,7 @@ def test_step_rows_never_copies_the_cache(chip, name):
 
     buf = cache["k"]
     dims = ",".join(str(d) for d in buf.shape)
-    cache_sized = [
-        m.group(0) for m in re.finditer(
-            r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", text)
-        if np.prod([int(d) for d in m.group(1).split(",")]) == buf.size]
-    assert not cache_sized, cache_sized
+    assert not _cache_sized_copies(text, buf)
     # entry arguments, the while's carry, the results: one layout
     layouts = set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})", text))
     assert len(layouts) == 1, layouts
@@ -251,6 +264,39 @@ def test_step_rows_never_copies_the_cache(chip, name):
     sliced = re.findall(r"copy-start[.\d]* = \(bf16\[1," + str(d_model)
                         + r",\d+,\d+\]", text)
     assert not sliced, sliced
+
+
+def test_admit_rows_at_token_budget_widths(chip):
+    """The admission programs of the Phi-3 cells once a dispatch is sized
+    in tokens (``serve.admit_width``, 6 slots): the longest bucket at one
+    row, ``(1, 1024)``, and the widest dispatch the cells' buckets give,
+    ``(4, 64)``. Neither copies a cache-sized buffer on the way to
+    ``place_rows``, and each holds fewer temporaries than ``(6, 1024)``,
+    the shape every admission had while it was padded to the slots."""
+    from tony_tpu.models import serve as S
+    slots = STEP_ROWS_CASES["phi3mini-6slots"][5]
+    assert (S.admit_width(1024, slots), S.admit_width(64, slots)) == (1, 4)
+    cfg, params, cache = _dense_serving(chip, "phi3mini-6slots")
+    logits = chip.shape((slots, cfg.vocab_size), cfg.logits_storage_dtype)
+
+    def compiled(width, bucket):
+        return S.admit_rows.lower(
+            params, cache, logits, chip.shape((width,), jnp.int32),
+            chip.shape((width, bucket), jnp.int32),
+            chip.shape((width,), jnp.int32), cfg=cfg).compile()
+
+    padded = compiled(slots, 1024).memory_analysis().temp_size_in_bytes
+    for width, bucket in ((1, 1024), (4, 64)):
+        program = compiled(width, bucket)
+        text = program.as_text()
+        assert text.startswith("HloModule jit_admit_rows")
+        # a 64-position bucket is under the kernels' 128-lane tile and
+        # takes flash_attention's dense arm, at every width
+        assert ("tpu_custom_call" in text) == (bucket >= 128), (width,
+                                                                bucket)
+        assert not _cache_sized_copies(text, cache["k"])
+        temporaries = program.memory_analysis().temp_size_in_bytes
+        assert temporaries < padded, (width, bucket, temporaries, padded)
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ from tony_tpu.models import transformer as T
 from tony_tpu.models.decode import generate
 from tony_tpu.models.serve import (ContinuousBatcher, ServeEngine,
                                    SpeculativeContinuousBatcher)
+from tony_tpu.runtime.metrics import MetricsRegistry
 
 CFG = T.PRESETS["tiny"].scaled(dtype=jnp.float32, remat=False)
 
@@ -761,9 +762,12 @@ class TestClosedBatchEngineEquivalence:
 
     def _open_loop(self, batcher, prompts, budgets):
         outs: dict = {i: [] for i in range(len(prompts))}
+        reg = MetricsRegistry()
+        padded0 = batcher.prefill_padded_tokens   # a template's forward
         eng = ServeEngine(
             batcher, on_delta=lambda r, t: outs[r].extend(t),
-            on_retired=lambda r, reason, n, final: outs[r].extend(final))
+            on_retired=lambda r, reason, n, final: outs[r].extend(final),
+            registry=reg)
         th = threading.Thread(target=eng.run, daemon=True)
         th.start()
         for i, (p, b) in enumerate(zip(prompts, budgets)):
@@ -774,6 +778,13 @@ class TestClosedBatchEngineEquivalence:
         eng.drain()
         th.join(timeout=300)
         assert not th.is_alive(), "engine did not drain"
+        # the positions the admission programs ran, beside the real
+        # prompts': in stats() and on the metrics plane
+        st = eng.stats()
+        assert (st["prefill_padded_tokens"] == batcher.prefill_padded_tokens
+                >= st["prefill_tokens"] > 0)
+        assert reg.counter("tony_prefill_padded_tokens_total").value == \
+            batcher.prefill_padded_tokens - padded0
         return [outs[i] for i in range(len(prompts))]
 
     def _pin(self, make, prompts, budgets, pin_steps=True):
@@ -892,23 +903,106 @@ class TestPipelinedServingSmoke:
         assert b3.serve(prompts, budgets) == b2.serve(prompts, budgets)
 
 
-def test_wide_wave_admits_in_dispatches_of_at_most_eight_rows(params,
-                                                               retrace_guard):
-    """12 slots filled at once: a bucket's admissions go through in
-    dispatches of at most ``_ADMIT_ROWS_MAX`` rows (one program a bucket
-    at that width, not at the slot count), every request still equals
-    its solo greedy generate, and a batcher of up to 8 slots keeps the
-    full-width dispatch it always had."""
+def test_admit_width_is_the_token_budget_over_the_bucket():
+    """The rule and its one constant, at the benchmark's own shapes: the
+    Phi-3 cells' 6 slots over buckets 64-1,024, the Kimi cell's 32 over
+    32-512. A bucket at or over the budget dispatches one row; short
+    buckets keep the slot count."""
+    from tony_tpu.models import serve as S
+    assert S._ADMIT_TOKEN_BUDGET == 256
+    assert [S.admit_width(b, 6) for b in (16, 32, 64, 128, 256, 512, 1024)
+            ] == [6, 6, 4, 2, 1, 1, 1]
+    assert [S.admit_width(b, 32) for b in (16, 32, 64, 128, 256, 512)
+            ] == [16, 8, 4, 2, 1, 1]
+    assert S.admit_width(16, 2) == 2 and S.admit_width(4096, 2) == 1
+
+
+#: (batcher kind, slots, prompt lengths — suffix lengths for "prefix" —
+#: and a bucket whose share of the first wave outnumbers its width)
+_WAVES = [
+    # PR 28's wave: 12 slots filled at once from ONE short bucket, whose
+    # width is the slot count — padding is free there, one dispatch
+    ("greedy", 12, (5, 3, 7, 4, 6, 3, 9, 12, 5, 8, 11, 4, 6, 13), None),
+    # buckets 32 / 64 / 128 at widths 8 / 4 / 2: ten of the first
+    # twelve share bucket 32 and go through in two dispatches
+    ("greedy", 12, (20, 25, 30, 20, 25, 30, 20, 25, 30, 20, 40, 50,
+                    40, 50, 40, 70, 100, 70, 5), 32),
+    # the Phi-3 cells' slot count: 6 / 6 / 4 / 2 / 1 over 16 - 256
+    ("greedy", 6, (40, 50, 40, 50, 40, 50, 70, 100, 70, 130, 200, 5, 20),
+     64),
+    # a bucket at the budget dispatches ONE row, whatever arrived
+    ("greedy", 2, (130, 200, 130, 70, 100, 5), 256),
+    ("speculative", 6, (40, 50, 40, 50, 40, 50, 70, 100, 5, 20), 64),
+    ("prefix", 6, (40, 50, 40, 50, 40, 50, 70, 100, 5, 20), 64),
+]
+
+
+@pytest.mark.parametrize(
+    "kind, slots, lengths, split", _WAVES,
+    ids=[f"{k}-{s}slots-{len(n)}req" for k, s, n, _ in _WAVES])
+def test_admission_dispatch_is_sized_in_tokens(params, retrace_guard,
+                                               kind, slots, lengths,
+                                               split):
+    """Every bucketed admission dispatch is ``admit_width(bucket)`` rows
+    wide — one traced program a bucket — a wave that holds more
+    requests of a bucket than its width goes through in several
+    dispatches, every request still equals its solo greedy generate, and
+    ``prefill_padded_tokens`` counts rows x bucket of every dispatch."""
     from tony_tpu.models import serve as S
     rng = np.random.RandomState(11)
-    prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
-               for n in (5, 3, 7, 4, 6, 3, 9, 12, 5, 8, 11, 4, 6, 13)]
-    batcher = ContinuousBatcher(params, CFG, batch=12, max_len=32, chunk=4)
-    assert batcher._admit_width == S._ADMIT_ROWS_MAX == 8
+    prefix = ([int(t) for t in rng.randint(0, CFG.vocab_size, size=9)]
+              if kind == "prefix" else [])
+    prompts = [prefix + [int(t) for t in
+                         rng.randint(0, CFG.vocab_size, size=n)]
+               for n in lengths]
+    if kind == "speculative":
+        batcher = SpeculativeContinuousBatcher(
+            params, CFG, params, CFG, batch=slots, max_len=288,
+            num_speculative=3, chunk=2)
+    else:
+        batcher = ContinuousBatcher(params, CFG, batch=slots, max_len=288,
+                                    chunk=4)
+    padded0 = 0
+    if prefix:
+        assert batcher.install_prefix("sys", prefix)
+        padded0 = len(prefix)
+    program = {"greedy": "admit_rows", "speculative": "spec_admit_rows",
+               "prefix": "prefix_admit_rows"}[kind]
+    dispatched = []                          # (rows, bucket, real rows)
+    admit = batcher._admit_rows
+
+    def recording(rows, toks, lens, keys, entry=None):
+        dispatched.append(tuple(toks.shape)
+                          + (int(np.sum(np.asarray(rows) < slots)),))
+        return admit(rows, toks, lens, keys, entry=entry)
+    batcher._admit_rows = recording
     outs = batcher.serve(prompts, max_new_tokens=5)
     for i, p in enumerate(prompts):
         assert outs[i] == _reference(params, p, 5), f"request {i}"
-    shapes = retrace_guard.new_traces("admit_rows")
-    assert shapes and all(shape[0] == 8 for shape in shapes), shapes
-    assert ContinuousBatcher(params, CFG, batch=6,
-                             max_len=32)._admit_width == 6
+
+    budget = S._ADMIT_TOKEN_BUDGET
+    for rows, bucket, real in dispatched:
+        assert rows == max(1, min(slots, budget // bucket)), dispatched
+        assert 1 <= real <= rows
+    assert sum(real for _, _, real in dispatched) == len(prompts)
+    assert batcher.prefill_padded_tokens == padded0 + sum(
+        rows * bucket for rows, bucket, _ in dispatched)
+    assert batcher.prefill_forward_tokens == len(prefix) + sum(lengths)
+    # one program a bucket: the shapes dispatched are the shapes traced
+    # (a shape an earlier case compiled is not traced again)
+    buckets = {bucket for _, bucket, _ in dispatched}
+    traced = retrace_guard.new_traces(program)
+    assert all(n == 1 for n in traced.values()), traced
+    assert set(traced) <= {(S.admit_width(b, slots), b) for b in buckets}
+    if split is not None:
+        # the first wave fills every slot at once: its requests of
+        # bucket ``split`` outnumber the width, so they took several
+        # dispatches, the first of them full
+        first = [S.bucket_for(n, 288 - len(prefix))
+                 for n in lengths[:slots]]
+        want = -(-first.count(split) // S.admit_width(split, slots))
+        assert want > 1
+        wave = dispatched[:len(set(first)) - 1 + want]
+        assert [d[1] for d in wave].count(split) == want, dispatched
+        assert (S.admit_width(split, slots), split,
+                S.admit_width(split, slots)) in wave

@@ -180,19 +180,20 @@ ENGINE_PHASES = tracing.PROFILER_PREFIX + "engine"
 #: share one program rather than compiling 16 tiny variants
 _MIN_ADMIT_BUCKET = 16
 
-#: most rows one bucketed admission dispatch prefills. The dispatch is
-#: padded to a fixed row count so each bucket compiles one program. With
-#: that count = the slot count, a wave that freed one or two of 32 slots
-#: prefilled 32 x bucket positions: a fifth of a saturated window at
-#: Kimi-K2.5's widths, and how many such dispatches a run happened to
-#: make moved its tokens per second by up to 5% from run to run (my chip
-#: runs, PR 28). A wider wave goes through in several dispatches. Any
-#: batcher of more than 8 slots dispatches differently for it; up to 8
-#: (the Phi-3 cells' 6) the width is the slot count as before. Only 32
-#: and 8 were read on the chip, and only at those widths: 8 is the
-#: benchmark's largest slot count that keeps its old dispatch, not the
-#: best of a sweep (PERF.md section 7).
-_ADMIT_ROWS_MAX = 8
+#: positions (rows x bucket) one bucketed admission dispatch prefills:
+#: the dispatch is ``clamp(budget // bucket, 1, slots)`` rows wide
+#: (:func:`admit_width`), a function of the bucket alone, so each bucket
+#: still compiles one program. Below about peak FLOP/s over peak bytes/s
+#: positions (197e12 / 819e9 = 240 on a v5e, bf16 weights) a prefill
+#: costs the read of the weights whatever its width, so padding rows are
+#: free and a wave of short prompts lands in one call; above it every
+#: padded position costs its FLOPs, so a bucket at or over the budget
+#: dispatches ONE row and a wider wave goes through in several
+#: dispatches. Padded to the slot count instead, a closed loop that
+#: frees one slot an admission prefilled 6 x the bucket for one prompt:
+#: 41% of a saturated Phi-3 window (PERF.md section 6, PR 29, with the
+#: budgets read on the chip).
+_ADMIT_TOKEN_BUDGET = 256
 
 #: rng-stream id for rows with no occupant (their draws are garbage the
 #: host discards; any fixed stream works)
@@ -201,6 +202,13 @@ _IDLE_STREAM = 0x7FFFFFFF
 
 def _count_trace(name: str, shape) -> None:
     TRACE_COUNTS[(name, tuple(shape))] += 1
+
+
+def admit_width(bucket: int, slots: int) -> int:
+    """Rows of one bucketed admission dispatch at ``bucket`` positions a
+    row: as many as fit ``_ADMIT_TOKEN_BUDGET``, at least one, at most
+    the slot count."""
+    return max(1, min(slots, _ADMIT_TOKEN_BUDGET // bucket))
 
 
 def bucket_for(n: int, cap: int,
@@ -303,9 +311,9 @@ def admit_rows(params, cache, logits, rows, prompts, lengths, cfg):
     true prompt lengths (TRACED — any mix of real lengths reuses the
     bucket's compiled program); rows: [K] target slots, with unused
     entries set to DISTINCT out-of-range sentinels (>= batch) whose
-    scatter updates drop — the batcher always pads K to the full slot
-    count, so each bucket compiles exactly one program however many
-    slots freed. The prefill runs all K rows
+    scatter updates drop — the batcher always pads K to the bucket's
+    :func:`admit_width`, so each bucket compiles exactly one program
+    however many slots freed. The prefill runs all K rows
     (:func:`~tony_tpu.models.decode.prefill_rows`), each slot's K/V land
     via one batch-axis scatter per buffer
     (:func:`~tony_tpu.models.decode.place_rows`), and each slot's
@@ -876,8 +884,6 @@ class ContinuousBatcher:
         self.params = params
         self.cfg = cfg
         self.batch = batch
-        #: rows a bucketed admission dispatch carries (see _ADMIT_ROWS_MAX)
-        self._admit_width = min(batch, _ADMIT_ROWS_MAX)
         self.max_len = max_len
         self.eos_id = eos_id
         if shared_prefix is not None:
@@ -918,6 +924,11 @@ class ContinuousBatcher:
         #: true tokens run through a prefill/extend forward at
         #: admission vs prefix positions satisfied by a template COPY
         self.prefill_forward_tokens = 0
+        #: positions those forwards RAN: a bucketed dispatch's rows x
+        #: bucket (:func:`admit_width`), a per-row program's own length.
+        #: forward / padded is the share of prefilled positions that
+        #: were a real prompt's
+        self.prefill_padded_tokens = 0
         self.prefix_copied_tokens = 0
         self.prefix_admits = 0
         #: sampling controls (greedy by default). Streams are
@@ -1033,6 +1044,7 @@ class ContinuousBatcher:
         if template is None:
             template = prefix_template(self.params, tokens, self.cfg)
             self.prefill_forward_tokens += len(tokens)
+            self.prefill_padded_tokens += len(tokens)
         else:
             template = validate_template_bufs(kv_wire_layout(self.cfg),
                                               tokens, template)
@@ -1313,7 +1325,7 @@ class ContinuousBatcher:
                     whole = groups[(pid, bucket)]
                     entry = (prompts[whole[0][1]].entry if pid is not None
                              else None)
-                    w = self._admit_width
+                    w = admit_width(bucket, self.batch)
                     for i in range(0, len(whole), w):
                         grp = whole[i:i + w]
                         rows, keys = self._marshal_wave(grp, w)
@@ -1321,11 +1333,13 @@ class ContinuousBatcher:
                                                           bucket, w)
                         self._admit_rows(rows, toks, lens, keys,
                                          entry=entry)
+                        self.prefill_padded_tokens += w * bucket
                         self._rebind_streams(grp, rows, keys)
                         self._count_admission(grp, prompts)
             else:
                 for row, req in pairs:
-                    self._admit_legacy(row, req, prompts)
+                    self.prefill_padded_tokens += self._admit_legacy(
+                        row, req, prompts)
                 rows, keys = self._marshal_wave(pairs)
                 self._rebind_streams(pairs, rows, keys)
                 self._count_admission(pairs, prompts)
@@ -1373,7 +1387,9 @@ class ContinuousBatcher:
             if stats:
                 self._device_stats.append(("admit", stats))
 
-    def _admit_legacy(self, row, req, prompts) -> None:
+    def _admit_legacy(self, row, req, prompts) -> int:
+        """Admit one request through its per-row program; returns the
+        positions that program ran."""
         p = prompts[req]
         if isinstance(p, _PrefixHit):
             self.cache, self.logits = prefix_admit_row(
@@ -1399,6 +1415,8 @@ class ContinuousBatcher:
                 self.params, self.cache, self.logits, row,
                 jnp.asarray(padded, jnp.int32),
                 jnp.asarray(n, jnp.int32), self.cfg)
+            return padded.shape[1]
+        return len(self._seq_of(p))
 
     # --- dispatch/fetch seams (overridden by the speculative batcher) ---
 
@@ -1676,7 +1694,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
             "speculative serving is not supported in disaggregated "
             "mode (the shipment carries no draft-model cache)")
 
-    def _admit_legacy(self, row, req, prompts) -> None:
+    def _admit_legacy(self, row, req, prompts) -> int:
         p = prompts[req]
         sub = jax.random.fold_in(self._req_key(req), 0)
         if isinstance(p, _PrefixHit):
@@ -1686,7 +1704,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
                 p.entry.draft_template,
                 jnp.asarray(p.suffix, jnp.int32)[None], sub, self.cfg,
                 self.draft_cfg, self.temperature, self.top_k, self.top_p)
-            return
+            return len(p.suffix)
         tokens = jnp.asarray(p, jnp.int32)[None]
         if self._prefix_template is not None:
             self.cache, self.d_cache, self.pending = spec_prefix_admit_row(
@@ -1699,6 +1717,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
                 self.params, self.draft_params, self.cache, self.d_cache,
                 self.pending, row, tokens, sub, self.cfg, self.draft_cfg,
                 self.temperature, self.top_k, self.top_p)
+        return len(p)
 
     def _issue(self):
         with self.phase_times.phase("dispatch"):
@@ -1977,6 +1996,11 @@ class ServeEngine:
             help="true prompt/suffix tokens run through a prefill or "
                  "extend forward at admission (the prefill-FLOPs "
                  "proxy the prefix fast path shrinks)")
+        self._prefill_pad_c = reg.counter(
+            "tony_prefill_padded_tokens_total",
+            help="positions those forwards ran: a bucketed dispatch's "
+                 "rows x bucket (serve.admit_width); prefill tokens "
+                 "over this is the share that was a real prompt's")
         self._prefix_tok_c = reg.counter(
             "tony_serve_prefix_tokens_total",
             help="prefix positions satisfied by a resident-template "
@@ -2219,6 +2243,7 @@ class ServeEngine:
                 # the prefix fast path's compute story, readable
                 # cross-process (the e2e zero-prefix-forward pin)
                 "prefill_tokens": self.b.prefill_forward_tokens,
+                "prefill_padded_tokens": self.b.prefill_padded_tokens,
                 "prefix_tokens": self.b.prefix_copied_tokens,
                 "prefix_admits": self.b.prefix_admits,
                 # the expert layers' device counters per program kind
@@ -2403,7 +2428,7 @@ class ServeEngine:
             return
         b = self.b
         before = (b.prefill_forward_tokens, b.prefix_copied_tokens,
-                  b.prefix_admits)
+                  b.prefix_admits, b.prefill_padded_tokens)
         b._admit_batch(pairs, prompts)
         self._admitted_c.inc(len(admitted))
         # fold the batcher's host-side prefill accounting into the
@@ -2414,6 +2439,8 @@ class ServeEngine:
             self._prefix_tok_c.inc(b.prefix_copied_tokens - before[1])
         if b.prefix_admits > before[2]:
             self._prefix_admits_c.inc(b.prefix_admits - before[2])
+        if b.prefill_padded_tokens > before[3]:
+            self._prefill_pad_c.inc(b.prefill_padded_tokens - before[3])
 
     def _pick_admissions(self):
         """The host half of an admission sweep: pop, preempt, and close
