@@ -1,478 +1,22 @@
-"""Benchmark: flagship transformer train-step throughput on one chip.
+"""Deterministic injected-floor simulations that tier-1 tests drive.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
-
-The reference publishes no performance numbers (BASELINE.md: "published":
-{}), so ``vs_baseline`` is measured in-run against the naive formulation of
-the same model — dense O(S²) attention and no fused kernels — i.e. what a
-line-for-line port of a CUDA/torch-style model to jax would do. Values > 1
-mean the framework's TPU-first path (flash-attention pallas kernels, bf16
-MXU matmuls, fused norms) beats the naive port on the same hardware.
+Each ``_*_arm`` below runs a REAL piece of the system (the slice backend
+against the fake gcloud, the coordinator's recovery path, the serving
+router over simulated or toy-width replicas, the cross-slice pipeline
+channel) with an injected latency floor where a cluster or a chip would
+put one, and returns a dict a test asserts on: ``tests/`` import this
+file as ``bench`` and call the arms by name. Nothing here measures the
+chip and no arm runs unless a test calls it. The benchmark is
+``benchmark/`` (``BENCHMARK.json``, ``python3 benchmark/run.py``); what
+it measured is in ``PERF.md`` and ``PERF_LEDGER.jsonl``.
 """
 
 from __future__ import annotations
 
-import functools
-import json
 import time
 
 import jax
 import jax.numpy as jnp
-
-# Peak dense bf16 FLOP/s per chip, keyed by substring of device_kind.
-# Order matters: more specific names first ("v5 lite" before "v5").
-_PEAK_FLOPS = (
-    ("v6 lite", 918e12), ("v6e", 918e12),
-    ("v5 lite", 197e12), ("v5e", 197e12),
-    ("v5p", 459e12), ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 46e12),
-)
-
-
-def _peak_flops() -> float:
-    """Peak dense bf16 FLOP/s of the attached chip. A device that is not
-    in the table is an error, not a default: an MFU against a guessed
-    peak is not a measurement."""
-    kind = jax.devices()[0].device_kind
-    for name, peak in _PEAK_FLOPS:
-        if name in kind.lower():
-            return peak
-    raise ValueError(f"no peak FLOP/s on record for device_kind {kind!r}; "
-                     f"add it to _PEAK_FLOPS with its source")
-
-
-def _bench_step(step, state, batch, iters: int, reps: int = 3) -> float:
-    """Median-of-windows step time. One window can record a bad moment
-    of a host shared with other work as the framework's throughput, so
-    each config is timed over ``reps`` windows and the median wins. Each
-    window ends in ``jax.block_until_ready``: dispatch is asynchronous,
-    and a window that does not wait for the device times the enqueue."""
-    state, m = step(state, batch)            # compile + warm
-    jax.block_until_ready((state, m))
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            state, m = step(state, batch)
-        jax.block_until_ready((state, m))
-        times.append((time.perf_counter() - t0) / iters)
-    times.sort()
-    return times[len(times) // 2]
-
-
-def main() -> None:
-    from tony_tpu.runtime import compile_cache
-    # ~2/3 of a cold bench run is XLA compilation (6 jitted programs); the
-    # persistent cache makes repeat runs start measuring immediately.
-    compile_cache.enable()
-
-    from tony_tpu.models import transformer as T
-    from tony_tpu.models.train import (default_optimizer, init_state,
-                                       make_train_step)
-
-    platform = jax.devices()[0].platform
-    if platform != "tpu":
-        # no chip, no number: a timing from XLA's CPU backend or the
-        # Pallas interpreter is not a slower version of the chip's
-        raise SystemExit(f"bench.py measures on a TPU; JAX found "
-                         f"platform {platform!r}")
-    # 512d/8L bf16, seq 1024. remat off (this size fits HBM comfortably
-    # on one chip, ~7% faster), layers fully unrolled (drops the scan's
-    # activation-stacking DUS ops, ~6% faster; compile cost is paid
-    # once), batch 32 (+12% over 16 in interleaved A/B once bf16 logits
-    # storage freed the headroom).
-    cfg = T.PRESETS["small"].scaled(remat=False, scan_unroll=8)
-    batch, seq, iters = 32, 1024, 20
-
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq + 1), 0,
-                                cfg.vocab_size)
-    data = {"inputs": tokens[:, :seq], "targets": tokens[:, 1:]}
-
-    def run(config, run_data, run_iters, reps=3) -> float:
-        params = T.init_params(jax.random.PRNGKey(0), config)
-        opt = default_optimizer(lr=1e-3)
-        state = init_state(params, opt)
-        step = make_train_step(
-            lambda p, b: T.lm_loss(p, b, config), opt)
-        return _bench_step(step, state, run_data, run_iters, reps=reps)
-
-    t_framework = run(cfg, data, iters)
-
-    # Naive port baseline: f32 params/compute, dense attention (remat off so
-    # it is the straight autodiff graph a naive port gets). Run at batch 8 —
-    # the naive formulation's own best config: at batch 16 its f32 dense
-    # attention residuals blow past HBM and it collapses pathologically,
-    # which would flatter vs_baseline. Compare per-token throughput.
-    import tony_tpu.models.transformer as tmod
-    naive_cfg = cfg.scaled(dtype=jnp.float32, remat=False)
-    n_batch = min(batch, 8)
-    n_data = {k: v[:n_batch] for k, v in data.items()}
-    orig = tmod._attention
-    tmod._attention = lambda q, k, v, *a: tmod.reference_attention(
-        q, k, v, causal=True)
-    try:
-        # 2 windows: the RATIO tolerates drift better than absolute numbers
-        t_naive = run(naive_cfg, n_data, iters, reps=2)
-    finally:
-        tmod._attention = orig
-
-    tokens_per_sec = batch * seq / t_framework
-    naive_tokens_per_sec = n_batch * seq / t_naive
-    out = {
-        "metric": "flagship_lm_train_throughput",
-        "value": round(tokens_per_sec, 1),
-        "unit": "tokens/s",
-        "vs_baseline": round(tokens_per_sec / naive_tokens_per_sec, 3),
-    }
-
-    peak = _peak_flops()
-    flops_tok = T.train_flops_per_token(cfg, seq)
-    out["mfu"] = round(tokens_per_sec * flops_tok / peak, 4)
-    out["device"] = jax.devices()[0].device_kind
-
-    # Secondary: KV-cache autoregressive decode throughput (the serving
-    # path: prefill + scan-decode as one compiled program).
-    from tony_tpu.models.decode import generate
-    d_batch, d_prompt, d_new = 16, 128, 256
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    prompt = jax.random.randint(jax.random.PRNGKey(3),
-                                (d_batch, d_prompt), 0, cfg.vocab_size)
-    # generate is already jit-compiled (static cfg/lengths)
-    gen = functools.partial(generate, cfg=cfg, max_new_tokens=d_new,
-                            temperature=0.0)
-    dec = gen(params, prompt, rng=jax.random.PRNGKey(4))
-    int(dec.tokens[0, 0])                    # compile + warm
-    t0 = time.perf_counter()
-    for i in range(3):
-        dec = gen(params, prompt, rng=jax.random.PRNGKey(5 + i))
-    int(dec.tokens[0, 0])
-    t_dec = (time.perf_counter() - t0) / 3
-    decode_tps = round(d_batch * d_new / t_dec, 1)
-    # GQA decode (n_kv_heads=2): the grouped cache read + GQA-native
-    # prefill kernels cut the decode-roofline HBM traffic — recorded
-    # as its own arm since the model differs from the MHA flagship.
-    gqa_cfg = cfg.scaled(n_kv_heads=2)
-    gqa_params = T.init_params(jax.random.PRNGKey(0), gqa_cfg)
-    gqa_gen = functools.partial(generate, cfg=gqa_cfg,
-                                max_new_tokens=d_new, temperature=0.0)
-    dec = gqa_gen(gqa_params, prompt, rng=jax.random.PRNGKey(4))
-    int(dec.tokens[0, 0])                    # compile + warm
-    t0 = time.perf_counter()
-    for i in range(3):
-        dec = gqa_gen(gqa_params, prompt, rng=jax.random.PRNGKey(9 + i))
-    int(dec.tokens[0, 0])
-    decode_gqa_tps = round(d_batch * d_new * 3
-                           / (time.perf_counter() - t0), 1)
-    out["decode_gqa_tokens_per_s"] = decode_gqa_tps
-    del gqa_params, gqa_gen
-    del params, prompt, dec, gen   # free HBM before the tight base run
-
-    def secondary(name, config, s_batch, s_seq, s_iters, key,
-                  with_mfu=True):
-        toks = jax.random.randint(jax.random.PRNGKey(key),
-                                  (s_batch, s_seq + 1), 0,
-                                  config.vocab_size)
-        s_data = {"inputs": toks[:, :s_seq], "targets": toks[:, 1:]}
-        tps = s_batch * s_seq / run(config, s_data, s_iters, reps=2)
-        out[f"{name}_tokens_per_s"] = round(tps, 1)
-        if with_mfu:
-            out[f"{name}_mfu"] = round(
-                tps * T.train_flops_per_token(config, s_seq) / peak, 4)
-
-    # GQA flagship (n_kv_heads=2): the grouped-query training win the
-    # GQA-native kernels buy (K/V projections + attention K/V reads
-    # ÷4). MFU accounting is GQA-aware (train_flops_per_token).
-    secondary("gqa", cfg.scaled(n_kv_heads=2), batch, seq, 15, key=8)
-    # "base" preset (768d/12L, BERT-base scale) at seq 2048 — stresses
-    # framework overheads the small preset doesn't. remat off fits at
-    # batch 8 on 16G HBM and is ~25% faster than remat at b=4.
-    secondary("base", T.PRESETS["base"].scaled(remat=False,
-                                               scan_unroll=12),
-              8, 2048, 10, key=2)
-    out["decode_tokens_per_s"] = decode_tps
-    # "large" preset (1536d/24L, 1.0B params) — remat on (the optimizer
-    # state already takes ~8 GB of HBM); the bigger matmuls give the
-    # best MFU of any preset.
-    secondary("large", T.PRESETS["large"], 4, 1024, 8, key=7)
-    # long context (seq 8192) — the regime where attention dominates
-    # layer FLOPs. Batch 4 is ~4% over 2 (interleaved A/B) and fits.
-    # MFU recorded so the fused-vs-two-pass backward budget decision
-    # (ops/attention.py _FUSED_PARTIALS_BYTES) has an efficiency
-    # number to regress against.
-    secondary("seq8k", cfg, 4, 8192, 10, key=6)
-    # sliding-window attention at the same shape: the kernels triage
-    # out-of-window blocks like above-diagonal ones (skip + DMA
-    # elision), so attention cost goes O(seq·window). Measured 1.34x
-    # over full causal at this shape when introduced (round 5).
-    secondary("seq8k_win1k", cfg.scaled(attn_window=1024), 4, 8192,
-              10, key=6)
-    # extreme context (seq 32768, b1) under the attention-output-save
-    # remat policy (round 5): saving the flash o/lse lets the
-    # backward skip re-running the O(S²) forward kernel — +19%
-    # measured over remat="full" at this shape.
-    secondary("seq32k", T.PRESETS["small"].scaled(
-        remat=True, remat_policy="attn"), 1, 32768, 5, key=9)
-    # sliding window at extreme context — the regime where the
-    # quadratic attention term dominates and the window pays most
-    # (2.16x over full causal when introduced; MFU is the honest
-    # windowed-FLOPs ratio, so it DROPS while tokens/s rises)
-    secondary("seq32k_win4k", T.PRESETS["small"].scaled(
-        remat=True, remat_policy="attn", attn_window=4096),
-        1, 32768, 5, key=9)
-    # ring-attention flash-chunk arm (cp=1 degenerate, 2 chunks on one
-    # chip): runs flash_attention_with_lse + the logsumexp hop merge —
-    # the exact per-hop compute of the cp ring — on real hardware, and
-    # checks it against the monolithic kernel. Reported as fwd+bwd
-    # tokens/s so the differentiated-lse path is exercised too.
-    out.update(_ring_flash_arm())
-    # serving-shape decode: a cache padded to realistic serving
-    # max_len (2k / 8k) with a short generated length — the arm the
-    # length-aware block-wise cache attention exists for. Cost should
-    # be ~flat in max_len (vs linear for the dense full-cache read,
-    # recorded as the contrast).
-    out.update(_serving_decode_arm(cfg))
-    # continuous batching at mixed generation budgets: step
-    # utilization (useful tokens per slot-step) vs the static-batch
-    # baseline that rides every batch to its longest request, with
-    # the pipelined (double-buffered dispatch) loop against the
-    # sequential contrast.
-    out.update(_continuous_batching_arm(cfg))
-    # admission latency: bucketed+batched admission (one program per
-    # power-of-two length bucket, one dispatch per freed-slot wave)
-    # vs the per-length per-row path it replaced.
-    out.update(_admission_arm(cfg))
-    # metrics-plane overhead: the serve loop is instrumented
-    # unconditionally (runtime/metrics.py), so this arm pins that
-    # registry observations stay within noise — instrumented vs
-    # NullRegistry serve on the same workload, plus a hard assert
-    # that per-sync observation cost is < 1% of chunk wall.
-    out.update(_metrics_overhead_arm(cfg))
-    # tracing-plane overhead: the serve engine opens TTFT-
-    # decomposition spans per request (runtime/tracing.py), so this
-    # arm pins sampled-on tracing within the same budget discipline
-    # as the metrics arm (< 1% of chunk wall, A/B within noise) and
-    # asserts the exported trace is schema-valid Chrome trace JSON.
-    out.update(_trace_overhead_arm(cfg))
-    # speculative decoding with a GENUINELY smaller draft: both models
-    # are first trained on a learnable sequence so the draft actually
-    # predicts the target (acceptance is what buys wall-clock; with a
-    # random draft speculation is a correctness demo only).
-    out.update(_speculative_arm())
-
-    # job bring-up wall against the fake gcloud fleet: cold 4-gang launch
-    # parallel vs the serial baseline (max-of-gangs vs sum-of-gangs), and
-    # the warm-restart wall where surviving slices are adopted and the
-    # content-stamp probe skips the tarball ship entirely. Hardware-free.
-    out.update(_launch_arm())
-
-    # elastic recovery: the same injected gang kill absorbed by the
-    # degraded-resume loop (survivors resync + resume; the lost gang
-    # regrows) vs the stop-the-world session re-run. Hardware-free and
-    # jax-free (fake trainer): the numbers measure ORCHESTRATION — loss
-    # detection, resync, relaunch — not model compile walls.
-    out.update(_elastic_arm())
-
-    # coordinator crash recovery: SIGKILL the coordinator mid-train and
-    # let journal replay re-adopt the live executors (user processes
-    # never stop, zero re-provisions) vs the cold full-job restart the
-    # journal-less stack pays — resubmit, re-provision, re-run every
-    # step since the last checkpoint. Hardware-free and jax-free; the
-    # recovery number is the coordinator's own recovery-wall gauge and
-    # the ratio is pinned >= 3x (tests/test_recovery.py runs the arm).
-    out.update(_recovery_arm())
-
-    # goodput ledger: interval-accounting overhead vs a NullRegistry
-    # ledger (< 1% of the measured step wall asserted inside the arm)
-    # plus goodput_fraction_train read off a real local-backend run's
-    # final GOODPUT jhist event — the job page's headline number.
-    # Hardware-free and jax-free.
-    out.update(_goodput_arm())
-
-    # tonylint full-repo analysis wall: the static gate must stay cheap
-    # enough to run in tier-1 on every PR (< 10 s asserted inside the
-    # arm), and the shipped tree must carry zero non-baselined findings.
-    # Hardware-free and jax-free.
-    out.update(_lint_arm())
-
-    # cluster daemon: back-to-back 3-job turnover through the warm
-    # slice pool (digest-affinity ALREADY_EXISTS adoption) vs cold
-    # sequential bring-up. Real daemon + oracle jobs, no hardware; the
-    # tier-1 pin (tests/test_cluster.py) asserts
-    # sched_warm_turnover_vs_cold >= 2.
-    out.update(_sched_arm())
-
-    # streaming serving data plane: the persistent token-push wire vs a
-    # request/response round trip per chunk, through an injected-latency
-    # transport (LatencyProxy). Deterministic: a tiny CPU model with a
-    # fixed per-sync fetch floor standing in for device compute, so the
-    # ratio measures TRANSPORT shape, not rig noise. The tier-1 pin
-    # (tests/test_serving.py) asserts stream-vs-rr >= 2 at a 50 ms round
-    # trip and streamed wall within 1.15x of the zero-delay wall.
-    out.update(_streaming_arm())
-
-    # disaggregated prefill/decode: a prefill gang ships KV packages to
-    # a decode gang over a tensor channel, so concurrent admissions
-    # never stall in-flight decode chunks — decode ITL p99 under
-    # admission churn vs the colocated engine at equal slots, with
-    # token-identical output asserted. Deterministic: injected prefill/
-    # decode compute floors (the streaming arm's technique); tier-1
-    # pins serving_disagg_itl_p99_vs_colocated >= 2 and the handoff
-    # wall visible on the metrics plane (tests/test_disagg.py).
-    out.update(_disagg_arm())
-
-    # live fleet operations on the simulated fleet: drain the most-
-    # loaded replica under concurrent streams; every migrated session's
-    # tokens are checked against the sim oracle, so the migration
-    # dup/drop gap is an exact count (== 0 tier-1-pinned,
-    # tests/test_fleet.py) and the drain wall is bounded by placement
-    # latency, not stream length.
-    out.update(_fleet_arm())
-
-    # SLO-tiered serving: identical 2x-overload open-loop mixed
-    # workload with and without QoS classes; interactive p99 TTFT
-    # holds under priority admission + batch-row preemption while the
-    # classless FIFO baseline blows through it (ratio >= 2
-    # tier-1-pinned) and every preemption eviction resumes
-    # token-identically (gap == 0 tier-1-pinned, tests/test_qos.py).
-    out.update(_qos_arm())
-
-    # warm scale-up: content-addressed weights shipped peer-to-peer
-    # over the channel plane vs cold storage load + retrace, plus the
-    # 8-replica rolling upgrade as one seed load + O(log N) fan-out vs
-    # N serial loads. Tier-1 pins warm_vs_cold >= 2 and the wave count
-    # (tests/test_weightstore.py).
-    out.update(_weight_ship_arm())
-
-    # prefix-aware routing + shared KV prefix tier: sessions placed
-    # where the prefix KV already lives (one replica computes the
-    # prefix once, the other warms in one template ship), suffix-only
-    # admission vs prefix-blind full prefill at 8x prefix reuse.
-    # Deterministic: a prefill floor per forward token + fetch floors;
-    # tier-1 pins serving_prefix_ttft_vs_blind >= 2 and the FLOPs
-    # reduction (tests/test_prefix.py).
-    out.update(_prefix_arm())
-
-    # cross-slice MPMD pipeline: the overlapped 1F1B schedule (channel
-    # sends ride the bounded window while the device computes the next
-    # microbatch) vs serialized stage execution (every tensor hop waits
-    # for its delivery ack) through an injected-DCN-latency transport.
-    # Deterministic: tiny stage blocks + fixed compute floors; the
-    # tier-1 pin (tests/test_channels.py) asserts overlap >= 1.5x.
-    out.update(_pipeline_arm())
-
-    # DCN bytes as a resource: int8 wire codec bytes ratio + interleaved
-    # (v=2) vs flat placement walls under injected latency. Tier-1 pins:
-    # bytes >= 1.9x, interleaved beats flat (tests/test_channels.py).
-    out.update(_pipeline_dcn_arm())
-
-    # device-prefetched vs synchronous train feed: with nonzero decode
-    # cost the pipelined loop's step wall should approach the
-    # pure-compute wall (decode + H2D overlap the device step) while the
-    # synchronous loop pays decode + compute serially; the data-wait
-    # histogram is the direct input-boundedness signal.
-    out.update(_input_pipeline_arm(cfg, batch, seq, steps=20))
-
-    print(json.dumps(out))
-
-
-def _input_pipeline_arm(cfg, batch, seq, steps: int = 20):
-    """Prefetched vs synchronous train feed (the train-path twin of the
-    serve loop's pipelined-vs-sequential arm).
-
-    Three loops over the SAME jitted step and batch shape:
-
-    - pure-compute: one preassembled device batch re-fed every step — the
-      floor the pipelined loop must approach;
-    - synchronous: each step decodes on the host (an emulated IO/decode
-      stall of 0.6x the compute wall, plus a real bytes→ndarray decode)
-      then assembles/transfers inline — steady-state wall >= decode +
-      compute, the pre-change ``global_batch``-inline behavior;
-    - prefetched: the same source behind a depth-2 DevicePrefetcher
-      driven by run_training — decode + H2D overlap device compute, so
-      step wall should sit within ~1.1x of pure compute and
-      ``tony_data_wait_seconds`` near zero.
-
-    The sleep-based stall is deliberate: reader decode is IO-dominated
-    (GIL released), so overlap potential is real, and the arm stays
-    deterministic across rigs."""
-    import numpy as np
-
-    from tony_tpu.io.prefetch import DevicePrefetcher
-    from tony_tpu.models import transformer as T
-    from tony_tpu.models.loop import run_training
-    from tony_tpu.models.train import (default_optimizer, init_state,
-                                       make_train_step)
-    from tony_tpu.runtime import metrics as M
-
-    opt = default_optimizer(lr=1e-3)
-    step = make_train_step(lambda p, b: T.lm_loss(p, b, cfg), opt)
-
-    def fresh_state():
-        return init_state(T.init_params(jax.random.PRNGKey(0), cfg), opt)
-
-    rs = np.random.RandomState(0)
-    raw = rs.randint(0, cfg.vocab_size,
-                     size=(batch, seq + 1)).astype(np.int32).tobytes()
-
-    # pure-compute floor: preassembled device batch, step in a tight loop
-    tokens = jnp.asarray(np.frombuffer(raw, np.int32).reshape(batch,
-                                                              seq + 1))
-    dev_batch = {"inputs": tokens[:, :seq], "targets": tokens[:, 1:]}
-    state = fresh_state()
-    state, m = step(state, dev_batch)            # compile + warm
-    float(m["loss"])
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        state, m = step(state, dev_batch)
-    float(m["loss"])
-    t_compute = (time.perf_counter() - t0) / steps
-
-    decode_s = 0.6 * t_compute      # nonzero decode cost, under compute
-
-    def host_batches():
-        while True:
-            time.sleep(decode_s)                 # emulated IO stall
-            arr = np.frombuffer(raw, np.int32).reshape(batch, seq + 1)
-            yield {"inputs": arr[:, :seq], "targets": arr[:, 1:]}
-
-    def sync_batches():
-        # inline decode + H2D on the step critical path (the contrast)
-        for hb in host_batches():
-            yield jax.tree.map(jnp.asarray, hb)
-
-    def timed(data):
-        saved = M.set_default(M.MetricsRegistry())
-        try:
-            st = fresh_state()
-            st, wm = step(st, dev_batch)         # warm (same shapes/jit)
-            float(wm["loss"])
-            t0 = time.perf_counter()
-            st, wm = run_training(step, st, data, steps)
-            float(wm["loss"])
-            wall = (time.perf_counter() - t0) / steps
-            wait = M.get_default().histogram("tony_data_wait_seconds").sum
-        finally:
-            M.set_default(saved)
-        return wall, wait
-
-    t_sync, wait_sync = timed(sync_batches())
-    t_pre, wait_pre = timed(DevicePrefetcher(host_batches(), depth=2))
-
-    return {
-        "train_feed_compute_ms_per_step": round(t_compute * 1e3, 2),
-        "train_feed_decode_ms_per_batch": round(decode_s * 1e3, 2),
-        "train_feed_sync_ms_per_step": round(t_sync * 1e3, 2),
-        "train_feed_prefetch_ms_per_step": round(t_pre * 1e3, 2),
-        # <= 1.1 = pipelined feed reaches the pure-compute floor
-        "train_feed_prefetch_vs_compute": round(t_pre / t_compute, 3),
-        # ~1 + decode share (1.6 here) = synchronous feed pays serially
-        "train_feed_sync_vs_compute": round(t_sync / t_compute, 3),
-        "train_feed_data_wait_s_sync": round(wait_sync, 4),
-        # ~0 = the prefetcher stays ahead of the step loop
-        "train_feed_data_wait_s_prefetch": round(wait_pre, 4),
-    }
 
 
 def _launch_arm(num_gangs: int = 4, create_delay_s: float = 0.6,
@@ -846,143 +390,6 @@ def _recovery_arm(steps: int = 36, step_wait: float = 0.25,
         "cold_restart_wall_s": round(cold_wall, 2),
         "cold_restart_steps_rerun": steps - primed,
         "recovery_vs_cold_restart": round(ratio, 2),
-    }
-
-
-def _goodput_arm(steps: int = 12, step_wait: float = 0.1) -> dict:
-    """Goodput-ledger overhead + a real attributed training run.
-
-    (a) Microbench: one enter/exit interval through the ledger, mirrored
-    into a live MetricsRegistry vs a NullRegistry (the metrics arm's A/B
-    discipline — snapshot-per-"step" included so the mirror path is in
-    the measurement). The train loop opens <= 4 intervals per step
-    (data_wait / step / checkpoint / eval), so 4x the per-interval cost
-    is asserted < 1% of the REAL mean step wall measured in (b) — the
-    issue's hard bound: attribution must be free.
-
-    (b) A 2-worker local-backend run of the jax-free fake trainer whose
-    final (cumulative) GOODPUT jhist event yields
-    ``goodput_fraction_train`` — the same headline the history job page
-    renders — plus the mean step wall used by (a)'s bound.
-
-    Emitted keys: ``goodput_interval_ns``, ``goodput_ledger_frac_of_step``
-    (< 0.01 asserted), ``goodput_ledger_live_vs_null`` (~1.0),
-    ``goodput_fraction_train``, ``goodput_step_wall_mean_s``."""
-    import os
-    import shutil
-    import sys
-    import tempfile
-
-    from tony_tpu.client.client import TonyClient
-    from tony_tpu.conf.config import TonyConfig
-    from tony_tpu.events.events import find_job_files, parse_events
-    from tony_tpu.runtime import goodput as goodput_mod
-    from tony_tpu.runtime import metrics as M
-
-    # (a) per-interval cost through the real enter/exit path; a snapshot
-    # every `per_snap` intervals models the trainer's publish cadence
-    n, per_snap = 100_000, 100
-
-    def timed(reg) -> float:
-        led = goodput_mod.GoodputLedger(registry=reg)
-        t0 = time.perf_counter()
-        for i in range(n):
-            with led.enter("step"):
-                pass
-            if i % per_snap == 0:
-                led.snapshot()
-        return (time.perf_counter() - t0) / n
-
-    live = timed(M.MetricsRegistry())
-    null = timed(M.NullRegistry())
-
-    # (b) the real run: step walls + the headline fraction from the
-    # final GOODPUT event
-    tmp = tempfile.mkdtemp(prefix="tony-goodput-bench-")
-    repo = os.path.dirname(os.path.abspath(__file__))
-    trainer = os.path.join(repo, "tests", "fixtures",
-                           "fake_elastic_trainer.py")
-    try:
-        cmd = (f"{sys.executable} {trainer} --steps {steps} "
-               f"--ckpt {os.path.join(tmp, 'progress')} "
-               f"--ckpt_every 2 --step_wait {step_wait} --tail_wait 0:1.5")
-        conf = TonyConfig({
-            "tony.staging.dir": os.path.join(tmp, "staging"),
-            "tony.history.location": os.path.join(tmp, "hist"),
-            "tony.application.timeout": "120000",
-            "tony.worker.instances": "2",
-            "tony.task.heartbeat-interval-ms": "100",
-            "tony.metrics.snapshot-interval-ms": "300",
-        })
-        rc = TonyClient(conf, cmd).run()
-        assert rc == 0, "goodput bench job failed"
-        final = None
-        for f in find_job_files(os.path.join(tmp, "hist")):
-            for e in parse_events(f):
-                if e.event_type == "GOODPUT":
-                    final = e
-        assert final is not None, "no GOODPUT event reached the jhist"
-        fraction = final.payload["fraction"]
-        assert 0 < fraction <= 1, fraction
-        sw_c = sw_s = 0.0
-        for tid, entry in final.payload["tasks"].items():
-            if tid.startswith("worker:"):
-                sw_c += entry["sw"]["c"]
-                sw_s += entry["sw"]["s"]
-        assert sw_c >= 2 * steps, "trainer ledgers never reached the jhist"
-        step_wall = sw_s / sw_c
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-
-    # the hard bound: <= 4 ledger intervals per train step must cost
-    # < 1% of the step wall they attribute
-    frac = 4 * live / step_wall
-    assert frac < 0.01, (
-        f"goodput ledger costs {frac:.2%} of the step wall — interval "
-        f"accounting is no longer free on the train loop")
-    return {
-        "goodput_interval_ns": round(live * 1e9, 1),
-        "goodput_ledger_frac_of_step": round(frac, 6),
-        "goodput_ledger_live_vs_null": round(live / max(null, 1e-12), 3),
-        "goodput_fraction_train": round(fraction, 4),
-        "goodput_step_wall_mean_s": round(step_wall, 4),
-    }
-
-
-def _lint_arm() -> dict:
-    """tonylint full-repo analysis wall (docs/static-analysis.md).
-
-    Runs every checker — per-file AST passes over all of tony_tpu/ plus
-    the repo-wide proto/frame/observability checks — and asserts the
-    whole sweep lands under 10 s, the budget that keeps the gate cheap
-    enough for tier-1 (tests/test_lint.py runs the same self-check).
-    Also asserts the shipped tree is clean: zero findings outside the
-    committed ratchet baseline.
-
-    Emitted keys: ``lint_full_repo_s`` (< 10 asserted),
-    ``lint_files_scanned``, ``lint_findings_unbaselined`` (== 0
-    asserted), ``lint_baseline_entries``."""
-    import os
-
-    from tony_tpu.devtools import lint
-
-    pkg = os.path.join(lint.REPO_ROOT, "tony_tpu")
-    t0 = time.perf_counter()
-    findings = lint.run([pkg])
-    wall = time.perf_counter() - t0
-    left, _suppressed, _stale = lint.apply_baseline(
-        findings, lint.load_baseline(
-            os.path.join(lint.REPO_ROOT, lint.DEFAULT_BASELINE)))
-    n_files = len(lint.scan_paths([pkg]))
-    assert wall < 10.0, f"tonylint full sweep took {wall:.1f}s (>= 10s)"
-    assert not left, "tonylint found unbaselined findings:\n" + \
-        "\n".join(f.render() for f in left)
-    return {
-        "lint_full_repo_s": round(wall, 3),
-        "lint_files_scanned": n_files,
-        "lint_findings_unbaselined": len(left),
-        "lint_baseline_entries": len(lint.load_baseline(
-            os.path.join(lint.REPO_ROOT, lint.DEFAULT_BASELINE))),
     }
 
 
@@ -1870,702 +1277,6 @@ def _prefix_arm(slots: int = 2, n_req: int = 8, prefix_len: int = 40,
     }
 
 
-def _ring_flash_arm(b=4, s=8192, h=8, d=64, iters=8):
-    from tony_tpu.ops.attention import (flash_attention,
-                                        flash_attention_with_lse)
-
-    half = s // 2
-    q = jax.random.normal(jax.random.PRNGKey(11), (b, s, h, d), jnp.bfloat16)
-    k = jax.random.normal(jax.random.PRNGKey(12), (b, s, h, d), jnp.bfloat16)
-    v = jax.random.normal(jax.random.PRNGKey(13), (b, s, h, d), jnp.bfloat16)
-
-    def two_chunk(q, k, v):
-        # q0 sees only the diagonal chunk; q1 sees one past hop (full)
-        # merged with its diagonal chunk — the 2-device causal ring,
-        # laid out sequentially on one chip
-        q0, q1 = q[:, :half], q[:, half:]
-        k0, k1 = k[:, :half], k[:, half:]
-        v0, v1 = v[:, :half], v[:, half:]
-        o0, _ = flash_attention_with_lse(q0, k0, v0, causal=True)
-        o10, lse10 = flash_attention_with_lse(q1, k0, v0, causal=False)
-        o11, lse11 = flash_attention_with_lse(q1, k1, v1, causal=True)
-        lse1 = jnp.logaddexp(lse10, lse11)
-        to_bshd = lambda w: w.transpose(0, 2, 1)[..., None]
-        o1 = (o10.astype(jnp.float32) * to_bshd(jnp.exp(lse10 - lse1))
-              + o11.astype(jnp.float32) * to_bshd(jnp.exp(lse11 - lse1)))
-        return jnp.concatenate([o0.astype(jnp.float32), o1], axis=1)
-
-    mono = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
-    ring = jax.jit(two_chunk)
-    err = float(jnp.max(jnp.abs(ring(q, k, v)
-                                - mono(q, k, v).astype(jnp.float32))))
-    grad = jax.jit(jax.grad(lambda q, k, v: two_chunk(q, k, v).sum(),
-                            argnums=(0, 1, 2)))
-    g = grad(q, k, v)
-    float(g[0][0, 0, 0, 0].astype(jnp.float32))         # compile + warm
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        g = grad(q, k, v)
-    float(g[0][0, 0, 0, 0].astype(jnp.float32))
-    tps = b * s * iters / (time.perf_counter() - t0)
-    return {"ringflash_tokens_per_s": round(tps, 1),
-            "ringflash_vs_mono_maxerr": round(err, 5)}
-
-
-def _serving_decode_arm(cfg, batch: int = 8, prompt_len: int = 128,
-                        steps: int = 256):
-    """Decode throughput vs padded cache size at FIXED generated length.
-
-    A serving cache is sized for the longest request (2k-32k), while most
-    requests finish far shorter; the dense cached-attention einsum pays
-    for every padded row anyway. This arm prefills+scans ``steps`` greedy
-    tokens into caches padded to 2048 and 8192 positions (live length
-    <= 384 throughout) and reports tokens/s at each — ~flat under the
-    block-wise length-aware path — plus a dense-forced 2048 contrast
-    (the pre-round-5 behavior, linear in max_len)."""
-    from tony_tpu.models import decode as D
-    from tony_tpu.models import transformer as T
-
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-
-    def make_fns(max_len, run_cfg):
-        # fresh closures per variant: the blockwise/dense dispatch happens
-        # at trace time off D._BLOCKWISE_MIN_LEN, so variants must not
-        # share a jit cache entry
-        @jax.jit
-        def do_prefill(p, toks):
-            return D.prefill(p, toks, run_cfg, max_len)
-
-        @functools.partial(jax.jit, static_argnames=("n",))
-        def scan_decode(p, logits, cache, n):
-            def step(carry, _):
-                lg, c = carry
-                token = jnp.argmax(lg, axis=-1)
-                lg, c = D.decode_step(p, token, c, c["length"], run_cfg)
-                return (lg, c), token
-
-            (_, _), gen = jax.lax.scan(step, (logits, cache), None,
-                                       length=n)
-            return gen
-
-        return do_prefill, scan_decode
-
-    def time_one(max_len, force_dense=False, b=batch, run_cfg=cfg,
-                 p_len=prompt_len, p=params):
-        prompt = jax.random.randint(jax.random.PRNGKey(17),
-                                    (b, p_len), 0, cfg.vocab_size)
-        saved = D._BLOCKWISE_MIN_LEN
-        if force_dense:
-            D._BLOCKWISE_MIN_LEN = 1 << 30
-        try:
-            do_prefill, scan_decode = make_fns(max_len, run_cfg)
-            # prefill (incl. the O(max_len) cache zero-init) runs OUTSIDE
-            # the timed region — the metric is decode-step cost vs padded
-            # max_len, and the fixed prefill would pull the ratio toward 1
-            # while the init's max_len-scaled writes pull it away
-            logits, cache = do_prefill(p, prompt)
-            gen = scan_decode(p, logits, cache, steps)
-            int(gen[0, 0])                       # compile + warm
-            reps = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                gen = scan_decode(p, logits, cache, steps)
-                int(gen[0, 0])
-                reps.append(time.perf_counter() - t0)
-            return b * steps / sorted(reps)[1]
-        finally:
-            D._BLOCKWISE_MIN_LEN = saved
-
-    tps2k = time_one(2048)
-    tps8k = time_one(8192)
-    tps2k_dense = time_one(2048, force_dense=True)
-    # serving-batch amortization: the b8 step is per-op-overhead-bound
-    # (~25 us/layer of fori_loop glue vs ~5 us of cache traffic —
-    # docs/performance.md flash-decode negative result), so a wider
-    # serving batch amortizes the fixed cost across 4x the rows; the
-    # per-slot ratio (wide/base throughput over the batch ratio) is the
-    # overhead share a batching queue can reclaim
-    wide = 4 * batch
-    tps2k_wide = time_one(2048, b=wide)
-    # int8 KV cache: HBM footprint and cache read traffic halve vs bf16
-    # (a serving host fits ~2x the slots or 2x max_len); throughput at
-    # the SAME shape should hold near parity — the b8 step is per-op-
-    # overhead-bound, not bandwidth-bound (docs/performance.md) — so the
-    # ratio below is a regression guard for the capacity win, not a
-    # speed claim
-    qcfg = cfg.scaled(kv_cache_dtype="int8")
-    tps8k_quant = time_one(8192, run_cfg=qcfg)
-    tps2k_wide_quant = time_one(2048, b=wide, run_cfg=qcfg)
-    # sliding-window decode at DEEP history (7k-token prompt): full
-    # attention walks every live cache block per token; a window-1024
-    # model walks ~4 blocks regardless of history — per-token serving
-    # cost O(window), the decode-side claim of attn_window.
-    deep = 7168
-    tps_deep_full = time_one(8192, p_len=deep)
-    tps_deep_win = time_one(8192, p_len=deep,
-                            run_cfg=cfg.scaled(attn_window=1024))
-    # rolling ring-buffer cache (kv_cache_capacity): same windowed math
-    # over a capacity-row ring instead of the max_len buffer — 8x less
-    # cache memory at this shape, measured speed parity; the length
-    # ceiling disappears (requests may run past max_len)
-    tps_deep_ring = time_one(8192, p_len=deep,
-                             run_cfg=cfg.scaled(attn_window=1024,
-                                                kv_cache_capacity=1024))
-    # weight-only int8 (models/quantize.py): halves the matmul weights'
-    # HBM read (the parameter-bound share of small-batch decode); the
-    # all-int8 arm composes it with the int8 KV cache at the wide batch
-    from tony_tpu.models.quantize import quantize_weights_int8
-    wq = quantize_weights_int8(params)
-    tps2k_wq = time_one(2048, p=wq)
-    tps2k_wide_all8 = time_one(2048, b=wide, run_cfg=qcfg, p=wq)
-
-    # quantized PREFILL: prefill over a long prompt is compute-bound
-    # (the opposite regime from decode), so _weinsum's prefill-shaped
-    # path converts the int8 weights to bf16 once per call and runs the
-    # dots at bf16 MXU throughput — both-operands-f32 (the decode trade)
-    # measured far below bf16 there. The ratio below pins the win; a
-    # regression back toward all-f32 prefill shows up directly.
-    def time_prefill(p, b_p=8, s_p=1024):
-        toks = jax.random.randint(jax.random.PRNGKey(21), (b_p, s_p), 0,
-                                  cfg.vocab_size)
-        fn = jax.jit(lambda pp, tk: D.prefill(pp, tk, cfg, 2048)[0])
-        float(fn(p, toks)[0, 0])                 # compile + warm
-        reps = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            float(fn(p, toks)[0, 0])
-            reps.append(time.perf_counter() - t0)
-        return b_p * s_p / sorted(reps)[1]
-
-    tps_prefill = time_prefill(params)
-    tps_prefill_wq = time_prefill(wq)
-    return {
-        "decode_maxlen2k_tokens_per_s": round(tps2k, 1),
-        "decode_maxlen8k_tokens_per_s": round(tps8k, 1),
-        "decode_maxlen2k_dense_tokens_per_s": round(tps2k_dense, 1),
-        # ~1.0 = cost flat in padded max_len (the done-criterion)
-        "decode_maxlen_8k_vs_2k": round(tps8k / tps2k, 3),
-        f"decode_maxlen2k_b{wide}_tokens_per_s": round(tps2k_wide, 1),
-        f"decode_b{wide}_vs_b{batch}_per_slot": round(
-            tps2k_wide / tps2k / (wide / batch), 2),
-        "decode_quant8_maxlen8k_tokens_per_s": round(tps8k_quant, 1),
-        "decode_quant8_vs_bf16_8k": round(tps8k_quant / tps8k, 2),
-        f"decode_quant8_maxlen2k_b{wide}_tokens_per_s": round(
-            tps2k_wide_quant, 1),
-        f"decode_quant8_vs_bf16_2k_b{wide}": round(
-            tps2k_wide_quant / tps2k_wide, 2),
-        "decode_deep7k_tokens_per_s": round(tps_deep_full, 1),
-        "decode_deep7k_win1k_tokens_per_s": round(tps_deep_win, 1),
-        "decode_win1k_vs_full_deep7k": round(
-            tps_deep_win / tps_deep_full, 2),
-        "decode_ring1k_deep7k_tokens_per_s": round(tps_deep_ring, 1),
-        "decode_ring_vs_linear_win_deep7k": round(
-            tps_deep_ring / tps_deep_win, 2),
-        "decode_wq8_maxlen2k_tokens_per_s": round(tps2k_wq, 1),
-        "decode_wq8_vs_bf16_2k": round(tps2k_wq / tps2k, 2),
-        f"decode_all_int8_b{wide}_tokens_per_s": round(
-            tps2k_wide_all8, 1),
-        f"decode_all_int8_vs_bf16_b{wide}": round(
-            tps2k_wide_all8 / tps2k_wide, 2),
-        "prefill_b8_1k_tokens_per_s": round(tps_prefill, 1),
-        "prefill_wq8_b8_1k_tokens_per_s": round(tps_prefill_wq, 1),
-        # near 1.0 = quantized serving no longer pays an f32-prefill
-        # latency tax (the pre-change all-f32 path sat well below it)
-        "prefill_wq8_vs_bf16": round(tps_prefill_wq / tps_prefill, 2),
-    }
-
-
-def _continuous_batching_arm(cfg, slots: int = 8, prompt_len: int = 64):
-    """Continuous batching vs static batches at mixed generation budgets.
-
-    24 requests, budgets cycling 32..256 (mean 144) through 8 slots. The
-    static baseline runs batches of 8 to each batch's LONGEST budget —
-    what plain generate() serving does; finished rows ride dead until
-    the stragglers finish. Reported both ways: wall-clock useful-token
-    throughput (includes the per-chunk host sync the continuous loop
-    pays) and step utilization = useful tokens / (decode steps x slots),
-    the host-independent number."""
-    import numpy as np
-
-    from tony_tpu.models import transformer as T
-    from tony_tpu.models.decode import generate
-    from tony_tpu.models.serve import ContinuousBatcher
-
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    rs = np.random.RandomState(5)
-    # one 256 per batch-of-8 keeps the static arm at ONE compile
-    base = [256, 32, 64, 96, 128, 160, 192, 224]
-    budgets = sum(([*rs.permutation(base)] for _ in range(3)), [])
-    budgets = [int(b) for b in budgets]
-    prompts = [list(rs.randint(0, cfg.vocab_size, size=prompt_len))
-               for _ in budgets]
-    useful = sum(budgets)
-    max_len = prompt_len + 256
-
-    # pipelined (default) loop: chunk N+1 dispatched before chunk N's
-    # fetch, so the host's fetch + bookkeeping overlap device compute
-    batcher = ContinuousBatcher(params, cfg, batch=slots, max_len=max_len,
-                                chunk=16)
-    batcher.serve(prompts[:slots], [16] * slots)      # compile + warm
-    t0 = time.perf_counter()
-    batcher.serve(prompts, budgets)
-    t_cb = time.perf_counter() - t0
-    cb_steps = batcher.steps_executed
-    cb_phases = batcher.phase_times
-
-    # sequential contrast (the pre-pipelining loop): every fetch
-    # serializes the round trip with compute — the overlap win is the
-    # ratio between these two on identical workload and device programs
-    seq = ContinuousBatcher(params, cfg, batch=slots, max_len=max_len,
-                            chunk=16, pipeline=False)
-    seq.serve(prompts[:slots], [16] * slots)          # compile + warm
-    t0 = time.perf_counter()
-    seq.serve(prompts, budgets)
-    t_cb_seq = time.perf_counter() - t0
-
-    gen = functools.partial(generate, cfg=cfg, max_new_tokens=256,
-                            temperature=0.0)
-    warm_prompt = jnp.asarray(prompts[:slots], jnp.int32)
-    out = gen(params, warm_prompt, rng=jax.random.PRNGKey(0))
-    int(out.tokens[0, 0])                             # compile + warm
-    static_steps = 0
-    t0 = time.perf_counter()
-    for i in range(0, len(prompts), slots):
-        batch_prompts = jnp.asarray(prompts[i:i + slots], jnp.int32)
-        out = gen(params, batch_prompts, rng=jax.random.PRNGKey(0))
-        int(out.tokens[0, 0])
-        static_steps += max(budgets[i:i + slots])
-    t_static = time.perf_counter() - t0
-
-    return {
-        # step utilization is the host-independent serving metric
-        # (useful tokens per slot-step); the wall numbers include every
-        # chunk/admit host sync — the pipelined loop overlaps each sync
-        # with the NEXT chunk's device compute (fetch + bookkeeping
-        # hidden behind compute).
-        # On a budget-only workload the pipelined loop runs the same
-        # chunk count as the sequential loop (admission events process
-        # synchronously — serve.py defer_issue), so the util numbers
-        # are directly comparable across rounds.
-        "serving_cb_step_util": round(useful / (cb_steps * slots), 3),
-        "serving_static_step_util": round(
-            useful / (static_steps * slots), 3),
-        "serving_cb_tokens_per_s": round(useful / t_cb, 1),
-        "serving_cb_sequential_tokens_per_s": round(
-            useful / t_cb_seq, 1),
-        # the overlap win, same programs and workload both sides
-        "serving_cb_pipelined_vs_sequential": round(t_cb_seq / t_cb, 2),
-        "serving_static_tokens_per_s": round(useful / t_static, 1),
-        "serving_cb_vs_static_wall": round(t_static / t_cb, 2),
-        # per-sync host phases (pipelined run): fetch is the blocking
-        # transport+compute wait the overlap hides; dispatch is pure
-        # host-side enqueue cost
-        "serving_cb_fetch_ms_per_sync": round(
-            1e3 * cb_phases.total("fetch")
-            / max(1, cb_phases.count("fetch")), 1),
-        "serving_cb_dispatch_ms_per_sync": round(
-            1e3 * cb_phases.total("dispatch")
-            / max(1, cb_phases.count("dispatch")), 1),
-    }
-
-
-def _admission_arm(cfg, slots: int = 8, n_req: int = 32,
-                   budget: int = 8):
-    """Admission cost: bucketed+batched vs per-length admission.
-
-    A churn-heavy workload (short budgets → admission-dominated): 32
-    requests over 12 DISTINCT prompt lengths (33..121) spanning two
-    power-of-two buckets (64, 128). Wall time per admission INCLUDES
-    each path's compiles — the per-length path recompiles for every new
-    prompt length, which IS its cost on real traffic (a serving host
-    sees arbitrary lengths forever), while the bucketed path compiles
-    once per bucket and pads. (12 distinct lengths, not 30+, keeps the
-    legacy arm's compile bill bounded on a cold cache while still
-    making the retrace cost unmistakable.) The admit phase is taken
-    from the batcher's own PhaseTimes, so the number excludes
-    decode/fetch time on both sides."""
-    import numpy as np
-
-    from tony_tpu.models import transformer as T
-    from tony_tpu.models.serve import ContinuousBatcher
-
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    rs = np.random.RandomState(9)
-    distinct = [33 + 8 * i for i in range(12)]            # 33..121
-    prompts = [list(rs.randint(0, cfg.vocab_size, size=int(n)))
-               for n in rs.choice(distinct, size=n_req)]
-    max_len = 128 + 2 * budget
-
-    def admit_ms_per_req(bucketed):
-        b = ContinuousBatcher(params, cfg, batch=slots, max_len=max_len,
-                              chunk=budget, bucketed_admission=bucketed)
-        b.serve(prompts, budget)
-        return 1e3 * b.phase_times.total("admit") / n_req
-
-    ms_bucketed = admit_ms_per_req(True)
-    ms_perlen = admit_ms_per_req(False)
-    return {
-        "serving_admit_ms_per_req_bucketed": round(ms_bucketed, 2),
-        "serving_admit_ms_per_req_perlength": round(ms_perlen, 2),
-        "serving_admission_speedup": round(ms_perlen / ms_bucketed, 2),
-    }
-
-
-def _metrics_overhead_arm(cfg, slots: int = 8, prompt_len: int = 64,
-                          budget: int = 128):
-    """Metrics-registry overhead on the serve hot loop.
-
-    The continuous batcher observes a handful of counters/gauges per host
-    SYNC (not per token) and folds PhaseTimes once per serve() call —
-    this arm verifies that stays free. Two measurements: (a) the same
-    mixed workload served with the default registry vs a NullRegistry
-    (whole-loop A/B — the ratio should be ~1.0, i.e. within the rig's
-    run-to-run noise); (b) a direct microbench of one observation through
-    the registry's get-or-create fast path, asserted to be < 1% of the
-    measured per-sync chunk wall (the issue's hard bound — registry cost
-    must never show up in serving latency)."""
-    import numpy as np
-
-    from tony_tpu.models import transformer as T
-    from tony_tpu.models.serve import ContinuousBatcher
-    from tony_tpu.runtime import metrics as M
-
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    rs = np.random.RandomState(3)
-    prompts = [list(rs.randint(0, cfg.vocab_size, size=prompt_len))
-               for _ in range(2 * slots)]
-
-    def timed_serve():
-        b = ContinuousBatcher(params, cfg, batch=slots,
-                              max_len=prompt_len + budget, chunk=16)
-        b.serve(prompts[:slots], [16] * slots)       # compile + warm
-        t0 = time.perf_counter()
-        b.serve(prompts, budget)
-        return time.perf_counter() - t0, b
-
-    saved = M.set_default(M.MetricsRegistry())
-    try:
-        t_on, b_on = timed_serve()
-        syncs = max(1, b_on.phase_times.count("fetch"))
-        M.set_default(M.NullRegistry())
-        t_off, _ = timed_serve()
-    finally:
-        M.set_default(saved)
-
-    # one observation through the exact serve call shape (lookup + inc)
-    reg = M.MetricsRegistry()
-    n = 200_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        reg.counter("bench_obs_total").inc()
-    per_obs_s = (time.perf_counter() - t0) / n
-    # the serve loop makes <~8 registry touches per sync (admit/retire/
-    # token/queue-depth counters) plus one TTFT-or-ITL histogram observe
-    # per DELTA (<= slots per sync) plus an O(#phases) fold per CALL
-    obs_per_sync = 8 + 2 * slots
-    frac = per_obs_s * obs_per_sync / (t_on / syncs)
-    assert frac < 0.01, (
-        f"registry observations are {frac:.2%} of per-sync chunk wall — "
-        f"the metrics plane is no longer free on the serve loop")
-    return {
-        "serving_metrics_obs_ns": round(per_obs_s * 1e9, 1),
-        "serving_metrics_obs_frac_of_chunk": round(frac, 6),
-        # ~1.0 = instrumented serve within noise of uninstrumented
-        "serving_metrics_instrumented_vs_null": round(t_on / t_off, 3),
-    }
-
-
-def _trace_overhead_arm(cfg, slots: int = 8, prompt_len: int = 64,
-                        budget: int = 128):
-    """Tracing-plane overhead on the serve hot loop + export validity.
-
-    The engine opens ~3 spans per request (engine.request / .queued /
-    .first_token — the TTFT decomposition) when sampling is on. Two
-    measurements, the metrics arm's discipline: (a) the same workload
-    served with sampling ON (rate 1.0) vs tracing OFF — the whole-loop
-    A/B should sit within run noise; (b) a direct microbench of one
-    start+end span through the tracer, asserted < 1 % of per-sync chunk
-    wall at the engine's spans-per-sync worst case. The bench job's own
-    exported trace must round-trip as schema-valid Chrome trace JSON
-    (every event a complete ``X`` with name/ts/dur/pid/tid, or an ``M``
-    metadata record)."""
-    import numpy as np
-
-    from tony_tpu.models import transformer as T
-    from tony_tpu.models.serve import ContinuousBatcher
-    from tony_tpu.runtime import metrics as M
-    from tony_tpu.runtime import tracing
-
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    rs = np.random.RandomState(7)
-    prompts = [list(rs.randint(0, cfg.vocab_size, size=prompt_len))
-               for _ in range(2 * slots)]
-
-    def timed_serve():
-        b = ContinuousBatcher(params, cfg, batch=slots,
-                              max_len=prompt_len + budget, chunk=16)
-        b.serve(prompts[:slots], [16] * slots)       # compile + warm
-        t0 = time.perf_counter()
-        b.serve(prompts, budget)
-        return time.perf_counter() - t0, b
-
-    saved_reg = M.set_default(M.MetricsRegistry())
-    saved_tr = tracing.set_tracer(
-        tracing.Tracer(proc="bench:0", sample_rate=1.0, ring_size=8192))
-    try:
-        t_on, b_on = timed_serve()
-        syncs = max(1, b_on.phase_times.count("fetch"))
-        spans = tracing.get_tracer().recent()
-        tracing.set_tracer(tracing.Tracer(proc="bench:0", enabled=False))
-        t_off, _ = timed_serve()
-    finally:
-        tracing.set_tracer(saved_tr)
-        M.set_default(saved_reg)
-
-    # schema-valid Chrome trace from the sampled run's spans: JSON
-    # round-trip + the invariants a viewer depends on
-    assert spans, "sampled serve recorded no spans"
-    chrome = json.loads(json.dumps(tracing.to_chrome(spans)))
-    assert isinstance(chrome["traceEvents"], list) and chrome["traceEvents"]
-    for e in chrome["traceEvents"]:
-        assert e["ph"] in ("X", "M"), e
-        if e["ph"] == "X":
-            assert isinstance(e["name"], str) and e["name"]
-            for key in ("ts", "dur"):
-                assert isinstance(e[key], (int, float)) and e[key] >= 0, e
-            assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
-    names = {e["name"] for e in chrome["traceEvents"] if e["ph"] == "X"}
-    assert {"engine.request", "engine.queued",
-            "engine.first_token"} <= names, names
-
-    # one start+end through the tracer, the exact engine call shape
-    tr = tracing.Tracer(proc="bench:0", sample_rate=1.0, ring_size=512)
-    n = 50_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        tr.start_span("bench.span").end()
-    per_span_s = (time.perf_counter() - t0) / n
-    # worst case per host sync: every slot retires and readmits — one
-    # request span ends + queued/first spans cycle (~3 span ops/slot)
-    spans_per_sync = 3 * slots
-    frac = per_span_s * spans_per_sync / (t_on / syncs)
-    assert frac < 0.01, (
-        f"span records are {frac:.2%} of per-sync chunk wall — the "
-        f"tracing plane is no longer free on the serve loop")
-    return {
-        "serving_trace_span_ns": round(per_span_s * 1e9, 1),
-        "serving_trace_span_frac_of_chunk": round(frac, 6),
-        # ~1.0 = sampled-on serve within noise of tracing-off
-        "serving_trace_sampled_vs_off": round(t_on / t_off, 3),
-        "serving_trace_spans_recorded": len(spans),
-    }
-
-
-def _speculative_arm(new: int = 256, k: int = 10):
-    """Batch-1 greedy vs device-loop speculative decoding, same target.
-
-    Speculation only pays when the draft predicts the target, so the arm
-    first trains target (base preset) and draft (1 layer, d128 — ~4% of
-    the target's step cost) on a deterministic affine token chain both
-    learn quickly; the measured ratio is then a REAL acceptance-driven
-    win, not a fixture. Token match vs greedy is reported (bf16 chunk-vs-
-    step near-ties can flip occasional tokens, as documented in
-    models/decode.py)."""
-    from tony_tpu.models import transformer as T
-    from tony_tpu.models.decode import (generate,
-                                        speculative_generate_device)
-    from tony_tpu.models.train import (default_optimizer, init_state,
-                                       make_train_step)
-
-    cfg_t = T.PRESETS["base"].scaled(remat=False)
-    cfg_d = T.PRESETS["base"].scaled(n_layers=1, d_model=128, n_heads=2,
-                                     d_ff=512, remat=False)
-
-    def make_data(rng, batch, seq):
-        x0 = jax.random.randint(rng, (batch, 1), 0, 4099)
-
-        def step(carry, _):
-            nxt = (13 * carry + 7) % 4099
-            return nxt, nxt
-
-        _, xs = jax.lax.scan(step, x0, None, length=seq)
-        toks = jnp.concatenate([x0, xs.squeeze(-1).T], axis=1)
-        return {"inputs": toks[:, :seq], "targets": toks[:, 1:]}
-
-    def train(cfg, steps, seed, snapshots=()):
-        """Returns final params, plus params snapshotted at the requested
-        step counts — one run covers a whole draft-quality sweep (the
-        weaker drafts are exact prefixes of the deterministic stream)."""
-        params = T.init_params(jax.random.PRNGKey(seed), cfg)
-        opt = default_optimizer(lr=1e-3)
-        state = init_state(params, opt)
-        step = make_train_step(lambda p, b: T.lm_loss(p, b, cfg), opt)
-        snaps = {}
-        for i in range(steps):
-            if i in snapshots:
-                # deep-copy: the train step DONATES its state, so a bare
-                # reference would be a deleted buffer one step later
-                snaps[i] = jax.tree.map(jnp.copy, state["params"])
-            state, _ = step(state,
-                            make_data(jax.random.PRNGKey(1000 + i), 16, 256))
-        return (state["params"], snaps) if snapshots else state["params"]
-
-    p_t = train(cfg_t, 120, 0)
-    # draft quality sweep: 400 steps ≈ near-perfect acceptance on this
-    # task; 100/25 are the mediocre/weak drafts the acceptance sweep
-    # below measures (the regime where min-commit decayed)
-    p_d, snaps = train(cfg_d, 400, 1, snapshots=(25, 100))
-    p_d_weak, p_d_mid = snaps[25], snaps[100]
-    prompt = make_data(jax.random.PRNGKey(7), 1, 65)["inputs"][:, :64]
-    greedy = functools.partial(generate, cfg=cfg_t, max_new_tokens=new,
-                               temperature=0.0)
-    spec = jax.jit(functools.partial(
-        speculative_generate_device, cfg=cfg_t, draft_cfg=cfg_d,
-        max_new_tokens=new, num_speculative=k))
-    out_g = greedy(p_t, prompt, rng=jax.random.PRNGKey(0))
-    out_s = spec(p_t, p_d, prompt)
-    match = float((out_g.tokens[0, -new:] == out_s[0, -new:]).mean())
-    ts_g, ts_s = [], []
-    for rep in range(4):                    # interleaved, median wins
-        t0 = time.perf_counter()
-        for i in range(3):
-            out_g = greedy(p_t, prompt, rng=jax.random.PRNGKey(i))
-        int(out_g.tokens[0, -1])
-        ts_g.append((time.perf_counter() - t0) / 3)
-        t0 = time.perf_counter()
-        for _ in range(3):
-            out_s = spec(p_t, p_d, prompt)
-        int(out_s[0, -1])
-        ts_s.append((time.perf_counter() - t0) / 3)
-    tg = sorted(ts_g)[len(ts_g) // 2]
-    tsp = sorted(ts_s)[len(ts_s) // 2]
-    out = {"spec_decode_tokens_per_s": round(new / tsp, 1),
-           "greedy_b1_tokens_per_s": round(new / tg, 1),
-           "spec_vs_greedy": round(tg / tsp, 2),
-           "spec_token_match": round(match, 3)}
-    # batch>1 acceptance sweep (per-row frontiers vs the min-commit
-    # baseline): per-row commits let each row keep its own acceptance,
-    # so the b8 ratio should hold up as the draft weakens — min-commit
-    # decays with the batch MINIMUM. tokens/round recorded for both.
-    # DISTINCT prompts per row: tiling one prompt would sync the rows'
-    # acceptances and flatter both policies.
-    b8 = make_data(jax.random.PRNGKey(8), 8, 64)["inputs"]
-    og = greedy(p_t, b8, rng=jax.random.PRNGKey(0)); int(og.tokens[0, -1])
-    t0 = time.perf_counter()
-    for i in range(3):
-        og = greedy(p_t, b8, rng=jax.random.PRNGKey(i))
-    int(og.tokens[0, -1])
-    t_g8 = (time.perf_counter() - t0) / 3
-
-    # ONE jitted fn per commit policy, hoisted out of the draft loop:
-    # draft params are runtime args, so all three drafts share a compile
-    spec_fns = {
-        commit: jax.jit(functools.partial(
-            speculative_generate_device, cfg=cfg_t, draft_cfg=cfg_d,
-            max_new_tokens=new, num_speculative=k, commit=commit,
-            return_rounds=True))
-        for commit in ("per_row", "min", "window")
-    }
-
-    def time_spec_b8(draft_p, commit):
-        fn = spec_fns[commit]
-        o, rounds = fn(p_t, draft_p, b8)
-        int(o[0, -1])                            # compile + warm
-        t0 = time.perf_counter()
-        for _ in range(3):
-            o, rounds = fn(p_t, draft_p, b8)
-        int(o[0, -1])
-        return (time.perf_counter() - t0) / 3, int(rounds)
-
-    for name, draft_p in (("", p_d), ("_d100", p_d_mid),
-                          ("_d25", p_d_weak)):
-        t_pr, r_pr = time_spec_b8(draft_p, "per_row")
-        t_mc, r_mc = time_spec_b8(draft_p, "min")
-        # bounded-window commit: per-row acceptance, scatter-free writes
-        # (one contiguous window slice + MXU one-hot merge per layer)
-        t_wd, r_wd = time_spec_b8(draft_p, "window")
-        out[f"spec_b8_vs_greedy{name}"] = round(t_g8 / t_pr, 2)
-        out[f"spec_b8_mincommit_vs_greedy{name}"] = round(t_g8 / t_mc, 2)
-        out[f"spec_b8_window_vs_greedy{name}"] = round(t_g8 / t_wd, 2)
-        out[f"spec_b8_tokens_per_round{name}"] = round(new / r_pr, 2)
-        out[f"spec_b8_mincommit_tokens_per_round{name}"] = round(
-            new / r_mc, 2)
-        out[f"spec_b8_window_tokens_per_round{name}"] = round(
-            new / r_wd, 2)
-    # speculative SAMPLING (temperature > 0): same round machinery with
-    # the min(1, p/q) accept test — committed stream distributed as
-    # direct target sampling; the win rides the draft's acceptance just
-    # like the greedy case. Temperature-only on this task: sampling
-    # wanders OFF the deterministic affine chain, and on those
-    # out-of-distribution contexts the toy draft's nucleus no longer
-    # overlaps the target's — top_p=0.9 measured acceptance collapse
-    # (1.17 tokens/round, 0.13x) where temperature-only holds 4.8
-    # tokens/round (see docs/performance.md)
-    gen_s = functools.partial(generate, cfg=cfg_t, max_new_tokens=new,
-                              temperature=0.9)
-    spec_s = jax.jit(functools.partial(
-        speculative_generate_device, cfg=cfg_t, draft_cfg=cfg_d,
-        max_new_tokens=new, num_speculative=k, temperature=0.9))
-    og = gen_s(p_t, b8, rng=jax.random.PRNGKey(0)); int(og.tokens[0, -1])
-    os_ = spec_s(p_t, p_d, b8, rng=jax.random.PRNGKey(0)); int(os_[0, -1])
-    t0 = time.perf_counter()
-    for i in range(3):
-        og = gen_s(p_t, b8, rng=jax.random.PRNGKey(i))
-    int(og.tokens[0, -1])
-    t_gs = (time.perf_counter() - t0) / 3
-    t0 = time.perf_counter()
-    for i in range(3):
-        os_ = spec_s(p_t, p_d, b8, rng=jax.random.PRNGKey(i))
-    int(os_[0, -1])
-    t_ss = (time.perf_counter() - t0) / 3
-    out["spec_b8_sampled_vs_sampled"] = round(t_gs / t_ss, 2)
-
-    out.update(_spec_serving_arm(cfg_t, cfg_d, p_t, p_d,
-                                 make_data, new=new, k=k))
-    return out
-
-
-def _spec_serving_arm(cfg_t, cfg_d, p_t, p_d, make_data, new, k,
-                      slots: int = 8, n_req: int = 16):
-    """Continuous batching WITH speculative decoding vs greedy continuous
-    batching, same workload and slot count, trained draft (the two
-    serving features composed). Both loops pay the same per-sync host
-    cost, so the ratio is fair to either. rounds/tokens recorded for the
-    speculative side (tokens-per-round = acceptance efficiency inside
-    the serving loop)."""
-    from tony_tpu.models.serve import (ContinuousBatcher,
-                                       SpeculativeContinuousBatcher)
-
-    prompts = [list(map(int, make_data(jax.random.PRNGKey(50 + i), 1, 65)
-                        ["inputs"][0, :64])) for i in range(n_req)]
-    useful = n_req * new
-    max_len = 64 + new
-
-    greedy_b = ContinuousBatcher(p_t, cfg_t, batch=slots, max_len=max_len,
-                                 chunk=16)
-    greedy_b.serve(prompts[:slots], [16] * slots)        # compile + warm
-    t0 = time.perf_counter()
-    greedy_b.serve(prompts, new)
-    t_greedy = time.perf_counter() - t0
-
-    spec_b = SpeculativeContinuousBatcher(
-        p_t, cfg_t, p_d, cfg_d, batch=slots, max_len=max_len,
-        num_speculative=k, chunk=2)
-    spec_b.serve(prompts[:slots], [16] * slots)          # compile + warm
-    t0 = time.perf_counter()
-    spec_b.serve(prompts, new)
-    t_spec = time.perf_counter() - t0
-
-    return {
-        "serving_spec_cb_tokens_per_s": round(useful / t_spec, 1),
-        "serving_greedy_cb_tokens_per_s": round(
-            useful / t_greedy, 1),
-        "serving_spec_cb_vs_greedy_cb": round(t_greedy / t_spec, 2),
-        "serving_spec_cb_tokens_per_round": round(
-            useful / (slots * spec_b.rounds_executed), 2),
-    }
-
-
 
 
 def _pipeline_arm(num_microbatches: int = 8, one_way_s: float = 0.05,
@@ -2963,7 +1674,3 @@ def _pipeline_dcn_arm(num_microbatches: int = 24, one_way_s: float = 0.05,
         # ...and the end-to-end wall at M microbatches, fill included
         "pipeline_interleaved_vs_flat_wall": round(fl_big / il_big, 2),
     }
-
-
-if __name__ == "__main__":
-    main()

@@ -475,15 +475,6 @@ def main() -> int:
                              "--attn_window, lifts the request-length "
                              "ceiling — O(capacity) memory however "
                              "long the stream")
-    parser.add_argument("--no_pipeline", action="store_true",
-                        help="sequential serve loop (the A/B baseline; "
-                             "default is double-buffered dispatch — "
-                             "chunk N+1 issued before chunk N's fetch)")
-    parser.add_argument("--no_bucketed_admission", action="store_true",
-                        help="per-length admission (compiles per "
-                             "distinct prompt length; default pads to "
-                             "power-of-two buckets and batches freed "
-                             "slots into one dispatch)")
     parser.add_argument("--warm_from", default="", metavar="HOST:PORT",
                         help="warm boot: pull content-addressed "
                              "weights peer-to-peer from a serving "
@@ -645,9 +636,7 @@ def main() -> int:
 
     kw = dict(batch=args.slots, max_len=max_len,
               temperature=args.temperature, top_k=args.top_k,
-              top_p=args.top_p, seed=args.seed,
-              pipeline=not args.no_pipeline,
-              bucketed_admission=not args.no_bucketed_admission)
+              top_p=args.top_p, seed=args.seed)
     if args.draft_preset:
         # the draft must share the target's vocabulary (speculation
         # compares token ids), so override the preset's vocab_size

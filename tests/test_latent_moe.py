@@ -253,8 +253,6 @@ REFUSALS = {
                                             kv_cache_capacity=16),
     "beam search": lambda cfg, p: D.beam_search(
         p, jnp.zeros((1, 4), jnp.int32), cfg, 4, beam_width=2),
-    "unbucketed admission": lambda cfg, p: S.ContinuousBatcher(
-        p, cfg, 2, 64, bucketed_admission=False),
 }
 
 

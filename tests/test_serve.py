@@ -614,8 +614,8 @@ class TestBucketedAdmission:
     def test_one_program_per_bucket(self, params, retrace_guard):
         """8 distinct prompt lengths spanning two buckets (<=16 and
         <=32) through repeated slot reuse: at most the two bucket
-        programs may trace, and the per-length admit_row program must
-        not trace at all."""
+        programs may trace, and the ring's per-length program must not
+        trace at all."""
         rng = np.random.RandomState(30)
         lengths = [3, 4, 5, 7, 9, 17, 20, 23]
         prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
@@ -624,7 +624,7 @@ class TestBucketedAdmission:
                                     chunk=3)
         outs = batcher.serve(prompts, max_new_tokens=4)
         retrace_guard.assert_max("admit_rows", 2)     # one per bucket
-        retrace_guard.assert_max("admit_row", 0)      # legacy path idle
+        retrace_guard.assert_max("admit_row_ring", 0)  # no ring here
         # spot-check one short and one long (bucket-32) request against
         # solo generate; full-coverage exactness is pinned elsewhere
         assert outs[0] == _reference(params, prompts[0], 4)
@@ -658,43 +658,13 @@ class TestBucketedAdmission:
         for i, p in enumerate(prompts):
             assert outs[i] == _reference(params, p, 6), i
 
-    def test_legacy_admission_still_exact(self, params):
-        """bucketed_admission=False keeps the batch-1 admit_row path
-        working and exact."""
-        rng = np.random.RandomState(0)
-        prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
-                   for n in (5, 3, 7, 4)]
-        batcher = ContinuousBatcher(params, CFG, batch=3, max_len=32,
-                                    chunk=4, bucketed_admission=False)
-        outs = batcher.serve(prompts, max_new_tokens=6)
-        for i, p in enumerate(prompts):
-            assert outs[i] == _reference(params, p, 6), i
-
-    def test_batch1_admission_pads_to_buckets_too(self, params,
-                                                  retrace_guard):
-        """The batch-1 admission retrace cap: with bucketed (batched)
-        admission OFF, eight distinct prompt lengths in one 16-token
-        bucket still compile at most ONE admit_row program — the old
-        monolithic-prefill body retraced once per distinct length.
-        Outputs stay per-request exact."""
-        rng = np.random.RandomState(35)
-        prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
-                   for n in (3, 4, 5, 6, 7, 8, 9, 10)]
-        batcher = ContinuousBatcher(params, CFG, batch=2, max_len=48,
-                                    chunk=3, bucketed_admission=False)
-        outs = batcher.serve(prompts, max_new_tokens=4)
-        retrace_guard.assert_max("admit_row", 1)
-        retrace_guard.assert_max("admit_rows", 0)
-        assert outs[0] == _reference(params, prompts[0], 4)
-        assert outs[7] == _reference(params, prompts[7], 4)
-        assert all(len(o) == 4 for o in outs)
-
     def test_ring_cache_falls_back_to_per_length_admission(
             self, params, retrace_guard):
         """Rolling caches cannot take padded prompts (wrapped writes
-        would land padding on live ring rows): the batcher silently
-        routes admission through admit_row and still serves correctly
-        (pipelined == sequential under the ring too)."""
+        would land padding on live ring rows): the batcher routes
+        admission through admit_row_ring, one program a distinct prompt
+        length, and still serves correctly (pipelined == sequential
+        under the ring too)."""
         rcfg = CFG.scaled(attn_window=8, kv_cache_capacity=8)
         rng = np.random.RandomState(34)
         prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
@@ -703,11 +673,12 @@ class TestBucketedAdmission:
         def run(pipeline):
             b = ContinuousBatcher(params, rcfg, batch=2, max_len=32,
                                   chunk=3, pipeline=pipeline)
-            assert not b.bucketed_admission
             return b.serve(prompts, max_new_tokens=4)
 
         outs = run(True)
         retrace_guard.assert_max("admit_rows", 0)
+        assert set(retrace_guard.new_traces("admit_row_ring")) == {
+            (1, 5), (1, 3)}
         assert outs == run(False)
         for o in outs:
             assert len(o) == 4
@@ -738,7 +709,6 @@ class TestBucketedAdmission:
             num_speculative=3, chunk=2)
         outs = batcher.serve(prompts, max_new_tokens=5)
         retrace_guard.assert_max("spec_admit_rows", 1)
-        retrace_guard.assert_max("spec_admit_row", 0)
         assert outs[0] == _reference(params, prompts[0], 5)
         assert all(len(o) == 5 for o in outs)
 
@@ -1006,3 +976,109 @@ def test_admission_dispatch_is_sized_in_tokens(params, retrace_guard,
         assert [d[1] for d in wave].count(split) == want, dispatched
         assert (S.admit_width(split, slots), split,
                 S.admit_width(split, slots)) in wave
+
+
+#: the five jitted programs that land a prompt in a slot
+_ADMITTERS = {"admit_rows", "admit_row_ring", "prefix_admit_rows",
+              "spec_admit_rows", "spec_prefix_admit_rows"}
+
+
+def _batcher_of(kind, params, max_len):
+    """(batcher, the admission program its cache type selects)."""
+    if kind == "ring":
+        return (ContinuousBatcher(
+            params, CFG.scaled(attn_window=8, kv_cache_capacity=8),
+            batch=2, max_len=max_len, chunk=3), "admit_row_ring")
+    if kind == "shared-prefix":
+        return (ContinuousBatcher(params, CFG, batch=2, max_len=max_len,
+                                  chunk=3, shared_prefix=[7, 8, 9]),
+                "prefix_admit_rows")
+    if kind == "speculative":
+        return (SpeculativeContinuousBatcher(
+            params, CFG, params, CFG, batch=2, max_len=max_len,
+            num_speculative=2, chunk=2), "spec_admit_rows")
+    return (ContinuousBatcher(params, CFG, batch=2, max_len=max_len,
+                              chunk=3), "admit_rows")
+
+
+@pytest.mark.parametrize("kind", ["dense", "ring", "shared-prefix",
+                                  "speculative"])
+def test_admission_program_follows_the_cache_type(params, retrace_guard,
+                                                  kind):
+    """Which program lands a prompt is decided by what the batcher was
+    built on — a linear cache, a ring, a prefix template, a draft
+    model — and by nothing a caller sets: each batcher traces its own
+    admission program and none of the other four. (``max_len`` 43 is
+    this test's alone, so the program is traced here, not found in an
+    earlier test's jit cache.)"""
+    rng = np.random.RandomState(41)
+    prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
+               for n in (5, 3, 6)]
+    batcher, program = _batcher_of(kind, params, 43)
+    outs = batcher.serve(prompts, max_new_tokens=4)
+    assert all(len(o) == 4 for o in outs)
+    assert retrace_guard.total_new(program) >= 1
+    for other in _ADMITTERS - {program}:
+        retrace_guard.assert_max(other, 0)
+
+
+@pytest.mark.parametrize("lengths, budget", [((5, 3, 4), 3),
+                                             ((5, 11, 7), 14)],
+                         ids=["short", "wrapping"])
+def test_ring_admission_is_exact(params, lengths, budget):
+    """A ring-cache batcher's tokens equal ``decode.generate``'s for each
+    request alone under the same ring configuration — while every
+    request stays inside the 8-row ring (short), and with prompts longer
+    than the ring and answers that wrap it (wrapping), slot reuse
+    included."""
+    rcfg = CFG.scaled(attn_window=8, kv_cache_capacity=8)
+    rng = np.random.RandomState(42)
+    prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
+               for n in lengths]
+    batcher = ContinuousBatcher(params, rcfg, batch=2, max_len=16,
+                                chunk=3)
+    outs = batcher.serve(prompts, max_new_tokens=budget)
+    for i, p in enumerate(prompts):
+        want = generate(params, jnp.asarray(p, jnp.int32)[None], rcfg,
+                        max_new_tokens=budget, rng=jax.random.PRNGKey(0),
+                        temperature=0.0)
+        assert outs[i] == [int(t) for t in
+                           np.asarray(want.tokens[0, len(p):])], i
+    assert batcher.prefill_padded_tokens == sum(lengths)
+    assert batcher.prefill_forward_tokens == sum(lengths)
+
+
+def test_serving_programs_are_the_five_admitters():
+    """The jitted admission entry points ``serve`` exports are exactly
+    the five, and no constructor argument selects among them."""
+    import inspect
+    from tony_tpu.models import serve as S
+    jitted = {name for name, fn in vars(S).items()
+              if "admit" in name and hasattr(fn, "lower")}
+    assert jitted == _ADMITTERS
+    for cls in (ContinuousBatcher, SpeculativeContinuousBatcher):
+        assert "bucketed_admission" not in inspect.signature(
+            cls.__init__).parameters
+
+
+def test_every_bench_arm_is_a_test_fixture():
+    """``bench.py`` at the repo root is what the tests use of it: every
+    ``_*_arm`` it defines is called by some file under ``tests/`` (the
+    benchmark is ``benchmark/``)."""
+    import ast
+    import os
+    import re
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "bench.py"), encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    arms = {n.name for n in tree.body if isinstance(n, ast.FunctionDef)
+            and re.fullmatch(r"_\w+_arm", n.name)}
+    assert arms, "bench.py defines no arm"
+    assert not any(isinstance(n, ast.FunctionDef) and n.name == "main"
+                   for n in tree.body)
+    called = set()
+    for name in os.listdir(here):
+        if name.endswith(".py"):
+            with open(os.path.join(here, name), encoding="utf-8") as f:
+                called |= set(re.findall(r"bench\.(_\w+_arm)\(", f.read()))
+    assert arms == called

@@ -52,7 +52,7 @@ cannot add any, and shrinking the baseline is always legal.
 
 Dependency-free on purpose (stdlib ``ast`` + ``json`` + ``re`` only): it
 must run on any machine that can run the tests, including inside the
-tier-1 self-check (``tests/test_lint.py``) and the bench's ``_lint_arm``.
+tier-1 self-check (``tests/test_lint.py``).
 """
 
 from __future__ import annotations

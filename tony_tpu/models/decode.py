@@ -1585,8 +1585,7 @@ def speculative_generate_device(params: dict, draft_params: dict,
     while_loop removes. Wall-clock wins over plain :func:`generate`
     additionally require a draft that actually predicts the target
     (tokens/round ≈ 1 + acceptance·k); with a random draft this is a
-    correctness demonstration, not a speedup. ``bench.py``'s arm trains
-    a real draft for that comparison.
+    correctness demonstration, not a speedup.
 
     Batch > 1 uses PER-ROW CACHE FRONTIERS: acceptance length is
     data-dependent per row, so the cache ``length`` and every position
@@ -1605,8 +1604,8 @@ def speculative_generate_device(params: dict, draft_params: dict,
     min-commit).
 
     ``commit="min"`` restores the decayed min-commit schedule (every row
-    commits the batch-minimum acceptance) — kept as the measured baseline
-    for the bench's acceptance sweep, not for production use.
+    commits the batch-minimum acceptance) — kept as the baseline the
+    tests compare the per-row schedules against, not for production use.
     ``return_rounds=True`` additionally returns the number of
     draft→verify rounds executed (tokens/round = the speculation
     efficiency the sweep records).

@@ -27,10 +27,9 @@ positions (decode-shaped calls at any batch size and f32 activations
 keep the fused-f32 path bit-for-bit; the strict on-a-power-of-two
 threshold keeps bucket-padded admission and exact-length prefills of
 the same prompt on the same kernel — see `_QUANT_PREFILL_MIN_S`). The
-scale still applies outside the contraction in f32. `bench.py`'s
-`prefill_wq8_vs_bf16` arm pins quantized prefill near float-weight
-prefill; without this, serving int8 weights paid a time-to-first-token
-tax exactly where admission cost matters (ADVICE.md round 5).
+scale still applies outside the contraction in f32. Without this,
+serving int8 weights paid a time-to-first-token tax exactly where
+admission cost matters (ADVICE.md round 5).
 
 Scope: the decode/serving entry points (`decode.prefill`,
 ``extend_step``/``decode_step`` and everything built on them — generate,

@@ -20,12 +20,15 @@ shared padded cache. On top of that, the device programs:
   to one power-of-two length bucket prefill in a single dispatch and
   land in K freed cache slots (one scatter per buffer), compiling once
   per bucket instead of once per distinct prompt length and paying one
-  transport dispatch however many slots freed in the chunk;
-- :func:`admit_row` — the single-slot admission the batched path
-  replaced, kept for direct API use and the ``bucketed_admission=False``
-  A/B arm; it pads to the same buckets (one program per bucket, not per
-  length). Rolling (ring) caches, whose wrapped writes cannot take
-  padded prompts, keep the exact-length :func:`admit_row_ring`;
+  transport dispatch however many slots freed in the chunk. Its
+  variants keep the same contract: :func:`prefix_admit_rows` (suffixes
+  against a prefix template), :func:`spec_admit_rows` and
+  :func:`spec_prefix_admit_rows` (both models' caches);
+- :func:`admit_row_ring` — the one per-row admission: a rolling (ring)
+  cache's wrapped writes cannot take padded prompts, so each request
+  prefills at its exact length. Which of the five runs is decided by
+  the cache type alone (``cfg.kv_cache_capacity``, a template, a draft
+  model), never by an option;
 - :func:`step_rows` — a ``lax.scan`` of ``n`` per-row decode steps over
   the whole batch (one dispatch per chunk, not per token; greedy by
   default, or sampled through the same top-k/temperature/nucleus stack
@@ -218,9 +221,8 @@ def bucket_for(n: int, cap: int,
     (the cache's admissible length). Powers of two are
     flash-block-aligned at every size, so TPU prefill never re-pads a
     bucket. THE bucket ladder — shared by the batcher's admission, the
-    batch-1 legacy path, the disaggregated prefill gang, and the decode
-    gang's KV landing, so two gangs padding independently agree on the
-    compiled-program set."""
+    disaggregated prefill gang, and the decode gang's KV landing, so two
+    gangs padding independently agree on the compiled-program set."""
     if ladder is not None:
         for b in ladder:
             if b >= n:
@@ -257,31 +259,6 @@ def _place_prefill(cache, mini, row, s_p):
                   cache[n], mini[n], (0, row) + (0,) * (mini[n].ndim - 2))
               for n in _kv_bufs(mini)}
     return dict(placed, length=cache["length"].at[row].set(s_p))
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",),
-                   donate_argnames=("cache", "logits"))
-def admit_row(params, cache, logits, row, prompt, length, cfg):
-    """Admit ONE request into cache slot ``row`` with the prompt padded
-    to an admission bucket.
-
-    prompt: [1, S_b] right-padded to a :func:`bucket_for` rung;
-    ``length`` the TRACED true prompt length — the batch-1 counterpart
-    of :func:`admit_rows`, compiling once per bucket instead of once
-    per distinct prompt length (the old monolithic-``prefill`` body
-    retraced per length, which made the legacy/batch-1 admission path a
-    compile sink on mixed-length workloads). The padding-tail K/V land
-    beyond the frontier and are unreachable (the bucketed-admission
-    argument). Rolling (ring) caches cannot take padded prompts —
-    :func:`admit_row_ring` keeps the exact-length program for them.
-    Returns (cache, logits) with the row's K/V filled, its frontier at
-    ``length``, and its next-step logits seeded from the true last
-    position."""
-    _count_trace("admit_row", prompt.shape)
-    lengths = jnp.reshape(jnp.asarray(length, jnp.int32), (1,))
-    lg, mini = prefill_rows(params, prompt, lengths, cfg)
-    return (_place_prefill(cache, mini, row, lengths[0]),
-            logits.at[row].set(lg[0]))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -424,34 +401,16 @@ def validate_template_bufs(proto: dict, tokens, bufs: dict) -> dict:
     return {n: jnp.asarray(a) for n, a in kv_from_wire(out).items()}
 
 
-def _extend_from_template(model_params, template, suffix, model_cfg):
-    """Build a [L, 1, P+S]-row mini cache from a prefix ``template`` and
-    run the ``suffix`` through the model against it (a chunked
-    :func:`extend_step` — suffix queries attend the full prefix history
-    exactly as a monolithic prefill of prefix+suffix would). Returns
-    (suffix logits [1, S, V], filled mini cache, total length P+S).
-    Shared by the greedy and speculative prefix admitters."""
-    p_len = template["k"].shape[2]
-    s_len = suffix.shape[1]
-    mini = dict(
-        {n: jnp.concatenate(
-            [x, jnp.zeros(x.shape[:2] + (s_len,) + x.shape[3:],
-                          x.dtype)], axis=2)
-         for n, x in template.items()},
-        length=jnp.asarray(p_len, jnp.int32))
-    lg, mini = extend_step(model_params, suffix, mini, p_len, model_cfg)
-    return lg, mini, p_len + s_len
-
-
 def _extend_rows_from_template(model_params, template, suffixes, lengths,
                                model_cfg):
-    """Batched-bucketed counterpart of :func:`_extend_from_template`:
-    tile the prefix template across K rows and run all K right-padded
+    """Tile the prefix template across K rows and run all K right-padded
     suffixes [K, S_b] through the model against it in one chunked
-    :func:`extend_step`. Each row's padding-tail K/V land beyond its
-    frontier (unreachable — the bucketed-admission argument). Returns
-    (per-row last-REAL-suffix-position logits [K, V], mini cache,
-    per-row totals P + lengths)."""
+    :func:`extend_step` (suffix queries attend the full prefix history
+    exactly as a monolithic prefill of prefix+suffix would). Each row's
+    padding-tail K/V land beyond its frontier (unreachable — the
+    bucketed-admission argument). Returns (per-row
+    last-REAL-suffix-position logits [K, V], mini cache, per-row totals
+    P + lengths)."""
     p_len = template["k"].shape[2]
     k_rows, s_len = suffixes.shape
     mini = dict(
@@ -464,23 +423,6 @@ def _extend_rows_from_template(model_params, template, suffixes, lengths,
     lg, mini = extend_step(model_params, suffixes, mini, p_len, model_cfg)
     return (lg[jnp.arange(k_rows), lengths - 1], mini,
             p_len + lengths.astype(jnp.int32))
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",),
-                   donate_argnames=("cache", "logits"))
-def prefix_admit_row(params, cache, logits, row, template, suffix, cfg):
-    """Admit a request that CONTINUES a shared prefix: the prefix's K/V
-    come from the precomputed ``template`` (one prefill for the whole
-    serve, not one per request) and only the request's ``suffix``
-    [1, S] runs a forward (:func:`_extend_from_template`). Admission
-    compute drops from O(P+S) to O(S) tokens; at a long system prompt
-    and short user turns that is the dominant admission cost. Per-length
-    program — the batcher's default is the bucketed
-    :func:`prefix_admit_rows`."""
-    _count_trace("prefix_admit_row", suffix.shape)
-    lg, mini, total = _extend_from_template(params, template, suffix, cfg)
-    return (_place_prefill(cache, mini, row, total),
-            logits.at[row].set(lg[0, -1]))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -662,38 +604,6 @@ class KVPackage:
                                              "temperature", "top_k",
                                              "top_p"),
                    donate_argnames=("t_cache", "d_cache", "pending"))
-def spec_admit_row(params, draft_params, t_cache, d_cache, pending, row,
-                   prompt, rng, cfg, draft_cfg, temperature=0.0,
-                   top_k=0, top_p=0.0):
-    """Speculative admission at the EXACT prompt length: prefill BOTH
-    models on the prompt into cache slot ``row`` (the draft keeps its
-    own per-slot K/V history) and seed the row's ``pending`` token from
-    the target's last-position logits — argmax at ``temperature=0``,
-    otherwise a sample through the same filter stack the rounds use
-    (the seed token is part of the request's sampled stream). Same
-    contract as :func:`admit_row` otherwise; the batcher's default is
-    the bucketed :func:`spec_admit_rows`."""
-    _count_trace("spec_admit_row", prompt.shape)
-    lg, mini_t = prefill(params, prompt, cfg, max_len=prompt.shape[1])
-    _, mini_d = prefill(draft_params, prompt, draft_cfg,
-                        max_len=prompt.shape[1])
-    s_p = prompt.shape[1]
-    t_cache = _place_prefill(t_cache, mini_t, row, s_p)
-    d_cache = _place_prefill(d_cache, mini_d, row, s_p)
-    if temperature == 0.0:
-        seed_tok = jnp.argmax(lg[0], axis=-1)
-    else:
-        seed_tok = jax.random.categorical(
-            rng, _filter_logits(lg[0].astype(jnp.float32), temperature,
-                                top_k, top_p), axis=-1)
-    pending = pending.at[row].set(seed_tok.astype(pending.dtype))
-    return t_cache, d_cache, pending
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "draft_cfg",
-                                             "temperature", "top_k",
-                                             "top_p"),
-                   donate_argnames=("t_cache", "d_cache", "pending"))
 def spec_admit_rows(params, draft_params, t_cache, d_cache, pending,
                     rows, prompts, lengths, keys, cfg, draft_cfg,
                     temperature=0.0, top_k=0, top_p=0.0):
@@ -711,37 +621,6 @@ def spec_admit_rows(params, draft_params, t_cache, d_cache, pending,
     seed_tok = _row_samples(lg, keys, temperature, top_k, top_p)
     pending = pending.at[rows].set(seed_tok.astype(pending.dtype),
                                    mode="drop", unique_indices=True)
-    return t_cache, d_cache, pending
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "draft_cfg",
-                                             "temperature", "top_k",
-                                             "top_p"),
-                   donate_argnames=("t_cache", "d_cache", "pending"))
-def spec_prefix_admit_row(params, draft_params, t_cache, d_cache, pending,
-                          row, t_template, d_template, suffix, rng, cfg,
-                          draft_cfg, temperature=0.0, top_k=0, top_p=0.0):
-    """Shared-prefix admission for the speculative batcher at the EXACT
-    suffix length: BOTH models' prefix K/V come from precomputed
-    templates and only the suffix runs a forward through each
-    (:func:`_extend_from_template`); the pending seed comes from the
-    target's last suffix position, argmax or sampled, as in
-    :func:`spec_admit_row`. The batcher's default is the bucketed
-    :func:`spec_prefix_admit_rows`."""
-    _count_trace("spec_prefix_admit_row", suffix.shape)
-    lg, mini_t, total = _extend_from_template(params, t_template,
-                                              suffix, cfg)
-    _, mini_d, _ = _extend_from_template(draft_params, d_template,
-                                         suffix, draft_cfg)
-    t_cache = _place_prefill(t_cache, mini_t, row, total)
-    d_cache = _place_prefill(d_cache, mini_d, row, total)
-    if temperature == 0.0:
-        seed_tok = jnp.argmax(lg[0, -1], axis=-1)
-    else:
-        seed_tok = jax.random.categorical(
-            rng, _filter_logits(lg[0, -1].astype(jnp.float32),
-                                temperature, top_k, top_p), axis=-1)
-    pending = pending.at[row].set(seed_tok.astype(pending.dtype))
     return t_cache, d_cache, pending
 
 
@@ -852,20 +731,21 @@ class ContinuousBatcher:
     as ``generate`` instead, from per-request key streams (see
     ``__init__``).
 
-    The serve loop is PIPELINED by default (``pipeline=True``): chunk
-    N+1 is dispatched before chunk N's tokens are fetched, overlapping
-    the fetch's transport round trip and the host bookkeeping with
-    device compute. ``pipeline=False`` keeps the sequential
-    issue→fetch→bookkeep→admit loop; both produce identical outputs in
-    every mode (test-enforced) — the sequential loop exists as the
-    equivalence baseline and A/B arm, not for production use.
+    The serve loop is PIPELINED (``pipeline=True``): chunk N+1 is
+    dispatched before chunk N's tokens are fetched, overlapping the
+    fetch's transport round trip and the host bookkeeping with device
+    compute. ``pipeline=False`` is the sequential
+    issue→fetch→bookkeep→admit loop that the tests hold the pipelined
+    one to, token for token, in the sampled and speculative modes where
+    ``decode.generate`` is no oracle; nothing else selects it.
 
-    Admission is BUCKETED and BATCHED by default: prompts pad to
-    power-of-two length buckets (compile once per bucket, not once per
-    distinct prompt length) and every slot freed in the same chunk lands
-    in one :func:`admit_rows` dispatch. Rolling (ring) caches fall back
-    to the exact-length :func:`admit_row_ring` path — padded prompts
-    cannot take wrapped writes.
+    Admission is BUCKETED and BATCHED: prompts pad to power-of-two
+    length buckets (compile once per bucket, not once per distinct
+    prompt length) and the slots freed in the same chunk land in
+    :func:`admit_width`-wide :func:`admit_rows` dispatches. A rolling
+    (ring) cache, which the batcher reads off ``cfg.kv_cache_capacity``,
+    takes one exact-length :func:`admit_row_ring` dispatch a request —
+    padded prompts cannot take wrapped writes.
     """
 
     #: first per-request stream position consumed by step_rows sampling
@@ -879,7 +759,6 @@ class ContinuousBatcher:
                  top_k: int = 0, top_p: float = 0.0,
                  seed: int = 0,
                  shared_prefix=None, pipeline: bool = True,
-                 bucketed_admission: bool = True,
                  admission_buckets: Sequence[int] | None = None) -> None:
         self.params = params
         self.cfg = cfg
@@ -888,9 +767,6 @@ class ContinuousBatcher:
         self.eos_id = eos_id
         if shared_prefix is not None:
             cfg.refuse("shared-prefix caching")
-        if not bucketed_admission:
-            cfg.refuse("batch-1 (unbucketed) admission, whose programs "
-                       "do not carry the expert layers' counters,")
         #: shared-prefix caching: when set (a token sequence, e.g. a
         #: system prompt), every request's prompt is interpreted as a
         #: CONTINUATION of it — the prefix prefills once into a K/V
@@ -925,7 +801,7 @@ class ContinuousBatcher:
         #: admission vs prefix positions satisfied by a template COPY
         self.prefill_forward_tokens = 0
         #: positions those forwards RAN: a bucketed dispatch's rows x
-        #: bucket (:func:`admit_width`), a per-row program's own length.
+        #: bucket (:func:`admit_width`), a ring admission's own length.
         #: forward / padded is the share of prefilled positions that
         #: were a real prompt's
         self.prefill_padded_tokens = 0
@@ -947,9 +823,6 @@ class ContinuousBatcher:
         self.chunk = max(1, chunk)
         #: double-buffered dispatch (see class docstring)
         self.pipeline = bool(pipeline)
-        #: bucketed+batched admission; ring caches force the per-length
-        #: fallback (wrapped writes can't take padded prompts)
-        self.bucketed_admission = bool(bucketed_admission) and not self._ring
         if admission_buckets is not None:
             ladder = sorted({int(b) for b in admission_buckets})
             if not ladder or ladder[0] < 1:
@@ -1126,7 +999,7 @@ class ContinuousBatcher:
         return next((e for e in entries if e.id == pid), None) \
             if pid is not None else None
 
-    # --- admission (bucketed/batched with a per-length fallback) ---
+    # --- admission (bucketed and batched; per request on a ring) ---
 
     def _bucket_for(self, n: int) -> int:
         """Padded admission length for an n-token prompt (suffix, when a
@@ -1297,52 +1170,55 @@ class ContinuousBatcher:
 
     def _admit_prompts(self, pairs, prompts) -> None:
         """Admit prompt (row, request-index) pairs: group by (resident
-        prefix, length bucket) and land each group in ONE device
-        dispatch (legacy per-row programs when bucketing is off/ring).
-        A prefix-hit group runs only its SUFFIXES through the model
-        against the stored template (:func:`prefix_admit_rows`) — the
-        admission fast path. Also rebinds each row's rng stream to its
-        new occupant — one scatter of the wave's marshalled keys, not
-        a dispatch per row."""
+        prefix, length bucket) and land each group in
+        :func:`admit_width`-wide device dispatches; on a ring cache, one
+        exact-length :func:`admit_row_ring` dispatch a request (a ring
+        hosts no prefix template, so every payload there is a whole
+        prompt). A prefix-hit group runs only its SUFFIXES through the
+        model against the stored template (:func:`prefix_admit_rows`) —
+        the admission fast path. Also rebinds each row's rng stream to
+        its new occupant — one scatter of the wave's marshalled keys,
+        not a dispatch per row."""
         if not pairs:
             return
         with self.phase_times.phase("admit"):
-            if self.bucketed_admission:
-                groups: dict[tuple, list] = {}
+            if self._ring:
                 for row, req in pairs:
-                    p = prompts[req]
-                    if isinstance(p, _PrefixHit):
-                        cap = self.max_len - len(p.entry.tokens)
-                        key = (p.entry.id,
-                               bucket_for(len(p.suffix), cap,
-                                          self.admission_buckets))
-                    else:
-                        key = (None, self._bucket_for(len(p)))
-                    groups.setdefault(key, []).append((row, req))
-                for pid, bucket in sorted(groups,
-                                          key=lambda k: (k[0] or "",
-                                                         k[1])):
-                    whole = groups[(pid, bucket)]
-                    entry = (prompts[whole[0][1]].entry if pid is not None
-                             else None)
-                    w = admit_width(bucket, self.batch)
-                    for i in range(0, len(whole), w):
-                        grp = whole[i:i + w]
-                        rows, keys = self._marshal_wave(grp, w)
-                        toks, lens = self._pad_prompts_to(grp, prompts,
-                                                          bucket, w)
-                        self._admit_rows(rows, toks, lens, keys,
-                                         entry=entry)
-                        self.prefill_padded_tokens += w * bucket
-                        self._rebind_streams(grp, rows, keys)
-                        self._count_admission(grp, prompts)
-            else:
-                for row, req in pairs:
-                    self.prefill_padded_tokens += self._admit_legacy(
-                        row, req, prompts)
+                    self.cache, self.logits = admit_row_ring(
+                        self.params, self.cache, self.logits, row,
+                        jnp.asarray(prompts[req], jnp.int32)[None],
+                        self.cfg)
+                    self.prefill_padded_tokens += len(prompts[req])
                 rows, keys = self._marshal_wave(pairs)
                 self._rebind_streams(pairs, rows, keys)
                 self._count_admission(pairs, prompts)
+                return
+            groups: dict[tuple, list] = {}
+            for row, req in pairs:
+                p = prompts[req]
+                if isinstance(p, _PrefixHit):
+                    cap = self.max_len - len(p.entry.tokens)
+                    key = (p.entry.id,
+                           bucket_for(len(p.suffix), cap,
+                                      self.admission_buckets))
+                else:
+                    key = (None, self._bucket_for(len(p)))
+                groups.setdefault(key, []).append((row, req))
+            for pid, bucket in sorted(groups,
+                                      key=lambda k: (k[0] or "", k[1])):
+                whole = groups[(pid, bucket)]
+                entry = (prompts[whole[0][1]].entry if pid is not None
+                         else None)
+                w = admit_width(bucket, self.batch)
+                for i in range(0, len(whole), w):
+                    grp = whole[i:i + w]
+                    rows, keys = self._marshal_wave(grp, w)
+                    toks, lens = self._pad_prompts_to(grp, prompts,
+                                                      bucket, w)
+                    self._admit_rows(rows, toks, lens, keys, entry=entry)
+                    self.prefill_padded_tokens += w * bucket
+                    self._rebind_streams(grp, rows, keys)
+                    self._count_admission(grp, prompts)
 
     def _count_admission(self, pairs, prompts) -> None:
         """Fold one admitted group into the host-side prefill-compute
@@ -1386,37 +1262,6 @@ class ContinuousBatcher:
                 self.cfg)
             if stats:
                 self._device_stats.append(("admit", stats))
-
-    def _admit_legacy(self, row, req, prompts) -> int:
-        """Admit one request through its per-row program; returns the
-        positions that program ran."""
-        p = prompts[req]
-        if isinstance(p, _PrefixHit):
-            self.cache, self.logits = prefix_admit_row(
-                self.params, self.cache, self.logits, row,
-                p.entry.template,
-                jnp.asarray(p.suffix, jnp.int32)[None], self.cfg)
-        elif self._prefix_template is not None:
-            self.cache, self.logits = prefix_admit_row(
-                self.params, self.cache, self.logits, row,
-                self._prefix_template,
-                jnp.asarray(prompts[req], jnp.int32)[None], self.cfg)
-        elif self._ring:
-            self.cache, self.logits = admit_row_ring(
-                self.params, self.cache, self.logits, row,
-                jnp.asarray(prompts[req], jnp.int32)[None], self.cfg)
-        else:
-            # batch-1 admissions pad to the bucket ladder too: one
-            # compiled program per bucket, not one per distinct length
-            n = len(prompts[req])
-            padded = np.zeros((1, self._bucket_for(n)), np.int64)
-            padded[0, :n] = prompts[req]
-            self.cache, self.logits = admit_row(
-                self.params, self.cache, self.logits, row,
-                jnp.asarray(padded, jnp.int32),
-                jnp.asarray(n, jnp.int32), self.cfg)
-            return padded.shape[1]
-        return len(self._seq_of(p))
 
     # --- dispatch/fetch seams (overridden by the speculative batcher) ---
 
@@ -1528,7 +1373,7 @@ class ContinuousBatcher:
         histograms, and — on return — the PhaseTimes accumulation as
         per-phase ``tony_serve_phase_*`` counters. Swap in a
         :class:`~tony_tpu.runtime.metrics.NullRegistry` to serve
-        uninstrumented (the bench contrast arm)."""
+        uninstrumented."""
         if isinstance(max_new_tokens, int):
             budget = [max_new_tokens] * len(prompts)
         else:
@@ -1567,7 +1412,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
     (:func:`spec_step_rows`, built on the same propose-and-verify round
     as ``decode.speculative_generate_device``). Slot reuse works exactly
     as in the greedy batcher: admission prefills BOTH caches (bucketed
-    and batched by default — :func:`spec_admit_rows`), retirement frees
+    and batched — :func:`spec_admit_rows`), retirement frees
     the slot, and idle rows decode garbage the host discards. The
     pipelined loop and its catch-up semantics are inherited unchanged —
     one packed array per sync keeps the double-buffered fetch a single
@@ -1613,7 +1458,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
                  chunk: int = 4, temperature: float = 0.0,
                  top_k: int = 0, top_p: float = 0.0,
                  seed: int = 0, shared_prefix=None,
-                 pipeline: bool = True, bucketed_admission: bool = True,
+                 pipeline: bool = True,
                  admission_buckets: Sequence[int] | None = None) -> None:
         for c in (cfg, draft_cfg):
             c.refuse("speculative decoding")
@@ -1621,7 +1466,6 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
                          chunk=chunk, temperature=temperature,
                          top_k=top_k, top_p=top_p, seed=seed,
                          shared_prefix=shared_prefix, pipeline=pipeline,
-                         bucketed_admission=bucketed_admission,
                          admission_buckets=admission_buckets)
         if num_speculative < 1:
             raise ValueError("num_speculative must be >= 1")
@@ -1693,31 +1537,6 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
         raise NotImplementedError(
             "speculative serving is not supported in disaggregated "
             "mode (the shipment carries no draft-model cache)")
-
-    def _admit_legacy(self, row, req, prompts) -> int:
-        p = prompts[req]
-        sub = jax.random.fold_in(self._req_key(req), 0)
-        if isinstance(p, _PrefixHit):
-            self.cache, self.d_cache, self.pending = spec_prefix_admit_row(
-                self.params, self.draft_params, self.cache, self.d_cache,
-                self.pending, row, p.entry.template,
-                p.entry.draft_template,
-                jnp.asarray(p.suffix, jnp.int32)[None], sub, self.cfg,
-                self.draft_cfg, self.temperature, self.top_k, self.top_p)
-            return len(p.suffix)
-        tokens = jnp.asarray(p, jnp.int32)[None]
-        if self._prefix_template is not None:
-            self.cache, self.d_cache, self.pending = spec_prefix_admit_row(
-                self.params, self.draft_params, self.cache, self.d_cache,
-                self.pending, row, self._prefix_template,
-                self._draft_prefix_template, tokens, sub, self.cfg,
-                self.draft_cfg, self.temperature, self.top_k, self.top_p)
-        else:
-            self.cache, self.d_cache, self.pending = spec_admit_row(
-                self.params, self.draft_params, self.cache, self.d_cache,
-                self.pending, row, tokens, sub, self.cfg, self.draft_cfg,
-                self.temperature, self.top_k, self.top_p)
-        return len(p)
 
     def _issue(self):
         with self.phase_times.phase("dispatch"):
@@ -1939,7 +1758,7 @@ class ServeEngine:
         # Registry instrumentation: a handful of locked increments per
         # host SYNC (token counts batch into one inc per consume; the
         # TTFT/ITL histograms observe once per DELTA, <= slots per
-        # sync), pinned < 1% of chunk wall by bench.py's overhead arm.
+        # sync).
         reg = registry or metrics_mod.get_default()
         self._reg = reg
         buckets = (metrics_mod.TIME_BUCKETS_S if latency_buckets is None
@@ -2686,9 +2505,9 @@ class ServeEngine:
                 inflight = nxt
 
     def _run_sequential(self) -> None:
-        """issue → fetch → bookkeep → admit; the equivalence baseline
-        and A/B arm (``pipeline=False``) — every fetch serializes the
-        transport round trip with device compute."""
+        """issue → fetch → bookkeep → admit (``pipeline=False``): the
+        reference the tests hold the pipelined loop to — every fetch
+        serializes the transport round trip with device compute."""
         b = self.b
         while self._wait_for_work():
             self._admit_free()

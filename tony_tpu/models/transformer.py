@@ -852,35 +852,6 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     return _lm_head(params, x, cfg, mesh, rules), auxes.sum()
 
 
-def train_flops_per_token(cfg: TransformerConfig, seq: int) -> float:
-    """Model FLOPs per trained token: forward matmul FLOPs × 3 (backward ≈ 2×
-    forward for matmul-dominated graphs). Counts *model* FLOPs only — remat
-    recompute is excluded, so this yields MFU (not HFU) when divided by
-    wall-clock achieved FLOPs. Attention is counted causal (half of the full
-    S² score/value matmuls), matching what the flash kernel actually executes.
-    Sliding-window models (cfg.attn_window) count only the attended
-    length — the mean over positions of min(position+1, window) — so MFU
-    stays an honest achieved/model-FLOPs ratio rather than crediting
-    skipped blocks.
-    """
-    d, f, L, v = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.vocab_size
-    kv_width = cfg.kv_heads * cfg.head_dim     # == d for MHA
-    proj = 4 * d * d + 4 * d * kv_width   # wq + wo, + wk + wv (GQA-aware)
-    if cfg.attn_window and cfg.attn_window < seq:
-        w = cfg.attn_window
-        # positions 0..w-1 attend position+1 keys; the rest attend w
-        mean_attended = (w * (w + 1) / 2 + (seq - w) * w) / seq
-        attn = 4 * mean_attended * d      # QK^T + AV over attended keys
-    else:
-        attn = 2 * seq * d                # QK^T + AV, causal half of 4·S·d
-    if cfg.num_experts:
-        mlp = 2 * d * cfg.num_experts + cfg.moe_top_k * 4 * d * f
-    else:
-        mlp = 6 * d * f                   # gate + up + down
-    fwd = L * (proj + attn + mlp) + 2 * d * v   # + lm_head
-    return 3.0 * fwd
-
-
 def lm_loss(params: dict, batch: dict, cfg: TransformerConfig,
             mesh: Mesh | None = None, rules=DEFAULT_RULES) -> jax.Array:
     """Next-token cross-entropy. batch: {"tokens": [B, S]} (shift inside) or

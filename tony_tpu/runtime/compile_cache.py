@@ -4,7 +4,7 @@ Cold compiles are a large share of a short job on the chip (the ``large``
 train step alone is tens of seconds), and user scripts run in a job
 directory named per application — a cache placed relative to the working
 directory would never hit. So every entry point that compiles
-(``rt.initialize()``, ``serve_lm.py``, ``generate.py``, ``bench.py``,
+(``rt.initialize()``, ``serve_lm.py``, ``generate.py``,
 ``chip_smoke.py``'s children) calls :func:`enable`, and this is the ONLY
 place the tree sets ``jax_compilation_cache_dir``:
 

@@ -25,8 +25,7 @@ Design constraints (this sits on the serve hot loop):
   lookup is a plain dict read; ``inc``/``observe`` take a per-instrument
   lock (a read-modify-write like ``+=`` is NOT GIL-atomic, so lock-free
   writers would silently lose concurrent increments; an uncontended
-  acquire is ~100 ns, pinned under 1 % of serve chunk wall by bench.py's
-  metrics-overhead arm). Snapshot/render READS stay lock-free — a reader
+  acquire is ~100 ns). Snapshot/render READS stay lock-free — a reader
   may see a histogram's ``sum`` and ``count`` momentarily torn, which
   monitoring tolerates by design (telemetry, not accounting). The
   registry lock is taken only when an instrument is first created.
@@ -315,7 +314,7 @@ class MetricsRegistry:
 
 class NullRegistry(MetricsRegistry):
     """A registry whose instruments swallow every observation — the
-    zero-cost-contrast arm for overhead benchmarks (``bench.py``)."""
+    uninstrumented side of an overhead comparison."""
 
     class _Null:
         name = "null"
@@ -350,7 +349,7 @@ def get_default() -> MetricsRegistry:
 
 
 def set_default(registry: MetricsRegistry) -> MetricsRegistry:
-    """Swap the default registry (tests, bench contrast arms). Returns
+    """Swap the default registry (tests, overhead comparisons). Returns
     the previous one."""
     global _default
     with _default_lock:
