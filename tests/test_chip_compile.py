@@ -46,6 +46,7 @@ class _Chip:
         self.one = SingleDeviceSharding(topo.devices[0])
         self.mesh = Mesh(np.array(topo.devices).reshape(2, 2),
                          ("dp", "tp"))
+        self.dp1 = Mesh(np.array(topo.devices[:1]), ("dp",))
 
     def shape(self, dims, dtype=jnp.bfloat16, spec=None):
         sharding = (self.one if spec is None
@@ -188,6 +189,74 @@ def test_large_lm_grad_compiles_on_2x2_mesh(chip):
     batch = {"inputs": tokens, "targets": tokens}
     chip.compile(jax.value_and_grad(
         lambda p, b: T.lm_loss(p, b, cfg, chip.mesh)), params, batch)
+
+
+def test_train_cell_step_fits_at_the_rung_the_ladder_takes(chip,
+                                                           monkeypatch):
+    """The benchmark's train step (``train-mistral7b-8k``: Mistral-7B
+    widths, 4 layers, 2 x 8,192, bf16 AdamW, donated state, through
+    ``make_train_step``) compiled for one v5e at the rung
+    ``models/remat.py`` takes when the device says 15.75 GiB, and at
+    rung 0. Held here so that a model or optimizer change that eats the
+    headroom fails a test and not a chip run:
+
+    - rung 0 (``remat_policy="full"``, the program of PRs 24–30) peaks
+      at 12,130,829,824 bytes by ``memory_analysis()`` — NOT the
+      9,118,390,784 of ``peak_bytes_in_use``, which does not see a
+      program's temporaries;
+    - the ladder takes rung 3 (3.77 GB saved: the flash output, q/k/v,
+      the output projection, the MLP's gate) at 15,903,482,368, under
+      the limit less the runtime's 258 MiB; rung 4 is refused by the
+      compiler ("Used 16.59G of 15.75G hbm");
+    - the ladder's own reckoning of rung 0 (state + gradients +
+      ``working_bytes``) is the compiler's peak within the band its
+      docstring gives."""
+    from tony_tpu.models import remat
+    from tony_tpu.models import transformer as T
+    from tony_tpu.models.train import (default_optimizer, init_state,
+                                       make_train_step)
+    from tony_tpu.runtime import metrics as metrics_mod
+    limit = int(15.75 * (1 << 30))
+    # a described chip gives no numbers: the limit is a v5e's, and nothing
+    # is resident before the step's own arguments (an AOT compile)
+    monkeypatch.setattr(remat, "device_memory", lambda: (limit, 0))
+    cfg = T.TransformerConfig(
+        vocab_size=32000, d_model=4096, n_layers=4, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq=32768, attn_window=4096,
+        dtype=jnp.bfloat16)
+    opt = default_optimizer(lr=3e-4, weight_decay=0.01, warmup_steps=1,
+                            total_steps=10000)
+    mesh = chip.dp1
+    on_mesh = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_mesh),
+        jax.eval_shape(lambda p: init_state(p, opt), shapes))
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=on_mesh)
+    batch = {"inputs": tokens, "targets": tokens}
+
+    def compiled(c):
+        step = make_train_step(lambda p, b: T.lm_loss(p, b, c, mesh), opt,
+                               mesh)
+        program = step.lower(state, batch).compile()
+        rung = int(metrics_mod.get_default().gauge(
+            "tony_train_saved_rung").value)
+        return (rung, program.memory_analysis().peak_memory_in_bytes,
+                program.as_text().count("tony_flash_fwd"))
+
+    rung, peak, fwd_calls = compiled(cfg)
+    rung0, peak0, fwd_calls0 = compiled(cfg.scaled(remat_policy="full"))
+    assert (rung, rung0) == (3, 0)
+    assert peak + (258 << 20) < limit, peak
+    assert 15.6e9 < peak < 16.2e9 and 11.9e9 < peak0 < 12.4e9, (peak, peak0)
+    assert fwd_calls < fwd_calls0           # the replayed forward is gone
+    held, grads = remat.bytes_a_device(state), remat.bytes_a_device(shapes)
+    reckoned = held + grads + remat.working_bytes(cfg, 2, 8192, mesh,
+                                                  T.DEFAULT_RULES)
+    assert -0.26e9 < reckoned - peak0 < 0.8e9, (reckoned, peak0)
+    assert peak - peak0 == pytest.approx(
+        remat.rung_bytes(cfg, 2, 8192, mesh, T.DEFAULT_RULES)[3], rel=0.01)
 
 
 # (d_model, heads, kv heads, d_ff, vocab, slots, cache rows) of the

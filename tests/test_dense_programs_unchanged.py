@@ -8,6 +8,15 @@ new output (``serve._device_stats`` is an EMPTY pytree there), no
 reordered op. A later PR that means to change a dense program replaces
 the hash it changed, and says so; run this file as a script with a
 checkout's root to print that checkout's hashes.
+
+PR 31 replaced ``mistral.grad`` (b30bf7cc7e203f12 → 51ab5765b7edf26b):
+a block under ``remat=True`` now marks what ``models/remat.LADDER`` may
+keep for the backward with ``checkpoint_name``. On the CPU the rung is 0
+and the program is the parent's op for op — a name lowers to nothing —
+but each name is an equation of the trace, so the counters in the private
+functions' names (``@_where_116`` → ``@_where_118``) moved; with those
+suffixes stripped the two texts are equal. ``phi.grad`` (``remat=False``:
+no names) and the four serving programs are the parent's, hash for hash.
 """
 
 import hashlib
@@ -16,10 +25,11 @@ import sys
 
 import pytest
 
-#: sha256[:16] of the lowered text at 06229a9 (PR 27), CPU backend
+#: sha256[:16] of the lowered text at 06229a9 (PR 27), CPU backend;
+#: ``mistral.grad`` as of PR 31 (docstring)
 PARENT = {
     "mistral.admit_rows": "e950ef452b1d16c7",
-    "mistral.grad": "b30bf7cc7e203f12",
+    "mistral.grad": "51ab5765b7edf26b",
     "mistral.step_rows": "e71b707f3a28fcb1",
     "phi.admit_rows": "32ad1762b4f0f5e8",
     "phi.grad": "05a842aa6f92e610",

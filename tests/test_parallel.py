@@ -1077,27 +1077,72 @@ def test_unknown_cp_strategy_rejected():
 
 
 class TestRematPolicy:
-    """remat_policy: full recompute vs dots (save MXU outputs, recompute
-    VPU) — same math, different memory/FLOP trade."""
+    """What the block keeps for its backward (models/remat.LADDER) is a
+    memory/FLOP trade, never the math: every rung gives rung 0's loss
+    and gradients to float32 round-off."""
 
-    def test_policies_agree_and_bogus_rejected(self):
+    @staticmethod
+    def _loss_and_grads(monkeypatch, rung, mesh=None, flash=False):
+        from tony_tpu.models import remat
         from tony_tpu.models import transformer as T
-        cfg_full = T.PRESETS["tiny"].scaled(dtype=jnp.float32)
-        cfg_dots = cfg_full.scaled(remat_policy="dots")
-        params = T.init_params(jax.random.PRNGKey(0), cfg_full)
-        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
-                                  cfg_full.vocab_size)
+        # a device with room for everything; the scope's ceiling picks
+        monkeypatch.setattr(remat, "device_memory", lambda: (1 << 50, 0))
+        if flash:
+            # the flash arm (interpreted kernels): its names join in
+            monkeypatch.setattr(T, "_attention", lambda q, k, v, *a: (
+                T.flash_attention(q, k, v, causal=True, block_q=16,
+                                  block_k=16)))
+        cfg = T.PRESETS["tiny"].scaled(dtype=jnp.float32)
+        params = T.init_params(jax.random.PRNGKey(0), cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0,
+                                  cfg.vocab_size)
         batch = {"inputs": toks[:, :32], "targets": toks[:, 1:]}
-        l_full = float(T.lm_loss(params, batch, cfg_full))
-        l_dots = float(T.lm_loss(params, batch, cfg_dots))
-        np.testing.assert_allclose(l_dots, l_full, rtol=1e-6)
-        g_full = jax.grad(lambda p: T.lm_loss(p, batch, cfg_full))(params)
-        g_dots = jax.grad(lambda p: T.lm_loss(p, batch, cfg_dots))(params)
-        for a, b in zip(jax.tree.leaves(g_full), jax.tree.leaves(g_dots)):
+        sc = remat.Scope(ceiling=rung)
+        with remat.scope(sc):
+            loss, grads = jax.jit(jax.value_and_grad(
+                lambda p: T.lm_loss(p, batch, cfg, mesh)))(params)
+        assert sc.rung == rung
+        return float(loss), grads
+
+    def _assert_rung_is_rung_0(self, monkeypatch, rung, **arm):
+        l0, g0 = self._loss_and_grads(monkeypatch, 0, **arm)
+        l, g = self._loss_and_grads(monkeypatch, rung, **arm)
+        np.testing.assert_allclose(l, l0, rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-6)
-        # invalid policy fails at CONFIG time, even with remat off
-        with pytest.raises(ValueError, match="remat_policy"):
-            cfg_full.scaled(remat_policy="bogus")
-        with pytest.raises(ValueError, match="remat_policy"):
-            cfg_full.scaled(remat=False, remat_policy="bogus")
+
+    @pytest.mark.parametrize("arm", ["dense", "flash"])
+    @pytest.mark.parametrize("rung", [1, 2, 3, 4])
+    def test_every_rung_is_rung_0(self, monkeypatch, rung, arm):
+        self._assert_rung_is_rung_0(monkeypatch, rung, flash=arm == "flash")
+
+    @pytest.mark.parametrize("rung", [2, 4])
+    def test_every_rung_is_rung_0_on_a_mesh(self, monkeypatch, rung):
+        mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+        self._assert_rung_is_rung_0(monkeypatch, rung, mesh=mesh)
+
+    def test_a_rung_keeps_its_names_and_full_pins_rung_0(self, monkeypatch):
+        """Rung 2 saves the output projection as a residual of the scanned
+        block (the kernel's operands join on the flash arm);
+        ``remat_policy="full"`` is rung 0 whatever the device has."""
+        from tony_tpu.models import remat
+        from tony_tpu.models import transformer as T
+        monkeypatch.setattr(remat, "device_memory", lambda: (1 << 50, 0))
+        cfg = T.PRESETS["tiny"].scaled(dtype=jnp.float32,
+                                       remat_policy="full")
+        params = T.init_params(jax.random.PRNGKey(0), cfg)
+        batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+        sc = remat.Scope()
+        with remat.scope(sc):
+            jax.grad(lambda p: T.lm_loss(p, batch, cfg))(params)
+        assert sc.rung == 0
+        with remat.scope(remat.Scope(ceiling=2)) as sc:
+            text = str(jax.make_jaxpr(jax.grad(lambda p: T.lm_loss(
+                p, batch, cfg.scaled(remat_policy="fit"))))(params))
+        assert sc.rung == 2
+        # the backward's scan reads the kept projection as a stacked
+        # input, 2 layers x [2, 32, 128], beside the block inputs
+        assert text.count("f32[2,2,32,128]") > str(jax.make_jaxpr(jax.grad(
+            lambda p: T.lm_loss(p, batch, cfg)))(params)).count(
+                "f32[2,2,32,128]")

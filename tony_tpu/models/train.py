@@ -13,6 +13,7 @@ mnist_distributed.py:113-126).
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import time
 from typing import Any, Callable
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tony_tpu.models import remat
 from tony_tpu.parallel.sharding import (DEFAULT_RULES, Rules,
                                         logical_sharding, param_shardings,
                                         shard_pytree)
@@ -96,6 +98,12 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array] | None,
     gradients (the 1F1B pipeline, transformer.lm_value_and_grad — 1F1B
     must run the loss inside the pipeline, so it cannot be a jax.grad
     target); ``loss_fn`` may then be None.
+
+    What the backward keeps of the forward is decided inside the trace
+    from the memory the device has (``models/remat.py``); the step tells
+    it what its own arguments hold. Should the compiler still refuse the
+    step for memory, the step is rebuilt one rung down and says so in
+    the log (``tony_train_saved_step_downs_total`` counts it).
     """
 
     fused = hasattr(optimizer, "fused_apply")
@@ -107,10 +115,14 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array] | None,
                          "default_optimizer(fused=False) with a mesh")
 
     vag = value_and_grad_fn or jax.value_and_grad(loss_fn)
+    saves = remat.Scope()
 
-    def step(state: TrainState, batch: Any):
+    def step(state: TrainState, batch: Any, ceiling: int):
+        # ``ceiling`` is ``saves.ceiling``, static: part of the program's
+        # key, so a step-down is a new trace and never a cached one
         _count_trace("train_step", batch)   # trace-time only: counts compiles
-        loss, grads = vag(state["params"], batch)
+        with remat.scope(saves):
+            loss, grads = vag(state["params"], batch)
         with jax.named_scope("optimizer"):
             if fused:
                 # single-pass update (ops/optim.py): params change inside
@@ -127,21 +139,36 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array] | None,
         return new_state, {"loss": loss, "grad_norm": gnorm,
                            "step": new_state["step"]}
 
-    jitted = jax.jit(step, donate_argnums=(0,) if donate else ())
+    jitted = jax.jit(step, static_argnums=(2,),
+                     donate_argnums=(0,) if donate else ())
+
+    # set_mesh must wrap the CALL, not the traced body: the ambient mesh is
+    # what lets bare-PartitionSpec sharding constraints resolve.
+    ambient = (contextlib.nullcontext if mesh is None
+               else functools.partial(jax.set_mesh, mesh))
 
     def under_mesh(fn):
-        if mesh is None:
-            return fn
+        def call(state, batch):
+            if not saves.held:
+                # a state that is not donated lives beside its successor
+                saves.held = (remat.bytes_a_device((state, batch))
+                              + (0 if donate else
+                                 remat.bytes_a_device(state)))
+            with ambient():
+                return fn(state, batch, saves.ceiling)
+        return call
 
-        def sharded(state, batch):
-            # set_mesh must wrap the CALL, not the traced body: the ambient
-            # mesh is what lets bare-PartitionSpec sharding constraints
-            # resolve.
-            with jax.set_mesh(mesh):
-                return fn(state, batch)
-        return sharded
+    dispatch = under_mesh(jitted)
 
-    run = _instrument_step(under_mesh(jitted))
+    def fitted(state, batch):
+        while True:
+            try:
+                return dispatch(state, batch)
+            except jax.errors.JaxRuntimeError as e:
+                if not remat.step_down(saves, e, state):
+                    raise
+
+    run = _instrument_step(fitted)
     # AOT handle on the SAME program (arrays or ShapeDtypeStructs in): what
     # lets a caller read the step's text — is the flash kernel in it? — or
     # compile it for a described topology, without running it

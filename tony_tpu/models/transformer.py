@@ -30,15 +30,17 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from tony_tpu.models import remat
 from tony_tpu.ops import mosaic
 from tony_tpu.ops.attention import flash_attention, reference_attention
 from tony_tpu.ops.norms import rms_norm_reference
 from tony_tpu.parallel.moe import moe_ffn
 from tony_tpu.parallel.ring_attention import ring_attention
-from tony_tpu.parallel.sharding import (DEFAULT_RULES, constrain,
-                                        shard_attention)
+from tony_tpu.parallel.sharding import (DEFAULT_RULES, _is_axes_leaf,
+                                        constrain, shard_attention)
 from tony_tpu.models.train import masked_cross_entropy
 
 
@@ -157,14 +159,12 @@ class TransformerConfig:
     # to pin it.
     logits_dtype: Any = None
     remat: bool = True
-    # Rematerialization policy when remat=True: "full" recomputes the
-    # whole block in backward (minimum memory); "dots" saves the
-    # NON-BATCHED matmul outputs — projections and MLP; the batched
-    # attention QK^T/AV dots are still recomputed
-    # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable) — more
-    # activation memory, but the backward stops re-paying the projection/
-    # MLP FLOPs that dominate the recompute bill.
-    remat_policy: str = "full"
+    # What a block keeps for its backward when remat=True. "fit" (the
+    # default): whatever of models/remat.LADDER the device's memory
+    # holds, decided when the step is traced — nothing to set. "full":
+    # the pin for minimum memory — only block inputs are kept and the
+    # backward replays each block's forward (also the tests' reference).
+    remat_policy: str = "fit"
     # lax.scan unroll factor over layers: 1 = rolled while-loop (fast
     # compile, the default); n_layers = fully unrolled (removes the scan's
     # activation-stacking dynamic-update-slices, ~6% faster per step on one
@@ -240,10 +240,11 @@ class TransformerConfig:
         if kv <= 0 or self.n_heads % kv:
             raise ValueError(f"n_kv_heads={kv} must be a positive divisor "
                              f"of n_heads={self.n_heads}")
-        if self.remat_policy not in ("full", "dots", "attn"):
+        if self.remat_policy not in ("fit", "full"):
             raise ValueError(f"unknown remat_policy "
-                             f"{self.remat_policy!r}; expected 'full', "
-                             f"'dots', or 'attn'")
+                             f"{self.remat_policy!r}; expected 'fit' "
+                             f"(keep what the device's memory holds) or "
+                             f"'full' (keep block inputs only)")
         if self.pp_schedule not in ("gpipe", "1f1b"):
             raise ValueError(f"unknown pp_schedule {self.pp_schedule!r}; "
                              f"expected 'gpipe' or '1f1b'")
@@ -627,22 +628,17 @@ def _attention(q, k, v, mesh: Mesh | None, cp_strategy: str = "ring",
         functools.partial(arm, causal=True, window=window), q, k, v, mesh)
 
 
-def _remat_policy(cfg: TransformerConfig):
-    """jax.checkpoint policy for cfg.remat_policy (None = save nothing)."""
-    if cfg.remat_policy == "full":
-        return None
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if cfg.remat_policy == "attn":
-        # save ONLY the flash kernel's outputs (o [B,S,H,D] + lse
-        # [B,H,S], named in ops/attention.py's vjp fwd rules): the
-        # backward replay recomputes the cheap projections but the
-        # O(S²) flash forward is DCE'd — the long-context policy, where
-        # remat="full" re-pays the very kernel that dominates the step
-        return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse")
-    raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}; "
-                     f"expected 'full', 'dots', or 'attn'")
+def _checkpointed(block_fn, cfg: TransformerConfig, params: dict,
+                  b: int, s: int, mesh, rules):
+    """``block_fn`` under ``jax.checkpoint``, keeping the rung of
+    :data:`tony_tpu.models.remat.LADDER` that fits this device
+    (:func:`tony_tpu.models.remat.decide`, at trace time)."""
+    grads = sum(jax.tree.leaves(jax.tree.map(
+        lambda ax, p: remat.sharded_bytes(p.shape, ax, p.dtype.itemsize,
+                                          mesh, rules),
+        logical_axes(cfg), params, is_leaf=_is_axes_leaf)))
+    rung = remat.decide(cfg, b, s, grads, mesh, rules)
+    return jax.checkpoint(block_fn, policy=remat.policy(rung))
 
 
 def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
@@ -658,6 +654,11 @@ def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
         rope = rope_tables(positions, cfg.head_dim, cfg.rope_base,
                            cfg.rope_scaling)
     cos, sin = rope
+    # what models/remat.LADDER may keep for the backward is marked by name
+    # (the flash kernel's operands and outputs in ops/attention.py);
+    # without remat there is nothing to choose, and the program's text
+    # stays the one it was (a name is an equation of the trace)
+    keep = checkpoint_name if cfg.remat else (lambda x, name: x)
 
     # named sections (metadata only): a profile is read by these scopes
     with jax.named_scope("attn"):
@@ -679,7 +680,8 @@ def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
         o = _attention(q, k, v, mesh, cfg.cp_strategy,
                        cfg.attn_window or None)
         attn_out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-        x = x + constrain(attn_out, ("batch", "seq", "embed"), mesh, rules)
+        x = x + keep(constrain(attn_out, ("batch", "seq", "embed"), mesh,
+                               rules), "attn_proj")
 
     with jax.named_scope("mlp"):
         h = rms_norm_reference(x, p["mlp_norm"], cfg.rms_eps)
@@ -702,8 +704,9 @@ def _block(x, p, cfg: TransformerConfig, mesh, rules, rope=None,
             aux = metrics.aux_loss
             mlp_out = moe_out
         else:
-            gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"])
-            up = jnp.einsum("bsd,df->bsf", h, p["w_up"])
+            gate = keep(jnp.einsum("bsd,df->bsf", h, p["w_gate"]),
+                        "mlp_gate")
+            up = keep(jnp.einsum("bsd,df->bsf", h, p["w_up"]), "mlp_up")
             inner = jax.nn.silu(gate) * up
             inner = constrain(inner, ("batch", "seq", "mlp"), mesh, rules)
             mlp_out = jnp.einsum("bsf,fd->bsd", inner, p["w_down"])
@@ -788,7 +791,8 @@ def _forward_pp(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         block_fn = functools.partial(_block, cfg=cfg, mesh=None, rules=rules,
                                      ep_axis=ep_axis)
         if cfg.remat:
-            block_fn = jax.checkpoint(block_fn, policy=_remat_policy(cfg))
+            block_fn = _checkpointed(block_fn, cfg, params, b, s, mesh,
+                                     rules)
 
         def body(carry, p):
             h, acc = carry
@@ -841,7 +845,7 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         # rope tables ride the non-differentiated argument slot; marking
         # them static would re-run the trig in every layer's rematerialized
         # forward, which is exactly what hoisting avoids
-        block_fn = jax.checkpoint(block_fn, policy=_remat_policy(cfg))
+        block_fn = _checkpointed(block_fn, cfg, params, b, s, mesh, rules)
 
     def scan_body(x, layer_params):
         x, aux = block_fn(x, layer_params, rope=rope)
@@ -915,7 +919,8 @@ def lm_value_and_grad(params: dict, batch: dict, cfg: TransformerConfig,
         block_fn = functools.partial(_block, cfg=cfg, mesh=None,
                                      rules=rules)
         if cfg.remat:
-            block_fn = jax.checkpoint(block_fn, policy=_remat_policy(cfg))
+            block_fn = _checkpointed(block_fn, cfg, params, b, s, mesh,
+                                     rules)
 
         def body(carry, p):
             h, acc = carry

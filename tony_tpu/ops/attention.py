@@ -738,14 +738,17 @@ def _flash_attention_bhsd(q, k, v, causal, g, bq, bk, band, window):
 def _flash_fwd_rule(q, k, v, causal, g, bq, bk, band, window):
     o, lse = _flash_forward(q, k, v, causal=causal, g=g, bq=bq,
                             bk=bk, band=band, window=window)
-    # checkpoint_name on the kernel OUTPUTS: under
-    # remat_policy="attn" (save_only_these_names) the remat replay
-    # fetches o/lse from the saved forward and DCE drops the flash
-    # forward kernel from the recompute graph entirely — the backward
-    # then re-runs only the cheap projections, not the O(S²) kernel.
-    # Under other policies the names are inert.
+    # checkpoint_name on the kernel's outputs and operands: what
+    # models/remat.LADDER may keep for the backward. With o/lse kept the
+    # remat replay drops the forward kernel (DCE); with the flat operands
+    # kept too — in the layout this kernel and its backward read, so
+    # nothing is transposed on the way in or out of the saved stack — it
+    # drops the projections behind them. Outside that policy the names
+    # are inert.
     o = checkpoint_name(o, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
+    q, k, v = (checkpoint_name(q, "flash_q"), checkpoint_name(k, "flash_k"),
+               checkpoint_name(v, "flash_v"))
     return o, (q, k, v, o, lse)
 
 
@@ -773,6 +776,8 @@ def _flash_lse_fwd_rule(q, k, v, causal, g, bq, bk, band, window):
                             bk=bk, band=band, window=window)
     o = checkpoint_name(o, "flash_out")       # see _flash_fwd_rule
     lse = checkpoint_name(lse, "flash_lse")
+    q, k, v = (checkpoint_name(q, "flash_q"), checkpoint_name(k, "flash_k"),
+               checkpoint_name(v, "flash_v"))
     return (o, lse), (q, k, v, o, lse)
 
 
