@@ -29,6 +29,12 @@ from benchmark.tests import test_families as cases
 from benchmark.tests.test_admit_readers import (  # noqa: F401 — PR 29's
     test_admissions_per_call_and_over_the_window,
     test_no_admission_reads_none)
+from benchmark.tests.test_window_full_family import (  # noqa: F401 — PR 35's
+    test_attention_share_of_the_admissions,
+    test_attention_share_reads_none_without_the_kernel_or_an_admission,
+    test_layer_type_rides_in_the_bias_and_only_a_sliding_layer_rotates,
+    test_reference_attention_is_the_window_or_the_whole_context,
+    test_the_cells_files_are_the_issues, test_window_share_of_live_rows)
 from benchmark.tests.test_families import (  # noqa: F401 — collected here
     test_dense_weights_are_the_parents_bit_for_bit,
     test_family_provides_the_whole_list,

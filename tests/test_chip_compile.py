@@ -470,3 +470,80 @@ def test_latent_prompt_attention_takes_an_unaligned_short_prompt(chip):
         chip.shape((2, 255, 64, la.rope_dim)),
         chip.shape((2, 255, 1, la.stored_row)),
         chip.shape((la.kv_rank, 64, la.nope_dim + la.v_dim))).compile()
+
+
+# ---------------------------------------------------------------------------
+# Window + full attention kinds, each owning its cache, at Command A+'s
+# widths and the cell's own depth, slots and rows (PR 35)
+# ---------------------------------------------------------------------------
+
+def _mixed_serving(chip):
+    """(cfg, params, cache, logits) of ``command-a-plus-l4-ep8`` as the
+    cell ``serve-commandaplus-mixedlen`` runs it — 3 window + 1 full
+    layer, 16 held experts, 32 slots x 16,384 rows — as shapes on the
+    described chip: 13.2 GB of arguments."""
+    from benchmark.lib import modelcfg
+    from tony_tpu.models import decode as D
+    c = modelcfg.load("command-a-plus-l4-ep8")
+    fam = modelcfg.family(c)
+    cfg = fam.program_config(c, dtype=jnp.bfloat16, remat=False)
+    slots, rows = 32, 16384
+    params = chip.place(jax.eval_shape(
+        lambda: fam.make_params(7, c, jnp.bfloat16)))
+    cache = jax.eval_shape(lambda: D.init_kv_cache(cfg, slots, rows))
+    cache = chip.place(dict(cache, length=chip.shape((slots,), jnp.int32)))
+    return cfg, params, cache, chip.shape((slots, cfg.vocab_size),
+                                          cfg.logits_storage_dtype)
+
+
+#: what one v5e chip's compiler allows a program (15.75 GiB)
+_HBM = int(15.75 * 2**30)
+
+
+def _copies_of_any(text, cache):
+    return [c for n in ("k", "v", "k_ring", "v_ring")
+            for c in _cache_sized_copies(text, cache[n])]
+
+
+def test_mixed_step_rows_holds_ring_and_linear_cache_in_place(chip):
+    """The decode chunk of the mixed model at the cell's size: the window
+    kinds' rings ([3, 32, 4096, 1024]) and the full kind's linear buffer
+    ([1, 32, 16384, 1024]) are each written and read in place — no
+    cache-sized copy — the routed experts reach their kernel stacked,
+    and the whole program fits the chip beside its 13.2 GB of
+    arguments."""
+    from tony_tpu.models import serve as S
+    cfg, params, cache, logits = _mixed_serving(chip)
+    assert cache["k_ring"].shape == (3, 32, 4096, 1024)
+    assert cache["k"].shape == (1, 32, 16384, 1024)
+    compiled = S.step_rows.lower(
+        params, cache, logits, chip.shape((32, 2), jnp.uint32),
+        chip.shape((32,), jnp.int32), n=8, cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step_rows")
+    assert not _copies_of_any(text, cache)
+    assert text.count("tony_moe_gmm") >= 12       # 4 layers x gate/up/down
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 30
+    assert memory.peak_memory_in_bytes < _HBM
+
+
+def test_mixed_admit_rows_at_the_longest_bucket_fits_the_chip(chip):
+    """The 16,384 bucket's admission (one row: ``admit_width``): the
+    flash kernel windowed on the window kinds and plain causal on the
+    full kind at 128 query / 8 K/V heads of 128, the rows landed in the
+    rings by position modulo 4,096 and in the linear buffer as they are
+    — no cache-sized copy on the way — and the program's peak under what
+    the chip allows beside weights and cache."""
+    from tony_tpu.models import serve as S
+    cfg, params, cache, logits = _mixed_serving(chip)
+    assert S.admit_width(16384, 32) == 1
+    compiled = S.admit_rows.lower(
+        params, cache, logits, chip.shape((1,), jnp.int32),
+        chip.shape((1, 16384), jnp.int32), chip.shape((1,), jnp.int32),
+        cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_admit_rows")
+    assert "tony_flash_fwd" in text and "tony_moe_gmm" in text
+    assert not _copies_of_any(text, cache)
+    assert compiled.memory_analysis().peak_memory_in_bytes < _HBM

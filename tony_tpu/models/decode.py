@@ -44,9 +44,9 @@ import jax.numpy as jnp
 from tony_tpu.models import transformer as T
 from tony_tpu.models.quantize import QuantizedWeight
 from tony_tpu.ops import mosaic
-from tony_tpu.ops.norms import rms_norm_reference
+from tony_tpu.ops.norms import layer_norm_reference, rms_norm_reference
 from tony_tpu.parallel.moe import (HeldExperts, held_experts_ffn, moe_ffn,
-                                   sigmoid_route)
+                                   shared_experts_ffn, sigmoid_route)
 
 
 #: TOKEN POSITIONS (the sequence axis, NOT batch x seq) STRICTLY ABOVE
@@ -134,26 +134,74 @@ def _ring_capacity(cfg: T.TransformerConfig) -> int:
 
 
 def _kv_spec(cfg: T.TransformerConfig) -> dict:
-    """name → (per-head width, dtype) of each position buffer: k/v hold
-    ``head_dim`` values per (token, kv-head); an int8 cache adds one f32
-    absmax scale per (token, kv-head) beside each."""
-    if cfg.kinded:
-        # latent attention: ONE compressed row a token, [c_kv; k_rope],
-        # shared by every head (kv heads = 1) — see _latent_qkv
-        return {"ckv": (cfg.latent.stored_row, cfg.dtype)}
+    """name → (per-head width, dtype) of each position buffer of the
+    dense decoder: k/v hold ``head_dim`` values per (token, kv-head); an
+    int8 cache adds one f32 absmax scale per (token, kv-head) beside
+    each. (A model with layer_kinds: :func:`cache_layout`.)"""
     if cfg.kv_quant:
         return {"k": (cfg.head_dim, jnp.int8), "v": (cfg.head_dim, jnp.int8),
                 "k_scale": (1, jnp.float32), "v_scale": (1, jnp.float32)}
     return {"k": (cfg.head_dim, cfg.dtype), "v": (cfg.head_dim, cfg.dtype)}
 
 
-def _kv_heads(cfg: T.TransformerConfig) -> int:
-    """Heads a stored row holds: the latent row is one for all heads."""
-    return 1 if cfg.kinded else cfg.kv_heads
+def ring_rows(cfg: T.TransformerConfig) -> int:
+    """Rows a slot's RING of a ``window`` kind holds: ``attn_window`` —
+    a query sees its window's rows, its own among them, and a decode
+    step writes its row before it reads — rounded up to whole 16-row
+    tiles (a bf16 buffer's [rows, lanes] tile is 16 x 128: a row count
+    off the tile pads every slot's slab and turns the whole-slot landing
+    of an admission into partial-tile writes). 4,096 stays 4,096."""
+    return -(-cfg.attn_window // 16) * 16
+
+
+def cache_layout(cfg: T.TransformerConfig, max_len: int,
+                 ring: bool = True) -> dict:
+    """name → (layers, rows a slot, row width, dtype) of every position
+    buffer. The dense decoder: its k/v (+ int8 scales), all layers, one
+    row count (``kv_cache_capacity`` or ``max_len``). A model with
+    layer_kinds: each ATTENTION owns its buffers, indexed by the layer's
+    place among the layers that attend so
+    (``TransformerConfig.attention_of``), with its OWN row count —
+    ``latent``: ``ckv``, one compressed row [c_kv; k_rope] a token,
+    ``max_len`` rows; ``full``: ``k`` / ``v``, ``max_len`` rows;
+    ``window``: ``k_ring`` / ``v_ring``, :func:`ring_rows` rows written
+    modulo their count — or ``max_len`` linear rows where ``ring`` is
+    False: the mini cache of a prefill, which :func:`place_rows` lands
+    in a ring by each row's length."""
+    if not cfg.kinded:
+        rows = _ring_capacity(cfg) or max_len
+        return {n: (cfg.n_layers, rows, cfg.kv_heads * w, dt)
+                for n, (w, dt) in _kv_spec(cfg).items()}
+    kv, out = cfg.kv_heads * cfg.head_dim, {}
+    for attention, n in cfg.attention_layers().items():
+        if attention == "latent":
+            out["ckv"] = (n, max_len, cfg.latent.stored_row, cfg.dtype)
+        elif attention == "full":
+            out["k"] = out["v"] = (n, max_len, kv, cfg.dtype)
+        else:
+            rows = ring_rows(cfg) if ring else max_len
+            out["k_ring"] = out["v_ring"] = (n, rows, kv, cfg.dtype)
+    return out
+
+
+def cache_bytes_by_kind(cfg: T.TransformerConfig, batch: int,
+                        max_len: int) -> dict:
+    """Bytes of the position buffers ``batch`` slots hold, by the KIND of
+    state: ``latent`` / ``window`` / ``full`` for a model with
+    layer_kinds (each attention's own buffers), ``ring`` or ``linear``
+    for the dense decoder."""
+    kind_of = {"ckv": "latent", "k_ring": "window", "v_ring": "window"}
+    flat = "ring" if _ring_capacity(cfg) else "linear"
+    out: dict = {}
+    for n, (layers, rows, width, dt) in cache_layout(cfg, max_len).items():
+        kind = kind_of.get(n, "full") if cfg.kinded else flat
+        out[kind] = out.get(kind, 0) + (layers * batch * rows * width
+                                        * jnp.dtype(dt).itemsize)
+    return out
 
 
 def init_kv_cache(cfg: T.TransformerConfig, batch: int,
-                  max_len: int) -> dict:
+                  max_len: int, ring: bool = True) -> dict:
     """Zeroed cache pytree: k/v of shape [L, B, max_len, KV·hd] — KV is
     cfg.kv_heads, so grouped-query configs carry an n_heads/n_kv_heads×
     smaller cache (the main GQA payoff at long max_len).
@@ -192,7 +240,11 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
     rows instead of ``max_len`` — writes wrap modulo the capacity
     (sliding-window models only; the ring read masks by each row's
     absolute position). Memory is O(capacity) however long the stream
-    runs."""
+    runs.
+
+    A model with layer_kinds: the buffers of :func:`cache_layout`, each
+    attention's own — a ``window`` kind's ring beside a ``full`` kind's
+    ``max_len`` rows (``ring=False``: linear, a prefill's mini cache)."""
     cap = _ring_capacity(cfg)
     rows = cap or max_len
     if cap and cfg.attn_window and cap >= 4 * cfg.attn_window:
@@ -205,9 +257,9 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
             f"attn_window={cfg.attn_window}: ring-cache attention reads "
             "every capacity row per token (O(capacity), not O(window)) — "
             "size the capacity near the window", stacklevel=2)
-    cache = {n: jnp.zeros((cfg.n_layers, batch, rows, _kv_heads(cfg) * w),
-                          dt)
-             for n, (w, dt) in _kv_spec(cfg).items()}
+    cache = {n: jnp.zeros((layers, batch, n_rows, width), dt)
+             for n, (layers, n_rows, width, dt)
+             in cache_layout(cfg, max_len, ring).items()}
     if cfg.experts is not None:
         # what the expert layers counted since the holding program began
         # (assignments landed on held experts, held experts touched):
@@ -217,10 +269,12 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
     return dict(cache, length=jnp.zeros((), jnp.int32))
 
 
+#: the ``window`` kinds' buffers: rows written modulo their row count
+_RING_BUFS = ("k_ring", "v_ring")
 #: cache keys that hold per-position buffers (and so follow every write/
 #: gather/tile path together); "length" and the expert layers' counters
 #: (``MOE_COUNTS``) are the only non-buffer keys
-_KV_BUFS = ("k", "v", "k_scale", "v_scale", "ckv")
+_KV_BUFS = ("k", "v", "k_scale", "v_scale", "ckv") + _RING_BUFS
 MOE_COUNTS = "moe_counts"
 
 
@@ -232,8 +286,17 @@ def _kv_state(cache: dict) -> dict:
 
 
 def cache_rows(cache: dict) -> int:
-    """Positions a cache (or mini cache, or template) holds per slot."""
-    return next(iter(_kv_bufs(cache).values())).shape[2]
+    """Positions a cache (or mini cache, or template) holds per slot:
+    its linear buffers' rows — a ``window`` kind's ring never limits a
+    request's length (only a model that is all rings counts those)."""
+    bufs = _kv_bufs(cache)
+    linear = [a for n, a in bufs.items() if n not in _RING_BUFS]
+    return (linear or list(bufs.values()))[0].shape[2]
+
+
+def _has_ring_bufs(cache: dict) -> bool:
+    """Whether a ``window`` kind's ring is among the cache's buffers."""
+    return any(n in cache for n in _RING_BUFS)
 
 
 def _kv_bufs(cache: dict) -> dict:
@@ -723,10 +786,43 @@ def _mlp(h, p, cfg):
 
 
 def _rope_tables(positions, cfg: T.TransformerConfig):
-    """(cos, sin) for a chunk's positions, once per chunk: over the head
-    dim, or over the rotary slice of a latent head."""
-    d = cfg.latent.rope_dim if cfg.kinded else cfg.head_dim
-    return T.rope_tables(positions, d, cfg.rope_base, cfg.rope_scaling)
+    """(cos, sin) for a chunk's positions, once per chunk, over the head
+    dim. A model with layer_kinds: {attention: (cos, sin)} — over the
+    rotary slice of a ``latent`` head, over the whole head of a
+    ``window`` kind, None for a ``full`` kind (no positional
+    rotation)."""
+    def tables(d):
+        return T.rope_tables(positions, d, cfg.rope_base, cfg.rope_scaling)
+    if not cfg.kinded:
+        return tables(cfg.head_dim)
+    return {a: None if a == "full" else tables(
+                cfg.latent.rope_dim if a == "latent" else cfg.head_dim)
+            for a in cfg.attention_layers()}
+
+
+def _norm(x, w, cfg: T.TransformerConfig):
+    """The model's norm at its epsilon: RMSNorm, or (a kinded block's
+    setting) the weight-only LayerNorm."""
+    if cfg.norm == "layer":
+        return layer_norm_reference(x, w, None, cfg.rms_eps)
+    return rms_norm_reference(x, w, cfg.rms_eps)
+
+
+def _head(params: dict, x, cfg: T.TransformerConfig):
+    """Normed x [B, S, D] or [B, D] → logits in
+    ``cfg.logits_storage_dtype`` (f32 accumulation): through the untied
+    ``lm_head``, or through the embedding where the head is tied, x
+    ``logit_scale``."""
+    io = "bsd,{}->bsv" if x.ndim == 3 else "bd,{}->bv"
+    if cfg.tie_embeddings:
+        logits = _weinsum(io.format("vd"), x, params["embed"],
+                          pet=jnp.float32)
+    else:
+        logits = _weinsum(io.format("dv"), x, params["lm_head"],
+                          pet=jnp.float32)
+    if cfg.logit_scale != 1.0:
+        logits = logits * cfg.logit_scale
+    return logits.astype(cfg.logits_storage_dtype)
 
 
 #: the routed experts' leaves, [layers, held, ...] each
@@ -740,7 +836,7 @@ def _layer_params(params: dict, cfg: T.TransformerConfig, li: int) -> dict:
         return jax.tree.map(lambda a: a[li], params["blocks"])
     kind, i = cfg.kind_index(li)
     group = params["blocks"][kind]
-    if kind != "moe":
+    if T.LAYER_KINDS[kind][1] != "moe":
         return jax.tree.map(lambda a: a[i], group)
     # the routed experts go to their kernel STACKED, with the layer's
     # index: a Mosaic call takes whole buffers, so a sliced layer would
@@ -751,7 +847,8 @@ def _layer_params(params: dict, cfg: T.TransformerConfig, li: int) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Latent attention + sparse experts: the layers of a model with layer_kinds
+# The layers of a model with layer_kinds: latent / window / full attention,
+# each over the buffers it owns; dense or sparse feed-forward
 # ---------------------------------------------------------------------------
 
 def _latent_scale(cfg: T.TransformerConfig) -> float:
@@ -889,10 +986,11 @@ def _latent_cached_attention(q_n, q_r, buf, li, q_start, p,
 
 
 def _sparse_mlp(h, p, cfg: T.TransformerConfig, live=None):
-    """Sigmoid-routed experts beside a shared one, on [B, S, D]: the
+    """Sigmoid-routed experts beside the shared ones, on [B, S, D]: the
     held experts' part of the routed sum (dropless,
     :func:`tony_tpu.parallel.moe.held_experts_ffn`) plus the whole shared
-    SwiGLU. ``live`` [B, S] bool: positions that hold a real token — a
+    experts (:func:`tony_tpu.parallel.moe.shared_experts_ffn`). ``live``
+    [B, S] bool: positions that hold a real token — a
     prompt's padding is not routed (its output is never read, and every
     padded position carries the same token, so on a seed whose token 0
     picks held experts a 32 x 512 prefill would land 16k rows on them).
@@ -909,55 +1007,125 @@ def _sparse_mlp(h, p, cfg: T.TransformerConfig, live=None):
         HeldExperts(e.first, e.n_held, e.total),
         live=None if live is None else live.reshape(b * s))
     with jax.named_scope("moe_shared"):
-        gate = _weinsum("bsd,df->bsf", h, p["shared_gate"])
-        up = _weinsum("bsd,df->bsf", h, p["shared_up"])
-        shared = _weinsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                          p["shared_down"], pet=jnp.float32)
+        shared = shared_experts_ffn(h, p["shared_gate"], p["shared_up"],
+                                    p["shared_down"], e.shared_mean,
+                                    matmul=_weinsum)
     out = (routed.reshape(b, s, d) + shared).astype(h.dtype)
     return out, jnp.stack([landed, touched])
 
 
-def _latent_mlp(x, p, cfg: T.TransformerConfig, bufs: dict, live=None):
-    """The feed-forward half of a layer with layer_kinds: the dense
-    SwiGLU, or the sparse experts, whose counts join ``bufs``."""
+def _kinded_ffn(x, h, a, p, cfg: T.TransformerConfig, bufs: dict,
+                live=None):
+    """The rest of a layer with layer_kinds after its attention output
+    ``a``: the dense SwiGLU, or the sparse experts, whose counts join
+    ``bufs``. Sequential block: the stream takes ``a``, the
+    feed-forward reads its own norm of that, ``x + a + ffn(norm(x +
+    a))``. ``parallel_block``: attention and feed-forward read the SAME
+    normed ``h`` and both add to the stream, ``x + a + ffn(h)``."""
+    x = x + a
     with jax.named_scope("mlp"):
-        h = rms_norm_reference(x, p["mlp_norm"], cfg.rms_eps)
+        if not cfg.parallel_block:
+            h = _norm(x, p["mlp_norm"], cfg)
         if "router" not in p:
             return x + _mlp(h, p, cfg), bufs
         out, counts = _sparse_mlp(h, p, cfg, live)
         return x + out, dict(bufs, **{MOE_COUNTS: bufs[MOE_COUNTS] + counts})
 
 
-def _latent_decode_block(x, p, bufs, li, pos, cfg, rope, window=None):
+def _gqa_qkv(h, p, rope, barrier: bool = False):
+    """q [B, S, H, hd], k and v [B, S, KV, hd] of a ``window`` or
+    ``full`` kind; q and k rotated where the kind has positions
+    (``rope`` None: it has none). ``barrier``: a decode step's v, as
+    :func:`_decode_block` keeps it from folding into the cache write."""
+    q = _weinsum("bsd,dhk->bshk", h, p["wq"])
+    k = _weinsum("bsd,dhk->bshk", h, p["wk"])
+    v = _weinsum("bsd,dhk->bshk", h, p["wv"])
+    if rope is not None:
+        q, k = T.apply_rope(q, *rope), T.apply_rope(k, *rope)
+    return q, k, (jax.lax.optimization_barrier(v) if barrier else v)
+
+
+#: the buffers a ``window`` / ``full`` kind's K and V go to
+_KIND_KV = {"window": ("k_ring", "v_ring"), "full": ("k", "v")}
+
+
+def _kinded_decode_block(x, p, bufs, li, pos, cfg, rope, window=None):
     """:func:`_decode_block` for a layer of a model with layer_kinds:
-    the chunk's compressed rows go through the SAME cache write paths,
-    the read is the absorbed form. Returns (x, bufs)."""
-    h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
-    with jax.named_scope("mla_attention"):
-        q_n, q_r, row = _latent_qkv(h, p, cfg, rope)
-        pos = jnp.asarray(pos)
+    the chunk's rows go through the SAME cache write paths into the
+    buffers the layer's attention owns, at its index among them.
+    ``latent``: the compressed row, read in the absorbed form;
+    ``window``: K and V at ``pos`` modulo the ring's rows, read as
+    :func:`_ring_cached_attention` (dense over the ring, masked by
+    offset); ``full``: K and V at ``pos``, read as
+    :func:`_cached_attention` (the live blocks). Returns (x, bufs)."""
+    attention, ai = cfg.attention_of(li)
+    h = _norm(x, p["attn_norm"], cfg)
+    pos = jnp.asarray(pos)
+    if attention == "latent":
+        with jax.named_scope("mla_attention"):
+            q_n, q_r, row = _latent_qkv(h, p, cfg, rope[attention])
+            with jax.named_scope("cache_write"):
+                bufs = dict(bufs, ckv=_write_kv_chunk(bufs["ckv"], row, ai,
+                                                      pos, window))
+            with jax.named_scope("cached_attention"):
+                o = _latent_cached_attention(q_n, q_r, bufs["ckv"], ai, pos,
+                                             p, cfg)
+            a = _weinsum("bshk,hkd->bsd", o, p["wo"])
+        return _kinded_ffn(x, h, a, p, cfg, bufs)
+    nk, nv = _KIND_KV[attention]
+    with jax.named_scope("attn_" + attention):
+        q, k, v = _gqa_qkv(h, p, rope[attention], barrier=True)
+        ring = attention == "window"
         with jax.named_scope("cache_write"):
-            bufs = dict(bufs, ckv=_write_kv_chunk(bufs["ckv"], row, li, pos,
-                                                  window))
+            # a ring takes the row at pos modulo its rows, by the per-row
+            # scatter; a linear buffer at pos, by the caller's write mode
+            at = pos % bufs[nk].shape[2] if ring else pos
+            bufs = dict(bufs, **{
+                n: _write_kv_chunk(bufs[n], t, ai, at,
+                                   None if ring else window)
+                for n, t in ((nk, k), (nv, v))})
         with jax.named_scope("cached_attention"):
-            o = _latent_cached_attention(q_n, q_r, bufs["ckv"], li, pos, p,
-                                         cfg)
-        x = x + _weinsum("bshk,hkd->bsd", o, p["wo"])
-    return _latent_mlp(x, p, cfg, bufs)
+            kv = {"k": bufs[nk], "v": bufs[nv]}
+            if ring:
+                q_pos = (jnp.broadcast_to(pos, (x.shape[0],))
+                         if pos.ndim == 0 else pos)
+                o = _ring_cached_attention(q, kv, ai, q_pos,
+                                           cfg.attn_window)
+            else:
+                o = _cached_attention(q, kv, ai, pos)
+        a = _weinsum("bshk,hkd->bsd", o, p["wo"])
+    return _kinded_ffn(x, h, a, p, cfg, bufs)
 
 
-def _latent_prompt_block(x, p, bufs, li, cfg, rope, s, live):
-    """One layer of :func:`_prompt_forward` for a model with layer_kinds:
-    expanded attention over the (padded) prompt, rows [0, s) written to
-    the latent cache; only the ``live`` positions are routed."""
-    h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
-    with jax.named_scope("mla_attention"):
-        q_n, q_r, row = _latent_qkv(h, p, cfg, rope)
-        o = _latent_prompt_attention(q_n, q_r, row, p, cfg)
-        x = x + _weinsum("bshk,hkd->bsd", o, p["wo"])
-    x, bufs = _latent_mlp(x, p, cfg, bufs, live)
-    return x, dict(bufs, ckv=_write_kv_chunk(
-        bufs["ckv"], row[:, :s], li, jnp.asarray(0, jnp.int32), None))
+def _kinded_prompt_block(x, p, bufs, li, cfg, rope, s, live):
+    """One layer of :func:`_prompt_forward` for a model with
+    layer_kinds: attention over the (padded) prompt — expanded latent
+    attention, or the flash kernel with the window on a ``window`` kind
+    and plain causal on a ``full`` kind — and rows [0, s) written
+    LINEARLY to the attention's buffers (a prefill's mini cache:
+    :func:`place_rows` lands a ``window`` kind's in its ring); only the
+    ``live`` positions are routed."""
+    attention, ai = cfg.attention_of(li)
+    h = _norm(x, p["attn_norm"], cfg)
+    if attention == "latent":
+        with jax.named_scope("mla_attention"):
+            q_n, q_r, row = _latent_qkv(h, p, cfg, rope[attention])
+            o = _latent_prompt_attention(q_n, q_r, row, p, cfg)
+            a = _weinsum("bshk,hkd->bsd", o, p["wo"])
+        writes = {"ckv": row}
+    else:
+        with jax.named_scope("attn_" + attention):
+            q, k, v = _gqa_qkv(h, p, rope[attention])
+            o = T._attention(q, k, v, None, window=(
+                cfg.attn_window if attention == "window" else None))
+            a = _weinsum("bshk,hkd->bsd", o, p["wo"])
+        writes = dict(zip(_KIND_KV[attention], (k, v)))
+    x, bufs = _kinded_ffn(x, h, a, p, cfg, bufs, live)
+    with jax.named_scope("cache_write"):
+        return x, dict(bufs, **{
+            n: _write_kv_chunk(bufs[n], t[:, :s], ai,
+                               jnp.asarray(0, jnp.int32), None)
+            for n, t in writes.items()})
 
 
 def _blocks_forward(params: dict, tokens: jax.Array, cache: dict, pos,
@@ -970,7 +1138,7 @@ def _blocks_forward(params: dict, tokens: jax.Array, cache: dict, pos,
     logits, so paying the lm_head vocab projection there is pure waste)."""
     x = params["embed"][tokens].astype(cfg.dtype)              # [B, K, D]
     b, n_q = tokens.shape
-    if _ring_capacity(cfg) and n_q > 1:
+    if (_ring_capacity(cfg) or _has_ring_bufs(cache)) and n_q > 1:
         raise ValueError(
             "rolling KV cache (kv_cache_capacity) supports single-token "
             "decode steps only — chunked verify (speculative decoding) "
@@ -982,7 +1150,7 @@ def _blocks_forward(params: dict, tokens: jax.Array, cache: dict, pos,
     # with the caches as xs/ys (see _decode_block: scan forces whole-cache
     # copies every step)
     bufs = _kv_state(cache)
-    block = _latent_decode_block if cfg.kinded else _decode_block
+    block = _kinded_decode_block if cfg.kinded else _decode_block
     for li in range(cfg.n_layers):
         x, bufs = block(x, _layer_params(params, cfg, li), bufs, li, pos,
                         cfg, rope, window)
@@ -1003,10 +1171,7 @@ def extend_step(params: dict, tokens: jax.Array, cache: dict, pos,
     :func:`_window_write`."""
     x, new_cache = _blocks_forward(params, tokens, cache, pos, cfg, window)
     with jax.named_scope("lm_head"):
-        x = rms_norm_reference(x, params["final_norm"], cfg.rms_eps)
-        logits = _weinsum("bsd,dv->bsv", x, params["lm_head"],
-                          pet=jnp.float32)
-        logits = logits.astype(cfg.logits_storage_dtype)
+        logits = _head(params, _norm(x, params["final_norm"], cfg), cfg)
     return logits, new_cache
 
 
@@ -1060,11 +1225,17 @@ def prefill(params: dict, tokens: jax.Array, cfg: T.TransformerConfig,
     behavior at low capacity factors."""
     b, s = tokens.shape
     cache = init_kv_cache(cfg, b, max_len)
+    if _has_ring_bufs(cache):
+        # a window kind's ring takes a prompt through the mini cache of
+        # the bucketed prefill, landed row by row (place_rows)
+        logits, mini = prefill_rows(params, tokens,
+                                    jnp.full((b,), s, jnp.int32), cfg)
+        cache = place_rows(dict(cache, length=jnp.zeros((b,), jnp.int32)),
+                           mini, jnp.arange(b), mini["length"])
+        return logits, dict(cache, length=jnp.asarray(s, jnp.int32))
     x, bufs = _prompt_forward(params, tokens, cfg, _kv_state(cache), s)
-    logits = _weinsum("bd,dv->bv", x[:, s - 1], params["lm_head"],
-                      pet=jnp.float32)
-    logits = logits.astype(cfg.logits_storage_dtype)
-    return logits, dict(bufs, length=jnp.asarray(s, jnp.int32))
+    return (_head(params, x[:, s - 1], cfg),
+            dict(bufs, length=jnp.asarray(s, jnp.int32)))
 
 
 def _prompt_forward(params, tokens, cfg, bufs, s, lengths=None):
@@ -1084,7 +1255,6 @@ def _prompt_forward(params, tokens, cfg, bufs, s, lengths=None):
     x = params["embed"][tokens].astype(cfg.dtype)
     positions = jnp.broadcast_to(jnp.arange(sp), (b, sp))
     rope = _rope_tables(positions, cfg)                 # once, not per layer
-    cos, sin = rope
     live = (positions < (s if lengths is None else lengths[:, None])
             if cfg.kinded else None)
 
@@ -1094,9 +1264,10 @@ def _prompt_forward(params, tokens, cfg, bufs, s, lengths=None):
     for li in range(cfg.n_layers):
         p = _layer_params(params, cfg, li)
         if cfg.kinded:
-            x, bufs = _latent_prompt_block(x, p, bufs, li, cfg, rope, s,
+            x, bufs = _kinded_prompt_block(x, p, bufs, li, cfg, rope, s,
                                            live)
             continue
+        cos, sin = rope
         h = rms_norm_reference(x, p["attn_norm"], cfg.rms_eps)
         q = _weinsum("bsd,dhk->bshk", h, p["wq"])
         k = _weinsum("bsd,dhk->bshk", h, p["wk"])
@@ -1124,7 +1295,7 @@ def _prompt_forward(params, tokens, cfg, bufs, s, lengths=None):
             for n, c in _kv_writes(bufs, k[:, :s], v[:, :s]).items():
                 bufs[n] = _write_kv_chunk(bufs[n], c, li,
                                           jnp.asarray(0, jnp.int32), None)
-    return rms_norm_reference(x, params["final_norm"], cfg.rms_eps), bufs
+    return _norm(x, params["final_norm"], cfg), bufs
 
 
 def prefill_rows(params: dict, tokens: jax.Array, lengths: jax.Array,
@@ -1152,14 +1323,34 @@ def prefill_rows(params: dict, tokens: jax.Array, lengths: jax.Array,
     real history), so ring configs keep the per-length admission path."""
     _check_no_ring(cfg, "bucketed prefill")
     k_rows, s = tokens.shape
-    cache = init_kv_cache(cfg, k_rows, s)
+    # a window kind's buffers are LINEAR here: the mini cache holds the
+    # padded prompt's rows by position, and place_rows lands each row's
+    # last ones in the ring by its own length
+    cache = init_kv_cache(cfg, k_rows, s, ring=False)
     x, bufs = _prompt_forward(params, tokens, cfg, _kv_state(cache), s,
                               lengths)
     xl = x[jnp.arange(k_rows), lengths - 1]                   # [K, D]
-    logits = _weinsum("bd,dv->bv", xl, params["lm_head"],
-                      pet=jnp.float32)
-    return (logits.astype(cfg.logits_storage_dtype),
+    return (_head(params, xl, cfg),
             dict(bufs, length=lengths.astype(jnp.int32)))
+
+
+def _ring_rows_of(mini_buf, lengths, c: int):
+    """What a ring of ``c`` rows holds of prompts prefilled LINEARLY:
+    ``mini_buf`` [L, K, S, F] by position (S > c), row k real up to
+    ``lengths[k]``. Ring row r takes the LAST position p < length with
+    p = r (mod c) — ``r + c * floor((length - 1 - r) / c)`` — so a
+    prompt's last ``min(length, c)`` rows land where decode's ``pos %
+    c`` writes would have put them, and the padding tail lands nowhere
+    (the reason a flat ring model admits at exact lengths). Ring rows no
+    position reaches (r >= length) take position 0's row: never read —
+    a query at q masks every ring row whose offset ``(q - r) mod c``
+    is beyond ``q``, and decode writes row r before a query reaches it.
+    Returns [L, K, c, F]."""
+    r = jnp.arange(c)[None, :]
+    src = r + c * ((lengths[:, None] - 1 - r) // c)             # [K, c]
+    src = jnp.clip(src, 0, mini_buf.shape[2] - 1)
+    return jax.vmap(lambda m, i: jnp.take(m, i, axis=1),
+                    in_axes=(1, 0), out_axes=1)(mini_buf, src)
 
 
 def place_rows(cache: dict, mini: dict, rows: jax.Array,
@@ -1171,11 +1362,24 @@ def place_rows(cache: dict, mini: dict, rows: jax.Array,
     ``lengths``. Out-of-range row indices are DROPPED (standard jit
     scatter semantics) — the batched admission path pads its row vector
     with distinct out-of-range sentinels, so a partial admission batch
-    writes exactly its real rows."""
+    writes exactly its real rows.
+
+    A ``window`` kind's ring (``_RING_BUFS``) shorter than the bucket
+    takes, per row, the last ``min(length, C)`` rows by position modulo
+    its row count (:func:`_ring_rows_of`), the whole slot in one
+    scatter; a bucket that fits the ring lands as any linear buffer
+    (no position has wrapped yet)."""
     s_b = cache_rows(mini)
-    placed = {n: cache[n].at[:, rows, :s_b].set(
-                  mini[n], mode="drop", unique_indices=True)
-              for n in _kv_bufs(mini)}
+    placed = {}
+    for n, m in _kv_bufs(mini).items():
+        c = cache[n].shape[2]
+        if n in _RING_BUFS and s_b > c:
+            placed[n] = cache[n].at[:, rows].set(
+                _ring_rows_of(m, lengths, c), mode="drop",
+                unique_indices=True)
+        else:
+            placed[n] = cache[n].at[:, rows, :s_b].set(
+                m, mode="drop", unique_indices=True)
     return dict(cache, **placed, length=cache["length"].at[rows].set(
         lengths.astype(jnp.int32), mode="drop", unique_indices=True))
 

@@ -110,8 +110,14 @@ _BLOCK_AXES = {
 #: experts (the grouped product reads plain arrays).
 _KINDED_AXES = {
     "wq_a": (1,), "wq_b": (1,), "wkv_a": (1,), "wo": (1, 2),
+    "wq": (1,), "wk": (1,), "wv": (1,),     # the window / full kinds'
     "shared_gate": (1,), "shared_up": (1,), "shared_down": (1,),
 }
+#: the same for SEVERAL shared experts, stacked [L, N, d, f] / [L, N, f,
+#: d]: gate and up contract d; down contracts the stack and f together
+#: (one scale an output channel — the product sums over both)
+_SHARED_STACK_AXES = {"shared_gate": (2,), "shared_up": (2,),
+                      "shared_down": (1, 2)}
 
 
 def quantize_weights_int8(params: dict) -> dict:
@@ -125,10 +131,14 @@ def quantize_weights_int8(params: dict) -> dict:
             axes = dict(_KINDED_AXES)
             if "router" not in group:       # the dense SwiGLU
                 axes.update(w_gate=(1,), w_up=(1,), w_down=(1,))
+            elif group["shared_gate"].ndim == 4:
+                axes.update(_SHARED_STACK_AXES)
             blocks[kind] = {n: _quantize(w, axes[n]) if n in axes else w
                             for n, w in group.items()}
-        return dict(params, blocks=blocks,
-                    lm_head=_quantize(params["lm_head"], (0,)))
+        out = dict(params, blocks=blocks)
+        if "lm_head" in params:     # a tied head is the embedding: kept
+            out["lm_head"] = _quantize(params["lm_head"], (0,))
+        return out
     blocks = dict(params["blocks"])
     moe = "router" in blocks
     for name, axes in _BLOCK_AXES.items():

@@ -157,6 +157,7 @@ import numpy as np
 
 from tony_tpu.models import transformer as T
 from tony_tpu.models.decode import (MOE_COUNTS, _check_draft_vocab,
+                                    cache_bytes_by_kind, ring_rows,
                                     _check_no_ring, _filter_logits, _kv_bufs,
                                     _propose_and_verify,
                                     _propose_and_verify_sampled,
@@ -745,7 +746,13 @@ class ContinuousBatcher:
     :func:`admit_width`-wide :func:`admit_rows` dispatches. A rolling
     (ring) cache, which the batcher reads off ``cfg.kv_cache_capacity``,
     takes one exact-length :func:`admit_row_ring` dispatch a request —
-    padded prompts cannot take wrapped writes.
+    padded prompts cannot take wrapped writes. A model with layer_kinds
+    whose WINDOW kinds hold a ring beside full kinds' linear rows is no
+    such cache: its prefill writes a linear mini cache that
+    :func:`~tony_tpu.models.decode.place_rows` lands in the rings by
+    each row's length, so it is admitted by :func:`admit_rows` like
+    every linear-cache model, and a request may not outgrow the FULL
+    kind's ``max_len`` rows (the window kind never limits a length).
     """
 
     #: first per-request stream position consumed by step_rows sampling
@@ -848,6 +855,24 @@ class ContinuousBatcher:
         #: other); a prompt's padding is not routed.
         self.moe_assignments = {"decode": 0, "admit": 0}
         self.moe_touches = {"decode": 0, "admit": 0}
+        #: bytes of the cache by the kind of state that owns them
+        #: (decode.cache_bytes_by_kind): a window kind's ring beside a
+        #: full kind's max_len rows
+        self.cache_bytes = cache_bytes_by_kind(cfg, batch, max_len)
+        #: (rows a slot's ring holds, layers that write one): the
+        #: whole-model ring of the flat decoder, or the window kinds' of
+        #: a model with layer_kinds; (0, 0) without a ring
+        self._ring_shape = (0, 0)
+        if self._ring:
+            self._ring_shape = (cfg.kv_cache_capacity, cfg.n_layers)
+        elif cfg.kinded and cfg.attn_window:
+            self._ring_shape = (ring_rows(cfg),
+                                cfg.attention_layers()["window"])
+        #: ring rows a finished request's positions overwrote (a layer a
+        #: position at or past the ring's rows): where this is 0 the
+        #: window never bound and a linear buffer of the ring's size
+        #: would have served
+        self.ring_rows_overwritten = 0
         self._device_stats: collections.deque = collections.deque()
         self.phase_times = PhaseTimes(ENGINE_PHASES)
         # seams usable standalone (no serve() call required); serve()
@@ -1315,6 +1340,18 @@ class ContinuousBatcher:
 
     def _retire(self, mask) -> None:
         self.cache = retire_rows(self.cache, jnp.asarray(mask))
+
+    def count_finished(self, prompt_len: int, emitted: int) -> int:
+        """Fold a finished request into the ring's accounting: it wrote
+        rows [0, prompt + emitted - 1) (its last token is never fed
+        back), and every one at or past the ring's rows overwrote an
+        older row in each layer that holds a ring. Returns the rows it
+        added (0 without a ring)."""
+        rows, layers = self._ring_shape
+        over = layers * max(0, prompt_len + emitted - 1 - rows) if rows \
+            else 0
+        self.ring_rows_overwritten += over
+        return over
 
     def _validate_request(self, prompt, max_new: int) -> None:
         """Reject a request the batcher could not serve: empty prompt,
@@ -1843,6 +1880,18 @@ class ServeEngine:
                                    "weights a program had to read"))
         } if batcher.cfg.experts is not None else {}
         self._moe_seen = {k: 0 for k in self._moe_c}
+        for kind, n in batcher.cache_bytes.items():
+            reg.gauge("tony_cache_bytes", kind=kind,
+                      help="bytes of the KV cache's position buffers, by "
+                           "the kind of state that owns them (a window "
+                           "kind's ring, a full kind's max_len rows, a "
+                           "latent row, or the dense decoder's linear / "
+                           "ring cache)").set(n)
+        self._ring_over_c = reg.counter(
+            "tony_ring_rows_overwritten_total",
+            help="ring rows finished requests overwrote: a layer a "
+                 "position at or past the ring's rows (0 = the window "
+                 "never bound)")
         self._qdepth_g.set(0)
         for g in self._qdepth_by_cls.values():
             g.set(0)
@@ -2069,6 +2118,10 @@ class ServeEngine:
                 # (zeros for a model without experts)
                 "moe_assignments": dict(self.b.moe_assignments),
                 "moe_expert_touches": dict(self.b.moe_touches),
+                # the cache by the kind of state that owns it, and what
+                # the rings overwrote (0 without a ring)
+                "cache_bytes": dict(self.b.cache_bytes),
+                "ring_rows_overwritten": self.b.ring_rows_overwritten,
                 "steps_executed": self.b.steps_executed,
             }
 
@@ -2395,6 +2448,14 @@ class ServeEngine:
             self._tokens_c.inc(appended)
         if retired:
             self._retired_c.inc(len(retired))
+            if self.b._ring_shape[0]:
+                # a request's prompt is its tokens, or a shipped prefill
+                self._ring_over_c.inc(sum(
+                    self.b.count_finished(
+                        req.prompt.length if isinstance(req.prompt,
+                                                        KVPackage)
+                        else len(req.prompt), req.emitted)
+                    for req in retired))
         if not deltas:
             return
         # what the transport does with the chunk, ON this thread: the

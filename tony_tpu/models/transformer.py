@@ -111,30 +111,48 @@ class LatentAttention:
 
 @dataclasses.dataclass(frozen=True)
 class SparseExperts:
-    """Sigmoid-routed SwiGLU experts beside a shared one: every token
+    """Sigmoid-routed SwiGLU experts beside shared ones: every token
     scores all ``total`` experts, picks ``top_k`` by score + selection
     bias, and weighs them by score normalised over the pick x ``scale``.
     THIS program holds experts ``[first, first + held)`` — one rank's
     share of an expert-parallel layer — and computes their part
     (:func:`tony_tpu.parallel.moe.held_experts_ffn`); ``held == total``
     is the whole layer. ``d_expert``: width of one routed expert and of
-    the shared one."""
+    each shared one. ``n_shared`` shared experts run whole for every
+    token, their outputs summed, or averaged where ``shared_mean``
+    (:func:`tony_tpu.parallel.moe.shared_experts_ffn`); one is a [d, f]
+    leaf, several a [n_shared, d, f] stack."""
     total: int
     top_k: int
     d_expert: int
     scale: float = 1.0
     first: int = 0
     held: int | None = None
+    n_shared: int = 1
+    shared_mean: bool = False
 
     @property
     def n_held(self) -> int:
         return self.total if self.held is None else self.held
 
 
-#: layer kinds of a model with a ``layer_kinds`` list: both attend
-#: through the latent cache; ``dense`` has a SwiGLU of ``d_ff``,
-#: ``moe`` the sparse experts
-LAYER_KINDS = ("dense", "moe")
+#: layer kinds of a model with a ``layer_kinds`` list: name -> (the
+#: attention it runs, its feed-forward). Attention: ``latent`` (one
+#: compressed row a token, :class:`LatentAttention`); ``window`` (GQA
+#: K/V of ``head_dim``, RoPE over the whole head, query i sees keys j
+#: with ``0 <= i - j < attn_window``: its state is a RING of about
+#: ``attn_window`` rows a slot); ``full`` (the same K/V, plain causal
+#: over the whole context and NO positional rotation — the interleaved
+#: local/global convention, where the window layers carry the
+#: positions: its state is ``max_len`` rows a slot). Feed-forward:
+#: ``dense`` (a SwiGLU of ``d_ff``) or ``moe`` (:class:`SparseExperts`).
+#: Each attention owns the cache buffers it writes, with its OWN row
+#: count (models/decode.py, ``cache_layout``).
+LAYER_KINDS = {
+    "dense": ("latent", "dense"), "moe": ("latent", "moe"),
+    "window_dense": ("window", "dense"), "window_moe": ("window", "moe"),
+    "full_dense": ("full", "dense"), "full_moe": ("full", "moe"),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,6 +165,10 @@ class TransformerConfig:
     # MHA). Shrinks K/V projections and — the real win — the decode cache
     # by n_heads/n_kv_heads; query heads attend their group's shared K/V.
     n_kv_heads: int | None = None
+    # Width of one attention head. 0 = d_model // n_heads (resolved in
+    # __post_init__); a model whose heads are wider than that (128 heads
+    # of 128 over a hidden size of 4,096: q is 16,384 wide) sets it.
+    head_dim: int = 0
     d_ff: int = 2048
     max_seq: int = 2048
     dtype: Any = jnp.bfloat16
@@ -225,14 +247,27 @@ class TransformerConfig:
     rope_scaling: RopeYarn | None = None
     # A model with MORE THAN ONE KIND of layer: the kind of each layer in
     # order (see LAYER_KINDS), parameters stacked per kind under
-    # params["blocks"][kind], and each kind owning the cache buffers it
-    # writes. None = the dense decoder: one kind, today's layout. Such a
-    # model attends through ``latent`` and routes through ``experts``;
-    # it is SERVED (models/decode.py, models/serve.py) — the train step
-    # and the serving features listed in ``unsupported`` refuse it.
+    # params["blocks"][kind], and each kind's attention owning the cache
+    # buffers it writes, with its own row count. None = the dense
+    # decoder: one kind, today's layout. Its ``latent`` kinds attend
+    # through ``latent``, its ``window`` kinds inside ``attn_window``,
+    # its ``moe`` kinds route through ``experts``; it is SERVED
+    # (models/decode.py, models/serve.py) — the train step and the
+    # serving features that :meth:`refuse` names refuse it.
     layer_kinds: tuple[str, ...] | None = None
     latent: LatentAttention | None = None
     experts: SparseExperts | None = None
+    # Settings of the kinded block (a model with layer_kinds). "rms", or
+    # "layer": a weight-only LayerNorm (mean subtracted, no bias), both
+    # at ``rms_eps``.
+    norm: str = "rms"
+    # One norm a layer; attention and feed-forward both read it and both
+    # add to the stream: x + attention(h) + ffn(h), h = norm(x).
+    parallel_block: bool = False
+    # The head is the embedding, transposed (no ``lm_head`` leaf);
+    # logits x ``logit_scale``.
+    tie_embeddings: bool = False
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         # fail where the config was written, not at first trace
@@ -266,41 +301,72 @@ class TransformerConfig:
                     f"kv_cache_capacity ({self.kv_cache_capacity}) must "
                     f"be >= attn_window ({self.attn_window}): a decode "
                     f"step reads its window's rows from the ring")
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        if self.head_dim <= 0:
+            raise ValueError(f"head_dim must be positive, got "
+                             f"{self.head_dim}")
         if self.layer_kinds is not None:
             if len(self.layer_kinds) != self.n_layers or any(
                     k not in LAYER_KINDS for k in self.layer_kinds):
                 raise ValueError(
-                    f"layer_kinds must name one of {LAYER_KINDS} for each "
-                    f"of the {self.n_layers} layers, got {self.layer_kinds}")
-            if self.latent is None or ("moe" in self.layer_kinds
-                                       and self.experts is None):
+                    f"layer_kinds must name one of {tuple(LAYER_KINDS)} "
+                    f"for each of the {self.n_layers} layers, got "
+                    f"{self.layer_kinds}")
+            attns = set(self.attentions)
+            ffns = {LAYER_KINDS[k][1] for k in self.layer_kinds}
+            if ("latent" in attns and self.latent is None) or (
+                    "moe" in ffns and self.experts is None):
                 raise ValueError(
                     "a model with layer_kinds attends through `latent` "
-                    "(LatentAttention) and its 'moe' layers route through "
-                    "`experts` (SparseExperts): set both")
-            if self.latent.rope_dim % 2:
+                    "(LatentAttention) in its latent kinds and its 'moe' "
+                    "kinds route through `experts` (SparseExperts): set "
+                    "both where both are listed")
+            if "window" in attns and not self.attn_window:
+                raise ValueError("a 'window' kind attends inside "
+                                 "`attn_window`: set it")
+            for what, on, need in (
+                    ("latent", self.latent is not None, "latent" in attns),
+                    ("experts", self.experts is not None, "moe" in ffns),
+                    ("attn_window", self.attn_window, "window" in attns)):
+                if on and not need:
+                    raise ValueError(
+                        f"`{what}` is set, and no kind of layer_kinds "
+                        f"{sorted(set(self.layer_kinds))} uses it")
+            if self.latent is not None and self.latent.rope_dim % 2:
                 raise ValueError("latent.rope_dim must be even")
+            if attns - {"latent"} and self.head_dim % 2:
+                raise ValueError("head_dim must be even (rotary halves)")
             e = self.experts
             if e is not None and not (0 <= e.first and 0 < e.n_held
                                       and e.first + e.n_held <= e.total
-                                      and 0 < e.top_k <= e.total):
+                                      and 0 < e.top_k <= e.total
+                                      and 0 < e.n_shared):
                 raise ValueError(
                     f"experts [{e.first}, {e.first + e.n_held}) must lie "
                     f"inside the {e.total} the router scores, top_k "
-                    f"{e.top_k} among them")
+                    f"{e.top_k} among them, beside {e.n_shared} >= 1 "
+                    f"shared")
+            if self.norm not in ("rms", "layer"):
+                raise ValueError(f"unknown norm {self.norm!r}; expected "
+                                 f"'rms' or 'layer'")
             for what, on in (("kv_cache_dtype='int8'", self.kv_quant),
-                             ("kv_cache_capacity (ring cache)",
-                              self.kv_cache_capacity),
-                             ("attn_window", self.attn_window),
+                             ("kv_cache_capacity (the whole-model ring "
+                              "cache)", self.kv_cache_capacity),
                              ("num_experts (the gshard block)",
                               self.num_experts)):
                 if on:
                     raise ValueError(
-                        f"{what} is not supported with layer_kinds: the "
-                        f"latent cache is one {self.latent.row}-wide row "
-                        f"a token in the model's dtype, full causal")
-        elif self.latent is not None or self.experts is not None:
-            raise ValueError("`latent` / `experts` describe the layers of "
+                        f"{what} is not supported with layer_kinds: each "
+                        f"kind's attention owns its buffers in the "
+                        f"model's dtype — a latent row, a ring of "
+                        f"attn_window rows, or max_len rows a slot")
+        elif (self.latent is not None or self.experts is not None
+              or self.norm != "rms" or self.parallel_block
+              or self.tie_embeddings or self.logit_scale != 1.0):
+            raise ValueError("`latent`, `experts`, `norm`, `parallel_block`"
+                             ", `tie_embeddings` and `logit_scale` describe "
                              "a model with `layer_kinds`: set it")
 
     @property
@@ -313,6 +379,24 @@ class TransformerConfig:
         kind = self.layer_kinds[li]
         return kind, self.layer_kinds[:li].count(kind)
 
+    @property
+    def attentions(self) -> tuple[str, ...]:
+        """The attention of each layer, in order: ``latent`` /
+        ``window`` / ``full`` (see LAYER_KINDS)."""
+        return tuple(LAYER_KINDS[k][0] for k in self.layer_kinds)
+
+    def attention_of(self, li: int) -> tuple[str, int]:
+        """(attention of layer ``li``, its index among the layers that
+        attend so: the layer's index in the cache buffers that attention
+        owns)."""
+        attns = self.attentions
+        return attns[li], attns[:li].count(attns[li])
+
+    def attention_layers(self) -> dict[str, int]:
+        """attention -> how many layers attend so, in first-use order."""
+        attns = self.attentions
+        return {a: attns.count(a) for a in dict.fromkeys(attns)}
+
     def refuse(self, what: str) -> None:
         """Raise where ``what`` (a serving or training feature) cannot
         take a model with ``layer_kinds`` yet — at construction, with the
@@ -320,13 +404,17 @@ class TransformerConfig:
         if self.kinded:
             raise NotImplementedError(
                 f"{what} is not supported for a model with layer_kinds "
-                f"(latent attention + sparse experts) yet: it is written "
-                f"against K and V rows of one head width and one stacked "
-                f"block group (ROADMAP.md, Reach A)")
+                f"(kinds {sorted(set(self.layer_kinds))}) yet: it is "
+                f"written against K and V rows of one head width in one "
+                f"linear buffer of one row count, and one stacked block "
+                f"group (ROADMAP.md, Reach A)")
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def scaled(self, **overrides) -> "TransformerConfig":
+        if ("head_dim" not in overrides
+                and overrides.keys() & {"d_model", "n_heads"}
+                and self.head_dim == self.d_model // self.n_heads):
+            overrides["head_dim"] = 0       # derived: derive it again
+        return dataclasses.replace(self, **overrides)
 
     @property
     def kv_heads(self) -> int:
@@ -343,8 +431,6 @@ class TransformerConfig:
             return self.logits_dtype
         return jnp.bfloat16 if self.dtype == jnp.bfloat16 else jnp.float32
 
-    def scaled(self, **overrides) -> "TransformerConfig":
-        return dataclasses.replace(self, **overrides)
 
 
 # Preset sizes (BASELINE.json progression: ... → BERT-base scale → beyond)
@@ -373,12 +459,14 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
                 * (fan_in ** -0.5)).astype(dt)
 
     if cfg.kinded:
-        return {
+        params = {
             "embed": dense(k_emb, (cfg.vocab_size, d), d),
             "blocks": _init_kinded_blocks(k_blocks, cfg, dense),
             "final_norm": jnp.ones((d,), dt),
-            "lm_head": dense(k_out, (d, cfg.vocab_size), d),
         }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = dense(k_out, (d, cfg.vocab_size), d)
+        return params
 
     ks = jax.random.split(k_blocks, 8)
     kv = cfg.kv_heads
@@ -419,27 +507,46 @@ def kinded_block_shapes(cfg: TransformerConfig, kind: str) -> dict:
     bias, logical axes) of a ``kind`` layer of a model with
     ``layer_kinds``. The one statement of that layout: ``init_params``
     and ``logical_axes`` stack it per kind."""
-    d, h, la = cfg.d_model, cfg.n_heads, cfg.latent
-    leaves = {
-        "attn_norm": ((d,), None, ("norm",)),
-        "wq_a": ((d, la.q_rank), d, ("embed", None)),
-        "q_norm": ((la.q_rank,), None, ("norm",)),
-        "wq_b": ((la.q_rank, h, la.qk_dim), la.q_rank,
-                 (None, "heads", "kv")),
-        "wkv_a": ((d, la.row), d, ("embed", None)),
-        "kv_norm": ((la.kv_rank,), None, ("norm",)),
-        "wkv_b": ((la.kv_rank, h, la.nope_dim + la.v_dim), la.kv_rank,
-                  (None, "heads", "kv")),
-        "wo": ((h, la.v_dim, d), h * la.v_dim, ("heads", "kv", "embed")),
-        "mlp_norm": ((d,), None, ("norm",)),
-    }
-    if kind == "dense":
+    d, h = cfg.d_model, cfg.n_heads
+    attention, ffn = LAYER_KINDS[kind]
+    leaves = {"attn_norm": ((d,), None, ("norm",))}
+    if attention == "latent":
+        la = cfg.latent
+        leaves.update({
+            "wq_a": ((d, la.q_rank), d, ("embed", None)),
+            "q_norm": ((la.q_rank,), None, ("norm",)),
+            "wq_b": ((la.q_rank, h, la.qk_dim), la.q_rank,
+                     (None, "heads", "kv")),
+            "wkv_a": ((d, la.row), d, ("embed", None)),
+            "kv_norm": ((la.kv_rank,), None, ("norm",)),
+            "wkv_b": ((la.kv_rank, h, la.nope_dim + la.v_dim), la.kv_rank,
+                      (None, "heads", "kv")),
+            "wo": ((h, la.v_dim, d), h * la.v_dim,
+                   ("heads", "kv", "embed")),
+        })
+    else:
+        # K/V heads replicate under tp when there are fewer than query
+        # heads, as the dense decoder's (logical_axes)
+        hd, kv = cfg.head_dim, cfg.kv_heads
+        kv_axis = "heads" if kv == h else None
+        leaves.update({
+            "wq": ((d, h, hd), d, ("embed", "heads", "kv")),
+            "wk": ((d, kv, hd), d, ("embed", kv_axis, "kv")),
+            "wv": ((d, kv, hd), d, ("embed", kv_axis, "kv")),
+            "wo": ((h, hd, d), h * hd, ("heads", "kv", "embed")),
+        })
+    if not cfg.parallel_block:
+        leaves["mlp_norm"] = ((d,), None, ("norm",))
+    if ffn == "dense":
         f = cfg.d_ff
         leaves.update({"w_gate": ((d, f), d, ("embed", "mlp")),
                        "w_up": ((d, f), d, ("embed", "mlp")),
                        "w_down": ((f, d), f, ("mlp", "embed"))})
         return leaves
     e, f = cfg.experts, cfg.experts.d_expert
+    # one shared expert is a [d, f] leaf, several a [n_shared, d, f] stack
+    ns = () if e.n_shared == 1 else (e.n_shared,)
+    nax = () if e.n_shared == 1 else (None,)
     leaves.update({
         # router and selection bias stay float32: a pick compares scores
         "router": ((d, e.total), d, ("embed", None)),
@@ -447,9 +554,9 @@ def kinded_block_shapes(cfg: TransformerConfig, kind: str) -> dict:
         "w_gate": ((e.n_held, d, f), d, ("expert", "embed", "mlp")),
         "w_up": ((e.n_held, d, f), d, ("expert", "embed", "mlp")),
         "w_down": ((e.n_held, f, d), f, ("expert", "mlp", "embed")),
-        "shared_gate": ((d, f), d, ("embed", "mlp")),
-        "shared_up": ((d, f), d, ("embed", "mlp")),
-        "shared_down": ((f, d), f, ("mlp", "embed")),
+        "shared_gate": (ns + (d, f), d, nax + ("embed", "mlp")),
+        "shared_up": (ns + (d, f), d, nax + ("embed", "mlp")),
+        "shared_down": (ns + (f, d), f, nax + ("mlp", "embed")),
     })
     return leaves
 
@@ -480,14 +587,16 @@ def logical_axes(cfg: TransformerConfig) -> dict:
     """Logical-axis pytree matching init_params (leading axis = "stage" so
     the same layout drives FSDP sharding and pipeline stage assignment)."""
     if cfg.kinded:
-        return {
+        axes = {
             "embed": ("vocab", "embed"),
             "blocks": {kind: {leaf: ("stage",) + axes for leaf, (_, _, axes)
                               in kinded_block_shapes(cfg, kind).items()}
                        for kind in dict.fromkeys(cfg.layer_kinds)},
             "final_norm": ("norm",),
-            "lm_head": ("embed", "vocab"),
         }
+        if not cfg.tie_embeddings:
+            axes["lm_head"] = ("embed", "vocab")
+        return axes
     # Under GQA the K/V head count can be smaller than any tp axis, so
     # those params replicate instead of claiming the "heads" rule (they are
     # n_heads/n_kv_heads× smaller than MHA's to begin with; Llama-style TP

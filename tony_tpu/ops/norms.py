@@ -98,13 +98,17 @@ rms_norm.defvjp(_rms_fwd, _rms_bwd)
 # LayerNorm
 # ---------------------------------------------------------------------------
 
-def layer_norm_reference(x, w, b, eps: float = 1e-6):
+def layer_norm_reference(x, w, b=None, eps: float = 1e-6):
+    """LayerNorm over the last dim, statistics in float32. ``b=None``:
+    the weight-only form (mean subtracted, no bias)."""
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     xc = xf - mu
     inv = jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
-    return (xc * inv * w.astype(jnp.float32)
-            + b.astype(jnp.float32)).astype(x.dtype)
+    y = xc * inv * w.astype(jnp.float32)
+    if b is not None:
+        y = y + b.astype(jnp.float32)
+    return y.astype(x.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -175,6 +175,27 @@ def sigmoid_route(h: jax.Array, router_w: jax.Array, bias: jax.Array,
     return picks.astype(jnp.int32), w
 
 
+def shared_experts_ffn(h: jax.Array, gate, up, down, mean: bool = False,
+                       matmul=None) -> jax.Array:
+    """The shared SwiGLU experts, whole, for every token of h [B, S, D]:
+    ONE expert as [D, F] / [F, D] leaves, SEVERAL as a stack [N, D, F] /
+    [N, F, D], run as one product N x F wide and summed over the stack —
+    divided by N where ``mean`` (averaged shared experts). ``matmul``:
+    the caller's einsum (a quantized leaf's dispatch); plain
+    ``jnp.einsum`` otherwise. Returns float32 [B, S, D]."""
+    mm = matmul or (lambda spec, x, w, pet=None: jnp.einsum(
+        spec, x, w, preferred_element_type=pet))
+    n = getattr(gate, "q", gate).shape[:-2]
+    if not n:
+        inner = jax.nn.silu(mm("bsd,df->bsf", h, gate)) \
+            * mm("bsd,df->bsf", h, up)
+        return mm("bsf,fd->bsd", inner, down, jnp.float32)
+    inner = jax.nn.silu(mm("bsd,ndf->bsnf", h, gate)) \
+        * mm("bsd,ndf->bsnf", h, up)
+    out = mm("bsnf,nfd->bsd", inner, down, jnp.float32)
+    return out / n[0] if mean else out
+
+
 #: rows of the sorted layout one trip of the expert loop takes: bounds
 #: the gathered activations ([rows, D]) whatever the prefill's size
 _EXPERT_CHUNK_ROWS = 2048
