@@ -319,9 +319,18 @@ def test_step_rows_never_copies_the_cache(chip, name):
     buf = cache["k"]
     dims = ",".join(str(d) for d in buf.shape)
     assert not _cache_sized_copies(text, buf)
-    # entry arguments, the while's carry, the results: one layout
+    # the cached read is the kernel over each slot's own live blocks
+    # (PR 36), on the stacked buffer as stored: one launch a layer
+    assert len(set(re.findall(r"%(tony_cached_attn[.\d]*) = ", text))) == 4
+    # entry arguments, the while's carry, the results: one layout. A
+    # buffer's layout names its tiling (``{3,2,1,0:T(8,128)(2,1)}``); the
+    # launch's ``operand_layout_constraints`` name a bare order of
+    # dimensions, which has to be the buffers' own
     layouts = set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})", text))
-    assert len(layouts) == 1, layouts
+    tiled = {lay for lay in layouts if ":" in lay}
+    assert len(tiled) == 1, layouts
+    order = next(iter(tiled)).split(":")[0]
+    assert layouts - tiled <= {order + "}"}, layouts
     carried = [ln for ln in text.splitlines()
                if " while(" in ln and f"bf16[{dims}]" in ln]
     assert carried, "no while carries the cache"
@@ -333,6 +342,54 @@ def test_step_rows_never_copies_the_cache(chip, name):
     sliced = re.findall(r"copy-start[.\d]* = \(bf16\[1," + str(d_model)
                         + r",\d+,\d+\]", text)
     assert not sliced, sliced
+
+
+@pytest.mark.parametrize("name", ["phi3mini-8slots", "mistral7b-6slots"])
+def test_decode_step_on_a_tp_mesh_reads_its_own_heads(chip, name):
+    """Tensor-parallel serving (``docs/serving.md``): ``decode_step``
+    under ``jax.set_mesh`` on the 2x2 ``("dp", "tp")`` mesh, weights cut
+    by the rules, the cache by slots and K/V heads. The cached read is a
+    Mosaic call, which the partitioner cannot split ("Mosaic kernels
+    cannot be automatically partitioned"): it runs a device inside
+    ``shard_cached_attention``'s island, on that device's slots and its
+    heads' columns of the stored rows — so the program holds the launch,
+    gathers no cache and copies none. Heads of 96 (16 a device: whole
+    rows of 1,536 lanes) and of 128 (GQA, 4 K/V heads a device)."""
+    import re
+
+    from tony_tpu.models import decode as D
+    from tony_tpu.models import transformer as T
+    from tony_tpu.parallel.sharding import param_shardings
+    d_model, heads, kv, d_ff, vocab, slots, rows = STEP_ROWS_CASES[name]
+    slots -= slots % 2
+    cfg = T.TransformerConfig(
+        vocab_size=vocab, d_model=d_model, n_layers=2, n_heads=heads,
+        n_kv_heads=kv, d_ff=d_ff, max_seq=rows, dtype=jnp.bfloat16)
+    params = chip.place(
+        jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg)),
+        param_shardings(T.logical_axes(cfg), chip.mesh))
+    width = kv * cfg.head_dim
+    buf = chip.shape((2, slots, rows, width),
+                     spec=P(None, "dp", None, "tp"))
+    per_slot = chip.shape((slots,), jnp.int32, spec=P("dp"))
+    cache = {"k": buf, "v": buf, "length": per_slot}
+    with jax.set_mesh(chip.mesh):
+        compiled = jax.jit(functools.partial(D.decode_step, cfg=cfg),
+                           donate_argnums=2).lower(
+            params, per_slot, cache, per_slot).compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%(tony_cached_attn[.\d]*) = ", text))) == 2
+    # a device's share of the cache, as the launch's operand and as the
+    # program's result: [L, slots / dp, rows, KV·hd / tp]
+    local = f"bf16[2,{slots // 2},{rows},{width // 2}]"
+    assert local in text
+    assert f"bf16[2,{slots},{rows}" not in text         # never whole
+    gathered = [ln for ln in text.splitlines()
+                if "all-gather" in ln and f",{rows}," in ln]
+    assert not gathered, gathered
+    assert not _cache_sized_copies(
+        text, jax.ShapeDtypeStruct((2, slots // 2, rows, width // 2),
+                                   jnp.bfloat16))
 
 
 def test_admit_rows_at_token_budget_widths(chip):
@@ -523,6 +580,10 @@ def test_mixed_step_rows_holds_ring_and_linear_cache_in_place(chip):
     assert text.startswith("HloModule jit_step_rows")
     assert not _copies_of_any(text, cache)
     assert text.count("tony_moe_gmm") >= 12       # 4 layers x gate/up/down
+    # each layer's cached read is the kernel over its slots' own live
+    # blocks (PR 36): three over the rings, one over the linear buffer
+    import re
+    assert len(set(re.findall(r"%(tony_cached_attn[.\d]*) = ", text))) == 4
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 1 << 30
     assert memory.peak_memory_in_bytes < _HBM

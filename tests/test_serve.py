@@ -1082,3 +1082,146 @@ def test_every_bench_arm_is_a_test_fixture():
             with open(os.path.join(here, name), encoding="utf-8") as f:
                 called |= set(re.findall(r"bench\.(_\w+_arm)\(", f.read()))
     assert arms == called
+
+
+# ---------------------------------------------------------------------------
+# Rows the decode chunks' cached reads visited against rows live (PR 36)
+# ---------------------------------------------------------------------------
+
+#: name -> (cfg changes, max_len, prompt lengths on 2 slots, the rows one
+#: step reads a slot at a position given the chunk's longest, the rows
+#: live at a position). Budgets of 8 at chunk 4: both slots hold their
+#: request from the first chunk to the second and last, so every read is
+#: a request's and the totals follow from the lengths alone
+ROWS_CASES = {
+    # under _BLOCKWISE_MIN_LEN rows: the dense einsum reads the buffer
+    "dense": ({}, 32, (5, 11), lambda pos, longest: 32,
+              lambda pos: pos + 1),
+    # the walk off the chip: every slot to the LONGEST row's last block
+    "walk": ({}, 600, (5, 300),
+             lambda pos, longest: (longest + 256) // 256 * 256,
+             lambda pos: pos + 1),
+    # a ring off the chip is read whole; live is what the window admits
+    "ring": ({"attn_window": 8, "kv_cache_capacity": 8}, 32, (5, 11),
+             lambda pos, longest: 8, lambda pos: min(pos + 1, 8)),
+    # the chip's arm (``on_chip_arm``): each slot its OWN blocks, of 128
+    "kernel": ({}, 700, (5, 300), lambda pos, longest: pos // 128 * 128 + 128,
+               lambda pos: pos + 1),
+    "kernel-ring": ({"attn_window": 600, "kv_cache_capacity": 600}, 900,
+                    (5, 300), lambda pos, longest: pos // 128 * 128 + 128,
+                    lambda pos: pos + 1),
+}
+
+
+@pytest.fixture
+def on_chip_arm(monkeypatch):
+    """``decode._read_arm`` answers as on the chip — the kernel, at the
+    128 rows a block that ``cached_attn_block`` never goes under — while
+    the launch itself stays the interpreter's (``ops.mosaic`` is not
+    touched): the engine serves THROUGH ``tony_cached_attn`` here. The
+    arm is chosen while a program is traced, so no traced program may
+    cross the patch in either direction."""
+    import types
+
+    from tony_tpu.models import decode as D
+    jax.clear_caches()
+    monkeypatch.setattr(D, "mosaic",
+                        types.SimpleNamespace(interpret=lambda: False))
+    monkeypatch.setattr(D, "cached_attn_block", lambda rows, row_bytes: 128)
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", sorted(ROWS_CASES))
+def test_cache_rows_read_and_live_follow_the_requests_lengths(
+        params, case, request):
+    scaled, max_len, lengths, rows_read, rows_live = ROWS_CASES[case]
+    if case.startswith("kernel"):
+        request.getfixturevalue("on_chip_arm")
+    cfg = CFG.scaled(**scaled)
+    rng = np.random.RandomState(3)
+    prompts = [list(rng.randint(0, cfg.vocab_size, size=n))
+               for n in lengths]
+    b = ContinuousBatcher(params, cfg, batch=2, max_len=max_len, chunk=4)
+    reg = MetricsRegistry()
+    eng = ServeEngine(b, registry=reg)
+    for rid, p in enumerate(prompts):
+        eng.submit(rid, p, 8)
+    eng.drain()
+    eng.run()
+    assert b.steps_executed == 8
+    kind = "ring" if scaled else "linear"
+    # step j of 8: slot r's query holds position lengths[r] + j
+    read = cfg.n_layers * sum(
+        rows_read(n + j, max(lengths) + j) for n in lengths
+        for j in range(8))
+    live = cfg.n_layers * sum(
+        rows_live(n + j) for n in lengths for j in range(8))
+    stats = eng.stats()
+    assert stats["cache_rows_read"] == {kind: read}
+    assert stats["cache_rows_live"] == {kind: live}
+    assert reg.counter("tony_cache_rows_read_total",
+                       kind=kind).value == read
+    assert reg.counter("tony_cache_rows_live_total",
+                       kind=kind).value == live
+
+
+@pytest.mark.parametrize("scaled, max_len", [
+    ({}, 700), ({"attn_window": 600, "kv_cache_capacity": 600}, 900)],
+    ids=["linear", "ring"])
+def test_engine_through_the_kernel_serves_the_jnp_reads_tokens(
+        params, scaled, max_len, request):
+    """Slots of unlike lengths, reused, one idle at the end: the tokens
+    served through ``tony_cached_attn`` (``on_chip_arm``) are the ones
+    the ``jnp`` reads serve."""
+    cfg = CFG.scaled(**scaled)
+    rng = np.random.RandomState(5)
+    prompts = [list(rng.randint(0, cfg.vocab_size, size=n))
+               for n in (300, 7, 130, 40)]
+    budgets = [9, 14, 5, 6]
+    want = ContinuousBatcher(params, cfg, batch=2, max_len=max_len,
+                             chunk=4).serve(prompts, budgets)
+    request.getfixturevalue("on_chip_arm")
+    b = ContinuousBatcher(params, cfg, batch=2, max_len=max_len, chunk=4)
+    assert b.serve(prompts, budgets) == want
+    kind = "ring" if scaled else "linear"
+    assert b.cache_rows_live[kind] / b.cache_rows_read[kind] > 0.3
+
+
+def test_speculative_batcher_counts_no_cached_reads(params):
+    """Its rounds read through ``extend_step``, which this count does
+    not reckon: no total, and no counter registered at a constant 0."""
+    from tony_tpu.models.serve import SpeculativeContinuousBatcher
+    b = SpeculativeContinuousBatcher(params, CFG, params, CFG, batch=2,
+                                     max_len=32, num_speculative=2)
+    reg = MetricsRegistry()
+    stats = ServeEngine(b, registry=reg).stats()
+    assert stats["cache_rows_read"] == stats["cache_rows_live"] == {}
+    assert "tony_cache_rows" not in reg.to_wire_json()
+
+
+def test_host_frontiers_are_the_devices_through_slot_reuse(params):
+    """The counts rest on the host's copy of ``cache["length"]``: after
+    5 requests of unlike lengths and budgets through 2 slots (slots
+    reused, one idle while the last request finishes) it still IS the
+    device's, and no more rows were live than read."""
+    rng = np.random.RandomState(4)
+    prompts = [list(rng.randint(0, CFG.vocab_size, size=n))
+               for n in (5, 3, 9, 4, 7)]
+    b = ContinuousBatcher(params, CFG, batch=2, max_len=64, chunk=4)
+    seen = []
+    issue = b._issue
+
+    def checked_issue():
+        np.testing.assert_array_equal(np.asarray(b.cache["length"]),
+                                      b._row_len)
+        seen.append(b._row_len.copy())
+        return issue()
+    b._issue = checked_issue
+    b.serve(prompts, [6, 50, 3, 4, 5])
+    np.testing.assert_array_equal(np.asarray(b.cache["length"]),
+                                  b._row_len)
+    assert len(seen) >= 5 and any(0 in s for s in seen)    # an idle slot
+    assert 0 < b.cache_rows_live["linear"] < b.cache_rows_read["linear"]
+    assert b.cache_rows_read["linear"] == \
+        CFG.n_layers * b.steps_executed * 2 * 64

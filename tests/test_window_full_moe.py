@@ -583,4 +583,5 @@ def test_configuration_file_states_its_cut():
         "engine_host_ms.serve", "step_utilization.serve",
         "decode_bw_pct.serve", "wire_emit_ms.serve",
         "moe_experts_roofline.serve", "admit_device_share_pct.serve",
-        "admit_device_ms.serve", "admit_attention_share_pct.serve"])
+        "admit_device_ms.serve", "admit_attention_share_pct.serve",
+        "cached_attn_share_pct.serve"])

@@ -23,7 +23,11 @@ params sharded by the model's logical axes (shard_pytree + logical_axes)
 and call under ``jax.set_mesh``; outputs are token-identical to unsharded
 decode (test-verified on a tp×dp mesh). The module adds no explicit
 sharding constraints of its own; the cache layout follows the q/k/v
-projections' propagated shardings.
+projections' propagated shardings. One island: on the chip a step's
+cached read is a Mosaic kernel, which XLA does not partition, so under a
+mesh it runs a device inside ``shard_cached_attention``'s ``shard_map``
+— slots over the batch axes, K/V heads over ``tp``, which is where
+propagation leaves the cache (:func:`_kernel_cached_attention`).
 
 Usage::
 
@@ -40,13 +44,18 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tony_tpu.models import transformer as T
 from tony_tpu.models.quantize import QuantizedWeight
 from tony_tpu.ops import mosaic
+from tony_tpu.ops.attention import (cached_attention, cached_attn_block,
+                                    cached_attn_work, live_blocks)
 from tony_tpu.ops.norms import layer_norm_reference, rms_norm_reference
 from tony_tpu.parallel.moe import (HeldExperts, held_experts_ffn, moe_ffn,
                                    shared_experts_ffn, sigmoid_route)
+from tony_tpu.parallel.sharding import (cache_heads_split,
+                                        shard_cached_attention)
 
 
 #: TOKEN POSITIONS (the sequence axis, NOT batch x seq) STRICTLY ABOVE
@@ -190,14 +199,20 @@ def cache_bytes_by_kind(cfg: T.TransformerConfig, batch: int,
     state: ``latent`` / ``window`` / ``full`` for a model with
     layer_kinds (each attention's own buffers), ``ring`` or ``linear``
     for the dense decoder."""
-    kind_of = {"ckv": "latent", "k_ring": "window", "v_ring": "window"}
-    flat = "ring" if _ring_capacity(cfg) else "linear"
     out: dict = {}
     for n, (layers, rows, width, dt) in cache_layout(cfg, max_len).items():
-        kind = kind_of.get(n, "full") if cfg.kinded else flat
+        kind = _buffer_kind(cfg, n)
         out[kind] = out.get(kind, 0) + (layers * batch * rows * width
                                         * jnp.dtype(dt).itemsize)
     return out
+
+
+def _buffer_kind(cfg: T.TransformerConfig, name: str) -> str:
+    """The kind of state that owns the position buffer ``name``."""
+    if not cfg.kinded:
+        return "ring" if _ring_capacity(cfg) else "linear"
+    return {"ckv": "latent", "k_ring": "window",
+            "v_ring": "window"}.get(name, "full")
 
 
 def init_kv_cache(cfg: T.TransformerConfig, batch: int,
@@ -248,15 +263,19 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
     cap = _ring_capacity(cfg)
     rows = cap or max_len
     if cap and cfg.attn_window and cap >= 4 * cfg.attn_window:
-        # _ring_cached_attention is dense over ALL capacity rows every
-        # step — per-token cost is O(capacity), NOT O(window). Capacity
-        # near the window is the intended regime; a large multiple
-        # silently forfeits the sliding window's cost bound.
+        # _ring_cached_attention reads the rows WRITTEN, not the rows in
+        # the window: off the chip it is dense over all capacity rows
+        # every step; on it the kernel visits the blocks that hold
+        # written rows — every block once the ring has wrapped. Either
+        # way a long stream's per-token cost is O(capacity), NOT
+        # O(window). Capacity near the window is the intended regime; a
+        # large multiple silently forfeits the sliding window's bound.
         warnings.warn(
             f"kv_cache_capacity={rows} is {rows // cfg.attn_window}x "
-            f"attn_window={cfg.attn_window}: ring-cache attention reads "
-            "every capacity row per token (O(capacity), not O(window)) — "
-            "size the capacity near the window", stacklevel=2)
+            f"attn_window={cfg.attn_window}: once the ring has wrapped, "
+            "ring-cache attention reads every capacity row per token "
+            "(O(capacity), not O(window)) — size the capacity near the "
+            "window", stacklevel=2)
     cache = {n: jnp.zeros((layers, batch, n_rows, width), dt)
              for n, (layers, n_rows, width, dt)
              in cache_layout(cfg, max_len, ring).items()}
@@ -537,6 +556,63 @@ def _cached_attention_blockwise(q, bufs, li, q_start,
     return _merge_query_heads(acc / l[..., None], q.dtype)
 
 
+def _read_arm(rows: int, kv_heads: int, quantized: bool, n_q: int = 1,
+              ring: bool = False) -> str:
+    """Which cached read a buffer of ``rows`` rows a slot takes — the ONE
+    ladder: the reads (:func:`_cached_attention`,
+    :func:`_ring_cached_attention`) and the host's count of what they
+    visit (:func:`cache_rows_visited`) both ask here.
+
+    ``"kernel"`` (:func:`_kernel_cached_attention`): on the chip — the
+    repo's one question, ``mosaic.interpret()`` — for what is visible in
+    the input that the kernel takes: ``_BLOCKWISE_MIN_LEN`` rows or more,
+    a cache in its own dtype (no int8 scales), ONE query position a slot
+    (``extend_step``'s chunks and speculation's verify keep the walk),
+    and K/V heads the ambient mesh leaves whole on a device
+    (``cache_heads_split``: a Mosaic call is not partitioned, it runs a
+    device inside a ``shard_map`` island). Otherwise the ``jnp`` reads,
+    which XLA partitions as it finds them: ``"dense"`` over the whole
+    buffer (a short one, and every ring), or the block-wise ``"walk"``
+    to the longest row."""
+    if (rows >= _BLOCKWISE_MIN_LEN and not mosaic.interpret()
+            and not quantized and n_q == 1
+            and cache_heads_split(kv_heads)):
+        return "kernel"
+    return "dense" if ring or rows < _BLOCKWISE_MIN_LEN else "walk"
+
+
+def _kernel_cached_attention(q, bufs, li, q_pos, window=None,
+                             ring: bool = False):
+    """The cached read as ``tony_cached_attn``
+    (:func:`tony_tpu.ops.attention.cached_attention`): each slot's OWN
+    live blocks of the stacked buffers, from its position ``q_pos`` [B] —
+    where the walk reads every slot to the LONGEST row and the dense ring
+    read every ring whole. The work list is built here, from ``q_pos``
+    alone: every layer of one buffer kind builds the same one, and XLA
+    keeps one. The block's height follows the stored row's bytes
+    (``cached_attn_block``).
+
+    Under a mesh (``jax.set_mesh``: tensor-parallel serving) the launch
+    runs a device, inside ``shard_cached_attention``'s island: each
+    device's slots and K/V heads, a work list of its own slots, the
+    queries spread over ITS heads (``qx`` is block-diagonal a K/V head,
+    so the split is exact)."""
+    d = q.shape[3]
+
+    def local(q, k_all, v_all, q_pos):
+        b, _, h, _ = q.shape
+        rows, f = k_all.shape[2:]
+        block = cached_attn_block(rows, f * k_all.dtype.itemsize)
+        work = cached_attn_work(q_pos, rows, block, window, ring)
+        qx = _spread_queries(q, f // d).reshape(b, f, h).transpose(0, 2, 1)
+        o = cached_attention(qx, k_all, v_all, li, q_pos, work,
+                             scale=d ** -0.5, head_dim=d, block=block,
+                             window=window, ring=ring)
+        return o[:, None]
+
+    return shard_cached_attention(local, q, bufs["k"], bufs["v"], q_pos)
+
+
 def _cached_attention(q, bufs, li, q_start, attn_window=None):
     """q: [B, K, H, hd] holding positions q_start..q_start+K-1; ``bufs``:
     the cache's stacked [L, B, max_len, KV·hd] k/v buffers (plus
@@ -550,16 +626,24 @@ def _cached_attention(q, bufs, li, q_start, attn_window=None):
     caches) with f32 accumulation — casting the whole cache to f32 would
     double the hot loop's HBM traffic and halve MXU throughput.
 
-    Large caches (max_len >= ``_BLOCKWISE_MIN_LEN``) dispatch to the
-    length-aware block-wise path so serving cost follows the live length
-    rather than the padded buffer."""
+    Large caches (max_len >= ``_BLOCKWISE_MIN_LEN``) take a length-aware
+    path, so serving cost follows the live length rather than the padded
+    buffer: on the chip ``tony_cached_attn`` over each slot's OWN live
+    blocks (:func:`_kernel_cached_attention`; one query position a slot,
+    a cache in its own dtype); off it, for int8 caches and for chunks of
+    several positions, the block-wise walk to the LONGEST row."""
     k_all, v_all = bufs["k"], bufs["v"]
-    max_len = k_all.shape[2]
-    if max_len >= _BLOCKWISE_MIN_LEN:
-        return _cached_attention_blockwise(q, bufs, li, q_start,
-                                           attn_window=attn_window)
     quant = "k_scale" in bufs
     b, n_q, h, d = q.shape
+    max_len = k_all.shape[2]
+    arm = _read_arm(max_len, k_all.shape[3] // d, quant, n_q)
+    if arm == "kernel":
+        return _kernel_cached_attention(
+            q, bufs, li, _q_positions(q_start, b, 1)[:, 0],
+            window=attn_window)
+    if arm == "walk":
+        return _cached_attention_blockwise(q, bufs, li, q_start,
+                                           attn_window=attn_window)
     k_cache, v_cache = _kv_rows(k_all, li), _kv_rows(v_all, li)
     if quant:
         k_cache, v_cache = (k_cache.astype(q.dtype),
@@ -595,10 +679,16 @@ def _ring_cached_attention(q, bufs, li, q_pos, attn_window: int):
     ``(q_pos - r) mod C`` is below ``min(attn_window, q_pos + 1)``:
     in-window history written by the CURRENT occupant (older residue in
     a reused slot can never satisfy the offset test — the slot-reuse
-    argument of serve.py carries over row-wise). Dense over the C ring
-    rows: C ≈ the window, the size regime where the dense einsum beats
-    the blockwise walk anyway. Single-position queries only (K = 1 —
-    the callers enforce it; chunked verify keeps the linear cache).
+    argument of serve.py carries over row-wise). Single-position
+    queries only (K = 1 — the callers enforce it; chunked verify keeps
+    the linear cache).
+
+    On the chip (:func:`_read_arm`; rings of ``_BLOCKWISE_MIN_LEN``
+    rows or more) the read is ``tony_cached_attn`` under this mask: each
+    slot's blocks up to its last WRITTEN row — a ring that has not
+    wrapped reads ``ceil((q_pos + 1) / block)`` blocks, an idle slot
+    one. What follows is the CPU arm, the int8 cache's, and the tests'
+    oracle: dense over the C ring rows of every slot, live or not.
 
     q: [B, 1, H, hd]; q_pos: [B] absolute positions. Quantized caches
     fold their scales outside the dots exactly as the linear paths do."""
@@ -606,6 +696,9 @@ def _ring_cached_attention(q, bufs, li, q_pos, attn_window: int):
     quant = "k_scale" in bufs
     b, n_q, h, d = q.shape
     c = k_all.shape[2]
+    if _read_arm(c, k_all.shape[3] // d, quant, n_q, ring=True) == "kernel":
+        return _kernel_cached_attention(q, bufs, li, q_pos,
+                                        window=attn_window, ring=True)
     k_cache, v_cache = _kv_rows(k_all, li), _kv_rows(v_all, li)
     if quant:
         k_cache, v_cache = (k_cache.astype(q.dtype),
@@ -625,6 +718,49 @@ def _ring_cached_attention(q, bufs, li, q_pos, attn_window: int):
         probs = probs * vs[:, :, None, None, :]
     return _merge_query_heads(
         _head_values(probs.astype(v_cache.dtype), v_cache), q.dtype)
+
+
+def cache_rows_visited(cfg: T.TransformerConfig, max_len: int,
+                       pos) -> dict:
+    """kind → (rows READ, rows LIVE) of the K/V reads of single-position
+    decode steps, summed over the layers of that kind: HOST arithmetic
+    (numpy) on ``pos`` [B, n], the position every slot's query holds at
+    each of a chunk's ``n`` steps — what ``ContinuousBatcher`` counts a
+    chunk at issue. LIVE: the rows the step's mask admits (``pos + 1``,
+    inside the window where there is one). READ: the rows of the blocks
+    that the arm :func:`_read_arm` names visits — the kernel each slot's
+    own (:func:`live_blocks`), the walk every slot's from the oldest
+    window's first block to the longest row's last, the dense reads the
+    whole buffer. live / read is how far the read follows the rows. The
+    kinds are :func:`cache_bytes_by_kind`'s that hold K and V (the
+    latent read is not these functions': it has no count)."""
+    pos = np.asarray(pos, np.int64)
+    quant = cfg.kv_cache_dtype == "int8"
+    layout, out = cache_layout(cfg, max_len), {}
+    for name in ("k", "k_ring"):                    # a row's V is its K's
+        if name not in layout:
+            continue
+        layers, rows, width, dt = layout[name]
+        kind = _buffer_kind(cfg, name)
+        ring = kind in ("ring", "window")
+        window = (cfg.attn_window or None) if ring or not cfg.kinded \
+            else None
+        live = np.minimum(pos + 1, min(window or rows, rows))
+        arm = _read_arm(rows, cfg.kv_heads, quant, ring=ring)
+        if arm == "kernel":
+            block = cached_attn_block(rows, width * jnp.dtype(dt).itemsize)
+            lo, hi = live_blocks(np, pos, rows, block, window, ring)
+            read = np.minimum((hi + 1) * block, rows) - lo * block
+        elif arm == "walk":
+            first = (np.maximum(pos.min(axis=0) - window + 1, 0)
+                     // DECODE_BLOCK if window else 0)
+            read = np.broadcast_to(
+                ((pos.max(axis=0) + DECODE_BLOCK) // DECODE_BLOCK - first)
+                * DECODE_BLOCK, pos.shape)
+        else:
+            read = np.full_like(pos, rows)
+        out[kind] = (layers * int(read.sum()), layers * int(live.sum()))
+    return out
 
 
 def _window_write(buf_all, chunk, li, pos, window):
@@ -1055,9 +1191,10 @@ def _kinded_decode_block(x, p, bufs, li, pos, cfg, rope, window=None):
     buffers the layer's attention owns, at its index among them.
     ``latent``: the compressed row, read in the absorbed form;
     ``window``: K and V at ``pos`` modulo the ring's rows, read as
-    :func:`_ring_cached_attention` (dense over the ring, masked by
-    offset); ``full``: K and V at ``pos``, read as
-    :func:`_cached_attention` (the live blocks). Returns (x, bufs)."""
+    :func:`_ring_cached_attention` (masked by offset); ``full``: K and V
+    at ``pos``, read as :func:`_cached_attention` — on the chip both
+    through ``tony_cached_attn``, each slot's own live blocks. Returns
+    (x, bufs)."""
     attention, ai = cfg.attention_of(li)
     h = _norm(x, p["attn_norm"], cfg)
     pos = jnp.asarray(pos)

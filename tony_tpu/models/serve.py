@@ -157,7 +157,8 @@ import numpy as np
 
 from tony_tpu.models import transformer as T
 from tony_tpu.models.decode import (MOE_COUNTS, _check_draft_vocab,
-                                    cache_bytes_by_kind, ring_rows,
+                                    cache_bytes_by_kind,
+                                    cache_rows_visited, ring_rows,
                                     _check_no_ring, _filter_logits, _kv_bufs,
                                     _propose_and_verify,
                                     _propose_and_verify_sampled,
@@ -873,6 +874,20 @@ class ContinuousBatcher:
         #: window never bound and a linear buffer of the ring's size
         #: would have served
         self.ring_rows_overwritten = 0
+        #: the host's copy of the device's per-row frontiers
+        #: (``cache["length"]``): an admission sets a row's, every decode
+        #: step advances every row's, a retirement zeroes it
+        self._row_len = np.zeros((batch,), np.int64)
+        #: cache rows the decode chunks' reads VISITED and rows LIVE
+        #: under their masks, by the kind of state, summed over its
+        #: layers (decode.cache_rows_visited, at each chunk's issue; an
+        #: idle slot counts as the device reads it). live / read is how
+        #: far the cached read follows each row's own length (the kinds
+        #: that hold K and V: a latent cache has none)
+        kinds = cache_rows_visited(cfg, max_len,
+                                   np.zeros((batch, 0), np.int64))
+        self.cache_rows_read = dict.fromkeys(kinds, 0)
+        self.cache_rows_live = dict.fromkeys(kinds, 0)
         self._device_stats: collections.deque = collections.deque()
         self.phase_times = PhaseTimes(ENGINE_PHASES)
         # seams usable standalone (no serve() call required); serve()
@@ -1082,9 +1097,14 @@ class ContinuousBatcher:
         The engine's admission sweep feeds both through one seam, so
         the slot/occupancy machinery cannot diverge between modes."""
         pkg, toks = [], []
+        shared_p = len(self.shared_prefix) if self.shared_prefix else 0
         for pair in pairs:
-            (pkg if isinstance(prompts[pair[1]], KVPackage)
-             else toks).append(pair)
+            p = prompts[pair[1]]
+            (pkg if isinstance(p, KVPackage) else toks).append(pair)
+            self._row_len[pair[0]] = (
+                p.length if isinstance(p, KVPackage)
+                else len(p.entry.tokens) + len(p.suffix)
+                if isinstance(p, _PrefixHit) else shared_p + len(p))
         if pkg:
             self._admit_packages(pkg, prompts)
         if toks:
@@ -1307,6 +1327,13 @@ class ContinuousBatcher:
                 self.top_p)
             if stats:
                 self._device_stats.append(("decode", stats))
+        visited = cache_rows_visited(
+            self.cfg, self.max_len,
+            self._row_len[:, None] + np.arange(self.chunk))
+        for kind, (read, live) in visited.items():
+            self.cache_rows_read[kind] += read
+            self.cache_rows_live[kind] += live
+        self._row_len += self.chunk
         self.steps_executed += self.chunk
         for r in range(self.batch):
             self._row_off[r] += self.chunk
@@ -1340,6 +1367,7 @@ class ContinuousBatcher:
 
     def _retire(self, mask) -> None:
         self.cache = retire_rows(self.cache, jnp.asarray(mask))
+        self._row_len[np.asarray(mask, bool)] = 0
 
     def count_finished(self, prompt_len: int, emitted: int) -> int:
         """Fold a finished request into the ring's accounting: it wrote
@@ -1522,6 +1550,10 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
         # pending token per slot (the committed token whose K/V is not
         # yet written) replaces the greedy batcher's per-slot logits
         self.pending = jnp.zeros((batch,), jnp.int32)
+        # a round's reads are the draft's steps and the verify's chunk
+        # (extend_step): not the single-position reads the greedy
+        # batcher counts, so there is no count and no counter
+        self.cache_rows_read, self.cache_rows_live = {}, {}
 
     def _chunk_tokens_max(self) -> int:
         # one sync = chunk rounds x up to k+1 commits per row
@@ -1892,6 +1924,19 @@ class ServeEngine:
             help="ring rows finished requests overwrote: a layer a "
                  "position at or past the ring's rows (0 = the window "
                  "never bound)")
+        # what the decode chunks' cached reads visited against what was
+        # live (the batcher counts at each issue; folded at each consume)
+        self._rows_c = {
+            (what, kind): reg.counter(
+                f"tony_cache_rows_{what}_total", help=text, kind=kind)
+            for kind in batcher.cache_rows_read
+            for what, text in (
+                ("read", "cache rows the decode steps' reads visited, a "
+                         "layer a row: whole blocks, idle slots too"),
+                ("live", "cache rows the decode steps' masks admitted; "
+                         "over rows read, how far the cached read "
+                         "follows each row's own length"))}
+        self._rows_seen = {k: 0 for k in self._rows_c}
         self._qdepth_g.set(0)
         for g in self._qdepth_by_cls.values():
             g.set(0)
@@ -2122,6 +2167,11 @@ class ServeEngine:
                 # the rings overwrote (0 without a ring)
                 "cache_bytes": dict(self.b.cache_bytes),
                 "ring_rows_overwritten": self.b.ring_rows_overwritten,
+                # rows the decode chunks' cached reads visited and rows
+                # live under their masks, by kind (live / read: how far
+                # the read follows each row's own length)
+                "cache_rows_read": dict(self.b.cache_rows_read),
+                "cache_rows_live": dict(self.b.cache_rows_live),
                 "steps_executed": self.b.steps_executed,
             }
 
@@ -2391,6 +2441,11 @@ class ServeEngine:
                      else self.b.moe_touches)[program]
             c.inc(total - self._moe_seen[(what, program)])
             self._moe_seen[(what, program)] = total
+        for (what, kind), c in self._rows_c.items():
+            total = (self.b.cache_rows_read if what == "read"
+                     else self.b.cache_rows_live)[kind]
+            c.inc(total - self._rows_seen[(what, kind)])
+            self._rows_seen[(what, kind)] = total
 
     def _consume_chunk(self, host_toks, snap) -> None:
         deltas, retired = [], []

@@ -986,3 +986,222 @@ def reference_attention(q, k, v, *, causal: bool = True,
     o, _ = _dense_with_lse(q, k, v, causal=causal, scale=scale,
                            window=window)
     return o
+
+
+# ---------------------------------------------------------------------------
+# Cached attention of a decode step: one query position a slot against the
+# slot's OWN live blocks of the stacked cache
+# ---------------------------------------------------------------------------
+
+#: HLO instruction name of the launch (``%tony_cached_attn.N``): the name
+#: a device trace is read by
+CACHED_ATTN_NAME = "tony_cached_attn"
+
+#: what a block of K (and one of V) should weigh: a grid step costs about
+#: 0.35 us beside its DMA, so a block wants to be large, and a slot reads
+#: its last block whole however few live rows it holds, so it wants to be
+#: small (:func:`cached_attn_block`)
+_CACHED_BLOCK_BYTES = 1 << 20
+
+
+def cached_attn_block(rows: int, row_bytes: int) -> int:
+    """Rows of one K/V block of :func:`cached_attention` over a buffer of
+    ``rows`` rows of ``row_bytes`` stored bytes each: the power of two
+    whose block lies nearest ``_CACHED_BLOCK_BYTES`` from below, at least
+    128 rows, the whole buffer where that is smaller. A function of what
+    the buffer is, not a setting."""
+    block = 128
+    while block * 2 * row_bytes <= _CACHED_BLOCK_BYTES:
+        block *= 2
+    return min(block, rows)
+
+
+def live_blocks(xp, pos, rows: int, block: int, window: int | None,
+                ring: bool):
+    """(first, last) block of a ``rows``-row buffer that holds a row the
+    mask admits for ONE query at position ``pos`` (any shape; ``xp`` is
+    ``jax.numpy`` for the traced work list, ``numpy`` for the host's
+    count of what a step visits). Linear: rows ``<= pos``, from the
+    window's first row where there is a window. Ring (rows written
+    modulo ``rows``): the rows written so far, ``0 .. min(pos, rows-1)``
+    — every block once the ring has wrapped. A slot at position 0 has
+    its one block: the step has just written the row at ``pos``."""
+    last = xp.minimum(pos, rows - 1) // block
+    if ring or window is None:
+        return xp.zeros_like(last), last
+    return xp.maximum(pos - window + 1, 0) // block, last
+
+
+def cached_attn_work(q_pos, rows: int, block: int,
+                     window: int | None = None, ring: bool = False):
+    """The work list of :func:`cached_attention`, from the slots'
+    positions ``q_pos`` [B] int32: ``(slot[t], block[t])`` for ``t <
+    n_work`` — every slot's live blocks in turn, a slot's consecutive and
+    ascending — beside each slot's first and last block and ``n_work =
+    sum_b blocks_b`` (traced: the kernel's grid bound). Entries at or
+    past ``n_work`` are never visited."""
+    q_pos = q_pos.astype(jnp.int32)
+    lo, hi = live_blocks(jnp, q_pos, rows, block, window, ring)
+    cnt = hi - lo + 1
+    ends = jnp.cumsum(cnt)
+    b = q_pos.shape[0]
+    t = jnp.arange(b * _cdiv(rows, block), dtype=jnp.int32)
+    # slot[t] = slots whose blocks all lie before t; the same compare
+    # gives where the slot's run began and its first block (no gather)
+    past = (t[:, None] >= ends[None, :]).astype(jnp.int32)      # [W, B]
+    slot = jnp.minimum(past.sum(axis=1), b - 1)
+    step = jnp.concatenate([lo[1:] - lo[:-1], jnp.zeros((1,), jnp.int32)])
+    blk = lo[0] + (past * step).sum(axis=1) + t - (past * cnt).sum(axis=1)
+    return (slot, jnp.clip(blk, 0, _cdiv(rows, block) - 1), lo, hi,
+            ends[-1])
+
+
+def _cached_attn_kernel(layer, q_pos, slot, blk, lo, hi, qx_ref, k_ref,
+                        v_ref, o_ref, ml_scr, acc_scr, *, scale: float,
+                        block: int, rows: int, window: int | None,
+                        ring: bool, heads: tuple[int, int] | None):
+    del layer
+    t = pl.program_id(0)
+    b, j = slot[t], blk[t]
+    pos = q_pos[b]
+
+    @pl.when(j == lo[b])
+    def _first():
+        ml_scr[:, 0:1] = jnp.full_like(ml_scr[:, 0:1], -jnp.inf)
+        ml_scr[:, 1:2] = jnp.zeros_like(ml_scr[:, 1:2])
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    k, v = k_ref[...], v_ref[...]                       # [S, KV·hd]
+    s = jax.lax.dot_general(
+        qx_ref[...], k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale     # [N, S]
+    r = j * block + lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    if ring:
+        # (pos - r) mod rows, for r < rows, without a vector remainder
+        off = pos % rows - r
+        off = jnp.where(off < 0, off + rows, off)
+        mask = off < jnp.minimum(window, pos + 1)
+    else:
+        mask = r <= pos
+        if window is not None:
+            mask = mask & (pos - r < window)
+    if rows % block:
+        # the buffer's last block runs past its rows: what lies there is
+        # no row (whatever bits the block's buffer held), and 0 x NaN in
+        # the value product would reach every output
+        mask = mask & (r < rows)
+        live = j * block + lax.broadcasted_iota(
+            jnp.int32, (block, 1), 0) < rows
+        v = jnp.where(live, v, jnp.zeros_like(v))
+    s = jnp.where(mask, s, -jnp.inf)
+    m_prev, l_prev = ml_scr[:, 0:1], ml_scr[:, 1:2]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    # a block the mask empties (a wrapped ring wider than its window)
+    # keeps m = -inf: subtract 0 there, exp(-inf - 0) = 0 and not nan
+    safe_m = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+    alpha = jnp.exp(m_prev - safe_m)
+    p = jnp.exp(s - safe_m)
+    ml_scr[:, 0:1] = m_new
+    ml_scr[:, 1:2] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    @pl.when(j == hi[b])
+    def _last():
+        # l > 0: every query attends the row its step wrote
+        o = acc_scr[...] / ml_scr[:, 1:2]
+        if heads is None:
+            o_ref[...] = o.astype(o_ref.dtype)
+        else:
+            # lane-aligned heads: each K/V head's queries keep their own
+            # head's columns here, and the row never leaves as stored
+            kv, d = heads
+            g = o.shape[0] // kv
+            for h in range(kv):
+                o_ref[h * g:(h + 1) * g, :] = o[
+                    h * g:(h + 1) * g, h * d:(h + 1) * d].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "head_dim", "block", "window", "ring", "interpret"))
+def _cached_attention(qx, k_all, v_all, layer, q_pos, work, *, scale,
+                      head_dim, block, window, ring, interpret):
+    slot, blk, lo, hi, n_work = work
+    b, n, f = qx.shape
+    rows = k_all.shape[2]
+    kv = f // head_dim
+    aligned = head_dim % _LANES == 0
+    out_w = head_dim if aligned else f
+
+    def kv_map(t, layer, q_pos, slot, blk, lo, hi):
+        return layer[0], slot[t], blk[t], 0
+
+    def slot_map(t, layer, q_pos, slot, blk, lo, hi):
+        return slot[t], 0, 0
+
+    out = pl.pallas_call(
+        functools.partial(
+            _cached_attn_kernel, scale=scale, block=block, rows=rows,
+            window=window, ring=ring,
+            heads=(kv, head_dim) if aligned else None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(n_work,),
+            in_specs=[
+                pl.BlockSpec((None, n, f), slot_map),
+                pl.BlockSpec((None, None, block, f), kv_map),
+                pl.BlockSpec((None, None, block, f), kv_map)],
+            out_specs=pl.BlockSpec((None, n, out_w), slot_map),
+            scratch_shapes=[pltpu.VMEM((n, _LANES), jnp.float32),
+                            pltpu.VMEM((n, f), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, n, out_w), qx.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=CACHED_ATTN_NAME,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), q_pos.astype(jnp.int32),
+      slot, blk, lo, hi, qx, k_all, v_all)
+    if aligned:
+        return out
+    # heads off the lane tiles (96): the kernel hands back whole stored
+    # rows and each K/V head keeps its own columns here (a select, as
+    # decode._head_values: no arithmetic)
+    own = jnp.eye(kv, dtype=bool)[None, :, None, :, None]
+    return jnp.where(own, out.reshape(b, kv, n // kv, kv, head_dim),
+                     0).sum(axis=3).reshape(b, n, head_dim)
+
+
+def cached_attention(qx, k_all, v_all, layer, q_pos, work, *, scale: float,
+                     head_dim: int, block: int, window: int | None = None,
+                     ring: bool = False, interpret: bool | None = None):
+    """One query position a slot against the slot's live blocks of a
+    stacked K/V cache: the decode step's cached read as ONE Mosaic kernel
+    (``tony_cached_attn``) whose grid is the work list — (slot, block)
+    pairs, ``n_work`` of them, a traced bound — so a slot's read follows
+    its OWN length: blocks past its last live row are never visited (no
+    DMA, no product, not an empty grid step), whatever the longest row of
+    the batch holds.
+
+    ``qx`` [B, H, KV·hd]: the step's queries laid block-diagonally over
+    the K/V heads (``decode._spread_queries``), so a stored row is
+    contracted whole, as stored; ``k_all`` / ``v_all`` [L, B, rows,
+    KV·hd]: the STACKED buffers, whose ``layer`` (traced) goes into the
+    index map — nothing slices or copies a layer; ``q_pos`` [B]; ``work``:
+    :func:`cached_attn_work` at this ``block``. The mask follows what
+    the buffer is: linear rows ``<= q_pos`` (within ``window`` of it
+    where given), or a ``ring`` written modulo its rows, masked by each
+    row's offset from the query. Operands in the cache's dtype, float32
+    scores, softmax state and accumulator (VMEM scratch, reset at a
+    slot's first block and written out at its last), ``p`` cast to the
+    cache's dtype before the value product: the arithmetic of
+    ``decode._cached_attention_blockwise``, which stays the CPU arm and
+    the oracle. Returns [B, H, hd] in ``qx``'s dtype.
+
+    Traced once a shape: every layer of a model calls the same jitted
+    wrapper with its own ``layer``."""
+    if interpret is None:
+        interpret = mosaic.interpret()
+    return _cached_attention(
+        qx, k_all, v_all, layer, q_pos, work, scale=scale,
+        head_dim=head_dim, block=block, window=window, ring=ring,
+        interpret=interpret)

@@ -178,6 +178,19 @@ def attention_spec(mesh: Mesh, batch_axes, seq_axis: str | None,
     return P(b_spec, s_spec, h_spec, None), s_spec
 
 
+def _island_mesh(mesh):
+    """The mesh a ``shard_map`` island would run over — ``mesh``, or the
+    ambient one (``jax.set_mesh``) — or None where there is nothing to
+    split: no mesh, one device, or every axis already Manual (a pipeline
+    stage body)."""
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty or mesh.size == 1 \
+            or not _auto_axes(mesh):
+        return None
+    return mesh
+
+
 def shard_attention(local_fn, q, k, v, mesh: Mesh | None, *,
                     batch_axes: Sequence[str] = _BATCH_AXES,
                     seq_axis: str | None = None,
@@ -204,10 +217,8 @@ def shard_attention(local_fn, q, k, v, mesh: Mesh | None, *,
     with local kv head j // rep, which is the global pairing only when
     K/V heads shard like Q's. Otherwise they expand to full width first:
     correctness over the payload saving."""
+    mesh = _island_mesh(mesh)
     if mesh is None:
-        mesh = jax.sharding.get_abstract_mesh()
-    if mesh is None or mesh.empty or mesh.size == 1 \
-            or not _auto_axes(mesh):
         return local_fn(q, k, v)
     # an axis that does not divide its dim is dropped (the operand
     # replicates over it and the devices repeat the work) — what the
@@ -228,3 +239,50 @@ def shard_attention(local_fn, q, k, v, mesh: Mesh | None, *,
             k, v = (jax.numpy.repeat(x, h // hk, axis=2) for x in (k, v))
     return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
+
+
+def cache_heads_split(kv_heads: int, mesh: Mesh | None = None, *,
+                      head_axis: str = _HEAD_AXIS) -> bool:
+    """Whether :func:`shard_cached_attention` can leave a K/V cache of
+    ``kv_heads`` heads where the mesh's "heads" axis puts it: no such
+    axis live, or one that divides the heads. Where it does not, a
+    stored row splits INSIDE a head and only a program the partitioner
+    can cut (the ``jnp`` reads) follows it without gathering the
+    cache."""
+    mesh = _island_mesh(mesh)
+    return (mesh is None or head_axis not in _auto_axes(mesh)
+            or kv_heads % mesh.shape[head_axis] == 0)
+
+
+def shard_cached_attention(local_fn, q, k_all, v_all, q_pos,
+                           mesh: Mesh | None = None, *,
+                           batch_axes: Sequence[str] = _BATCH_AXES,
+                           head_axis: str = _HEAD_AXIS):
+    """Run a per-device cached read ``local_fn(q, k_all, v_all, q_pos)``
+    — q [B, 1, H, hd] at positions ``q_pos`` [B] against the STACKED
+    cache [L, B, rows, KV·hd] — as ONE ``shard_map`` island, the decode
+    step's counterpart of :func:`shard_attention` and for its reason: the
+    read is a Mosaic custom call, which the partitioner cannot split.
+    Slots over the "batch" axes, K/V heads over the "heads" axis: the
+    merged trailing axis KV·hd splits between heads (the caller has
+    asked :func:`cache_heads_split`), each device holds whole stored
+    rows of ITS heads and the query heads that read them (H is K/V-head
+    major), and a slot's softmax never crosses devices — so the split is
+    exact and the island holds no collective. The cache is never
+    gathered: the island's specs are where sharding propagation leaves
+    it (``wk`` / ``wv`` are cut by heads).
+
+    With nothing to split (:func:`_island_mesh`) the call is
+    ``local_fn`` as is. A batch axis that does not divide the slots is
+    dropped, as in :func:`shard_attention`."""
+    mesh = _island_mesh(mesh)
+    if mesh is None:
+        return local_fn(q, k_all, v_all, q_pos)
+    batch_axes = tuple(a for a in batch_axes if a in mesh.shape)
+    while q.shape[0] % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = batch_axes[:-1]
+    spec, _ = attention_spec(mesh, batch_axes, None, head_axis)
+    cache = P(None, spec[0], None, spec[2])
+    return jax.shard_map(
+        local_fn, mesh=mesh, in_specs=(spec, cache, cache, P(spec[0])),
+        out_specs=spec, check_vma=False)(q, k_all, v_all, q_pos)
