@@ -35,6 +35,12 @@ from benchmark.tests.test_window_full_family import (  # noqa: F401 — PR 35's
     test_layer_type_rides_in_the_bias_and_only_a_sliding_layer_rotates,
     test_reference_attention_is_the_window_or_the_whole_context,
     test_the_cells_files_are_the_issues, test_window_share_of_live_rows)
+from benchmark.tests.test_scmoe_family import (  # noqa: F401 — PR 37's
+    test_a_program_without_zero_experts_is_refused_in_check,
+    test_gmm_share_of_the_decode_chunks,
+    test_gmm_share_reads_none_without_the_kernel_or_a_chunk,
+    test_reference_block_is_softmax_over_all_outputs_and_identity_zeros,
+    test_the_wide_decode_cells_files_are_the_issues)
 from benchmark.tests.test_families import (  # noqa: F401 — collected here
     test_dense_weights_are_the_parents_bit_for_bit,
     test_family_provides_the_whole_list,
@@ -79,3 +85,13 @@ def test_a_sparse_family_counts_its_trees(config):
     assert jax.tree.map(lambda x: (x.shape, x.dtype), made) == \
         jax.tree.map(lambda x: (x.shape, x.dtype), own)
     assert len(fam.layer_kinds(c)) == c["num_hidden_layers"]
+    # the latent cache the program allocates is the family's own count:
+    # a stored row an attention (a double layer runs two)
+    from tony_tpu.models import decode as D
+    cfg = fam.program_config(c, dtype=jnp.bfloat16)
+    if "ckv" in D.cache_layout(cfg, 128):
+        per_row = (fam.decode_step_bytes(c, 1.0, None)
+                   - fam.decode_step_bytes(c, 0.0, None))
+        attentions, _, width, _ = D.cache_layout(cfg, 128)["ckv"]
+        assert per_row in (attentions * width * 2,
+                           attentions * cfg.latent.row * 2)

@@ -534,23 +534,27 @@ def test_latent_prompt_attention_takes_an_unaligned_short_prompt(chip):
 # widths and the cell's own depth, slots and rows (PR 35)
 # ---------------------------------------------------------------------------
 
-def _mixed_serving(chip):
-    """(cfg, params, cache, logits) of ``command-a-plus-l4-ep8`` as the
-    cell ``serve-commandaplus-mixedlen`` runs it — 3 window + 1 full
-    layer, 16 held experts, 32 slots x 16,384 rows — as shapes on the
-    described chip: 13.2 GB of arguments."""
+def _cell_serving(chip, config: str, slots: int, rows: int):
+    """(cfg, params, cache, logits) of a benchmark configuration as its
+    serving cell runs it, as shapes on the described chip."""
     from benchmark.lib import modelcfg
     from tony_tpu.models import decode as D
-    c = modelcfg.load("command-a-plus-l4-ep8")
+    c = modelcfg.load(config)
     fam = modelcfg.family(c)
     cfg = fam.program_config(c, dtype=jnp.bfloat16, remat=False)
-    slots, rows = 32, 16384
     params = chip.place(jax.eval_shape(
         lambda: fam.make_params(7, c, jnp.bfloat16)))
     cache = jax.eval_shape(lambda: D.init_kv_cache(cfg, slots, rows))
     cache = chip.place(dict(cache, length=chip.shape((slots,), jnp.int32)))
     return cfg, params, cache, chip.shape((slots, cfg.vocab_size),
                                           cfg.logits_storage_dtype)
+
+
+def _mixed_serving(chip):
+    """``command-a-plus-l4-ep8`` as the cell
+    ``serve-commandaplus-mixedlen`` runs it — 3 window + 1 full layer,
+    16 held experts, 32 slots x 16,384 rows: 13.2 GB of arguments."""
+    return _cell_serving(chip, "command-a-plus-l4-ep8", 32, 16384)
 
 
 #: what one v5e chip's compiler allows a program (15.75 GiB)
@@ -607,4 +611,67 @@ def test_mixed_admit_rows_at_the_longest_bucket_fits_the_chip(chip):
     assert text.startswith("HloModule jit_admit_rows")
     assert "tony_flash_fwd" in text and "tony_moe_gmm" in text
     assert not _copies_of_any(text, cache)
+    assert compiled.memory_analysis().peak_memory_in_bytes < _HBM
+
+
+# ---------------------------------------------------------------------------
+# The double layer (two latent attentions, two dense SwiGLUs, a shortcut-
+# connected routed block with zero experts) at LongCat-Flash-Chat's widths
+# and the cell's own depth, slots and rows (PR 37)
+# ---------------------------------------------------------------------------
+
+def _double_layer_serving(chip):
+    """``longcat-flash-l4-ep32`` as the cell
+    ``serve-longcatflash-wide-decode`` runs it — 4 double layers, 16 held
+    experts, 64 slots x 4,096 rows in 8 latent row-sets: 13.07 GB of
+    arguments."""
+    return _cell_serving(chip, "longcat-flash-l4-ep32", 64, 4096)
+
+
+def test_double_layer_step_rows_copies_no_cache_and_no_half(chip):
+    """The decode chunk at the cell's size: the latent buffer ([8, 64,
+    4096, 640]: two row-sets a layer) is written and read in place in all
+    eight attentions, the routed experts reach their kernel stacked, and
+    a half's matrices are cut [layer, half] where they are used — a
+    layer's slice holding both halves has two readers and was copied out
+    whole (2.1 GB of temporaries; 0.16 GB as kept) — so the program fits
+    the chip beside its 13.07 GB of arguments."""
+    import re
+
+    from tony_tpu.models import serve as S
+    cfg, params, cache, logits = _double_layer_serving(chip)
+    buf = cache["ckv"]
+    assert buf.shape == (8, 64, 4096, 640)
+    compiled = S.step_rows.lower(
+        params, cache, logits, chip.shape((64, 2), jnp.uint32),
+        chip.shape((64,), jnp.int32), n=8, cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step_rows")
+    dims = ",".join(str(d) for d in buf.shape)
+    assert not re.findall(r"bf16\[" + dims + r"\]\{[^}]*\} copy\(", text)
+    assert len(set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})",
+                              text))) == 1
+    assert text.count("tony_moe_gmm") >= 12       # 4 layers x gate/up/down
+    assert "moe_zero" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 29
+    assert memory.peak_memory_in_bytes < _HBM
+
+
+def test_double_layer_admit_rows_at_the_longest_bucket_fits_the_chip(chip):
+    """The 2,048 bucket's admission (one row: ``admit_width``): expanded
+    latent attention through the flash kernel in all eight attentions,
+    the rows landed in their own row-sets, the routed block dropless over
+    2,048 tokens, under what the chip allows beside weights and cache."""
+    from tony_tpu.models import serve as S
+    cfg, params, cache, logits = _double_layer_serving(chip)
+    assert S.admit_width(2048, 64) == 1
+    compiled = S.admit_rows.lower(
+        params, cache, logits, chip.shape((1,), jnp.int32),
+        chip.shape((1, 2048), jnp.int32), chip.shape((1,), jnp.int32),
+        cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_admit_rows")
+    assert "tony_flash_fwd" in text and "tony_moe_gmm" in text
+    assert not _cache_sized_copies(text, cache["ckv"])
     assert compiled.memory_analysis().peak_memory_in_bytes < _HBM

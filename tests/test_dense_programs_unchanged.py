@@ -17,6 +17,16 @@ but each name is an equation of the trace, so the counters in the private
 functions' names (``@_where_116`` → ``@_where_118``) moved; with those
 suffixes stripped the two texts are equal. ``phi.grad`` (``remat=False``:
 no names) and the four serving programs are the parent's, hash for hash.
+
+PR 37 added the serving programs of the two models with ``layer_kinds``
+the benchmark holds — latent attention with a dense and two expert layers
+beside a shared expert (``kimi``), window and full kinds in a parallel
+block with averaged shared experts (``commanda``), both at toy width —
+at the hashes of ITS parent (350c6c9, PR 36): the double layer, softmax
+routing, zero experts and the scales on the latent bottlenecks arrived
+beside them and changed no op of theirs (``n_zero == 0`` keeps the
+device counters at two entries and traces no zero term; a scale of 1.0
+traces no multiply).
 """
 
 import hashlib
@@ -34,6 +44,11 @@ PARENT = {
     "phi.admit_rows": "32ad1762b4f0f5e8",
     "phi.grad": "05a842aa6f92e610",
     "phi.step_rows": "51240cd8f81fe970",
+    # at 350c6c9 (PR 36), the parent of PR 37
+    "kimi.admit_rows": "334f32fe7cfa91ad",
+    "kimi.step_rows": "e1e355cce2a334e4",
+    "commanda.admit_rows": "8548109614c9a831",
+    "commanda.step_rows": "54bbd7fab9606df0",
 }
 
 
@@ -52,7 +67,26 @@ def lowered(which: str) -> str:
             vocab_size=512, d_model=128, n_layers=2, n_heads=4,
             n_kv_heads=2, d_ff=256, max_seq=1024, attn_window=64,
             dtype=jnp.bfloat16),
+        "kimi": lambda: T.TransformerConfig(
+            vocab_size=512, d_model=128, n_layers=3, n_heads=4, d_ff=256,
+            max_seq=1024, dtype=jnp.bfloat16, remat=False, rms_eps=1e-5,
+            rope_base=50000.0,
+            rope_scaling=T.RopeYarn(64.0, 32.0, 1.0, 4096, 1.0, 1.0),
+            layer_kinds=("dense", "moe", "moe"),
+            latent=T.LatentAttention(48, 32, 32, 16, 32),
+            experts=T.SparseExperts(16, 4, 64, 2.827, 4, 8)),
+        "commanda": lambda: T.TransformerConfig(
+            vocab_size=512, d_model=128, n_layers=2, n_heads=8,
+            n_kv_heads=2, head_dim=16, max_seq=1024, dtype=jnp.bfloat16,
+            remat=False, rms_eps=1e-5, rope_base=50000.0, attn_window=64,
+            layer_kinds=("window_moe", "full_moe"),
+            experts=T.SparseExperts(16, 4, 64, first=4, held=8,
+                                    n_shared=2, shared_mean=True),
+            norm="layer", parallel_block=True, tie_embeddings=True,
+            logit_scale=0.5),
     }[model]
+    if callable(cfg):
+        cfg = cfg()
     slots = 4
     sds = jax.ShapeDtypeStruct
     params = jax.eval_shape(

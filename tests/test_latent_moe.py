@@ -164,10 +164,11 @@ def test_no_token_dropped_and_the_bias_picks_but_does_not_weigh():
         zp = jnp.take_along_axis(z, picks, -1)
         np.testing.assert_allclose(
             w, zp / zp.sum(-1, keepdims=True) * 2.5, rtol=1e-6)
-        out, landed, touched = jax.jit(
+        out, landed, touched, zeros = jax.jit(
             lambda h, p, w: moe.held_experts_ffn(
                 h, p, w, gate[:, 2:4], up[:, 2:4], down[:, 2:4], 0,
                 moe.HeldExperts(2, 2, e)))(h, picks, w)
+        assert zeros == 0                   # a layer without zero experts
         want = 0.0
         for ex in (2, 3):
             y = (jax.nn.silu(h @ gate[0, ex]) * (h @ up[0, ex])) @ down[0, ex]

@@ -584,4 +584,5 @@ def test_configuration_file_states_its_cut():
         "decode_bw_pct.serve", "wire_emit_ms.serve",
         "moe_experts_roofline.serve", "admit_device_share_pct.serve",
         "admit_device_ms.serve", "admit_attention_share_pct.serve",
-        "cached_attn_share_pct.serve"])
+        "cached_attn_share_pct.serve",
+        "moe_gmm_share_pct.serve"])         # PR 37's, read here too

@@ -53,7 +53,8 @@ from tony_tpu.ops.attention import (cached_attention, cached_attn_block,
                                     cached_attn_work, live_blocks)
 from tony_tpu.ops.norms import layer_norm_reference, rms_norm_reference
 from tony_tpu.parallel.moe import (HeldExperts, held_experts_ffn, moe_ffn,
-                                   shared_experts_ffn, sigmoid_route)
+                                   shared_experts_ffn, sigmoid_route,
+                                   softmax_route)
 from tony_tpu.parallel.sharding import (cache_heads_split,
                                         shard_cached_attention)
 
@@ -168,9 +169,11 @@ def cache_layout(cfg: T.TransformerConfig, max_len: int,
     """name → (layers, rows a slot, row width, dtype) of every position
     buffer. The dense decoder: its k/v (+ int8 scales), all layers, one
     row count (``kv_cache_capacity`` or ``max_len``). A model with
-    layer_kinds: each ATTENTION owns its buffers, indexed by the layer's
-    place among the layers that attend so
-    (``TransformerConfig.attention_of``), with its OWN row count —
+    layer_kinds: each ATTENTION owns its buffers, indexed by its place
+    among the attentions of that sort
+    (``TransformerConfig.attention_of``; a double layer runs two and
+    owns two consecutive indices, never one row-set for both halves),
+    with its OWN row count —
     ``latent``: ``ckv``, one compressed row [c_kv; k_rope] a token,
     ``max_len`` rows; ``full``: ``k`` / ``v``, ``max_len`` rows;
     ``window``: ``k_ring`` / ``v_ring``, :func:`ring_rows` rows written
@@ -281,10 +284,12 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
              in cache_layout(cfg, max_len, ring).items()}
     if cfg.experts is not None:
         # what the expert layers counted since the holding program began
-        # (assignments landed on held experts, held experts touched):
-        # state the 'moe' layers own, riding the cache through the layer
+        # (assignments landed on held experts, held experts touched, and
+        # — a model with zero experts — assignments that were one):
+        # state the routed layers own, riding the cache through the layer
         # loop; serve.step_rows / admit_rows hand it out with the tokens
-        cache[MOE_COUNTS] = jnp.zeros((2,), jnp.int32)
+        cache[MOE_COUNTS] = jnp.zeros((3 if cfg.experts.n_zero else 2,),
+                                      jnp.int32)
     return dict(cache, length=jnp.zeros((), jnp.int32))
 
 
@@ -732,11 +737,19 @@ def cache_rows_visited(cfg: T.TransformerConfig, max_len: int,
     own (:func:`live_blocks`), the walk every slot's from the oldest
     window's first block to the longest row's last, the dense reads the
     whole buffer. live / read is how far the read follows the rows. The
-    kinds are :func:`cache_bytes_by_kind`'s that hold K and V (the
-    latent read is not these functions': it has no count)."""
+    kinds are :func:`cache_bytes_by_kind`'s; ``latent`` is always the
+    walk (:func:`_latent_cached_attention`: every slot to the longest
+    row's last block), summed over its attentions — two a double
+    layer."""
     pos = np.asarray(pos, np.int64)
     quant = cfg.kv_cache_dtype == "int8"
     layout, out = cache_layout(cfg, max_len), {}
+    if "ckv" in layout:
+        attentions, rows = layout["ckv"][:2]
+        block = min(DECODE_BLOCK, rows)
+        read = (pos.max(axis=0, initial=0) + block) // block * block
+        out["latent"] = (attentions * int(read.sum()) * pos.shape[0],
+                         attentions * int((pos + 1).sum()))
     for name in ("k", "k_ring"):                    # a row's V is its K's
         if name not in layout:
             continue
@@ -971,15 +984,28 @@ def _layer_params(params: dict, cfg: T.TransformerConfig, li: int) -> dict:
     if not cfg.kinded:
         return jax.tree.map(lambda a: a[li], params["blocks"])
     kind, i = cfg.kind_index(li)
-    group = params["blocks"][kind]
-    if T.LAYER_KINDS[kind][1] != "moe":
+    group, ffn = params["blocks"][kind], T.LAYER_KINDS[kind][1]
+    if ffn not in T._ROUTED_FFNS:
         return jax.tree.map(lambda a: a[i], group)
     # the routed experts go to their kernel STACKED, with the layer's
     # index: a Mosaic call takes whole buffers, so a sliced layer would
     # be copied out (1 GB a matrix at 12 x 7168 x 2048) in every step
-    p = {n: jax.tree.map(lambda a: a[i], a) for n, a in group.items()
-         if n not in _ROUTED}
-    return dict(p, routed=tuple(group[n] for n in _ROUTED), routed_layer=i)
+    routed = dict(routed=tuple(group[n] for n in _ROUTED), routed_layer=i)
+    if ffn != "scmoe":
+        p = {n: jax.tree.map(lambda a: a[i], a) for n, a in group.items()
+             if n not in _ROUTED}
+        return dict(p, **routed)
+    # a double layer: each half's leaves ([layers, 2, ...]) are cut
+    # [layer, half] in ONE step, the dense SwiGLU under the names _mlp
+    # reads — a layer's slice with both halves in it has two readers and
+    # is copied out whole (0.3 GB a matrix at 2 x 6144 x 12288, 2.1 GB of
+    # temporaries a decode chunk: described-chip compile, PR 37)
+    shared = ("router", "router_bias") + _ROUTED
+    halves = tuple(
+        {T._HALF_MLP.get(n, n): jax.tree.map(lambda a: a[i, h], w)
+         for n, w in group.items() if n not in shared} for h in range(2))
+    return dict(routed, halves=halves, router=group["router"][i],
+                router_bias=group["router_bias"][i])
 
 
 # ---------------------------------------------------------------------------
@@ -1000,16 +1026,23 @@ def _latent_qkv(h, p, cfg: T.TransformerConfig, rope):
     stored_row]): ``row`` is what the cache stores, the normed
     compressed c_kv beside the ONE rotated key head every query head
     shares, then zeros up to whole lane tiles
-    (``LatentAttention.stored_row``)."""
+    (``LatentAttention.stored_row``). Where the model scales its normed
+    bottlenecks (``q_scale``, ``kv_scale``) the constants go on here,
+    so the stored row carries ``kv_scale`` and both reads take it as it
+    is; the rotary key head is not scaled."""
     la = cfg.latent
     cos, sin = rope
     cq = rms_norm_reference(_weinsum("bsd,dr->bsr", h, p["wq_a"]),
                             p["q_norm"], cfg.rms_eps)
+    if la.q_scale != 1.0:
+        cq = (cq * la.q_scale).astype(cq.dtype)
     q = _weinsum("bsr,rhk->bshk", cq, p["wq_b"])
     q_n = q[..., :la.nope_dim]
     q_r = T.apply_rope(q[..., la.nope_dim:], cos, sin)
     kv = _weinsum("bsd,dr->bsr", h, p["wkv_a"])
     c = rms_norm_reference(kv[..., :la.kv_rank], p["kv_norm"], cfg.rms_eps)
+    if la.kv_scale != 1.0:
+        c = (c * la.kv_scale).astype(c.dtype)
     k_r = T.apply_rope(kv[:, :, None, la.kv_rank:], cos, sin)
     tail = jnp.zeros(k_r.shape[:-1] + (la.stored_row - la.row,), k_r.dtype)
     return q_n, q_r, jnp.concatenate([c[:, :, None, :], k_r, tail], axis=-1)
@@ -1122,32 +1155,39 @@ def _latent_cached_attention(q_n, q_r, buf, li, q_start, p,
 
 
 def _sparse_mlp(h, p, cfg: T.TransformerConfig, live=None):
-    """Sigmoid-routed experts beside the shared ones, on [B, S, D]: the
-    held experts' part of the routed sum (dropless,
+    """The routed block (``cfg.experts``) on [B, S, D]: the held experts'
+    part of the routed sum and the zero experts' term (dropless,
     :func:`tony_tpu.parallel.moe.held_experts_ffn`) plus the whole shared
-    experts (:func:`tony_tpu.parallel.moe.shared_experts_ffn`). ``live``
+    experts where the model has any
+    (:func:`tony_tpu.parallel.moe.shared_experts_ffn`). ``live``
     [B, S] bool: positions that hold a real token — a
     prompt's padding is not routed (its output is never read, and every
     padded position carries the same token, so on a seed whose token 0
     picks held experts a 32 x 512 prefill would land 16k rows on them).
-    Returns (out, counts [2] int32: assignments landed here, held
-    experts touched)."""
+    Returns (out, counts int32: assignments landed here, held experts
+    touched and — a model with zero experts — assignments that were
+    one)."""
     e = cfg.experts
     b, s, d = h.shape
     flat = h.reshape(b * s, d)
+    route = softmax_route if e.route == "softmax" else sigmoid_route
     with jax.named_scope("moe_route"):
-        picks, w = sigmoid_route(flat, p["router"], p["router_bias"],
-                                 e.top_k, e.scale)
-    routed, landed, touched = held_experts_ffn(
+        picks, w = route(flat, p["router"], p["router_bias"], e.top_k,
+                         e.scale)
+    routed, landed, touched, zeros = held_experts_ffn(
         flat, picks, w, *p["routed"], p["routed_layer"],
-        HeldExperts(e.first, e.n_held, e.total),
+        HeldExperts(e.first, e.n_held, e.total, e.n_zero),
         live=None if live is None else live.reshape(b * s))
-    with jax.named_scope("moe_shared"):
-        shared = shared_experts_ffn(h, p["shared_gate"], p["shared_up"],
-                                    p["shared_down"], e.shared_mean,
-                                    matmul=_weinsum)
-    out = (routed.reshape(b, s, d) + shared).astype(h.dtype)
-    return out, jnp.stack([landed, touched])
+    if e.n_shared:
+        with jax.named_scope("moe_shared"):
+            shared = shared_experts_ffn(
+                h, p["shared_gate"], p["shared_up"], p["shared_down"],
+                e.shared_mean, matmul=_weinsum)
+        out = routed.reshape(b, s, d) + shared
+    else:
+        out = routed.reshape(b, s, d)
+    counts = [landed, touched] + ([zeros] if e.n_zero else [])
+    return out.astype(h.dtype), jnp.stack(counts)
 
 
 def _kinded_ffn(x, h, a, p, cfg: T.TransformerConfig, bufs: dict,
@@ -1185,6 +1225,88 @@ def _gqa_qkv(h, p, rope, barrier: bool = False):
 _KIND_KV = {"window": ("k_ring", "v_ring"), "full": ("k", "v")}
 
 
+def _latent_decode_attention(h, p, bufs, ai, pos, cfg, rope, window,
+                             scope: str = "mla_attention"):
+    """Latent attention of a decode chunk on normed ``h``: the rows
+    written to ``ckv`` at index ``ai`` through the caller's write mode,
+    then read in the absorbed form. Returns (attention output after
+    ``wo``, bufs)."""
+    with jax.named_scope(scope):
+        q_n, q_r, row = _latent_qkv(h, p, cfg, rope["latent"])
+        with jax.named_scope("cache_write"):
+            bufs = dict(bufs, ckv=_write_kv_chunk(bufs["ckv"], row, ai,
+                                                  pos, window))
+        with jax.named_scope("cached_attention"):
+            o = _latent_cached_attention(q_n, q_r, bufs["ckv"], ai, pos,
+                                         p, cfg)
+        return _weinsum("bshk,hkd->bsd", o, p["wo"]), bufs
+
+
+def _latent_prompt(h, p, cfg, rope, scope: str = "mla_attention"):
+    """Latent attention of a (padded) prompt on normed ``h``, expanded.
+    Returns (attention output after ``wo``, the rows the cache stores)."""
+    with jax.named_scope(scope):
+        q_n, q_r, row = _latent_qkv(h, p, cfg, rope["latent"])
+        o = _latent_prompt_attention(q_n, q_r, row, p, cfg)
+        return _weinsum("bshk,hkd->bsd", o, p["wo"]), row
+
+
+def _scmoe_block(x, p, bufs, cfg, attend, live=None):
+    """The double layer with its shortcut-connected routed block, on the
+    stream x [B, S, D] (``p``: :func:`_layer_params`' — the halves'
+    leaves under ``halves``); ``attend(i, h, half, bufs) -> (a, bufs)`` is
+    half ``i``'s attention on its normed input (a decode chunk's or a
+    prompt's). With ``A_i`` / ``F_i`` the halves' attention and dense
+    SwiGLU and ``M`` the routed block:
+
+        x1 = x  + A_0(norm(x))
+        h1 = norm(x1)
+        m  = M(h1)              # reads the FIRST half's normed stream
+        x2 = x1 + F_0(h1)
+        x3 = x2 + A_1(norm(x2))
+        y  = x3 + F_1(norm(x3)) + m     # lands after the SECOND half
+
+    ``M`` is issued where ``h1`` exists: in an expert-parallel deployment
+    its exchange runs under ``F_0``, ``A_1`` and ``F_1``; on one chip the
+    compiler orders it, and nothing stands in for the exchange."""
+    m = None
+    for i, half in enumerate(p["halves"]):
+        a, bufs = attend(i, _norm(x, half["attn_norm"], cfg), half, bufs)
+        x = x + a
+        h = _norm(x, half["mlp_norm"], cfg)
+        if i == 0:
+            m, counts = _sparse_mlp(h, p, cfg, live)
+            bufs = dict(bufs, **{MOE_COUNTS: bufs[MOE_COUNTS] + counts})
+        with jax.named_scope(f"mlp_{i}"):
+            x = x + _mlp(h, half, cfg)
+    return x + m, bufs
+
+
+def _scmoe_decode_block(x, p, bufs, ai, pos, cfg, rope, window):
+    """A decode chunk through a double layer: each half writes its rows
+    to ``ckv`` at its own index (``ai``, ``ai + 1``) and reads them in
+    the absorbed form, through the paths every latent kind uses."""
+    pos = jnp.asarray(pos)
+
+    def attend(i, h, half, bufs):
+        return _latent_decode_attention(h, half, bufs, ai + i, pos, cfg,
+                                        rope, window, f"mla_attention_{i}")
+    return _scmoe_block(x, p, bufs, cfg, attend)
+
+
+def _scmoe_prompt_block(x, p, bufs, ai, cfg, rope, s, live):
+    """A (padded) prompt through a double layer: expanded latent
+    attention in each half, rows [0, s) of each written linearly at its
+    own index; only the ``live`` positions are routed."""
+    def attend(i, h, half, bufs):
+        a, row = _latent_prompt(h, half, cfg, rope, f"mla_attention_{i}")
+        with jax.named_scope("cache_write"):
+            return a, dict(bufs, ckv=_write_kv_chunk(
+                bufs["ckv"], row[:, :s], ai + i, jnp.asarray(0, jnp.int32),
+                None))
+    return _scmoe_block(x, p, bufs, cfg, attend, live)
+
+
 def _kinded_decode_block(x, p, bufs, li, pos, cfg, rope, window=None):
     """:func:`_decode_block` for a layer of a model with layer_kinds:
     the chunk's rows go through the SAME cache write paths into the
@@ -1196,18 +1318,13 @@ def _kinded_decode_block(x, p, bufs, li, pos, cfg, rope, window=None):
     through ``tony_cached_attn``, each slot's own live blocks. Returns
     (x, bufs)."""
     attention, ai = cfg.attention_of(li)
+    if T.LAYER_KINDS[cfg.layer_kinds[li]][1] == "scmoe":
+        return _scmoe_decode_block(x, p, bufs, ai, pos, cfg, rope, window)
     h = _norm(x, p["attn_norm"], cfg)
     pos = jnp.asarray(pos)
     if attention == "latent":
-        with jax.named_scope("mla_attention"):
-            q_n, q_r, row = _latent_qkv(h, p, cfg, rope[attention])
-            with jax.named_scope("cache_write"):
-                bufs = dict(bufs, ckv=_write_kv_chunk(bufs["ckv"], row, ai,
-                                                      pos, window))
-            with jax.named_scope("cached_attention"):
-                o = _latent_cached_attention(q_n, q_r, bufs["ckv"], ai, pos,
-                                             p, cfg)
-            a = _weinsum("bshk,hkd->bsd", o, p["wo"])
+        a, bufs = _latent_decode_attention(h, p, bufs, ai, pos, cfg, rope,
+                                           window)
         return _kinded_ffn(x, h, a, p, cfg, bufs)
     nk, nv = _KIND_KV[attention]
     with jax.named_scope("attn_" + attention):
@@ -1243,12 +1360,11 @@ def _kinded_prompt_block(x, p, bufs, li, cfg, rope, s, live):
     :func:`place_rows` lands a ``window`` kind's in its ring); only the
     ``live`` positions are routed."""
     attention, ai = cfg.attention_of(li)
+    if T.LAYER_KINDS[cfg.layer_kinds[li]][1] == "scmoe":
+        return _scmoe_prompt_block(x, p, bufs, ai, cfg, rope, s, live)
     h = _norm(x, p["attn_norm"], cfg)
     if attention == "latent":
-        with jax.named_scope("mla_attention"):
-            q_n, q_r, row = _latent_qkv(h, p, cfg, rope[attention])
-            o = _latent_prompt_attention(q_n, q_r, row, p, cfg)
-            a = _weinsum("bshk,hkd->bsd", o, p["wo"])
+        a, row = _latent_prompt(h, p, cfg, rope)
         writes = {"ckv": row}
     else:
         with jax.named_scope("attn_" + attention):

@@ -129,9 +129,14 @@ def quantize_weights_int8(params: dict) -> dict:
         blocks = {}
         for kind, group in params["blocks"].items():
             axes = dict(_KINDED_AXES)
-            if "router" not in group:       # the dense SwiGLU
+            if "mlp_gate" in group:
+                # a double layer: each half's leaves behind an axis of 2
+                axes = {n: tuple(a + 1 for a in ax)
+                        for n, ax in axes.items()}
+                axes.update(mlp_gate=(2,), mlp_up=(2,), mlp_down=(2,))
+            elif "router" not in group:     # the dense SwiGLU
                 axes.update(w_gate=(1,), w_up=(1,), w_down=(1,))
-            elif group["shared_gate"].ndim == 4:
+            if "shared_gate" in group and group["shared_gate"].ndim == 4:
                 axes.update(_SHARED_STACK_AXES)
             blocks[kind] = {n: _quantize(w, axes[n]) if n in axes else w
                             for n, w in group.items()}

@@ -310,9 +310,10 @@ def admit_rows(params, cache, logits, rows, prompts, lengths, cfg):
 def _device_stats(cache: dict) -> dict:
     """What the program counted on the device, as it leaves with the
     tokens: the expert layers' ``[assignments landed on held experts,
-    held experts touched]`` (summed over layers; a decode chunk's idle
-    slots included — they are routed like any other — a prompt's padding
-    not) for a model with experts,
+    held experts touched]`` and, where the model has zero experts,
+    ``assignments that were one`` (summed over layers; a decode chunk's
+    idle slots included — they are routed like any other — a prompt's
+    padding not) for a model with experts,
     NOTHING for one without: an empty pytree adds no output, so the dense
     programs lower to the HLO they always had."""
     return {MOE_COUNTS: cache[MOE_COUNTS]} if MOE_COUNTS in cache else {}
@@ -856,6 +857,9 @@ class ContinuousBatcher:
         #: other); a prompt's padding is not routed.
         self.moe_assignments = {"decode": 0, "admit": 0}
         self.moe_touches = {"decode": 0, "admit": 0}
+        #: (token, pick) assignments that were a ZERO expert (the
+        #: identity: no weight read, no product); 0 for a model without
+        self.moe_zero_assignments = {"decode": 0, "admit": 0}
         #: bytes of the cache by the kind of state that owns them
         #: (decode.cache_bytes_by_kind): a window kind's ring beside a
         #: full kind's max_len rows
@@ -882,8 +886,8 @@ class ContinuousBatcher:
         #: under their masks, by the kind of state, summed over its
         #: layers (decode.cache_rows_visited, at each chunk's issue; an
         #: idle slot counts as the device reads it). live / read is how
-        #: far the cached read follows each row's own length (the kinds
-        #: that hold K and V: a latent cache has none)
+        #: far the cached read follows each row's own length (a latent
+        #: cache's read is the walk to the longest row for every slot)
         kinds = cache_rows_visited(cfg, max_len,
                                    np.zeros((batch, 0), np.int64))
         self.cache_rows_read = dict.fromkeys(kinds, 0)
@@ -1358,10 +1362,11 @@ class ContinuousBatcher:
         fetch."""
         while self._device_stats:
             where, stats = self._device_stats.popleft()
-            landed, touched = (int(v) for v in
-                               np.asarray(stats[MOE_COUNTS]))
+            landed, touched, *zeros = (int(v) for v in
+                                       np.asarray(stats[MOE_COUNTS]))
             self.moe_assignments[where] += landed
             self.moe_touches[where] += touched
+            self.moe_zero_assignments[where] += sum(zeros)
             if where == "decode":
                 break
 
@@ -1909,9 +1914,18 @@ class ServeEngine:
                                 "on experts held here"),
                 ("expert_touches", "(layer, held expert) pairs with at "
                                    "least one assignment: the expert "
-                                   "weights a program had to read"))
+                                   "weights a program had to read"),
+                ("zero_assignments", "(token, pick) assignments that "
+                                     "were a zero expert: the identity, "
+                                     "no weight read"))
         } if batcher.cfg.experts is not None else {}
         self._moe_seen = {k: 0 for k in self._moe_c}
+        #: the batcher's running totals behind each counter (dicts it
+        #: updates in place)
+        self._moe_totals = {
+            "assignments": batcher.moe_assignments,
+            "expert_touches": batcher.moe_touches,
+            "zero_assignments": batcher.moe_zero_assignments}
         for kind, n in batcher.cache_bytes.items():
             reg.gauge("tony_cache_bytes", kind=kind,
                       help="bytes of the KV cache's position buffers, by "
@@ -2163,6 +2177,7 @@ class ServeEngine:
                 # (zeros for a model without experts)
                 "moe_assignments": dict(self.b.moe_assignments),
                 "moe_expert_touches": dict(self.b.moe_touches),
+                "moe_zero_assignments": dict(self.b.moe_zero_assignments),
                 # the cache by the kind of state that owns it, and what
                 # the rings overwrote (0 without a ring)
                 "cache_bytes": dict(self.b.cache_bytes),
@@ -2437,8 +2452,7 @@ class ServeEngine:
         with self.b.phase_times.phase("consume"):
             self._consume_chunk(host_toks, snap)
         for (what, program), c in self._moe_c.items():
-            total = (self.b.moe_assignments if what == "assignments"
-                     else self.b.moe_touches)[program]
+            total = self._moe_totals[what][program]
             c.inc(total - self._moe_seen[(what, program)])
             self._moe_seen[(what, program)] = total
         for (what, kind), c in self._rows_c.items():

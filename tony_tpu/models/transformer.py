@@ -88,6 +88,10 @@ class LatentAttention:
     nope_dim: int
     rope_dim: int
     v_dim: int
+    # constants on the normed bottlenecks (``c_q * q_scale``, ``c_kv *
+    # kv_scale``; the cache stores the scaled c_kv): 1.0 = none
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @property
     def row(self) -> int:
@@ -111,15 +115,23 @@ class LatentAttention:
 
 @dataclasses.dataclass(frozen=True)
 class SparseExperts:
-    """Sigmoid-routed SwiGLU experts beside shared ones: every token
-    scores all ``total`` experts, picks ``top_k`` by score + selection
-    bias, and weighs them by score normalised over the pick x ``scale``.
-    THIS program holds experts ``[first, first + held)`` — one rank's
-    share of an expert-parallel layer — and computes their part
-    (:func:`tony_tpu.parallel.moe.held_experts_ffn`); ``held == total``
-    is the whole layer. ``d_expert``: width of one routed expert and of
-    each shared one. ``n_shared`` shared experts run whole for every
-    token, their outputs summed, or averaged where ``shared_mean``
+    """Routed SwiGLU experts, beside shared ones where there are any:
+    every token scores all ``total`` experts — and ``n_zero`` more that
+    have no weights —, picks ``top_k`` by score + selection bias, and
+    weighs them by ``route``: ``"sigmoid"`` scores normalised over the
+    pick x ``scale`` (:func:`tony_tpu.parallel.moe.sigmoid_route`), or
+    ``"softmax"`` over all ``total + n_zero`` scores x ``scale``, NOT
+    renormalised (:func:`tony_tpu.parallel.moe.softmax_route`). A pick
+    is of one of three classes: a routed expert HELD here — THIS
+    program holds ``[first, first + held)``, one rank's share of an
+    expert-parallel layer, and computes their part
+    (:func:`tony_tpu.parallel.moe.held_experts_ffn`; ``held == total``
+    is the whole layer) —, a routed expert held elsewhere, whose part
+    is left out, or a ZERO expert in ``[total, total + n_zero)``: the
+    identity, ``w x h``, no weight read and no product. ``d_expert``:
+    width of one routed expert and of each shared one. ``n_shared``
+    shared experts (0: none) run whole for every token, their outputs
+    summed, or averaged where ``shared_mean``
     (:func:`tony_tpu.parallel.moe.shared_experts_ffn`); one is a [d, f]
     leaf, several a [n_shared, d, f] stack."""
     total: int
@@ -130,10 +142,17 @@ class SparseExperts:
     held: int | None = None
     n_shared: int = 1
     shared_mean: bool = False
+    route: str = "sigmoid"
+    n_zero: int = 0
 
     @property
     def n_held(self) -> int:
         return self.total if self.held is None else self.held
+
+    @property
+    def n_scored(self) -> int:
+        """The router's outputs: the routed experts, then the zero ones."""
+        return self.total + self.n_zero
 
 
 #: layer kinds of a model with a ``layer_kinds`` list: name -> (the
@@ -145,14 +164,28 @@ class SparseExperts:
 #: over the whole context and NO positional rotation — the interleaved
 #: local/global convention, where the window layers carry the
 #: positions: its state is ``max_len`` rows a slot). Feed-forward:
-#: ``dense`` (a SwiGLU of ``d_ff``) or ``moe`` (:class:`SparseExperts`).
-#: Each attention owns the cache buffers it writes, with its OWN row
-#: count (models/decode.py, ``cache_layout``).
+#: ``dense`` (a SwiGLU of ``d_ff``), ``moe`` (:class:`SparseExperts`), or
+#: ``scmoe``: the DOUBLE layer with a shortcut-connected expert block —
+#: two (attention, dense SwiGLU) halves in sequence, each attention
+#: with its own rows of the cache, and ONE routed block that reads the
+#: first half's normed stream and joins the stream after the second
+#: half's SwiGLU (models/decode.py, ``_scmoe_decode_block``). Each
+#: attention owns the cache buffers it writes, with its OWN row count
+#: (models/decode.py, ``cache_layout``).
 LAYER_KINDS = {
     "dense": ("latent", "dense"), "moe": ("latent", "moe"),
     "window_dense": ("window", "dense"), "window_moe": ("window", "moe"),
     "full_dense": ("full", "dense"), "full_moe": ("full", "moe"),
+    "latent2_scmoe": ("latent", "scmoe"),
 }
+#: feed-forwards that route through :class:`SparseExperts`
+_ROUTED_FFNS = ("moe", "scmoe")
+
+
+def kind_attentions(kind: str) -> int:
+    """Attentions a layer of ``kind`` runs: the sets of cache rows it
+    owns (2 for the double layer, 1 otherwise)."""
+    return 2 if LAYER_KINDS[kind][1] == "scmoe" else 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -316,19 +349,20 @@ class TransformerConfig:
                     f"{self.layer_kinds}")
             attns = set(self.attentions)
             ffns = {LAYER_KINDS[k][1] for k in self.layer_kinds}
+            routed = bool(ffns & set(_ROUTED_FFNS))
             if ("latent" in attns and self.latent is None) or (
-                    "moe" in ffns and self.experts is None):
+                    routed and self.experts is None):
                 raise ValueError(
                     "a model with layer_kinds attends through `latent` "
                     "(LatentAttention) in its latent kinds and its 'moe' "
-                    "kinds route through `experts` (SparseExperts): set "
-                    "both where both are listed")
+                    "and 'scmoe' kinds route through `experts` "
+                    "(SparseExperts): set both where both are listed")
             if "window" in attns and not self.attn_window:
                 raise ValueError("a 'window' kind attends inside "
                                  "`attn_window`: set it")
             for what, on, need in (
                     ("latent", self.latent is not None, "latent" in attns),
-                    ("experts", self.experts is not None, "moe" in ffns),
+                    ("experts", self.experts is not None, routed),
                     ("attn_window", self.attn_window, "window" in attns)):
                 if on and not need:
                     raise ValueError(
@@ -341,13 +375,23 @@ class TransformerConfig:
             e = self.experts
             if e is not None and not (0 <= e.first and 0 < e.n_held
                                       and e.first + e.n_held <= e.total
-                                      and 0 < e.top_k <= e.total
-                                      and 0 < e.n_shared):
+                                      and 0 <= e.n_zero
+                                      and 0 < e.top_k <= e.n_scored
+                                      and 0 <= e.n_shared):
                 raise ValueError(
                     f"experts [{e.first}, {e.first + e.n_held}) must lie "
-                    f"inside the {e.total} the router scores, top_k "
-                    f"{e.top_k} among them, beside {e.n_shared} >= 1 "
-                    f"shared")
+                    f"inside the {e.total} routed experts the router "
+                    f"scores (beside {e.n_zero} >= 0 zero experts), top_k "
+                    f"{e.top_k} among them, beside {e.n_shared} >= 0 "
+                    f"shared (0: none)")
+            if e is not None and e.route not in ("sigmoid", "softmax"):
+                raise ValueError(f"unknown experts.route {e.route!r}; "
+                                 f"expected 'sigmoid' or 'softmax'")
+            if "scmoe" in ffns and self.parallel_block:
+                raise ValueError(
+                    "parallel_block has no meaning in a 'scmoe' layer: "
+                    "its routed block already reads the first half's "
+                    "norm and lands after the second half")
             if self.norm not in ("rms", "layer"):
                 raise ValueError(f"unknown norm {self.norm!r}; expected "
                                  f"'rms' or 'layer'")
@@ -386,16 +430,23 @@ class TransformerConfig:
         return tuple(LAYER_KINDS[k][0] for k in self.layer_kinds)
 
     def attention_of(self, li: int) -> tuple[str, int]:
-        """(attention of layer ``li``, its index among the layers that
-        attend so: the layer's index in the cache buffers that attention
-        owns)."""
+        """(attention of layer ``li``, the index of its FIRST attention
+        among the attentions of that sort: its index in the cache
+        buffers that attention owns). A double layer's second half
+        follows at the next index (:func:`kind_attentions`)."""
         attns = self.attentions
-        return attns[li], attns[:li].count(attns[li])
+        return attns[li], sum(
+            kind_attentions(k) for k, a in zip(self.layer_kinds[:li], attns)
+            if a == attns[li])
 
     def attention_layers(self) -> dict[str, int]:
-        """attention -> how many layers attend so, in first-use order."""
-        attns = self.attentions
-        return {a: attns.count(a) for a in dict.fromkeys(attns)}
+        """attention -> how many ATTENTIONS attend so (the leading axis
+        of the buffers it owns: a double layer counts two), in first-use
+        order."""
+        out: dict[str, int] = {}
+        for kind, a in zip(self.layer_kinds, self.attentions):
+            out[a] = out.get(a, 0) + kind_attentions(kind)
+        return out
 
     def refuse(self, what: str) -> None:
         """Raise where ``what`` (a serving or training feature) cannot
@@ -509,6 +560,8 @@ def kinded_block_shapes(cfg: TransformerConfig, kind: str) -> dict:
     and ``logical_axes`` stack it per kind."""
     d, h = cfg.d_model, cfg.n_heads
     attention, ffn = LAYER_KINDS[kind]
+    if ffn == "scmoe":
+        return _scmoe_block_shapes(cfg)
     leaves = {"attn_norm": ((d,), None, ("norm",))}
     if attention == "latent":
         la = cfg.latent
@@ -543,21 +596,51 @@ def kinded_block_shapes(cfg: TransformerConfig, kind: str) -> dict:
                        "w_up": ((d, f), d, ("embed", "mlp")),
                        "w_down": ((f, d), f, ("mlp", "embed"))})
         return leaves
-    e, f = cfg.experts, cfg.experts.d_expert
-    # one shared expert is a [d, f] leaf, several a [n_shared, d, f] stack
-    ns = () if e.n_shared == 1 else (e.n_shared,)
-    nax = () if e.n_shared == 1 else (None,)
-    leaves.update({
+    leaves.update(_routed_block_shapes(cfg))
+    return leaves
+
+
+def _routed_block_shapes(cfg: TransformerConfig) -> dict:
+    """The leaves of a routed block (``cfg.experts``): router, selection
+    bias, the held experts, and the shared ones where there are any."""
+    d, e, f = cfg.d_model, cfg.experts, cfg.experts.d_expert
+    leaves = {
         # router and selection bias stay float32: a pick compares scores
-        "router": ((d, e.total), d, ("embed", None)),
-        "router_bias": ((e.total,), None, (None,)),
+        "router": ((d, e.n_scored), d, ("embed", None)),
+        "router_bias": ((e.n_scored,), None, (None,)),
         "w_gate": ((e.n_held, d, f), d, ("expert", "embed", "mlp")),
         "w_up": ((e.n_held, d, f), d, ("expert", "embed", "mlp")),
         "w_down": ((e.n_held, f, d), f, ("expert", "mlp", "embed")),
-        "shared_gate": (ns + (d, f), d, nax + ("embed", "mlp")),
-        "shared_up": (ns + (d, f), d, nax + ("embed", "mlp")),
-        "shared_down": (ns + (f, d), f, nax + ("mlp", "embed")),
-    })
+    }
+    if e.n_shared:
+        # one shared expert is a [d, f] leaf, several a [n_shared, d, f]
+        # stack
+        ns = () if e.n_shared == 1 else (e.n_shared,)
+        nax = () if e.n_shared == 1 else (None,)
+        leaves.update({
+            "shared_gate": (ns + (d, f), d, nax + ("embed", "mlp")),
+            "shared_up": (ns + (d, f), d, nax + ("embed", "mlp")),
+            "shared_down": (ns + (f, d), f, nax + ("mlp", "embed")),
+        })
+    return leaves
+
+
+#: the dense SwiGLU of a double layer's half, under names of its own: the
+#: routed experts of the same layer are ``w_gate`` / ``w_up`` / ``w_down``
+_HALF_MLP = {"mlp_gate": "w_gate", "mlp_up": "w_up", "mlp_down": "w_down"}
+
+
+def _scmoe_block_shapes(cfg: TransformerConfig) -> dict:
+    """The double layer (kind ``latent2_scmoe``): the leaves of a
+    ``dense`` layer — latent attention, its norms, a SwiGLU of ``d_ff``
+    — for each of the two halves, stacked on a leading axis of 2 (the
+    SwiGLU as ``mlp_gate`` / ``mlp_up`` / ``mlp_down``), beside the ONE
+    routed block's."""
+    half = kinded_block_shapes(cfg, "dense")
+    back = {v: k for k, v in _HALF_MLP.items()}
+    leaves = {back.get(n, n): ((2,) + shape, fan_in, (None,) + axes)
+              for n, (shape, fan_in, axes) in half.items()}
+    leaves.update(_routed_block_shapes(cfg))
     return leaves
 
 

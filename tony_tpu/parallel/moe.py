@@ -151,10 +151,13 @@ def moe_ffn_manual(x: jax.Array, router_w: jax.Array, w_in_local: jax.Array,
 
 class HeldExperts(NamedTuple):
     """Which of a layer's routed experts live here: ``[first, first +
-    held)`` of ``total``. The router keeps its ``total`` outputs."""
+    held)`` of ``total``. The router keeps its ``total`` outputs, and
+    ``n_zero`` more for experts that compute nothing: picks in ``[total,
+    total + n_zero)``."""
     first: int
     held: int
     total: int
+    n_zero: int = 0
 
 
 def sigmoid_route(h: jax.Array, router_w: jax.Array, bias: jax.Array,
@@ -173,6 +176,40 @@ def sigmoid_route(h: jax.Array, router_w: jax.Array, bias: jax.Array,
     w = jnp.take_along_axis(z, picks, axis=-1)
     w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
     return picks.astype(jnp.int32), w
+
+
+def softmax_route(h: jax.Array, router_w: jax.Array, bias: jax.Array,
+                  top_k: int, scale: float):
+    """Softmax routing with a selection bias, over ALL the router's
+    outputs (the routed experts, then the zero ones). h: [T, D];
+    router_w: [D, E] and bias: [E], both float32. Returns (picks [T, k]
+    int32, weights [T, k] float32): the ``top_k`` largest of ``z + bias``
+    with ``z = softmax(h @ router_w)`` over all E — the bias steers the
+    pick and nothing else — weighted by ``z x scale``, NOT renormalised
+    over the pick: what the unpicked experts scored stays out of the
+    sum. Float32 for :func:`sigmoid_route`'s reason."""
+    z = jax.nn.softmax(jnp.einsum(
+        "td,de->te", h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    _, picks = lax.top_k(z + bias.astype(jnp.float32), top_k)
+    return (picks.astype(jnp.int32),
+            jnp.take_along_axis(z, picks, axis=-1) * scale)
+
+
+def zero_experts_term(h: jax.Array, picks: jax.Array, weights: jax.Array,
+                      total: int, live=None):
+    """What a token's ZERO experts give: each is the identity, so ``(sum
+    of the weights of its picks >= total) x h`` — float32, no gather, no
+    product with a weight. h: [T, D]; picks, weights: [T, k]; ``live``
+    [T] bool as :func:`held_experts_ffn`'s. Returns (out [T, D] float32,
+    the count of (token, pick) pairs that are zero experts)."""
+    with jax.named_scope("moe_zero"):
+        zero = picks >= total
+        if live is not None:
+            zero = zero & live[:, None]
+        w = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1)
+        return (w[:, None] * h.astype(jnp.float32),
+                zero.sum(dtype=jnp.int32))
 
 
 def shared_experts_ffn(h: jax.Array, gate, up, down, mean: bool = False,
@@ -207,11 +244,16 @@ _ONE_HOT_MAX_TOKENS = 256
 def held_experts_ffn(h: jax.Array, picks: jax.Array, weights: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                      layer: int, share: HeldExperts, live=None):
-    """The routed part of a sparse SwiGLU layer that THIS chip's experts
-    give: ``sum over picked e in [first, first + held) of w_e *
-    SwiGLU_e(h)``; what the absent experts would add is left out.
-    Dropless: every assignment that lands on a held expert is computed,
-    none is padded to a capacity and none is dropped.
+    """The routed part of a sparse SwiGLU layer that THIS chip gives. A
+    pick is of one of three classes: a HELD expert, ``e in [first, first
+    + held)`` — ``w_e * SwiGLU_e(h)``, computed here; an ABSENT routed
+    expert (any other ``e < total``) — left out, here as on the rank
+    that holds it nothing stands in; a ZERO expert, ``e in [total, total
+    + n_zero)`` — the identity, ``w_e * h``, computed whole for this
+    chip's own tokens (:func:`zero_experts_term`: it needs no weights,
+    so it never leaves the token's rank). Dropless: every assignment
+    that lands on a held expert is computed, none is padded to a
+    capacity and none is dropped.
 
     h: [T, D]; picks, weights: [T, k] (:func:`sigmoid_route`);
     w_gate, w_up: [layers, held, D, F]; w_down: [layers, held, F, D] —
@@ -220,8 +262,10 @@ def held_experts_ffn(h: jax.Array, picks: jax.Array, weights: jax.Array,
     Mosaic call would first be copied out whole. ``live`` [T] bool: the
     tokens to route at all (None: every one) — a prompt's padding lands
     nowhere. Returns (out [T, D]
-    float32, assignments, touched): the count of (token, pick) pairs that
-    landed here and of held experts with at least one.
+    float32, assignments, touched, zeros): the count of (token, pick)
+    pairs that landed on a held expert, of held experts with at least
+    one, and of pairs that are zero experts (the int 0 for a layer
+    without any: it traces nothing).
 
     The assignments are sorted by expert and each expert's rows padded to
     whole tiles (:mod:`tony_tpu.ops.grouped_matmul`), so the products cost
@@ -308,4 +352,9 @@ def held_experts_ffn(h: jax.Array, picks: jax.Array, weights: jax.Array,
         n_chunks = (pad_end[-1] + chunk - 1) // chunk
         out = lax.fori_loop(0, n_chunks, body,
                             jnp.zeros((t, d), jnp.float32))
-    return out, on.sum(dtype=jnp.int32), (counts > 0).sum(dtype=jnp.int32)
+    zeros = 0
+    if share.n_zero:
+        term, zeros = zero_experts_term(h, picks, weights, share.total, live)
+        out = out + term
+    return (out, on.sum(dtype=jnp.int32), (counts > 0).sum(dtype=jnp.int32),
+            zeros)
