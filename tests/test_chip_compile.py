@@ -293,6 +293,18 @@ def _cache_sized_copies(text, buf):
             if np.prod([int(d) for d in m.group(1).split(",")]) == buf.size]
 
 
+def _held_in_one_layout(text, dims):
+    """Every mention of the buffer ``bf16[dims]`` names ONE tiled layout
+    (``{3,2,1,0:T(8,128)(2,1)}``: entry arguments, loop carries, results),
+    and a launch's ``operand_layout_constraints`` — a bare order of
+    dimensions — name that layout's order."""
+    import re
+    layouts = set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})", text))
+    tiled = {lay for lay in layouts if ":" in lay}
+    assert len(tiled) == 1, layouts
+    assert layouts - tiled <= {next(iter(tiled)).split(":")[0] + "}"}, layouts
+
+
 @pytest.mark.parametrize("name", sorted(STEP_ROWS_CASES))
 def test_step_rows_never_copies_the_cache(chip, name):
     """The decode chunk (``serve.step_rows``, ``n=8``, per-row frontiers)
@@ -326,11 +338,7 @@ def test_step_rows_never_copies_the_cache(chip, name):
     # buffer's layout names its tiling (``{3,2,1,0:T(8,128)(2,1)}``); the
     # launch's ``operand_layout_constraints`` name a bare order of
     # dimensions, which has to be the buffers' own
-    layouts = set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})", text))
-    tiled = {lay for lay in layouts if ":" in lay}
-    assert len(tiled) == 1, layouts
-    order = next(iter(tiled)).split(":")[0]
-    assert layouts - tiled <= {order + "}"}, layouts
+    _held_in_one_layout(text, dims)
     carried = [ln for ln in text.splitlines()
                if " while(" in ln and f"bf16[{dims}]" in ln]
     assert carried, "no while carries the cache"
@@ -508,8 +516,7 @@ def test_latent_step_rows_never_copies_the_cache_or_an_expert_layer(chip):
     assert buf.shape == (3, slots, rows, 640)
     dims = ",".join(str(d) for d in buf.shape)
     assert not re.findall(r"bf16\[" + dims + r"\]\{[^}]*\} copy\(", text)
-    assert len(set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})",
-                              text))) == 1
+    _held_in_one_layout(text, dims)
     assert text.count("tony_moe_gmm") >= 6        # 2 layers x gate/up/down
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 7168 * 2048 * 2
 
@@ -649,8 +656,7 @@ def test_double_layer_step_rows_copies_no_cache_and_no_half(chip):
     assert text.startswith("HloModule jit_step_rows")
     dims = ",".join(str(d) for d in buf.shape)
     assert not re.findall(r"bf16\[" + dims + r"\]\{[^}]*\} copy\(", text)
-    assert len(set(re.findall(r"bf16\[" + dims + r"\](\{[^}]*\})",
-                              text))) == 1
+    _held_in_one_layout(text, dims)
     assert text.count("tony_moe_gmm") >= 12       # 4 layers x gate/up/down
     assert "moe_zero" in text
     memory = compiled.memory_analysis()
@@ -675,3 +681,115 @@ def test_double_layer_admit_rows_at_the_longest_bucket_fits_the_chip(chip):
     assert "tony_flash_fwd" in text and "tony_moe_gmm" in text
     assert not _cache_sized_copies(text, cache["ckv"])
     assert compiled.memory_analysis().peak_memory_in_bytes < _HBM
+
+
+# ---------------------------------------------------------------------------
+# The latent cached read as ``tony_cached_attn`` (PR 38): the stored row is
+# the one K/V head and its own value, each slot's own live blocks
+# ---------------------------------------------------------------------------
+
+def _launches(text):
+    """name -> operand names of every ``tony_cached_attn`` launch of a
+    compiled program: (grid bound, layer, q_pos, slot, blk, lo, hi, qx,
+    the cache — and V where there is one)."""
+    import re
+    return {m.group(1): re.findall(r"%([\w.\-]+)", m.group(2))
+            for m in re.finditer(
+                r"%(tony_cached_attn[.\d]*) = [^\n]*? custom-call\(([^)]*)\)",
+                text)}
+
+
+def _source(text, name):
+    """The instruction a launch's operand comes from, moves between
+    memory spaces (``copy-start`` / ``copy-done``) followed back."""
+    import re
+    while True:
+        m = re.search(r"%" + re.escape(name)
+                      + r" = [^\n]* copy-(?:start|done)\(%([\w.\-]+)", text)
+        if m is None:
+            return name
+        name = m.group(1)
+
+
+@pytest.mark.parametrize("config,slots,rows,reads", [
+    ("kimi-k2.5-l6-ep32", 32, 2048, 6),
+    ("longcat-flash-l4-ep32", 64, 4096, 8)])
+def test_latent_step_rows_reads_each_slots_own_blocks(chip, config, slots,
+                                                      rows, reads):
+    """The decode chunk of both latent cells at their own depth, slots
+    and rows: ONE ``tony_cached_attn`` launch a latent read (6 a step in
+    Kimi's cell, 8 — two a double layer — in LongCat's) on the stacked
+    buffer as stored, with no V operand; the work list is built from the
+    positions alone, so all the launches of a step share ONE; the walk's
+    loop with its float32 ``[B, H, 640]`` carry is gone; no cache-sized
+    copy; and the kernel's blocks fit VMEM (the compile would refuse)."""
+    from tony_tpu.models import serve as S
+    cfg, params, cache, logits = _cell_serving(chip, config, slots, rows)
+    buf = cache["ckv"]
+    assert buf.shape == (reads, slots, rows, 640)
+    compiled = S.step_rows.lower(
+        params, cache, logits, chip.shape((slots, 2), jnp.uint32),
+        chip.shape((slots,), jnp.int32), n=8, cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step_rows")
+    launches = _launches(text)
+    assert len(launches) == reads
+    dims = ",".join(str(d) for d in buf.shape)
+    for operands in launches.values():
+        assert len(operands) == 9              # qx and ONE cache operand
+    # slot, blk, lo, hi: one producer each for all the launches
+    for i in (3, 4, 5, 6):
+        assert len({_source(text, ops[i]) for ops in launches.values()}) == 1
+    assert f"f32[{slots},{cfg.n_heads},640]" not in text    # the carry
+    assert not _cache_sized_copies(text, buf)
+    _held_in_one_layout(text, dims)
+    assert compiled.memory_analysis().peak_memory_in_bytes < _HBM
+
+
+@pytest.mark.parametrize("axes", ["dp2-tp2", "dp4"])
+def test_latent_decode_step_on_a_mesh(chip, topo, axes):
+    """``decode_step`` of a latent model (Kimi-K2.5's attention, two
+    dense layers) under ``jax.set_mesh``. The latent cache has ONE head:
+    nothing of it splits over ``tp``. So with a live "heads" axis
+    ``_read_arm`` leaves the read to the ``jnp`` walk, which the
+    partitioner cuts as it did — no launch reaches it bare ("Mosaic
+    kernels cannot be automatically partitioned") — and with batch axes
+    alone the launch runs a device inside ``shard_cached_attention``'s
+    island, on its own slots. Either way no cache row is gathered and
+    the cache stays a device's share."""
+    import re
+
+    from tony_tpu.models import decode as D
+    from tony_tpu.models import transformer as T
+    from tony_tpu.parallel.sharding import param_shardings
+    mesh = chip.mesh if axes == "dp2-tp2" else Mesh(
+        np.array(topo.devices), ("dp",))
+    dp = mesh.shape["dp"]
+    cfg = T.TransformerConfig(
+        vocab_size=20480, d_model=7168, n_layers=2, n_heads=64, d_ff=18432,
+        dtype=jnp.bfloat16, remat=False, rms_eps=1e-5, rope_base=50000.0,
+        rope_scaling=T.RopeYarn(64.0, 32.0, 1.0, 4096, 1.0, 1.0),
+        layer_kinds=("dense", "dense"),
+        latent=T.LatentAttention(1536, 512, 128, 64, 128))
+    slots, rows = 8, 2048
+    params = chip.place(
+        jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg)),
+        param_shardings(T.logical_axes(cfg), mesh))
+    on = lambda spec: NamedSharding(mesh, spec)         # noqa: E731
+    per_slot = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=on(P("dp")))
+    cache = {"length": per_slot, "ckv": jax.ShapeDtypeStruct(
+        (2, slots, rows, 640), jnp.bfloat16,
+        sharding=on(P(None, "dp", None, None)))}
+    with jax.set_mesh(mesh):
+        arm = D._read_arm(rows, 1, False)
+        text = jax.jit(functools.partial(D.decode_step, cfg=cfg),
+                       donate_argnums=2).lower(
+            params, per_slot, cache, per_slot).compile().as_text()
+    assert arm == ("walk" if axes == "dp2-tp2" else "kernel")
+    launches = set(re.findall(r"%(tony_cached_attn[.\d]*) = ", text))
+    assert len(launches) == (0 if arm == "walk" else 2)
+    assert f"bf16[2,{slots // dp},{rows},640]" in text
+    assert f"bf16[2,{slots},{rows}" not in text         # never whole
+    gathered = [ln for ln in text.splitlines()
+                if "all-gather" in ln and f",{rows}," in ln]
+    assert not gathered, gathered

@@ -435,8 +435,8 @@ def test_served_through_the_batcher_with_zero_assignments_in_stats(tiny):
         + st["moe_zero_assignments"]["decode"] <= every
     assert 0 < st["moe_expert_touches"]["decode"] <= steps * layers * 4
     assert st["cache_bytes"] == {"latent": 4 * 3 * 96 * 128 * 4}
-    # the latent read is the walk: every slot to the longest live row's
-    # last block (one block of 96 here), in all four row-sets
+    # off the chip the latent read is the walk: every slot to the longest
+    # live row's last block (one block of 96 here), in all four row-sets
     assert st["cache_rows_read"] == {"latent": steps * 3 * 96 * 4}
     assert 0 < st["cache_rows_live"]["latent"] < st["cache_rows_read"][
         "latent"]

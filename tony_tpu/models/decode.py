@@ -27,7 +27,9 @@ projections' propagated shardings. One island: on the chip a step's
 cached read is a Mosaic kernel, which XLA does not partition, so under a
 mesh it runs a device inside ``shard_cached_attention``'s ``shard_map``
 — slots over the batch axes, K/V heads over ``tp``, which is where
-propagation leaves the cache (:func:`_kernel_cached_attention`).
+propagation leaves the cache (:func:`_kernel_cached_attention`; a latent
+cache's one head does not split, so its launch takes slots alone and a
+live ``tp`` axis keeps the ``jnp`` walk: :func:`_kernel_latent_attention`).
 
 Usage::
 
@@ -565,8 +567,9 @@ def _read_arm(rows: int, kv_heads: int, quantized: bool, n_q: int = 1,
               ring: bool = False) -> str:
     """Which cached read a buffer of ``rows`` rows a slot takes — the ONE
     ladder: the reads (:func:`_cached_attention`,
-    :func:`_ring_cached_attention`) and the host's count of what they
-    visit (:func:`cache_rows_visited`) both ask here.
+    :func:`_ring_cached_attention`, :func:`_latent_cached_attention` —
+    a latent row is ONE K/V head, ``kv_heads`` 1) and the host's count of
+    what they visit (:func:`cache_rows_visited`) both ask here.
 
     ``"kernel"`` (:func:`_kernel_cached_attention`): on the chip — the
     repo's one question, ``mosaic.interpret()`` — for what is visible in
@@ -737,18 +740,24 @@ def cache_rows_visited(cfg: T.TransformerConfig, max_len: int,
     own (:func:`live_blocks`), the walk every slot's from the oldest
     window's first block to the longest row's last, the dense reads the
     whole buffer. live / read is how far the read follows the rows. The
-    kinds are :func:`cache_bytes_by_kind`'s; ``latent`` is always the
-    walk (:func:`_latent_cached_attention`: every slot to the longest
-    row's last block), summed over its attentions — two a double
-    layer."""
+    kinds are :func:`cache_bytes_by_kind`'s; ``latent``
+    (:func:`_latent_cached_attention`, the stored row as one K/V head)
+    has the kernel or the walk and no dense read, summed over its
+    attentions — two a double layer."""
     pos = np.asarray(pos, np.int64)
     quant = cfg.kv_cache_dtype == "int8"
     layout, out = cache_layout(cfg, max_len), {}
     if "ckv" in layout:
-        attentions, rows = layout["ckv"][:2]
-        block = min(DECODE_BLOCK, rows)
-        read = (pos.max(axis=0, initial=0) + block) // block * block
-        out["latent"] = (attentions * int(read.sum()) * pos.shape[0],
+        attentions, rows, width, dt = layout["ckv"]
+        if _read_arm(rows, 1, False) == "kernel":
+            block = cached_attn_block(rows, width * jnp.dtype(dt).itemsize)
+            _, hi = live_blocks(np, pos, rows, block, None, False)
+            read = int(np.minimum((hi + 1) * block, rows).sum())
+        else:
+            block = min(DECODE_BLOCK, rows)
+            read = int(((pos.max(axis=0, initial=0) + block)
+                        // block * block).sum()) * pos.shape[0]
+        out["latent"] = (attentions * read,
                          attentions * int((pos + 1).sum()))
     for name in ("k", "k_ring"):                    # a row's V is its K's
         if name not in layout:
@@ -1084,6 +1093,31 @@ def _latent_prompt_attention(q_n, q_r, row, p, cfg: T.TransformerConfig):
     return o[..., :la.v_dim]
 
 
+def _kernel_latent_attention(qx, buf, li, q_pos, cfg: T.TransformerConfig):
+    """The absorbed read as ``tony_cached_attn``'s latent arm
+    (:func:`tony_tpu.ops.attention.cached_attention` with no V operand):
+    the stored row ``[c_kv; k_r; tail]`` is the ONE K/V head the query
+    heads share and its own value, so each slot's own live blocks are
+    read once and contracted twice — scores against ``qx`` [B, 1, H,
+    row], a head's ``[q~; q_r; 0]`` at positions ``q_pos`` [B], the value
+    product over the row's first ``kv_rank`` columns. Returns the
+    softmax-weighted c_kv [B, 1, H, kv_rank], which the caller takes
+    through ``W_uv``. The work list is built from ``q_pos`` alone: every
+    latent read of a step builds the same one, and XLA keeps one. Under
+    a mesh the launch runs a device inside ``shard_cached_attention``'s
+    island, on its own slots."""
+    def local(qx, rows_all, q_pos):
+        rows, f = rows_all.shape[2:]
+        block = cached_attn_block(rows, f * rows_all.dtype.itemsize)
+        work = cached_attn_work(q_pos, rows, block)
+        o = cached_attention(qx[:, 0], rows_all, None, li, q_pos, work,
+                             scale=_latent_scale(cfg),
+                             head_dim=cfg.latent.kv_rank, block=block)
+        return o[:, None]
+
+    return shard_cached_attention(local, qx, buf, None, q_pos)
+
+
 def _latent_cached_attention(q_n, q_r, buf, li, q_start, p,
                              cfg: T.TransformerConfig,
                              block: int = DECODE_BLOCK):
@@ -1102,7 +1136,16 @@ def _latent_cached_attention(q_n, q_r, buf, li, q_start, p,
     :func:`_cached_attention_blockwise` (which this sits beside): cost
     follows the live length. q_n, q_r: [B, Q, H, .] at positions
     q_start..q_start+Q-1; ``buf``: the stacked [L, B, max_len, row]
-    buffer. Returns [B, Q, H, v_dim]."""
+    buffer. Returns [B, Q, H, v_dim].
+
+    On the chip (:func:`_read_arm`, the stored row as ONE K/V head: one
+    query position a slot, ``_BLOCKWISE_MIN_LEN`` rows or more, no
+    "heads" axis live in an ambient mesh) the read is
+    ``tony_cached_attn`` over each slot's OWN live blocks
+    (:func:`_kernel_latent_attention`). What follows it here is the walk
+    of every slot to the LONGEST row: the CPU arm and the oracle, and
+    the read of ``extend_step``'s chunks, speculation's verify and a
+    tensor-parallel mesh."""
     la = cfg.latent
     b, n_q, heads, _ = q_n.shape
     max_len = buf.shape[2]
@@ -1110,13 +1153,17 @@ def _latent_cached_attention(q_n, q_r, buf, li, q_start, p,
     w_uk = p["wkv_b"][..., :la.nope_dim]              # [c, H, nope]
     w_uv = p["wkv_b"][..., la.nope_dim:]              # [c, H, v]
     qc = jnp.einsum("bqhk,chk->bqhc", q_n, w_uk)
+    tail = jnp.zeros(q_r.shape[:-1] + (la.stored_row - la.row,), q_r.dtype)
+    qx = jnp.concatenate([qc, q_r, tail], axis=-1)    # [B, Q, H, row]
+    if _read_arm(max_len, 1, False, n_q) == "kernel":
+        ctx = _kernel_latent_attention(
+            qx, buf, li, _q_positions(q_start, b, 1)[:, 0], cfg)
+        return jnp.einsum("bqhc,chk->bqhk", ctx, w_uv)
     # [B, row, Q·H], built once outside the loop: the stored rows then
     # meet it as the LEFT operand, contracted over their minor axis as
     # stored (the same orientation as _head_scores) — as a right operand
     # the compiler re-lays-out the whole cache to put rows minor
-    tail = jnp.zeros(q_r.shape[:-1] + (la.stored_row - la.row,), q_r.dtype)
-    qx = jnp.concatenate([qc, q_r, tail], axis=-1).reshape(
-        b, n_q * heads, la.stored_row).transpose(0, 2, 1)
+    qx = qx.reshape(b, n_q * heads, la.stored_row).transpose(0, 2, 1)
     q_pos = _q_positions(q_start, b, n_q)             # [B, Q]
     row_pos = jnp.repeat(q_pos, heads, axis=1)        # [B, Q·H]
     n_active = (jnp.max(q_pos) + block) // block

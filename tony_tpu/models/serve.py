@@ -886,8 +886,7 @@ class ContinuousBatcher:
         #: under their masks, by the kind of state, summed over its
         #: layers (decode.cache_rows_visited, at each chunk's issue; an
         #: idle slot counts as the device reads it). live / read is how
-        #: far the cached read follows each row's own length (a latent
-        #: cache's read is the walk to the longest row for every slot)
+        #: far the cached read follows each row's own length
         kinds = cache_rows_visited(cfg, max_len,
                                    np.zeros((batch, 0), np.int64))
         self.cache_rows_read = dict.fromkeys(kinds, 0)

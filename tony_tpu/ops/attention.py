@@ -1057,10 +1057,13 @@ def cached_attn_work(q_pos, rows: int, block: int,
 
 
 def _cached_attn_kernel(layer, q_pos, slot, blk, lo, hi, qx_ref, k_ref,
-                        v_ref, o_ref, ml_scr, acc_scr, *, scale: float,
-                        block: int, rows: int, window: int | None,
-                        ring: bool, heads: tuple[int, int] | None):
+                        *refs, scale: float, block: int, rows: int,
+                        window: int | None, ring: bool,
+                        heads: tuple[int, int] | None):
     del layer
+    # a K/V pair, or a latent row that is its own value: no V operand,
+    # the value product takes the K block that is already in VMEM
+    v_ref, o_ref, ml_scr, acc_scr = refs if len(refs) == 4 else (None, *refs)
     t = pl.program_id(0)
     b, j = slot[t], blk[t]
     pos = q_pos[b]
@@ -1071,7 +1074,8 @@ def _cached_attn_kernel(layer, q_pos, slot, blk, lo, hi, qx_ref, k_ref,
         ml_scr[:, 1:2] = jnp.zeros_like(ml_scr[:, 1:2])
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    k, v = k_ref[...], v_ref[...]                       # [S, KV·hd]
+    k = k_ref[...]                                      # [S, KV·hd]
+    v = k[:, :acc_scr.shape[1]] if v_ref is None else v_ref[...]
     s = jax.lax.dot_general(
         qx_ref[...], k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale     # [N, S]
@@ -1129,9 +1133,17 @@ def _cached_attention(qx, k_all, v_all, layer, q_pos, work, *, scale,
     slot, blk, lo, hi, n_work = work
     b, n, f = qx.shape
     rows = k_all.shape[2]
-    kv = f // head_dim
     aligned = head_dim % _LANES == 0
-    out_w = head_dim if aligned else f
+    if v_all is None:
+        # the row is its own value, its first head_dim columns: cut on a
+        # lane tile inside the kernel, else the whole row leaves and is
+        # cut here
+        heads, caches = None, (k_all,)
+        out_w = acc_w = head_dim if aligned else f
+    else:
+        kv, caches = f // head_dim, (k_all, v_all)
+        heads = (kv, head_dim) if aligned else None
+        out_w, acc_w = head_dim if aligned else f, f
 
     def kv_map(t, layer, q_pos, slot, blk, lo, hi):
         return layer[0], slot[t], blk[t], 0
@@ -1142,27 +1154,27 @@ def _cached_attention(qx, k_all, v_all, layer, q_pos, work, *, scale,
     out = pl.pallas_call(
         functools.partial(
             _cached_attn_kernel, scale=scale, block=block, rows=rows,
-            window=window, ring=ring,
-            heads=(kv, head_dim) if aligned else None),
+            window=window, ring=ring, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(n_work,),
-            in_specs=[
-                pl.BlockSpec((None, n, f), slot_map),
-                pl.BlockSpec((None, None, block, f), kv_map),
-                pl.BlockSpec((None, None, block, f), kv_map)],
+            in_specs=[pl.BlockSpec((None, n, f), slot_map)] + [
+                pl.BlockSpec((None, None, block, f), kv_map)
+                for _ in caches],
             out_specs=pl.BlockSpec((None, n, out_w), slot_map),
             scratch_shapes=[pltpu.VMEM((n, _LANES), jnp.float32),
-                            pltpu.VMEM((n, f), jnp.float32)]),
+                            pltpu.VMEM((n, acc_w), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, n, out_w), qx.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=CACHED_ATTN_NAME,
     )(jnp.asarray(layer, jnp.int32).reshape(1), q_pos.astype(jnp.int32),
-      slot, blk, lo, hi, qx, k_all, v_all)
+      slot, blk, lo, hi, qx, *caches)
     if aligned:
         return out
+    if v_all is None:
+        return out[..., :head_dim]
     # heads off the lane tiles (96): the kernel hands back whole stored
     # rows and each K/V head keeps its own columns here (a select, as
     # decode._head_values: no arithmetic)
@@ -1196,6 +1208,14 @@ def cached_attention(qx, k_all, v_all, layer, q_pos, work, *, scale: float,
     cache's dtype before the value product: the arithmetic of
     ``decode._cached_attention_blockwise``, which stays the CPU arm and
     the oracle. Returns [B, H, hd] in ``qx``'s dtype.
+
+    The value operand follows what the buffer is, too. ``v_all`` None: a
+    LATENT cache, whose stored row ``[c_kv; k_r; tail]`` is the one K/V
+    head every query head shares and its own value — ``qx`` [B, H, row]
+    is ``[q~; q_r; 0]`` a head (``decode._latent_cached_attention``),
+    ``head_dim`` the value's width (``kv_rank``), and the value product
+    takes the first ``head_dim`` columns of the K block that is already
+    in VMEM: no second operand, no second DMA of the rows.
 
     Traced once a shape: every layer of a model calls the same jitted
     wrapper with its own ``layer``."""

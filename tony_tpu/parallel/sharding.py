@@ -272,17 +272,25 @@ def shard_cached_attention(local_fn, q, k_all, v_all, q_pos,
     gathered: the island's specs are where sharding propagation leaves
     it (``wk`` / ``wv`` are cut by heads).
 
+    ``v_all`` None: a latent cache, whose one stored row a token is key
+    and value of every query head — ``local_fn(q, k_all, q_pos)``. One
+    head does not split, so the caller has asked
+    ``cache_heads_split(1)`` and no "heads" axis is live here: slots
+    over the batch axes, nothing else.
+
     With nothing to split (:func:`_island_mesh`) the call is
     ``local_fn`` as is. A batch axis that does not divide the slots is
     dropped, as in :func:`shard_attention`."""
     mesh = _island_mesh(mesh)
+    caches = (k_all,) if v_all is None else (k_all, v_all)
     if mesh is None:
-        return local_fn(q, k_all, v_all, q_pos)
+        return local_fn(q, *caches, q_pos)
     batch_axes = tuple(a for a in batch_axes if a in mesh.shape)
     while q.shape[0] % math.prod(mesh.shape[a] for a in batch_axes):
         batch_axes = batch_axes[:-1]
     spec, _ = attention_spec(mesh, batch_axes, None, head_axis)
     cache = P(None, spec[0], None, spec[2])
     return jax.shard_map(
-        local_fn, mesh=mesh, in_specs=(spec, cache, cache, P(spec[0])),
-        out_specs=spec, check_vma=False)(q, k_all, v_all, q_pos)
+        local_fn, mesh=mesh,
+        in_specs=(spec, *(cache for _ in caches), P(spec[0])),
+        out_specs=spec, check_vma=False)(q, *caches, q_pos)
