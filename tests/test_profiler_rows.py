@@ -72,6 +72,33 @@ def test_phase_enters_prefixed_row_and_accumulates(rows):
     assert set(pt.summary()["dispatch"]) == {"total_s", "count", "mean_ms"}
 
 
+@pytest.mark.parametrize("capturing", [False, True])
+def test_phase_attrs_ride_the_row_only_under_a_capture(monkeypatch,
+                                                       capturing):
+    """What a row says of its program (``seq``, a batch's size) is the
+    profiler's to format; with no capture running nobody reads it, and it
+    is let go before that. The interval is observed either way — also
+    when the block raises."""
+    log = []
+
+    class Row(_Row):
+        is_enabled = staticmethod(lambda: capturing)
+
+        def __init__(self, name, **kw):
+            super().__init__(log, "row", name, **kw)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Row)
+    pt = PhaseTimes("tony.engine")
+    with pt.phase("dispatch", seq=7, live=3):
+        pass
+    with pytest.raises(KeyError):
+        with pt.phase("dispatch", seq=8, live=3):
+            raise KeyError("x")
+    want = [{"seq": 7, "live": 3}, {"seq": 8, "live": 3}] if capturing \
+        else [{}, {}]
+    assert log == [("row", "tony.engine.dispatch", kw) for kw in want]
+    assert pt.count("dispatch") == 2
+
+
 def test_phase_without_prefix_enters_no_row(rows):
     pt = PhaseTimes()
     with pt.phase("fetch"):
@@ -256,11 +283,43 @@ def test_capture_holds_the_engine_rows(params, tmp_path):
     names = {e[0] for e in events}
     assert {"tony.engine." + p for p in (
         "dispatch", "fetch", "consume", "emit", "admit", "admit_pick",
-        "retire")} <= names
+        "retire", "admit_dispatch", "account")} <= names
     # emit nests in consume: each emit lies inside some consume
     consumes = [e for e in events if e[0] == "tony.engine.consume"]
     for _, s, t, _ in (e for e in events if e[0] == "tony.engine.emit"):
         assert any(cs <= s and t <= ct for _, cs, ct, _ in consumes)
+
+
+def test_capture_rows_carry_the_device_queue_order(params, tmp_path):
+    """Every program the engine enqueues is a row with its ``seq``: the
+    chunks' ``dispatch`` rows and the admissions' ``admit_dispatch`` rows
+    together count 0, 1, 2, ... in the order of their start times (the
+    device queue's order), each ``fetch`` blocks on a chunk's seq, and an
+    admission row says what was dispatched."""
+    b = ContinuousBatcher(params, CFG, batch=2, max_len=64, chunk=3)
+    b.serve(_prompts(5, (4, 20)), max_new_tokens=4)       # compile outside
+    with profiler.trace(str(tmp_path)):
+        # two buckets in the first wave: two dispatches in one admit
+        b.serve(_prompts(6, (4, 20, 5)), max_new_tokens=7)
+    events = _tony_events(str(tmp_path))
+    by = {}
+    for name, s, t, stats in sorted(events, key=lambda e: e[1]):
+        by.setdefault(name.rsplit(".", 1)[1], []).append((s, t, stats))
+    enqueued = sorted(by["dispatch"] + by["admit_dispatch"])
+    assert [int(st["seq"]) for _, _, st in enqueued] == \
+        list(range(len(enqueued))) and len(enqueued) == b.seq
+    chunk_seqs = [int(st["seq"]) for _, _, st in by["dispatch"]]
+    assert [int(st["seq"]) for _, _, st in by["fetch"]] == chunk_seqs
+    for _, _, st in by["dispatch"]:
+        assert 0 <= int(st["live"]) <= 2 and int(st["waiting"]) >= 0
+    first, second, third = by["admit_dispatch"]
+    assert [(int(st["bucket"]), int(st["rows"]), int(st["tokens"]))
+            for _, _, st in (first, second, third)] == [
+        (16, 2, 4), (32, 2, 20), (16, 2, 5)]
+    # both dispatches of the first wave nest in ONE admit
+    (s0, t0, _), (s1, t1, _) = first, second
+    assert any(s <= s0 and t1 <= t for s, t, _ in by["admit"])
+    assert len(by["admit"]) == 2
 
 
 def test_capture_holds_train_steps_with_their_children(tmp_path):

@@ -585,4 +585,8 @@ def test_configuration_file_states_its_cut():
         "moe_experts_roofline.serve", "admit_device_share_pct.serve",
         "admit_device_ms.serve", "admit_attention_share_pct.serve",
         "cached_attn_share_pct.serve",
-        "moe_gmm_share_pct.serve"])         # PR 37's, read here too
+        "moe_gmm_share_pct.serve",          # PR 37's, read here too
+        # PR 39's: the engine's timeline, read in every saturated cell
+        "chunk_turn_ms.serve", "admit_stall_ms.serve",
+        "admit_stall_share_pct.serve", "device_starved_pct.serve",
+        "slot_vacant_ms.serve"])

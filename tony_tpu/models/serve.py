@@ -136,6 +136,61 @@ queue). Two request WAITS ride the same accumulator through
 once per admission) and ``first_token`` (admission → first consumed
 delta, once per request that produced a token). They are waits, not
 loop phases: they overlap across requests and do not sum to the wall.
+``admit_dispatch`` nests in ``admit``, one per device dispatch (a wave
+of two buckets is two): the marshalling that feeds the jit call and the
+call, so ``admit − admit_dispatch`` is the padding and the stream
+rebinding. ``account`` is the host arithmetic that is instrumentation
+itself (the cache-row count at each issue, the device counters' fold at
+each fetch and consume): what counting costs is counted.
+
+THE DEVICE-QUEUE TIMELINE is measured in the same accumulator, with no
+capture running. The device runs programs in the order the engine
+thread enqueued them, so that order is the causal chain:
+
+- every program — a decode chunk or an admission dispatch — takes the
+  next sequence number ``seq`` as it is enqueued. ``dispatch`` rows
+  carry ``seq``, ``live`` (occupied slots) and ``waiting`` (requests in
+  the wait queues); ``admit_dispatch`` rows ``seq``, ``bucket``,
+  ``rows`` (the dispatch's width) and ``tokens`` (real positions);
+  ``fetch`` rows the ``seq`` they block on. In a capture the n-th
+  ``jit_step_rows`` / ``jit_admit_rows`` execution on the device line
+  after its first ``seq`` is the row with that number.
+- a RUN is the feeding of the device between two ``wait`` blocks; within
+  it a TURN is the interval from one fetch's return to the next (the
+  run's first opens at its first enqueue). A turn holds exactly one
+  decode chunk and the admission dispatches enqueued between it and the
+  chunk before. Observed where the fetch returns
+  (:meth:`ContinuousBatcher._await`): ``turn`` every one; ``turn_clean``
+  when the chunk was enqueued before the previous fetch returned and no
+  admission lies between the two (device-bound, it is the chunk's own
+  time); ``turn_admit`` when at least one does (its mean less
+  ``turn_clean``'s is what an admission adds to the gap of every live
+  stream); ``turn_loaded`` when it held an admission or a request was
+  waiting at its close — offered load, not a drain, so shares over it
+  do not move with how long the tail runs.
+- ``starved``, at every enqueue that finds nothing enqueued before it
+  still unfetched (a deferred issue, every chunk of the sequential loop,
+  a restart after everything retired): the time since the run's last
+  fetch returned — the device's idle time the host caused, by
+  construction.
+- a request's ``first_token`` splits where the chunk that was in flight
+  at its admission returned: ``first_token_queued`` (the admission stood
+  behind that chunk; 0 when none was in flight) and ``first_token_ride``
+  (the admission on the device, the chunk the first token rides, its
+  consumption); the two sum to ``first_token``. ``slot_vacant``, at each
+  admission, is ``t_admit − max(the return of the chunk whose
+  consumption freed the slot, the request's t_queued)``: how long a free
+  slot and a runnable request both waited for the loop to come round.
+  The request spans name their causes: ``engine.queued`` ends with
+  ``slot`` and ``freed_seq`` (the chunk that freed it, −1 for a slot
+  never used), ``engine.first_token`` with ``admit_seq`` and
+  ``chunk_seq`` (the chunk that delivered).
+
+``wait`` and the turns tile the engine thread's wall but for each run's
+edges (the sweep before its first enqueue, the consume and settle after
+its last fetch); inside a turn ``dispatch + fetch + consume +
+admit_pick + admit + retire + account`` leave only the loop's own
+branches untimed.
 
 ``TRACE_COUNTS`` records one entry per (program, static shape) TRACE —
 a Python side effect inside the jitted bodies, executed at trace time
@@ -203,6 +258,11 @@ _ADMIT_TOKEN_BUDGET = 256
 #: rng-stream id for rows with no occupant (their draws are garbage the
 #: host discards; any fixed stream works)
 _IDLE_STREAM = 0x7FFFFFFF
+
+
+def _nobody_waiting() -> tuple[int, int]:
+    """(occupied slots, requests waiting) of a batcher no engine drives."""
+    return 0, 0
 
 
 def _count_trace(name: str, shape) -> None:
@@ -893,9 +953,15 @@ class ContinuousBatcher:
         self.cache_rows_live = dict.fromkeys(kinds, 0)
         self._device_stats: collections.deque = collections.deque()
         self.phase_times = PhaseTimes(ENGINE_PHASES)
+        #: () -> (occupied slots, requests waiting) as the loop driving
+        #: this batcher sees them (:meth:`ServeEngine._queue_load`): what
+        #: a ``dispatch`` row says of its batch, and what makes a turn
+        #: loaded. An engine lends its own for the length of its run.
+        self._load = _nobody_waiting
         # seams usable standalone (no serve() call required); serve()
         # re-seeds for per-workload reproducibility
         self._reset_streams()
+        self._reset_timeline()
 
     # --- per-request rng streams ---
 
@@ -916,6 +982,92 @@ class ContinuousBatcher:
 
     def _req_key(self, req: int):
         return jax.random.fold_in(self._base_key, req)
+
+    # --- the device-queue timeline (module docstring) ---
+
+    def _reset_timeline(self) -> None:
+        #: programs enqueued so far — decode chunks and admission
+        #: dispatches alike, in device-queue order: the next one's
+        #: sequence number
+        self.seq = 0
+        #: decode chunks issued and not fetched, oldest first: (seq,
+        #: admission dispatches between the chunk before it and it,
+        #: issued right behind that chunk while it was unfetched)
+        self._unfetched: collections.deque = collections.deque()
+        #: seq of the newest fetched chunk: the device has run every
+        #: program up to it (a chunk dropped unfetched stays ahead of
+        #: this mark until a later fetch returns, as it does on the
+        #: device)
+        self._seq_run = -1
+        #: one past the last chunk issued (the run's first seq before
+        #: its first chunk): the programs since are admissions
+        self._seq_chunk = 0
+        #: when the turn in progress opened — the last fetch's return,
+        #: or the first enqueue of a run; None between runs
+        self._t_turn: float | None = None
+        #: seq of the admission dispatch that last landed in each row
+        self._row_seq = [-1] * self.batch
+
+    def _enqueued(self, rows=()) -> None:
+        """Count one program into the device queue, as the call that
+        enqueued it returns (inside the phase that made the call);
+        ``rows`` are the slots an admission dispatch lands in. A program
+        that finds nothing enqueued before it still unfetched starts on
+        an idle device, and the host is why: ``starved`` is the time
+        since the last fetch returned."""
+        now = time.perf_counter()
+        if self._t_turn is None:
+            self._t_turn = now          # a run's first turn opens here
+            self._seq_chunk = self.seq
+        elif self.seq - 1 == self._seq_run:
+            self.phase_times.observe("starved", now - self._t_turn)
+        for row in rows:
+            self._row_seq[row] = self.seq
+        self.seq += 1
+
+    def _dispatch_phase(self):
+        """The ``dispatch`` phase of the chunk about to be issued; its
+        row says which program of the queue it is and how full its batch
+        was."""
+        live, waiting = self._load()
+        return self.phase_times.phase("dispatch", seq=self.seq, live=live,
+                                      waiting=waiting)
+
+    def _chunk_enqueued(self) -> None:
+        """File the chunk just enqueued among the unfetched, with what
+        its turn will be when its fetch returns: since the device runs
+        programs in the order they were enqueued, the turn holds this
+        chunk and the admission dispatches since the chunk before it."""
+        seq = self.seq
+        clean = bool(self._unfetched) and self._unfetched[-1][0] == seq - 1
+        self._enqueued()
+        self._unfetched.append((seq, seq - self._seq_chunk, clean))
+        self._seq_chunk = seq + 1
+
+    def _await(self, handle):
+        """Block on the oldest unfetched chunk (the ``fetch`` phase; its
+        row carries the seq it blocks on) and close the turn its return
+        ends — the interval since the fetch before it returned:
+        ``turn`` always; ``turn_admit`` when admission dispatches lay
+        between the two chunks; ``turn_clean`` when none did and the
+        chunk was enqueued before that fetch returned (a device-bound
+        turn is then the chunk's own time on the device);
+        ``turn_loaded`` when it held an admission or a request is
+        waiting at its close (offered load, not a drain)."""
+        seq, admits, clean = self._unfetched.popleft()
+        pt = self.phase_times
+        with pt.phase("fetch", seq=seq):
+            host = np.asarray(handle)
+        now = time.perf_counter()
+        took, self._t_turn, self._seq_run = now - self._t_turn, now, seq
+        pt.observe("turn", took)
+        if admits:
+            pt.observe("turn_admit", took)
+        elif clean:
+            pt.observe("turn_clean", took)
+        if admits or self._load()[1]:
+            pt.observe("turn_loaded", took)
+        return host
 
     # --- resident prefix templates (prefix-aware serving) ---
 
@@ -1155,11 +1307,15 @@ class ContinuousBatcher:
             keys[i] = pkg.rng_key
             for n, a in kv_from_wire(pkg.bufs).items():
                 mini[n][:, i:i + 1, :a.shape[2]] = a
-        self.cache, self.logits, self._row_keys = land_kv_rows(
-            self.cache, self.logits, jnp.asarray(rows),
-            {n: jnp.asarray(a) for n, a in mini.items()},
-            jnp.asarray(lens), jnp.asarray(lgs), self._row_keys,
-            jnp.asarray(keys))
+        with self.phase_times.phase("admit_dispatch", seq=self.seq,
+                                    bucket=s_b, rows=b,
+                                    tokens=int(lens.sum())):
+            self.cache, self.logits, self._row_keys = land_kv_rows(
+                self.cache, self.logits, jnp.asarray(rows),
+                {n: jnp.asarray(a) for n, a in mini.items()},
+                jnp.asarray(lens), jnp.asarray(lgs), self._row_keys,
+                jnp.asarray(keys))
+            self._enqueued(row for row, _ in grp)
         for row, req in grp:
             self._row_off[row] = pkgs[req].rng_off
 
@@ -1226,17 +1382,25 @@ class ContinuousBatcher:
         model against the stored template (:func:`prefix_admit_rows`) —
         the admission fast path. Also rebinds each row's rng stream to
         its new occupant — one scatter of the wave's marshalled keys,
-        not a dispatch per row."""
+        not a dispatch per row. The whole wave is the phase ``admit``;
+        each device dispatch in it — the marshalling that feeds the
+        call, and the call — is an ``admit_dispatch`` nested in it, so
+        ``admit − admit_dispatch`` is the padding and the rebinding."""
         if not pairs:
             return
-        with self.phase_times.phase("admit"):
+        pt = self.phase_times
+        with pt.phase("admit"):
             if self._ring:
                 for row, req in pairs:
-                    self.cache, self.logits = admit_row_ring(
-                        self.params, self.cache, self.logits, row,
-                        jnp.asarray(prompts[req], jnp.int32)[None],
-                        self.cfg)
-                    self.prefill_padded_tokens += len(prompts[req])
+                    n = len(prompts[req])
+                    with pt.phase("admit_dispatch", seq=self.seq, bucket=n,
+                                  rows=1, tokens=n):
+                        self.cache, self.logits = admit_row_ring(
+                            self.params, self.cache, self.logits, row,
+                            jnp.asarray(prompts[req], jnp.int32)[None],
+                            self.cfg)
+                        self._enqueued((row,))
+                    self.prefill_padded_tokens += n
                 rows, keys = self._marshal_wave(pairs)
                 self._rebind_streams(pairs, rows, keys)
                 self._count_admission(pairs, prompts)
@@ -1260,10 +1424,16 @@ class ContinuousBatcher:
                 w = admit_width(bucket, self.batch)
                 for i in range(0, len(whole), w):
                     grp = whole[i:i + w]
-                    rows, keys = self._marshal_wave(grp, w)
                     toks, lens = self._pad_prompts_to(grp, prompts,
                                                       bucket, w)
-                    self._admit_rows(rows, toks, lens, keys, entry=entry)
+                    with pt.phase("admit_dispatch", seq=self.seq,
+                                  bucket=bucket, rows=w, tokens=sum(
+                                      len(self._seq_of(prompts[req]))
+                                      for _, req in grp)):
+                        rows, keys = self._marshal_wave(grp, w)
+                        self._admit_rows(rows, toks, lens, keys,
+                                         entry=entry)
+                        self._enqueued(row for row, _ in grp)
                     self.prefill_padded_tokens += w * bucket
                     self._rebind_streams(grp, rows, keys)
                     self._count_admission(grp, prompts)
@@ -1322,7 +1492,7 @@ class ContinuousBatcher:
         """Issue one device chunk WITHOUT fetching it (async dispatch —
         returns the not-yet-materialized device tokens). The pipelined
         loop issues chunk N+1 here before fetching chunk N."""
-        with self.phase_times.phase("dispatch"):
+        with self._dispatch_phase():
             offs = jnp.asarray(self._row_off, jnp.int32)
             toks, self.cache, self.logits, stats = step_rows(
                 self.params, self.cache, self.logits, self._row_keys,
@@ -1330,12 +1500,15 @@ class ContinuousBatcher:
                 self.top_p)
             if stats:
                 self._device_stats.append(("decode", stats))
-        visited = cache_rows_visited(
-            self.cfg, self.max_len,
-            self._row_len[:, None] + np.arange(self.chunk))
-        for kind, (read, live) in visited.items():
-            self.cache_rows_read[kind] += read
-            self.cache_rows_live[kind] += live
+            self._chunk_enqueued()
+        # counting is host time too: ``account`` holds what it costs
+        with self.phase_times.phase("account"):
+            visited = cache_rows_visited(
+                self.cfg, self.max_len,
+                self._row_len[:, None] + np.arange(self.chunk))
+            for kind, (read, live) in visited.items():
+                self.cache_rows_read[kind] += read
+                self.cache_rows_live[kind] += live
         self._row_len += self.chunk
         self.steps_executed += self.chunk
         for r in range(self.batch):
@@ -1347,10 +1520,10 @@ class ContinuousBatcher:
         plus the transport round trip — the cost the pipelined loop
         overlaps with the NEXT chunk. Returns per-row sequences of newly
         generated tokens."""
-        with self.phase_times.phase("fetch"):
-            toks = np.asarray(handle)
+        toks = self._await(handle)
+        with self.phase_times.phase("account"):
             self._collect_device_stats()
-            return toks
+        return toks
 
     def _collect_device_stats(self) -> None:
         """Fold what the device counted, up to and including the OLDEST
@@ -1612,7 +1785,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
             "mode (the shipment carries no draft-model cache)")
 
     def _issue(self):
-        with self.phase_times.phase("dispatch"):
+        with self._dispatch_phase():
             offs = jnp.asarray(self._row_off, jnp.int32)
             packed, self.cache, self.d_cache, self.pending = (
                 spec_step_rows(self.params, self.draft_params, self.cache,
@@ -1620,6 +1793,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
                                offs, self.chunk, self.cfg, self.draft_cfg,
                                self.k, self.temperature, self.top_k,
                                self.top_p))
+            self._chunk_enqueued()
         self.rounds_executed += self.chunk
         self.steps_executed += self.chunk * (self.k + 1)
         for r in range(self.batch):
@@ -1629,8 +1803,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
     def _fetch(self, handle):
         # ONE host fetch per sync (see spec_step_rows: separate fetches
         # pay separate transport round trips)
-        with self.phase_times.phase("fetch"):
-            packed = np.asarray(handle)                # [n, B, k+2]
+        packed = self._await(handle)                   # [n, B, k+2]
         return [
             [int(t) for i in range(packed.shape[0])
              for t in packed[i, row, 1:1 + packed[i, row, 0]]]
@@ -1670,8 +1843,8 @@ class _EngineRequest:
 
     __slots__ = ("rid", "prompt", "budget", "stream", "rng_skip",
                  "emitted", "done", "reason", "t_submit", "t_last",
-                 "t_queued", "t_admit", "span", "queued_span",
-                 "first_span", "cls", "history", "requeued")
+                 "t_queued", "t_admit", "t_ride", "admit_seq", "span",
+                 "queued_span", "first_span", "cls", "history", "requeued")
 
     def __init__(self, rid, prompt, budget: int, stream: int,
                  t_submit: float, rng_skip: int = 0,
@@ -1694,6 +1867,12 @@ class _EngineRequest:
         #: the ends of the ``queue_wait`` / ``first_token`` waits
         self.t_queued = t_submit
         self.t_admit = t_submit
+        #: when the chunk that was in flight at the admission returned
+        #: (``t_admit`` itself when none was): ``first_token`` splits
+        #: here into ``first_token_queued`` and ``first_token_ride``
+        self.t_ride = t_submit
+        #: seq of the admission dispatch that carried it
+        self.admit_seq = -1
         #: QoS tier (one of :data:`QOS_CLASSES`)
         self.cls = cls
         #: emitted token VALUES, tracked only for evictable rows (batch
@@ -1828,6 +2007,15 @@ class ServeEngine:
         batcher.rounds_executed = 0
         batcher.phase_times = PhaseTimes(ENGINE_PHASES)
         batcher._reset_streams()
+        batcher._reset_timeline()
+        batcher._load = self._queue_load
+        #: per slot, (when the chunk whose consumption freed it returned,
+        #: that chunk's seq) — (0.0, -1) for a slot never occupied: the
+        #: start of ``slot_vacant`` and the cause ``engine.queued`` names
+        self._freed = [(0.0, -1)] * batcher.batch
+        #: requests admitted behind a chunk still in flight: their
+        #: ``first_token_queued`` ends when its fetch returns
+        self._behind: list[_EngineRequest] = []
         # Registry instrumentation: a handful of locked increments per
         # host SYNC (token counts batch into one inc per consume; the
         # TTFT/ITL histograms observe once per DELTA, <= slots per
@@ -2216,6 +2404,7 @@ class ServeEngine:
                 self._draining = True
                 self._stopped = True
             self.b._engine_running = False
+            self.b._load = _nobody_waiting     # let go of this engine
             self._abort_outstanding("stopped")
             metrics_mod.observe_phase_times(self.b.phase_times, self._reg)
 
@@ -2241,6 +2430,13 @@ class ServeEngine:
             req.span.end(reason=reason, tokens=req.emitted)
             self._emit_retired(req)
 
+    def _queue_load(self) -> tuple[int, int]:
+        """(occupied slots, requests waiting), for the batcher's
+        ``dispatch`` rows and its ``turn_loaded``."""
+        with self._lock:
+            return (sum(r is not None for r in self._occupant),
+                    self._wait_total_locked())
+
     def _wait_for_work(self) -> bool:
         """Block until there is runnable work (True) or the engine is
         drained-empty / stopped (False). Live OCCUPANTS count as work,
@@ -2258,6 +2454,8 @@ class ServeEngine:
                     return True
                 if self._draining:
                     return False
+                # the run ends here: the next enqueue opens a new one
+                self.b._t_turn = None
                 with self.b.phase_times.phase("wait"), \
                         goodput_mod.get_ledger().enter("idle"):
                     self._work.wait()
@@ -2366,6 +2564,8 @@ class ServeEngine:
         before = (b.prefill_forward_tokens, b.prefix_copied_tokens,
                   b.prefix_admits, b.prefill_padded_tokens)
         b._admit_batch(pairs, prompts)
+        for (row, _), req in zip(pairs, admitted):
+            req.admit_seq = b._row_seq[row]
         self._admitted_c.inc(len(admitted))
         # fold the batcher's host-side prefill accounting into the
         # registry (the batcher itself is registry-unaware)
@@ -2420,16 +2620,23 @@ class ServeEngine:
                 self._emit_retired(old)
         if admitted:
             tr = tracing.get_tracer()
+            pt = self.b.phase_times
+            in_flight = bool(self.b._unfetched)
             now = time.perf_counter()
-            for req in admitted:
+            for (row, _), req in zip(pairs, admitted):
                 # the wait for a slot ends here — counted (a wait, not
                 # a loop phase: waits of different requests overlap and
                 # do not sum to the thread's wall) at the same instant
                 # the per-request span ends
-                self.b.phase_times.observe("queue_wait",
-                                           now - req.t_queued)
-                req.t_admit = now
-                req.queued_span.end()
+                pt.observe("queue_wait", now - req.t_queued)
+                # ... and so does the time a free slot and a runnable
+                # request both stood waiting for the loop to come round
+                t_freed, freed_seq = self._freed[row]
+                pt.observe("slot_vacant", now - max(t_freed, req.t_queued))
+                req.t_admit = req.t_ride = now
+                if in_flight:
+                    self._behind.append(req)
+                req.queued_span.end(slot=row, freed_seq=freed_seq)
                 if req.span.recording:
                     # admit → first consumed delta: the prefill+decode
                     # share of TTFT, next to engine.queued's queue share
@@ -2448,27 +2655,39 @@ class ServeEngine:
         landed mid-flight) carry garbage and are discarded — the same
         discard as idle-slot garbage. The whole of it is the phase
         ``consume``; the callbacks inside it are ``emit``."""
+        for req in self._behind:
+            # the chunk it stood behind has returned; its admission and
+            # the chunk its first token rides are what is left
+            req.t_ride = self.b._t_turn
+        self._behind.clear()
         with self.b.phase_times.phase("consume"):
             self._consume_chunk(host_toks, snap)
-        for (what, program), c in self._moe_c.items():
-            total = self._moe_totals[what][program]
-            c.inc(total - self._moe_seen[(what, program)])
-            self._moe_seen[(what, program)] = total
-        for (what, kind), c in self._rows_c.items():
-            total = (self.b.cache_rows_read if what == "read"
-                     else self.b.cache_rows_live)[kind]
-            c.inc(total - self._rows_seen[(what, kind)])
-            self._rows_seen[(what, kind)] = total
+        with self.b.phase_times.phase("account"):
+            for (what, program), c in self._moe_c.items():
+                total = self._moe_totals[what][program]
+                c.inc(total - self._moe_seen[(what, program)])
+                self._moe_seen[(what, program)] = total
+            for (what, kind), c in self._rows_c.items():
+                total = (self.b.cache_rows_read if what == "read"
+                         else self.b.cache_rows_live)[kind]
+                c.inc(total - self._rows_seen[(what, kind)])
+                self._rows_seen[(what, kind)] = total
+
+    def _free_slot(self, row: int, t: float) -> None:
+        self._occupant[row] = None
+        self._freed[row] = (t, self.b._seq_run)
 
     def _consume_chunk(self, host_toks, snap) -> None:
         deltas, retired = [], []
         eos = self.b.eos_id
+        # a slot this chunk frees has been free since the chunk returned
+        t_chunk = self.b._t_turn
         with self._lock:
             for row, req in enumerate(snap):
                 if req is None or req.done:
                     if req is not None and self._occupant[row] is req:
                         # cancelled mid-flight: free the slot now
-                        self._occupant[row] = None
+                        self._free_slot(row, t_chunk)
                     continue
                 new = []
                 for t in host_toks[row]:
@@ -2483,7 +2702,7 @@ class ServeEngine:
                                       else "budget")
                         self._reqs.pop(req.rid, None)
                         if self._occupant[row] is req:
-                            self._occupant[row] = None
+                            self._free_slot(row, t_chunk)
                         break
                 if new:
                     if req.history is not None:
@@ -2504,9 +2723,15 @@ class ServeEngine:
                 self._ttft_by_cls[req.cls].observe(now - req.t_submit)
                 # admission → first delta, counted where it ends (a
                 # wait like queue_wait: overlapping, not a loop phase)
-                self.b.phase_times.observe("first_token",
-                                           now - req.t_admit)
-                req.first_span.end()
+                pt = self.b.phase_times
+                pt.observe("first_token", now - req.t_admit)
+                # ... split where the chunk in flight at the admission
+                # returned: behind it, then the admission on the device
+                # and the chunk this delta rode
+                pt.observe("first_token_queued", req.t_ride - req.t_admit)
+                pt.observe("first_token_ride", now - req.t_ride)
+                req.first_span.end(admit_seq=req.admit_seq,
+                                   chunk_seq=self.b._seq_run)
             else:
                 gap = (now - req.t_last) / len(new)
                 self._itl_h.observe(gap)
@@ -2564,7 +2789,7 @@ class ServeEngine:
                 if req is None:
                     continue
                 if req.done:
-                    self._occupant[row] = None
+                    self._free_slot(row, time.perf_counter())
                 else:
                     live = True
             return live
@@ -2627,8 +2852,10 @@ class ServeEngine:
                 if nxt is not None and not occupied:
                     # every request retired while the speculative chunk
                     # was in flight (eos beat the budget bound): drop it
-                    # unfetched — all its rows are garbage
+                    # unfetched — all its rows are garbage, and no turn
+                    # will close on it
                     nxt = None
+                    b._unfetched.pop()
                 if nxt is None and occupied:
                     nxt = (b._issue(), list(self._occupant))
                 inflight = nxt

@@ -43,14 +43,37 @@ log = logging.getLogger(__name__)
 _server_started = False
 
 
+class _Phase:
+    """One ``with`` block of :meth:`PhaseTimes.phase`: the row is entered
+    inside the timed interval, and the interval is observed whether the
+    block returns or raises. A class and not a generator: a dozen of
+    these a decode chunk run on the engine thread."""
+
+    __slots__ = ("times", "name", "row", "t0")
+
+    def __init__(self, times: "PhaseTimes", name: str, row) -> None:
+        self.times = times
+        self.name = name
+        self.row = row
+
+    def __enter__(self) -> None:
+        self.t0 = time.perf_counter()
+        self.row.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self.row.__exit__(*exc)
+        self.times.observe(self.name, time.perf_counter() - self.t0)
+        return False
+
+
 class PhaseTimes:
     """Wall-clock accumulator for the phases of a host-driven loop.
 
     Usage::
 
         times = PhaseTimes("tony.engine")
-        with times.phase("dispatch"):   # profiler row tony.engine.dispatch
-            handle = issue_chunk()
+        with times.phase("dispatch", seq=7):  # row tony.engine.dispatch
+            handle = issue_chunk()            # seq=7 rides as metadata
         with times.phase("fetch"):
             host = np.asarray(handle)
         times.observe("queue_wait", now - t_queued)   # not a with block
@@ -76,16 +99,15 @@ class PhaseTimes:
         self._total: dict[str, float] = {}
         self._count: dict[str, int] = {}
 
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        row = (tracing.profiler_annotation(f"{self.prefix}.{name}")
-               if self.prefix else tracing.NO_ANNOTATION)
-        t0 = time.perf_counter()
-        try:
-            with row:
-                yield
-        finally:
-            self.observe(name, time.perf_counter() - t0)
+    def phase(self, name: str, **attrs) -> "_Phase":
+        """Time the enclosed block under ``name``. ``attrs`` ride the
+        profiler row as its metadata (what a capture shows beside the
+        event: a sequence number, a batch's size); they are not
+        accumulated, and with no capture running the profiler drops
+        them unread."""
+        return _Phase(self, name, tracing.profiler_annotation(
+            f"{self.prefix}.{name}", **attrs)
+            if self.prefix else tracing.NO_ANNOTATION)
 
     def observe(self, name: str, seconds: float) -> None:
         """Add one interval that was not a ``with`` block (a wait that

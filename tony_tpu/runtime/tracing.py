@@ -15,9 +15,14 @@ Design constraints (mirrors metrics.py):
 - **dependency-free** — stdlib only; importable from the jax-free
   serving client, the executor, and user training processes alike;
 - **cheap when off** — an unsampled span is one RNG draw and a constant
-  return; a recorded span is one dict build + two deque appends. The
-  bench's trace-overhead arm pins the sampled-on cost under 1 % of a
-  serve chunk's wall;
+  return; a recorded span is one dict build + two deque appends. Read on
+  the chip (PERF.md section 6, PR 39; three seeds a cell, every
+  request's three spans recorded against ``TONY_TRACE_SAMPLE_RATE=0``):
+  3,388.7 / 3,394.4 / 3,394.4 against 3,394.4 / 3,405.8 / 3,411.4
+  tokens/s in ``serve-kimik25-saturated`` (0-0.5 %, counted in chunks of
+  0.17 %), 511.7 / 511.7 / 505.8 against 511.7 three times in
+  ``serve-phi3mini-saturated``, ``itl_p95_ms`` within 0.3 % either way:
+  less than a pair of runs resolves;
 - **never load-bearing** — a tracing failure (spool IO, malformed batch,
   dump error) is logged and dropped; it must never cost a heartbeat, a
   request, or a step.
@@ -133,7 +138,9 @@ def profiler_annotation(name: str, step_num: int | None = None, **attrs):
     ``name`` on the host timeline of a ``jax.profiler`` capture — the
     same clock as the device planes. With ``step_num`` the row is a STEP
     root (``jax.profiler.StepTraceAnnotation``): xprof's step view groups
-    the device ops under it. ``attrs`` ride as the event's metadata.
+    the device ops under it. ``attrs`` ride as the event's metadata;
+    with no capture running nothing would read them, so they are let go
+    before the profiler formats them.
 
     JAX is looked up in ``sys.modules`` and never imported: the serving
     client, the router and the executor use this module too and must
@@ -141,6 +148,8 @@ def profiler_annotation(name: str, step_num: int | None = None, **attrs):
     prof = getattr(sys.modules.get("jax"), "profiler", None)
     if prof is None:
         return NO_ANNOTATION
+    if attrs and not prof.TraceAnnotation.is_enabled():
+        attrs = {}
     if step_num is not None:
         return prof.StepTraceAnnotation(name, step_num=step_num, **attrs)
     return prof.TraceAnnotation(name, **attrs)
