@@ -540,7 +540,7 @@ def _streaming_arm(slots: int = 3, n_req: int = 6, prompt_len: int = 8,
 
         def _admit_batch(self, pairs, prompts):
             time.sleep(round_trip_s)
-            super()._admit_batch(pairs, prompts)
+            return super()._admit_batch(pairs, prompts)
 
     rs = np.random.RandomState(11)
     prompts = [[int(t) for t in rs.randint(0, cfg.vocab_size,

@@ -32,11 +32,12 @@ CFG = T.PRESETS["tiny"].scaled(dtype=jnp.float32, remat=False)
 
 # what each thing costs, in the clock's units (whole numbers: exact sums)
 ISSUE, ADMIT_CALL, PAD, RETIRE, EMIT, ACCOUNT = 1, 2, 3, 1, 1, 1   # host
+DRAW_CALL = 1                      # the first-token draw's dispatch: host
 CHUNK_DEV, ADMIT_DEV = 100, 30                                   # device
 IDLE = 1000                                      # one block on the queue
 
-TILING = ("dispatch", "fetch", "consume", "admit_pick", "admit", "retire",
-          "account")
+TILING = ("dispatch", "fetch", "first_fetch", "consume", "admit_pick",
+          "admit", "retire", "account")
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +82,18 @@ class _Handle:
         w = self.world
         w.t = max(w.t, self.done)
         w.log.append(("fetch", w.t, self.done))
+        return np.asarray(self.toks)
+
+
+class _FirstHandle(_Handle):
+    """A wave's first tokens on the simulated device: ready when the
+    device has run everything enqueued before the draw (the draw itself
+    takes it no time)."""
+
+    def __array__(self, dtype=None, copy=None):
+        w = self.world
+        w.t = max(w.t, self.done)
+        w.log.append(("first_fetch", w.t, self.done))
         return np.asarray(self.toks)
 
 
@@ -131,7 +144,8 @@ def _scripted(cls, world):
 
 
 #: the program's own, however many worlds a test has wrapped them in
-_PROGRAMS = (S.step_rows, S.spec_step_rows, S.cache_rows_visited)
+_PROGRAMS = (S.step_rows, S.spec_step_rows, S.cache_rows_visited,
+             S.first_tokens)
 
 
 def _patch_world(monkeypatch, world):
@@ -154,6 +168,11 @@ def _patch_world(monkeypatch, world):
             world.tick(ACCOUNT)
             return fn(*a, **kw)
         return call
+    def draw(*a, **kw):
+        world.tick(DRAW_CALL)
+        return _FirstHandle(world, _PROGRAMS[3](*a, **kw),
+                            world.enqueue("draw", 0))
+    monkeypatch.setattr(S, "first_tokens", draw)
     monkeypatch.setattr(S, "step_rows", chunk(_PROGRAMS[0]))
     monkeypatch.setattr(S, "spec_step_rows", chunk(_PROGRAMS[1]))
     monkeypatch.setattr(S, "cache_rows_visited", counted(_PROGRAMS[2]))
@@ -227,6 +246,7 @@ def _run(monkeypatch, params, *, kind="pipelined", script=SCRIPT):
     assert [len(got[r]) for r in range(len(script))] == \
         [budget for _, budget, _ in script]
     world.script, world.tokens_a_chunk = script, b._chunk_tokens_max()
+    world.engine = engine
     return world, b, got, world.t - t0, t0
 
 
@@ -266,6 +286,16 @@ def _expect(world, t0, t_end):
     budgets = {rid: b for rid, (_, b, _) in enumerate(world.script)}
     t_queued = {}
     snaps = {}          # chunk's seq -> rows' rids when it was enqueued
+    wave = []           # the (row, rid) pairs admitted last
+    draws = []          # waves whose first-token draw is still unfetched
+    ahead = set()       # rids whose first token left ahead of its chunk
+
+    def first_delta(rid, t):
+        t_admit, t_ride = admitted[rid]
+        observe("first_token", t - t_admit)
+        observe("first_token_queued", t_ride - t_admit)
+        observe("first_token_ride", t - t_ride)
+
     for i, (kind, t, data) in enumerate(world.log):
         if kind == "submit":
             waiting.add(data)
@@ -290,6 +320,19 @@ def _expect(world, t0, t_end):
                         t - max(freed.get(row, 0.0), t_queued[rid]))
                 on_row[row] = rid
                 left[rid] = budgets[rid]
+            wave = list(data)
+        elif kind == "draw":
+            draws.append(wave)
+        elif kind == "first_fetch":
+            # fetched in the order drawn; every request of the wave gets
+            # ONE token here, and that is its first delta
+            for row, rid in draws.pop(0):
+                first_delta(rid, t)
+                observe("first_token_early", t - admitted[rid][0])
+                ahead.add(rid)
+                left[rid] -= 1
+                if left[rid] <= 0:
+                    freed[row] = t
         elif kind in ("chunk", "admit"):
             seq = len(programs)
             if run_first is None:
@@ -323,12 +366,14 @@ def _expect(world, t0, t_end):
             for row, rid in snaps[seq].items():
                 if left.get(rid, 0) <= 0:
                     continue
-                if left[rid] == budgets[rid]:           # its first delta
-                    t_admit, t_ride = admitted[rid]
-                    observe("first_token", t - t_admit)
-                    observe("first_token_queued", t_ride - t_admit)
-                    observe("first_token_ride", t - t_ride)
-                left[rid] -= world.tokens_a_chunk
+                if left[rid] == budgets[rid]:
+                    # its first delta rides a chunk: the loop drew none
+                    # ahead of it (the speculative one)
+                    first_delta(rid, t)
+                # column 0 of a row's first chunk is the token that left
+                # ahead of it
+                left[rid] -= world.tokens_a_chunk - (rid in ahead)
+                ahead.discard(rid)
                 if left[rid] <= 0:
                     freed[row] = t
     slivers += t_end - (turn_open if turn_open is not None
@@ -356,7 +401,7 @@ def _held(b, totals):
         assert (pt.total(name), pt.count(name)) == (total, count), name
     for name in ("turn", "turn_clean", "turn_admit", "turn_loaded",
                  "starved", "first_token_queued", "first_token_ride",
-                 "slot_vacant"):
+                 "first_token_early", "slot_vacant"):
         if name not in totals:
             assert pt.count(name) == 0, name
 
@@ -395,6 +440,22 @@ def test_every_loop_matches_the_log(monkeypatch, params, kind):
     # closes on one
     assert n_chunks - n_fetches == (3 if kind == "speculative" else 0)
     assert b.seq == n_admits + n_chunks
+    # the draw is a program in the queue and no turn knows it: it takes
+    # no seq and is no admission dispatch. One a wave, fetched once (the
+    # wait is a phase of its own, so the tiling above still holds) and
+    # consumed once; every first token left by it, a chunk early
+    n_draws = sum(k == "draw" for k, _, _ in world.log)
+    early = world.engine.stats()["first_tokens_early"]
+    if kind == "speculative":
+        assert n_draws == pt.count("first_fetch") == early == 0
+        # ... where it rides its chunk, as it did
+        assert pt.total("first_token_ride") >= len(SCRIPT) * CHUNK_DEV
+    else:
+        assert n_draws == pt.count("admit") == pt.count("first_fetch") > 0
+        assert early / pt.count("first_token") == 1.0
+        assert pt.count("first_token_early") == early
+        assert pt.total("first_token_ride") < len(SCRIPT) * CHUNK_DEV
+    assert pt.count("consume") == n_fetches + pt.count("first_fetch")
     if kind == "sequential":
         assert pt.count("turn_clean") == 0      # never a chunk in flight
         # every turn but a run's first opens on an idle device
@@ -432,17 +493,24 @@ def test_the_pipelined_script_by_hand(monkeypatch, params):
         "turn_loaded": (367 + 397 + 260, 8),
         # fetch return -> consume's two callbacks 2, pad 3, call 2
         "starved": (7 + 7, 2),
-        # 135 = pad 3 + call 2 + admission 30 + chunk 100; run 2's wave
-        # 165 (both dispatches ahead); the joiner 229 = 99 behind the
-        # chunk in flight + 130
-        "first_token": (5 * 135 + 2 * 165 + 229, 8),
+        # 35 = pad 3 + call 2 + admission 30: the draw's own call (1)
+        # runs while the device runs the admission, and NO chunk is in
+        # it; run 2's wave 65 (both dispatches ahead); the joiner 129 =
+        # 99 behind the chunk in flight + 30
+        "first_token": (5 * 35 + 2 * 65 + 129, 8),
         "first_token_queued": (99, 8),
-        "first_token_ride": (5 * 135 + 2 * 165 + 130, 8),
-        # the two admissions after a deferred issue: 2 of callbacks
-        "slot_vacant": (2 + 2, 8),
-        "queue_wait": (237 + 267, 8),
+        "first_token_ride": (5 * 35 + 2 * 65 + 30, 8),
+        # the two admissions after a deferred issue: 2 of callbacks; the
+        # joiner is submitted as request 6's first delta leaves — a
+        # chunk (100) earlier than the token used to — and is admitted
+        # where it was: its wait, and its never-used slot's, grow by it
+        "slot_vacant": (2 + 2 + 100, 8),
+        "queue_wait": (237 + 267 + 100, 8),
         "admit_dispatch": (7 * ADMIT_CALL, 7),
         "wait": (3 * IDLE, 3)}
+    # every first token left ahead of its chunk
+    assert (pt.total("first_token_early"), pt.count("first_token_early")) \
+        == (pt.total("first_token"), 8)
     assert wall == 4247
 
 

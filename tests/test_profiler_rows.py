@@ -230,9 +230,11 @@ def test_engine_counts_waits_where_they_end(params):
     assert pt.count("queue_wait") == admissions      # the re-admission too
     assert pt.count("first_token") == 3              # once per request
     assert pt.total("queue_wait") >= 0.0 and pt.total("first_token") > 0.0
-    # the loop's new phases: every fetched chunk is consumed; deltas and
-    # retirements are emitted inside consume; the sweep runs each turn
-    assert pt.count("consume") == pt.count("fetch") > 0
+    # the loop's new phases: every fetched chunk is consumed, and every
+    # fetched wave of first tokens; deltas and retirements are emitted
+    # inside consume; the sweep runs each turn
+    assert pt.count("consume") == pt.count("fetch") \
+        + pt.count("first_fetch") > pt.count("fetch") > 0
     assert 0 < pt.count("emit") <= pt.count("consume")
     assert pt.total("emit") <= pt.total("consume")
     assert pt.count("admit_pick") >= pt.count("admit") > 0
@@ -243,7 +245,8 @@ def test_engine_counts_waits_where_they_end(params):
     assert pt.prefix == "tony.engine"
     # ... and ride the registry fold the engine already does at exit
     for phase in ("queue_wait", "first_token", "emit", "consume",
-                  "admit_pick", "dispatch", "fetch", "admit", "retire"):
+                  "admit_pick", "dispatch", "fetch", "first_fetch",
+                  "admit", "retire"):
         assert reg.counter("tony_serve_phase_ops_total",
                            phase=phase).value == pt.count(phase), phase
 
@@ -282,8 +285,8 @@ def test_capture_holds_the_engine_rows(params, tmp_path):
     events = _tony_events(str(tmp_path))
     names = {e[0] for e in events}
     assert {"tony.engine." + p for p in (
-        "dispatch", "fetch", "consume", "emit", "admit", "admit_pick",
-        "retire", "admit_dispatch", "account")} <= names
+        "dispatch", "fetch", "first_fetch", "consume", "emit", "admit",
+        "admit_pick", "retire", "admit_dispatch", "account")} <= names
     # emit nests in consume: each emit lies inside some consume
     consumes = [e for e in events if e[0] == "tony.engine.consume"]
     for _, s, t, _ in (e for e in events if e[0] == "tony.engine.emit"):

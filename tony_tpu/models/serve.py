@@ -33,6 +33,9 @@ shared padded cache. On top of that, the device programs:
   the whole batch (one dispatch per chunk, not per token; greedy by
   default, or sampled through the same top-k/temperature/nucleus stack
   as ``decode.generate`` — from PER-REQUEST key streams, see below);
+- :func:`first_tokens` — the draw step 0 of the next chunk will make,
+  made once a wave right behind its admissions, so that a request's
+  first token leaves when its admission has run (below);
 - :func:`retire_rows` — zero the freed rows' frontiers so idle slots
   never walk off the end of the cache.
 
@@ -94,6 +97,18 @@ issue/fetch/consume/settle cycle runs against a LIVE admission queue —
 requests are submitted (and cancelled) at any time, from any thread, and
 each request's newly generated tokens are emitted as a DELTA the moment
 the chunk that produced them is consumed, not when the request retires.
+THE FIRST TOKEN LEAVES WITH ITS ADMISSION: it is step 0 of the chunk
+enqueued behind the admission — a function of the logits the admission
+seeded and of the request's own key, so it exists the instant the
+admission ends. After each admission wave the engine enqueues one
+:func:`first_tokens` draw over those logits, fetches it in device-queue
+order (before the fetch of the first chunk enqueued after it, never
+before a chunk enqueued ahead of it: :meth:`ServeEngine._fetch`) and
+sends each admitted request a first delta of ONE token; ``emitted``,
+``budget`` and ``history`` move with it, and the chunk that carries the
+same token as its column 0 drops it. (The speculative batcher keeps its
+own first token — the seed its admission draws into ``pending`` — with
+its first chunk.)
 That is what a streaming serving data plane needs: time-to-first-token
 and inter-token latency are properties of delta emission, and a
 persistent-connection server (``tony_tpu/serving/``) pushes each delta
@@ -130,29 +145,38 @@ Where the engine thread's wall goes is named from inside
 ``on_delta`` / ``on_retired`` callbacks: frame packing and socket sends
 on the engine thread — ``consume``'s self time is ``consume − emit``),
 ``admit_pick`` (the admission sweep outside the device dispatch: lock,
-class-priority pop, preemption) and ``wait`` (blocked on an empty
-queue). Two request WAITS ride the same accumulator through
-``observe``: ``queue_wait`` (entering the wait queue → slot admission,
-once per admission) and ``first_token`` (admission → first consumed
-delta, once per request that produced a token). They are waits, not
-loop phases: they overlap across requests and do not sum to the wall.
-``admit_dispatch`` nests in ``admit``, one per device dispatch (a wave
-of two buckets is two): the marshalling that feeds the jit call and the
-call, so ``admit − admit_dispatch`` is the padding and the stream
-rebinding. ``account`` is the host arithmetic that is instrumentation
-itself (the cache-row count at each issue, the device counters' fold at
-each fetch and consume): what counting costs is counted.
+class-priority pop, preemption), ``first_fetch`` (blocked on a wave's
+first-token draw; its delivery is a ``consume`` with its ``emit``, like
+a chunk's) and ``wait`` (blocked on an empty queue). Two request WAITS
+ride the same accumulator through ``observe``: ``queue_wait`` (entering
+the wait queue → slot admission, once per admission) and ``first_token``
+(admission → first delta, once per request that produced a token;
+``first_token_early`` is the same wait of the requests whose first token
+left ahead of its chunk — over ``first_token``'s count, the share that
+did; ``tony_serve_first_tokens_early_total`` and ``stats()`` hold the
+count). They are waits, not loop phases: they overlap across requests
+and do not sum to the wall. ``admit`` is a whole admission wave;
+``admit_dispatch`` nests in it, one per device dispatch (a wave of two
+buckets is two): the marshalling that feeds the jit call and the call,
+so ``admit − admit_dispatch`` is the padding, the stream rebinding and
+the draw's dispatch. ``account`` is the host arithmetic that is
+instrumentation itself (the cache-row count at each issue, the device
+counters' fold at each fetch and consume): what counting costs is
+counted.
 
 THE DEVICE-QUEUE TIMELINE is measured in the same accumulator, with no
 capture running. The device runs programs in the order the engine
 thread enqueued them, so that order is the causal chain:
 
 - every program — a decode chunk or an admission dispatch — takes the
-  next sequence number ``seq`` as it is enqueued. ``dispatch`` rows
-  carry ``seq``, ``live`` (occupied slots) and ``waiting`` (requests in
-  the wait queues); ``admit_dispatch`` rows ``seq``, ``bucket``,
-  ``rows`` (the dispatch's width) and ``tokens`` (real positions);
-  ``fetch`` rows the ``seq`` they block on. In a capture the n-th
+  next sequence number ``seq`` as it is enqueued (a wave's first-token
+  draw takes none: it stands right ahead of the next chunk, whose
+  ``seq`` its ``first_fetch`` row carries, and no turn knows it).
+  ``dispatch`` rows carry ``seq``, ``live`` (occupied slots) and
+  ``waiting`` (requests in the wait queues); ``admit_dispatch`` rows
+  ``seq``, ``bucket``, ``rows`` (the dispatch's width) and ``tokens``
+  (real positions); ``fetch`` rows the ``seq`` they block on. In a
+  capture the n-th
   ``jit_step_rows`` / ``jit_admit_rows`` execution on the device line
   after its first ``seq`` is the row with that number.
 - a RUN is the feeding of the device between two ``wait`` blocks; within
@@ -176,21 +200,23 @@ thread enqueued them, so that order is the causal chain:
 - a request's ``first_token`` splits where the chunk that was in flight
   at its admission returned: ``first_token_queued`` (the admission stood
   behind that chunk; 0 when none was in flight) and ``first_token_ride``
-  (the admission on the device, the chunk the first token rides, its
-  consumption); the two sum to ``first_token``. ``slot_vacant``, at each
+  (the admission on the device, the fetch of the draw and its
+  delivery — no chunk; the speculative batcher's still rides its first
+  chunk); the two sum to ``first_token``. ``slot_vacant``, at each
   admission, is ``t_admit − max(the return of the chunk whose
   consumption freed the slot, the request's t_queued)``: how long a free
   slot and a runnable request both waited for the loop to come round.
   The request spans name their causes: ``engine.queued`` ends with
   ``slot`` and ``freed_seq`` (the chunk that freed it, −1 for a slot
-  never used), ``engine.first_token`` with ``admit_seq`` and
-  ``chunk_seq`` (the chunk that delivered).
+  never used), ``engine.first_token`` with ``admit_seq``, ``chunk_seq``
+  (the first chunk enqueued behind the admission: the one that carries
+  the token) and ``early`` (it left ahead of that chunk).
 
 ``wait`` and the turns tile the engine thread's wall but for each run's
 edges (the sweep before its first enqueue, the consume and settle after
-its last fetch); inside a turn ``dispatch + fetch + consume +
-admit_pick + admit + retire + account`` leave only the loop's own
-branches untimed.
+its last fetch); inside a turn ``dispatch + fetch + first_fetch +
+consume + admit_pick + admit + retire + account`` leave only the loop's
+own branches untimed.
 
 ``TRACE_COUNTS`` records one entry per (program, static shape) TRACE —
 a Python side effect inside the jitted bodies, executed at trace time
@@ -310,6 +336,17 @@ def _row_samples(logits, keys, temperature, top_k, top_p):
     f = _filter_logits(logits.astype(jnp.float32), temperature, top_k,
                        top_p)
     return jax.vmap(jax.random.categorical)(keys, f)
+
+
+def _stream_draws(logits, keys, offsets, temperature, top_k, top_p):
+    """One decision per row at its OWN stream position: row ``r`` samples
+    from ``fold_in(keys[r], offsets[r])`` (argmax at ``temperature ==
+    0``). The one draw behind every step of :func:`step_rows` and behind
+    :func:`first_tokens`, so the token that leaves ahead of a chunk IS
+    that chunk's step 0."""
+    step_keys = (jax.vmap(jax.random.fold_in)(keys, offsets)
+                 if temperature > 0.0 else None)
+    return _row_samples(logits, step_keys, temperature, top_k, top_p)
 
 
 def _place_prefill(cache, mini, row, s_p):
@@ -532,15 +569,30 @@ def step_rows(params, cache, logits, keys, offsets, n, cfg,
 
     def body(carry, j):
         lg, c = carry
-        step_keys = (jax.vmap(jax.random.fold_in)(keys, offsets + j)
-                     if temperature > 0.0 else None)
-        tok = _row_samples(lg, step_keys, temperature, top_k, top_p)
+        tok = _stream_draws(lg, keys, offsets + j, temperature, top_k,
+                            top_p)
         lg, c = decode_step(params, tok, c, c["length"], cfg)
         return (lg, c), tok
 
     (lg, cache), toks = jax.lax.scan(body, (logits, cache),
                                      jnp.arange(n))
     return toks.T, cache, lg, _device_stats(cache)
+
+
+@functools.partial(jax.jit, static_argnames=("temperature", "top_k",
+                                             "top_p"))
+def first_tokens(logits, keys, offsets, temperature=0.0, top_k=0,
+                 top_p=0.0):
+    """Every row's NEXT token from the engine's ``logits`` as they stand:
+    the draw step 0 of the next :func:`step_rows` makes
+    (:func:`_stream_draws`), enqueued right behind an admission wave so
+    that an admitted request's first token can leave when its admission
+    has run and not a chunk later. One width (all rows in, [B] tokens
+    out; the host keeps the rows it admitted) and nothing donated: ONE
+    traced program whatever the wave held, and whichever admission
+    program seeded the logits."""
+    _count_trace("first_tokens", logits.shape)
+    return _stream_draws(logits, keys, offsets, temperature, top_k, top_p)
 
 
 @functools.partial(jax.jit, donate_argnames=("cache",))
@@ -1250,7 +1302,12 @@ class ContinuousBatcher:
         prefill here) or a :class:`KVPackage` (disaggregated serving —
         the prefill already ran on another gang; landing is a scatter).
         The engine's admission sweep feeds both through one seam, so
-        the slot/occupancy machinery cannot diverge between modes."""
+        the slot/occupancy machinery cannot diverge between modes. The
+        whole wave is the phase ``admit``; each device dispatch in it —
+        the marshalling that feeds the call, and the call — is an
+        ``admit_dispatch`` nested in it, so ``admit − admit_dispatch``
+        is the padding, the rebinding and the dispatch of the wave's
+        first-token draw. Returns that draw (:meth:`_draw_first`)."""
         pkg, toks = [], []
         shared_p = len(self.shared_prefix) if self.shared_prefix else 0
         for pair in pairs:
@@ -1260,10 +1317,26 @@ class ContinuousBatcher:
                 p.length if isinstance(p, KVPackage)
                 else len(p.entry.tokens) + len(p.suffix)
                 if isinstance(p, _PrefixHit) else shared_p + len(p))
-        if pkg:
-            self._admit_packages(pkg, prompts)
-        if toks:
-            self._admit_prompts(toks, prompts)
+        with self.phase_times.phase("admit"):
+            if pkg:
+                self._admit_packages(pkg, prompts)
+            if toks:
+                self._admit_prompts(toks, prompts)
+            return self._draw_first()
+
+    def _draw_first(self):
+        """Enqueue the draw of the first token of every row the wave
+        just admitted (:func:`first_tokens`, behind the wave's last
+        dispatch and ahead of the next chunk) and return its
+        not-yet-materialized [B] tokens; the engine fetches them in
+        device-queue order (:meth:`ServeEngine._fetch`) and keeps the
+        rows it admitted. It reads ``logits`` and not an admission
+        program's internals, so it serves every admission program
+        alike. Not an admission dispatch and not a chunk: it takes no
+        ``seq``, and no turn's meaning moves."""
+        return first_tokens(self.logits, self._row_keys,
+                            jnp.asarray(self._row_off, jnp.int32),
+                            self.temperature, self.top_k, self.top_p)
 
     def _admit_packages(self, pairs, pkgs) -> None:
         """Land shipped-KV admissions: group by the landing bucket
@@ -1275,17 +1348,14 @@ class ContinuousBatcher:
         on device); zero padding differs from the colocated path's
         prefill-garbage padding only beyond the frontiers, where no
         query can reach — token outputs are identical."""
-        if not pairs:
-            return
-        with self.phase_times.phase("admit"):
-            groups: dict[int, list] = {}
-            for row, req in pairs:
-                w = pkgs[req].width
-                s_b = w if self._ring else bucket_for(
-                    w, self.max_len, self.admission_buckets)
-                groups.setdefault(s_b, []).append((row, req))
-            for s_b in sorted(groups):
-                self._land_group(groups[s_b], pkgs, s_b)
+        groups: dict[int, list] = {}
+        for row, req in pairs:
+            w = pkgs[req].width
+            s_b = w if self._ring else bucket_for(
+                w, self.max_len, self.admission_buckets)
+            groups.setdefault(s_b, []).append((row, req))
+        for s_b in sorted(groups):
+            self._land_group(groups[s_b], pkgs, s_b)
 
     def _land_group(self, grp, pkgs, s_b: int) -> None:
         b = self.batch
@@ -1382,61 +1452,55 @@ class ContinuousBatcher:
         model against the stored template (:func:`prefix_admit_rows`) —
         the admission fast path. Also rebinds each row's rng stream to
         its new occupant — one scatter of the wave's marshalled keys,
-        not a dispatch per row. The whole wave is the phase ``admit``;
-        each device dispatch in it — the marshalling that feeds the
-        call, and the call — is an ``admit_dispatch`` nested in it, so
-        ``admit − admit_dispatch`` is the padding and the rebinding."""
-        if not pairs:
-            return
+        not a dispatch per row."""
         pt = self.phase_times
-        with pt.phase("admit"):
-            if self._ring:
-                for row, req in pairs:
-                    n = len(prompts[req])
-                    with pt.phase("admit_dispatch", seq=self.seq, bucket=n,
-                                  rows=1, tokens=n):
-                        self.cache, self.logits = admit_row_ring(
-                            self.params, self.cache, self.logits, row,
-                            jnp.asarray(prompts[req], jnp.int32)[None],
-                            self.cfg)
-                        self._enqueued((row,))
-                    self.prefill_padded_tokens += n
-                rows, keys = self._marshal_wave(pairs)
-                self._rebind_streams(pairs, rows, keys)
-                self._count_admission(pairs, prompts)
-                return
-            groups: dict[tuple, list] = {}
+        if self._ring:
             for row, req in pairs:
-                p = prompts[req]
-                if isinstance(p, _PrefixHit):
-                    cap = self.max_len - len(p.entry.tokens)
-                    key = (p.entry.id,
-                           bucket_for(len(p.suffix), cap,
-                                      self.admission_buckets))
-                else:
-                    key = (None, self._bucket_for(len(p)))
-                groups.setdefault(key, []).append((row, req))
-            for pid, bucket in sorted(groups,
-                                      key=lambda k: (k[0] or "", k[1])):
-                whole = groups[(pid, bucket)]
-                entry = (prompts[whole[0][1]].entry if pid is not None
-                         else None)
-                w = admit_width(bucket, self.batch)
-                for i in range(0, len(whole), w):
-                    grp = whole[i:i + w]
-                    toks, lens = self._pad_prompts_to(grp, prompts,
-                                                      bucket, w)
-                    with pt.phase("admit_dispatch", seq=self.seq,
-                                  bucket=bucket, rows=w, tokens=sum(
-                                      len(self._seq_of(prompts[req]))
-                                      for _, req in grp)):
-                        rows, keys = self._marshal_wave(grp, w)
-                        self._admit_rows(rows, toks, lens, keys,
-                                         entry=entry)
-                        self._enqueued(row for row, _ in grp)
-                    self.prefill_padded_tokens += w * bucket
-                    self._rebind_streams(grp, rows, keys)
-                    self._count_admission(grp, prompts)
+                n = len(prompts[req])
+                with pt.phase("admit_dispatch", seq=self.seq, bucket=n,
+                              rows=1, tokens=n):
+                    self.cache, self.logits = admit_row_ring(
+                        self.params, self.cache, self.logits, row,
+                        jnp.asarray(prompts[req], jnp.int32)[None],
+                        self.cfg)
+                    self._enqueued((row,))
+                self.prefill_padded_tokens += n
+            rows, keys = self._marshal_wave(pairs)
+            self._rebind_streams(pairs, rows, keys)
+            self._count_admission(pairs, prompts)
+            return
+        groups: dict[tuple, list] = {}
+        for row, req in pairs:
+            p = prompts[req]
+            if isinstance(p, _PrefixHit):
+                cap = self.max_len - len(p.entry.tokens)
+                key = (p.entry.id,
+                       bucket_for(len(p.suffix), cap,
+                                  self.admission_buckets))
+            else:
+                key = (None, self._bucket_for(len(p)))
+            groups.setdefault(key, []).append((row, req))
+        for pid, bucket in sorted(groups,
+                                  key=lambda k: (k[0] or "", k[1])):
+            whole = groups[(pid, bucket)]
+            entry = (prompts[whole[0][1]].entry if pid is not None
+                     else None)
+            w = admit_width(bucket, self.batch)
+            for i in range(0, len(whole), w):
+                grp = whole[i:i + w]
+                toks, lens = self._pad_prompts_to(grp, prompts,
+                                                  bucket, w)
+                with pt.phase("admit_dispatch", seq=self.seq,
+                              bucket=bucket, rows=w, tokens=sum(
+                                  len(self._seq_of(prompts[req]))
+                                  for _, req in grp)):
+                    rows, keys = self._marshal_wave(grp, w)
+                    self._admit_rows(rows, toks, lens, keys,
+                                     entry=entry)
+                    self._enqueued(row for row, _ in grp)
+                self.prefill_padded_tokens += w * bucket
+                self._rebind_streams(grp, rows, keys)
+                self._count_admission(grp, prompts)
 
     def _count_admission(self, pairs, prompts) -> None:
         """Fold one admitted group into the host-side prefill-compute
@@ -1784,6 +1848,12 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
             "speculative serving is not supported in disaggregated "
             "mode (the shipment carries no draft-model cache)")
 
+    def _draw_first(self):
+        # the first token here is the seed the admission itself drew
+        # into ``pending``, a buffer the next round's dispatch donates:
+        # it leaves with its first chunk, as every committed token does
+        return None
+
     def _issue(self):
         with self._dispatch_phase():
             offs = jnp.asarray(self._row_off, jnp.int32)
@@ -1844,7 +1914,8 @@ class _EngineRequest:
     __slots__ = ("rid", "prompt", "budget", "stream", "rng_skip",
                  "emitted", "done", "reason", "t_submit", "t_last",
                  "t_queued", "t_admit", "t_ride", "admit_seq", "span",
-                 "queued_span", "first_span", "cls", "history", "requeued")
+                 "queued_span", "first_span", "cls", "history", "requeued",
+                 "sent_ahead")
 
     def __init__(self, rid, prompt, budget: int, stream: int,
                  t_submit: float, rng_skip: int = 0,
@@ -1885,6 +1956,10 @@ class _EngineRequest:
         #: retirement must not be emitted (the rid is still live) and
         #: its counters must not move
         self.requeued = False
+        #: the token that left ahead of this placement's first chunk
+        #: (:meth:`ServeEngine._consume_first`), until that chunk is
+        #: consumed: its column 0 is the same draw, and is dropped
+        self.sent_ahead: int | None = None
         # TTFT-decomposition spans (tracing.NOOP_SPAN when unsampled):
         # engine.request (submit→retire) with children engine.queued
         # (submit→slot admit) and engine.first_token (admit→first
@@ -1906,7 +1981,9 @@ class ServeEngine:
       request are consumed (NOT on retirement) — the emission point
       time-to-first-token and inter-token latency are measured at
       (``tony_serve_ttft_seconds`` / ``tony_serve_intertoken_seconds``
-      land in the registry here).
+      land in the registry here). A request's FIRST delta holds one
+      token and fires when its admission has run on the device, ahead
+      of the chunk enqueued behind it (:meth:`_fetch`).
     - ``on_retired(rid, reason, n_tokens, final_tokens)`` fires exactly
       once per request, reason one of ``"eos"``/``"budget"``/
       ``"cancelled"``/``"stopped"``/``"preempted"`` (the last only for
@@ -2016,6 +2093,13 @@ class ServeEngine:
         #: requests admitted behind a chunk still in flight: their
         #: ``first_token_queued`` ends when its fetch returns
         self._behind: list[_EngineRequest] = []
+        #: first-token draws enqueued and not fetched, oldest first:
+        #: (the ``seq`` the next chunk took, the draw's device tokens,
+        #: the wave's (row, request) pairs). A draw stands in the device
+        #: queue ahead of that chunk and behind every older one, and is
+        #: fetched in that order (:meth:`_fetch`)
+        self._firsts: collections.deque = collections.deque()
+        self._redraw_warned = False
         # Registry instrumentation: a handful of locked increments per
         # host SYNC (token counts batch into one inc per consume; the
         # TTFT/ITL histograms observe once per DELTA, <= slots per
@@ -2061,6 +2145,11 @@ class ServeEngine:
             c: reg.histogram("tony_serve_intertoken_seconds",
                              buckets=buckets, **{"class": c})
             for c in QOS_CLASSES}
+        self._early_c = reg.counter(
+            "tony_serve_first_tokens_early_total",
+            help="requests whose first token left with its admission, "
+                 "ahead of the chunk enqueued behind it (over the "
+                 "first_token phase's ops: the share that did)")
         self._preempt_c = reg.counter(
             "tony_serve_preemptions_total",
             help="batch rows evicted-to-queue for an interactive "
@@ -2375,6 +2464,9 @@ class ServeEngine:
                 "cache_rows_read": dict(self.b.cache_rows_read),
                 "cache_rows_live": dict(self.b.cache_rows_live),
                 "steps_executed": self.b.steps_executed,
+                # requests whose first token left ahead of its chunk
+                "first_tokens_early":
+                    self.b.phase_times.count("first_token_early"),
             }
 
     # --- the loop (one driving thread) ---
@@ -2423,6 +2515,7 @@ class ServeEngine:
             for q in self._waitq.values():
                 q.clear()
             self._occupant = [None] * self.b.batch
+            self._firsts.clear()
             self._set_qdepth_locked()
         for req in doomed:
             req.queued_span.end()
@@ -2563,9 +2656,12 @@ class ServeEngine:
         b = self.b
         before = (b.prefill_forward_tokens, b.prefix_copied_tokens,
                   b.prefix_admits, b.prefill_padded_tokens)
-        b._admit_batch(pairs, prompts)
+        first = b._admit_batch(pairs, prompts)
         for (row, _), req in zip(pairs, admitted):
             req.admit_seq = b._row_seq[row]
+        if first is not None:
+            self._firsts.append((b.seq, first, [
+                (row, req) for (row, _), req in zip(pairs, admitted)]))
         self._admitted_c.inc(len(admitted))
         # fold the batcher's host-side prefill accounting into the
         # registry (the batcher itself is registry-unaware)
@@ -2657,7 +2753,7 @@ class ServeEngine:
         ``consume``; the callbacks inside it are ``emit``."""
         for req in self._behind:
             # the chunk it stood behind has returned; its admission and
-            # the chunk its first token rides are what is left
+            # the fetch of its first token are what is left
             req.t_ride = self.b._t_turn
         self._behind.clear()
         with self.b.phase_times.phase("consume"):
@@ -2677,9 +2773,77 @@ class ServeEngine:
         self._occupant[row] = None
         self._freed[row] = (t, self.b._seq_run)
 
+    def _fetch(self, handle):
+        """The loops' fetch seam: block on the oldest unfetched chunk —
+        and first on every first-token draw that stands AHEAD of it in
+        the device queue (enqueued before it; the wait is the phase
+        ``first_fetch``, the delivery a ``consume``). Never on one
+        enqueued behind it: the admission it follows has yet to run,
+        and waiting for it here would add that admission to the gap of
+        every live stream. So an admitted request's first token leaves
+        when the device has reached it, one chunk's time ahead of the
+        chunk that carries it as column 0."""
+        b = self.b
+        seq = b._unfetched[0][0]
+        while self._firsts and self._firsts[0][0] <= seq:
+            _, first, rows = self._firsts.popleft()
+            with b.phase_times.phase("first_fetch", seq=seq):
+                host = np.asarray(first)
+            with b.phase_times.phase("consume"):
+                self._consume_first(host, rows, seq)
+        return b._fetch(handle)
+
+    def _take_locked(self, req, row: int, toks, t_free: float) -> list:
+        """Move ``req``'s books by ``toks``, in order, up to the token
+        that ends it (eos or its budget; the surplus is discarded): a
+        preemption, a cancel or a failover from here on sees what the
+        client saw. A request that ends frees its slot, free since
+        ``t_free``. Returns the tokens taken."""
+        eos = self.b.eos_id
+        new = []
+        for t in toks:
+            t = int(t)
+            new.append(t)
+            req.emitted += 1
+            req.budget -= 1
+            if req.budget == 0 or (eos is not None and t == eos):
+                req.done = True
+                req.reason = ("eos" if eos is not None and t == eos
+                              else "budget")
+                self._reqs.pop(req.rid, None)
+                if self._occupant[row] is req:
+                    self._free_slot(row, t_free)
+                break
+        if new and req.history is not None:
+            # evictable row: a preemption folds these into the
+            # reincarnation's prompt
+            req.history.extend(new)
+        return new
+
+    def _consume_first(self, host, rows, chunk_seq: int) -> None:
+        """Deliver a wave's first tokens (``host``: the draw's [B]
+        tokens; ``rows``: the wave's (row, request) pairs), ahead of
+        chunk ``chunk_seq`` — the first enqueued behind the wave, which
+        carries the same tokens as its column 0. A request cancelled or
+        preempted behind its admission gets nothing (its reincarnation
+        re-prefills the whole prompt); one that ends on this token (eos,
+        a budget of 1) retires here and frees its slot, and its row of
+        the chunk is discarded as a mid-flight cancel's is."""
+        deltas, retired = [], []
+        now = time.perf_counter()
+        with self._lock:
+            for row, req in rows:
+                if req.done:
+                    continue
+                req.sent_ahead = int(host[row])
+                deltas.append((req, self._take_locked(
+                    req, row, (req.sent_ahead,), now)))
+                if req.done:
+                    retired.append(req)
+        self._deliver(deltas, retired, chunk_seq, early=True)
+
     def _consume_chunk(self, host_toks, snap) -> None:
         deltas, retired = [], []
-        eos = self.b.eos_id
         # a slot this chunk frees has been free since the chunk returned
         t_chunk = self.b._t_turn
         with self._lock:
@@ -2689,29 +2853,35 @@ class ServeEngine:
                         # cancelled mid-flight: free the slot now
                         self._free_slot(row, t_chunk)
                     continue
-                new = []
-                for t in host_toks[row]:
-                    t = int(t)
-                    new.append(t)
-                    req.emitted += 1
-                    req.budget -= 1
-                    if req.budget == 0 or (eos is not None and t == eos):
-                        # surplus chunk tokens past completion discarded
-                        req.done = True
-                        req.reason = ("eos" if eos is not None and t == eos
-                                      else "budget")
-                        self._reqs.pop(req.rid, None)
-                        if self._occupant[row] is req:
-                            self._free_slot(row, t_chunk)
-                        break
+                toks = host_toks[row]
+                if req.sent_ahead is not None:
+                    # column 0 left ahead of this chunk: the same draw
+                    # from the same logits, so the same token
+                    if int(toks[0]) != req.sent_ahead \
+                            and not self._redraw_warned:
+                        self._redraw_warned = True
+                        import logging
+                        logging.getLogger(__name__).error(
+                            "request %r: first token sent as %d, its "
+                            "chunk drew %d", req.rid, req.sent_ahead,
+                            int(toks[0]))
+                    req.sent_ahead = None
+                    toks = toks[1:]
+                new = self._take_locked(req, row, toks, t_chunk)
                 if new:
-                    if req.history is not None:
-                        # evictable row: a preemption folds these into
-                        # the reincarnation's prompt
-                        req.history.extend(new)
                     deltas.append((req, new))
                 if req.done:
                     retired.append(req)
+        self._deliver(deltas, retired, self.b._seq_run)
+
+    def _deliver(self, deltas, retired, chunk_seq: int,
+                 early: bool = False) -> None:
+        """Close the waits that end with these deltas and hand them to
+        the transport: ``deltas`` is [(request, its new tokens)], and
+        those of them in ``retired`` (ended by their last token, under
+        the lock) retire with theirs as the final delta. ``chunk_seq``
+        is the chunk that delivered, or — ``early`` — the one the tokens
+        left ahead of."""
         now = time.perf_counter()
         appended = 0
         finals = {id(req): new for req, new in deltas
@@ -2727,11 +2897,16 @@ class ServeEngine:
                 pt.observe("first_token", now - req.t_admit)
                 # ... split where the chunk in flight at the admission
                 # returned: behind it, then the admission on the device
-                # and the chunk this delta rode
+                # up to this delta
                 pt.observe("first_token_queued", req.t_ride - req.t_admit)
                 pt.observe("first_token_ride", now - req.t_ride)
+                if early:
+                    # the same wait, of those that did not ride a chunk:
+                    # over first_token's ops, the share that left early
+                    pt.observe("first_token_early", now - req.t_admit)
+                    self._early_c.inc()
                 req.first_span.end(admit_seq=req.admit_seq,
-                                   chunk_seq=self.b._seq_run)
+                                   chunk_seq=chunk_seq, early=early)
             else:
                 gap = (now - req.t_last) / len(new)
                 self._itl_h.observe(gap)
@@ -2751,7 +2926,7 @@ class ServeEngine:
                     for req in retired))
         if not deltas:
             return
-        # what the transport does with the chunk, ON this thread: the
+        # what the transport does with the tokens, ON this thread: the
         # frame server packs and sends each delta from these callbacks
         with self.b.phase_times.phase("emit"):
             # a retiring request's FINAL delta rides its retirement
@@ -2843,7 +3018,7 @@ class ServeEngine:
                 if (not self._stopped and not self._certainly_final()
                         and not self._defer_issue(snap)):
                     nxt = (b._issue(), list(self._occupant))
-                self._consume(b._fetch(handle), snap)
+                self._consume(self._fetch(handle), snap)
                 self._settle()
                 if self._stopped:
                     return               # drop any in-flight chunk
@@ -2872,5 +3047,5 @@ class ServeEngine:
                     self._settle()
                     break
                 snap = list(self._occupant)
-                self._consume(b._fetch(b._issue()), snap)
+                self._consume(self._fetch(b._issue()), snap)
                 self._settle()
