@@ -15,6 +15,15 @@ an assertion that fails earlier (the tree equalities) fails this test,
 and so does the case passing. The same tree equalities stand as positive
 assertions in ``test_a_sparse_family_counts_its_trees`` below and, with
 the published numbers, in ``tests/test_latent_moe.py``.
+
+PR 41's family (``ssm_hybrid_decoder``: dense, state-space layers, a TIED
+head) meets the first inequality and fails the case's LAST statement
+instead — a decode step's bytes held at two a parameter less the
+embedding — because its head reads the embedding whole in every step (and
+a mixer's ``dt_bias``, ``A_log`` and ``D`` are float32): the second known
+line. Its own counts stand, to the unit, in
+``benchmark/tests/test_ssm_family.py::test_counts_at_the_published_widths``,
+collected here.
 """
 
 import json
@@ -43,6 +52,17 @@ from benchmark.tests.test_scmoe_family import (  # noqa: F401 — PR 37's
     test_gmm_share_of_the_decode_chunks,
     test_gmm_share_reads_none_without_the_kernel_or_a_chunk,
     test_reference_block_is_softmax_over_all_outputs_and_identity_zeros)
+from benchmark.tests.test_ssm_family import (  # noqa: F401 — PR 41's
+    test_counts_at_the_published_widths,
+    test_each_fault_of_the_state_crosses_the_toy_limits,
+    test_no_kernel_no_chunk_or_no_shape_function_reads_none,
+    test_reference_mixer_is_the_recurrence_a_token_at_a_time,
+    test_state_update_share_and_roofline_of_the_decode_chunks,
+    test_the_live_mask_skips_padding_as_an_admission_must)
+from benchmark.tests.test_ssm_family import (  # noqa: F401
+    test_refused_with_the_reason as test_ssm_family_refuses_with_the_reason,
+    test_the_cells_files_are_the_issues as
+    test_the_state_space_cells_files_are_the_issues)
 from benchmark.tests.test_timeline_readers import (  # noqa: F401 — PR 39's
     test_a_program_without_the_phases_reads_none,
     test_admissions_without_a_clean_turn_read_none,
@@ -61,9 +81,11 @@ def test_the_wide_decode_cells_files_are_the_issues(monkeypatch):
     cell's list of metrics whole, so every entry a later PR appends turns
     ``python -m pytest benchmark/tests`` red on it (PERF.md section 7, PR
     39) — and the file is the benchmark's, which a program PR may not
-    edit. Here the case reads ``BENCHMARK.json`` as PR 37 left it: what
-    it pinned must still stand untouched, and what follows its entry is
-    appended (later PRs hold their own entries by their own cases)."""
+    edit. It holds its cell as the LAST of ``workloads`` too, which PR
+    41's cell now follows. Here the case reads ``BENCHMARK.json`` as PR
+    37 left it: what it pinned must still stand untouched, and what
+    follows its entries is appended (later PRs hold their own entries by
+    their own cases)."""
     def as_pr37_left(f):
         obj = json.load(f)
         if "per_layer" in obj:
@@ -73,16 +95,26 @@ def test_the_wide_decode_cells_files_are_the_issues(monkeypatch):
                 "chunk_turn_ms.serve", "admit_stall_ms.serve",
                 "admit_stall_share_pct.serve", "device_starved_pct.serve",
                 "first_token_queued_ms.serve", "first_token_ride_ms.serve",
-                "slot_vacant_ms.serve"]
+                "slot_vacant_ms.serve", "ssm_step_share_pct.serve",
+                "ssm_state_roofline.serve"]
             obj["per_layer"] = obj["per_layer"][:last + 1]
+            cells = [w["name"] for w in obj["workloads"]]
+            last = cells.index("serve-longcatflash-wide-decode")
+            assert cells[last + 1:] == ["serve-granite4hmicro-wide-decode"]
+            obj["workloads"] = obj["workloads"][:last + 1]
         return obj
     monkeypatch.setattr(scmoe_cases, "json",
                         types.SimpleNamespace(load=as_pr37_left))
     scmoe_cases.test_the_wide_decode_cells_files_are_the_issues()
 
 
-#: first line of the statement that only a dense family can meet
-DENSE_ONLY = "assert fam.forward_flops_per_token(c, 1024) > 2 * fam.param_count(c)"
+#: first lines of the two statements that only a dense family with an
+#: untied head can meet: the second holds a decode step's bytes at two a
+#: parameter LESS THE EMBEDDING, where a tied head reads the embedding
+#: whole in every step (and a mixer's dt_bias, A_log and D are float32)
+DENSE_ONLY = (
+    "assert fam.forward_flops_per_token(c, 1024) > 2 * fam.param_count(c)",
+    "assert fam.decode_step_bytes(c, 0.0, None) == 2 * (")
 SPARSE = [n for n in cases.CONFIGS
           if modelcfg.load(n)["family"] != "dense_decoder"]
 
@@ -96,11 +128,13 @@ def test_counts_are_the_trees(config):
     except AssertionError:
         at = traceback.extract_tb(sys.exc_info()[2])[-1]
         if not at.line.startswith(DENSE_ONLY):
-            raise                   # a real miscount, not the known one
+            raise                   # a real miscount, not a known one
         pytest.xfail("benchmark/tests/test_families.py::"
                      "test_counts_are_the_trees holds forward FLOPs a token "
-                     "above 2 x every parameter: dense-only, the "
-                     "benchmark's to make family-aware")
+                     "above 2 x every parameter, and a decode step's bytes "
+                     "at two a parameter less the embedding: dense-only "
+                     "with an untied head, the benchmark's to make "
+                     "family-aware")
     pytest.fail("the dense-only inequality holds for a sparse family now: "
                 "run the imported case whole")
 

@@ -793,3 +793,77 @@ def test_latent_decode_step_on_a_mesh(chip, topo, axes):
     gathered = [ln for ln in text.splitlines()
                 if "all-gather" in ln and f",{rows}," in ln]
     assert not gathered, gathered
+
+
+# ---------------------------------------------------------------------------
+# A state-space layer kind beside full attention at granite-4.0-h-micro's
+# widths, all 40 layers, the cell's own slots and rows (PR 41)
+# ---------------------------------------------------------------------------
+
+def _hybrid_serving(chip):
+    """``granite-4.0-h-micro-l40`` as the cell
+    ``serve-granite4hmicro-wide-decode`` runs it — 36 state-space mixers
+    and 4 full-attention layers, 64 slots: 2.42 GB of recurrent state,
+    0.16 GB of conv window and 2.15 GB of K/V rows beside 6.38 GB of
+    weights."""
+    return _cell_serving(chip, "granite-4.0-h-micro-l40", 64, 4096)
+
+
+def _state_sized_copies(text, cache):
+    return [c for n in ("ssm", "conv", "k", "v")
+            for c in _cache_sized_copies(text, cache[n])]
+
+
+def test_hybrid_step_rows_updates_the_state_in_place(chip):
+    """The decode chunk at the cell's size: every mixer's state update is
+    ONE ``tony_ssm_step`` launch against the stacked state ([36, 64, 128,
+    4096]: N on the sublanes, heads x head width on the lanes) aliased in
+    and out — no state-sized and no cache-sized copy in the program —,
+    the four full layers read through ``tony_cached_attn`` at heads of 64
+    (rows 512 wide), and the whole fits the chip beside its 11.2 GB of
+    arguments."""
+    import re
+
+    from tony_tpu.models import serve as S
+    cfg, params, cache, logits = _hybrid_serving(chip)
+    assert cache["ssm"].shape == (36, 64, 128, 4096)
+    assert cache["conv"].shape == (36, 64, 3, 4352)
+    assert cache["k"].shape == (4, 64, 4096, 512)
+    compiled = S.step_rows.lower(
+        params, cache, logits, chip.shape((64, 2), jnp.uint32),
+        chip.shape((64,), jnp.int32), n=8, cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step_rows")
+    assert not _state_sized_copies(text, cache)
+    assert len(set(re.findall(r"%(tony_ssm_step[.\d]*) = ", text))) == 36
+    assert len(set(re.findall(r"%(tony_cached_attn[.\d]*) = ", text))) == 4
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 30
+    assert memory.peak_memory_in_bytes < _HBM
+    print("hybrid step_rows peak", memory.peak_memory_in_bytes,
+          "temp", memory.temp_size_in_bytes)
+
+
+def test_hybrid_admit_rows_at_the_longest_bucket_fits_the_chip(chip):
+    """The 2,048 bucket's admission (one row: ``admit_width``): the
+    chunked scan in ``jnp`` (eight chunks of 256 carry a [128, 4096]
+    state through each of 36 mixers), the flash kernel plain causal at
+    32 / 8 heads of 64, the state landed whole and the rows as they are
+    — nothing state- or cache-sized copied on the way — and the
+    program's peak under what the chip allows beside weights and
+    cache."""
+    from tony_tpu.models import serve as S
+    cfg, params, cache, logits = _hybrid_serving(chip)
+    assert S.admit_width(2048, 64) == 1
+    compiled = S.admit_rows.lower(
+        params, cache, logits, chip.shape((1,), jnp.int32),
+        chip.shape((1, 2048), jnp.int32), chip.shape((1,), jnp.int32),
+        cfg=cfg).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_admit_rows")
+    assert "tony_flash_fwd" in text
+    assert not _state_sized_copies(text, cache)
+    memory = compiled.memory_analysis()
+    assert memory.peak_memory_in_bytes < _HBM
+    print("hybrid admit_rows 2048 peak", memory.peak_memory_in_bytes,
+          "temp", memory.temp_size_in_bytes)

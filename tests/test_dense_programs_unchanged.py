@@ -27,6 +27,15 @@ routing, zero experts and the scales on the latent bottlenecks arrived
 beside them and changed no op of theirs (``n_zero == 0`` keeps the
 device counters at two entries and traces no zero term; a scale of 1.0
 traces no multiply).
+
+PR 41 added the double layer itself (``longcat``: the benchmark's toy of
+it, ``tests/data/tiny-scmoe.json`` through its family's
+``program_config``) at the hashes of ITS parent (cf6d3ce, PR 40), and
+changed none of the ten above: the state-space kind, ``embed_scale``,
+``residual_scale`` and ``attn_scale`` arrived beside them, and each traces
+nothing at its default (1.0, 1.0, None) — in ``_embed``, ``_branch``,
+``_gqa_qkv`` and ``_latent_scale``, which every kinded program now passes
+through.
 """
 
 import hashlib
@@ -49,6 +58,9 @@ PARENT = {
     "kimi.step_rows": "e1e355cce2a334e4",
     "commanda.admit_rows": "8548109614c9a831",
     "commanda.step_rows": "54bbd7fab9606df0",
+    # at cf6d3ce (PR 40), the parent of PR 41
+    "longcat.admit_rows": "df2589367ee4f5c2",
+    "longcat.step_rows": "22a2dd9c22f171ad",
 }
 
 
@@ -84,6 +96,7 @@ def lowered(which: str) -> str:
                                     n_shared=2, shared_mean=True),
             norm="layer", parallel_block=True, tie_embeddings=True,
             logit_scale=0.5),
+        "longcat": lambda: _toy_of_the_benchmark("tiny-scmoe.json"),
     }[model]
     if callable(cfg):
         cfg = cfg()
@@ -107,6 +120,15 @@ def lowered(which: str) -> str:
     return S.admit_rows.lower(params, cache, logits, rows,
                               sds((slots, 64), jnp.int32), rows,
                               cfg).as_text()
+
+
+def _toy_of_the_benchmark(name: str):
+    import jax.numpy as jnp
+    from benchmark.lib import modelcfg
+    c = modelcfg.load(os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "data", name))
+    return modelcfg.family(c).program_config(c, dtype=jnp.bfloat16,
+                                             remat=False)
 
 
 def digest(which: str) -> str:

@@ -51,13 +51,14 @@ import numpy as np
 from tony_tpu.models import transformer as T
 from tony_tpu.models.quantize import QuantizedWeight
 from tony_tpu.ops import mosaic
+from tony_tpu.ops import ssm as ssm_ops
 from tony_tpu.ops.attention import (cached_attention, cached_attn_block,
                                     cached_attn_work, live_blocks)
 from tony_tpu.ops.norms import layer_norm_reference, rms_norm_reference
 from tony_tpu.parallel.moe import (HeldExperts, held_experts_ffn, moe_ffn,
                                    shared_experts_ffn, sigmoid_route,
                                    softmax_route)
-from tony_tpu.parallel.sharding import (cache_heads_split,
+from tony_tpu.parallel.sharding import (_island_mesh, cache_heads_split,
                                         shard_cached_attention)
 
 
@@ -181,7 +182,8 @@ def cache_layout(cfg: T.TransformerConfig, max_len: int,
     ``window``: ``k_ring`` / ``v_ring``, :func:`ring_rows` rows written
     modulo their count — or ``max_len`` linear rows where ``ring`` is
     False: the mini cache of a prefill, which :func:`place_rows` lands
-    in a ring by each row's length."""
+    in a ring by each row's length. An ``ssm`` kind owns NO position
+    buffer: its state has no rows (:func:`state_layout`)."""
     if not cfg.kinded:
         rows = _ring_capacity(cfg) or max_len
         return {n: (cfg.n_layers, rows, cfg.kv_heads * w, dt)
@@ -192,23 +194,46 @@ def cache_layout(cfg: T.TransformerConfig, max_len: int,
             out["ckv"] = (n, max_len, cfg.latent.stored_row, cfg.dtype)
         elif attention == "full":
             out["k"] = out["v"] = (n, max_len, kv, cfg.dtype)
-        else:
+        elif attention == "window":
             rows = ring_rows(cfg) if ring else max_len
             out["k_ring"] = out["v_ring"] = (n, rows, kv, cfg.dtype)
     return out
 
 
+def state_layout(cfg: T.TransformerConfig) -> dict:
+    """name → (mixers, what ONE slot holds, dtype) of every STATE buffer:
+    state that is the same size at every length, so it has no position
+    axis, no rows to mask and nothing a length could slice. The ``ssm``
+    kinds own two, indexed by a mixer's place among the ``ssm`` mixers
+    (``TransformerConfig.attention_of``): ``ssm``, the recurrence
+    ``[d_state, n_heads · head_dim]`` (the stored layout of
+    :mod:`tony_tpu.ops.ssm`) in ``ssm.state_dtype`` (None: the model's
+    dtype); ``conv``, the convolution's last ``d_conv - 1`` INPUTS
+    ``[d_conv - 1, conv_dim]``. Every step rewrites a slot's state whole
+    and an admission lands it whole (:func:`place_rows`); nothing else
+    protects a slot's next occupant. Empty for a model without them."""
+    m = cfg.ssm             # set only beside layer_kinds that use it
+    if m is None:
+        return {}
+    n = cfg.attention_layers()["ssm"]
+    return {"ssm": (n, (m.d_state, m.d_inner), m.state_dtype or cfg.dtype),
+            "conv": (n, (m.d_conv - 1, m.conv_dim), cfg.dtype)}
+
+
 def cache_bytes_by_kind(cfg: T.TransformerConfig, batch: int,
                         max_len: int) -> dict:
-    """Bytes of the position buffers ``batch`` slots hold, by the KIND of
-    state: ``latent`` / ``window`` / ``full`` for a model with
-    layer_kinds (each attention's own buffers), ``ring`` or ``linear``
-    for the dense decoder."""
+    """Bytes of the buffers ``batch`` slots hold, by the KIND of state:
+    ``latent`` / ``window`` / ``full`` for a model with layer_kinds (each
+    attention's own position buffers) beside ``ssm`` / ``conv`` (a
+    state-space mixer's state buffers, whatever ``max_len``); ``ring`` or
+    ``linear`` for the dense decoder."""
     out: dict = {}
     for n, (layers, rows, width, dt) in cache_layout(cfg, max_len).items():
         kind = _buffer_kind(cfg, n)
         out[kind] = out.get(kind, 0) + (layers * batch * rows * width
                                         * jnp.dtype(dt).itemsize)
+    for n, (layers, shape, dt) in state_layout(cfg).items():
+        out[n] = layers * batch * int(np.prod(shape)) * jnp.dtype(dt).itemsize
     return out
 
 
@@ -284,6 +309,8 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
     cache = {n: jnp.zeros((layers, batch, n_rows, width), dt)
              for n, (layers, n_rows, width, dt)
              in cache_layout(cfg, max_len, ring).items()}
+    cache.update({n: jnp.zeros((layers, batch) + shape, dt)
+                  for n, (layers, shape, dt) in state_layout(cfg).items()})
     if cfg.experts is not None:
         # what the expert layers counted since the holding program began
         # (assignments landed on held experts, held experts touched, and
@@ -298,9 +325,12 @@ def init_kv_cache(cfg: T.TransformerConfig, batch: int,
 #: the ``window`` kinds' buffers: rows written modulo their row count
 _RING_BUFS = ("k_ring", "v_ring")
 #: cache keys that hold per-position buffers (and so follow every write/
-#: gather/tile path together); "length" and the expert layers' counters
-#: (``MOE_COUNTS``) are the only non-buffer keys
+#: gather/tile path together); beside them the STATE buffers
+#: (``_STATE_BUFS``: no position axis, landed whole), "length" and the
+#: expert layers' counters (``MOE_COUNTS``)
 _KV_BUFS = ("k", "v", "k_scale", "v_scale", "ckv") + _RING_BUFS
+#: the ``ssm`` kinds' buffers (:func:`state_layout`)
+_STATE_BUFS = ("ssm", "conv")
 MOE_COUNTS = "moe_counts"
 
 
@@ -947,13 +977,13 @@ def _rope_tables(positions, cfg: T.TransformerConfig):
     """(cos, sin) for a chunk's positions, once per chunk, over the head
     dim. A model with layer_kinds: {attention: (cos, sin)} — over the
     rotary slice of a ``latent`` head, over the whole head of a
-    ``window`` kind, None for a ``full`` kind (no positional
+    ``window`` kind, None for a ``full`` or ``ssm`` kind (no positional
     rotation)."""
     def tables(d):
         return T.rope_tables(positions, d, cfg.rope_base, cfg.rope_scaling)
     if not cfg.kinded:
         return tables(cfg.head_dim)
-    return {a: None if a == "full" else tables(
+    return {a: None if a in ("full", "ssm") else tables(
                 cfg.latent.rope_dim if a == "latent" else cfg.head_dim)
             for a in cfg.attention_layers()}
 
@@ -1023,7 +1053,8 @@ def _layer_params(params: dict, cfg: T.TransformerConfig, li: int) -> dict:
 # ---------------------------------------------------------------------------
 
 def _latent_scale(cfg: T.TransformerConfig) -> float:
-    scale = cfg.latent.qk_dim ** -0.5
+    scale = (cfg.latent.qk_dim ** -0.5 if cfg.attn_scale is None
+             else cfg.attn_scale)
     if cfg.rope_scaling is not None:
         scale *= cfg.rope_scaling.softmax_scale
     return scale
@@ -1244,25 +1275,45 @@ def _kinded_ffn(x, h, a, p, cfg: T.TransformerConfig, bufs: dict,
     ``bufs``. Sequential block: the stream takes ``a``, the
     feed-forward reads its own norm of that, ``x + a + ffn(norm(x +
     a))``. ``parallel_block``: attention and feed-forward read the SAME
-    normed ``h`` and both add to the stream, ``x + a + ffn(h)``."""
-    x = x + a
+    normed ``h`` and both add to the stream, ``x + a + ffn(h)``. Each
+    branch joins the stream x ``residual_scale``."""
+    x = x + _branch(a, cfg)
     with jax.named_scope("mlp"):
         if not cfg.parallel_block:
             h = _norm(x, p["mlp_norm"], cfg)
         if "router" not in p:
-            return x + _mlp(h, p, cfg), bufs
+            return x + _branch(_mlp(h, p, cfg), cfg), bufs
         out, counts = _sparse_mlp(h, p, cfg, live)
-        return x + out, dict(bufs, **{MOE_COUNTS: bufs[MOE_COUNTS] + counts})
+        return (x + _branch(out, cfg),
+                dict(bufs, **{MOE_COUNTS: bufs[MOE_COUNTS] + counts}))
 
 
-def _gqa_qkv(h, p, rope, barrier: bool = False):
+def _branch(y, cfg: T.TransformerConfig):
+    """A branch's output as it joins the stream: x ``residual_scale``
+    (1.0: the output itself, nothing traced)."""
+    if cfg.residual_scale == 1.0:
+        return y
+    return (y * cfg.residual_scale).astype(y.dtype)
+
+
+def _gqa_qkv(h, p, rope, cfg: T.TransformerConfig, barrier: bool = False):
     """q [B, S, H, hd], k and v [B, S, KV, hd] of a ``window`` or
     ``full`` kind; q and k rotated where the kind has positions
     (``rope`` None: it has none). ``barrier``: a decode step's v, as
-    :func:`_decode_block` keeps it from folding into the cache write."""
+    :func:`_decode_block` keeps it from folding into the cache write.
+
+    ``cfg.attn_scale``: every read of these kinds — the flash kernel,
+    ``tony_cached_attn``, the ``jnp`` reads — scales its scores by
+    ``head_dim ** -0.5``, so a model with a softmax scale of its own has
+    ``attn_scale x head_dim ** 0.5`` FOLDED INTO q here, once, in q's
+    dtype (a power of two — 1/64 at heads of 64 gives 1/8 — is exact in
+    bfloat16; any other factor costs q one more rounding). None: nothing
+    traced."""
     q = _weinsum("bsd,dhk->bshk", h, p["wq"])
     k = _weinsum("bsd,dhk->bshk", h, p["wk"])
     v = _weinsum("bsd,dhk->bshk", h, p["wv"])
+    if cfg.attn_scale is not None:
+        q = (q * (cfg.attn_scale * q.shape[-1] ** 0.5)).astype(q.dtype)
     if rope is not None:
         q, k = T.apply_rope(q, *rope), T.apply_rope(k, *rope)
     return q, k, (jax.lax.optimization_barrier(v) if barrier else v)
@@ -1270,6 +1321,130 @@ def _gqa_qkv(h, p, rope, barrier: bool = False):
 
 #: the buffers a ``window`` / ``full`` kind's K and V go to
 _KIND_KV = {"window": ("k_ring", "v_ring"), "full": ("k", "v")}
+
+
+# --- the state-space mixer (``ssm`` kinds): cfg.ssm, ops/ssm.py ---------
+
+def _ssm_project(h, p, cfg: T.TransformerConfig):
+    """The mixer's ONE input projection of normed h [B, S, D], split
+    ``[z | c | dt]``: the gate [.., d_inner], the convolution's input
+    [.., conv_dim] and each head's step before its bias [.., H]."""
+    m = cfg.ssm
+    zcd = _weinsum("bsd,df->bsf", h, p["w_in"])
+    cut = m.d_inner + m.conv_dim
+    return zcd[..., :m.d_inner], zcd[..., m.d_inner:cut], zcd[..., cut:]
+
+
+def _ssm_conv(window, p):
+    """silu(bias + sum_j w[j] * window[.., j, :]) over the LAST axis but
+    one of ``window`` [.., d_conv, conv_dim] (the inputs at t - d_conv +
+    1 .. t), in float32."""
+    w = p["conv_w"].astype(jnp.float32)
+    return jax.nn.silu(p["conv_b"].astype(jnp.float32)
+                       + jnp.sum(window.astype(jnp.float32) * w, axis=-2))
+
+
+def _ssm_inputs(conv, dt, p, cfg: T.TransformerConfig):
+    """The recurrence's operands from the convolution's output ``conv``
+    [B, S, conv_dim] float32 and the raw steps ``dt`` [B, S, H]: x
+    [B, S, H, P] in the model's dtype, b and c [B, S, G, N], dt =
+    softplus(dt + dt_bias) float32, a = -exp(A_log) [H]."""
+    m = cfg.ssm
+    lead = conv.shape[:-1]
+    x = conv[..., :m.d_inner].reshape(lead + (m.n_heads, m.head_dim))
+    gn = m.n_groups * m.d_state
+    b = conv[..., m.d_inner:m.d_inner + gn].reshape(
+        lead + (m.n_groups, m.d_state))
+    c = conv[..., m.d_inner + gn:].reshape(lead + (m.n_groups, m.d_state))
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    return x.astype(cfg.dtype), b, c, dt, -jnp.exp(p["A_log"])
+
+
+def _ssm_output(y, x, z, p, cfg: T.TransformerConfig):
+    """y [B, S, H, P] float32 from the recurrence → the mixer's output:
+    the skip ``D x``, the gate FIRST and then the norm over all d_inner
+    (one group), the output projection."""
+    y = y + p["D"][:, None] * x.astype(jnp.float32)
+    g = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+    g = rms_norm_reference(g.astype(cfg.dtype), p["gate_norm"], cfg.rms_eps)
+    return _weinsum("bsf,fd->bsd", g, p["w_out"])
+
+
+def _ssm_state_step(state_all, ai, decay, inp, b, c):
+    """One step of every slot's recurrence against the stacked state: on
+    the chip ``tony_ssm_step`` (:func:`tony_tpu.ops.ssm.ssm_step`); off it
+    — the repo's one question, ``mosaic.interpret()`` — and under a mesh
+    (a Mosaic call is not partitioned) the ``jnp`` arm, the oracle."""
+    if mosaic.interpret() or _island_mesh(None) is not None:
+        return ssm_ops.ssm_step_reference(state_all, ai, decay, inp, b, c)
+    return ssm_ops.ssm_step(state_all, ai, decay, inp, b, c)
+
+
+def _ssm_decode_mixer(h, p, bufs, ai, cfg: T.TransformerConfig):
+    """The mixer of a decode step on normed h [B, 1, D]: the conv window
+    (``conv`` at index ``ai`` beside this position's input) shifts by
+    one, and the recurrence reads the slot's whole state, updates it and
+    writes it back (``ssm`` at ``ai``). No position enters: the state IS
+    the history. Returns (the mixer's output, bufs)."""
+    if h.shape[1] != 1:
+        raise ValueError(
+            "a state-space mixer decodes single-position steps only: its "
+            "state is one recurrence a slot, not rows a chunk could "
+            "rewind (speculation's verify chunks need a linear cache)")
+    m = cfg.ssm
+    with jax.named_scope("ssm_mixer"):
+        z, c_in, dt = _ssm_project(h, p, cfg)
+        with jax.named_scope("ssm_conv"):
+            window = jnp.concatenate([bufs["conv"][ai], c_in], axis=1)
+            conv = _ssm_conv(window, p)[:, None]
+            bufs = dict(bufs, conv=bufs["conv"].at[ai].set(window[:, 1:]))
+        x, b, c, dt, a = _ssm_inputs(conv, dt, p, cfg)
+        with jax.named_scope("ssm_state"):
+            dt0 = dt[:, 0]                                      # [B, H]
+            inp = dt0[..., None] * x[:, 0].astype(jnp.float32)
+            y, state = _ssm_state_step(
+                bufs["ssm"], ai,
+                jnp.repeat(jnp.exp(dt0 * a), m.head_dim, axis=1),
+                inp.reshape(inp.shape[0], -1), b[:, 0], c[:, 0])
+            bufs = dict(bufs, ssm=state)
+        y = y.reshape(x.shape)
+        return _ssm_output(y, x, z, p, cfg), bufs
+
+
+def _ssm_prompt_mixer(h, p, bufs, ai, cfg: T.TransformerConfig, live):
+    """The mixer over a (padded) prompt on normed h [B, S, D], and the
+    state it hands to decode. A recurrence runs THROUGH a padded tail
+    unless told not to — causality hides nothing here — so ``live``
+    [B, S] stops each row at its own length: the step ``dt`` is 0 past it
+    (decay 1, input 0: the carried state is the state AT the length), and
+    the conv state is the conv's INPUT at the last ``d_conv - 1`` live
+    positions (zeros before position 0), gathered by the row's length.
+    Both land whole in the mini cache at index ``ai``. Returns (the
+    mixer's output — garbage past a row's length, never read —, bufs)."""
+    m = cfg.ssm
+    taps = m.d_conv - 1
+    with jax.named_scope("ssm_mixer"):
+        z, c_in, dt = _ssm_project(h, p, cfg)
+        s = h.shape[1]
+        with jax.named_scope("ssm_conv"):
+            padded = jnp.pad(c_in, ((0, 0), (taps, 0), (0, 0)))
+            window = jnp.stack([padded[:, j:j + s] for j in range(m.d_conv)],
+                               axis=2)
+            conv = _ssm_conv(window, p)
+            # the input at position q is padded[:, q + taps]
+            last = jnp.sum(live, axis=1, dtype=jnp.int32)[:, None] \
+                + jnp.arange(taps)
+            conv_state = jnp.take_along_axis(padded, last[:, :, None], axis=1)
+        x, b, c, dt, a = _ssm_inputs(conv, dt, p, cfg)
+        with jax.named_scope("ssm_state"):
+            y, state = ssm_ops.ssm_chunked(
+                x, jnp.where(live[..., None], dt, 0.0), a,
+                b.astype(cfg.dtype), c.astype(cfg.dtype), m.chunk)
+        bufs = dict(bufs,
+                    ssm=bufs["ssm"].at[ai].set(
+                        state.astype(bufs["ssm"].dtype)),
+                    conv=bufs["conv"].at[ai].set(conv_state))
+        return _ssm_output(y, x, z, p, cfg), bufs
 
 
 def _latent_decode_attention(h, p, bufs, ai, pos, cfg, rope, window,
@@ -1319,14 +1494,14 @@ def _scmoe_block(x, p, bufs, cfg, attend, live=None):
     m = None
     for i, half in enumerate(p["halves"]):
         a, bufs = attend(i, _norm(x, half["attn_norm"], cfg), half, bufs)
-        x = x + a
+        x = x + _branch(a, cfg)
         h = _norm(x, half["mlp_norm"], cfg)
         if i == 0:
             m, counts = _sparse_mlp(h, p, cfg, live)
             bufs = dict(bufs, **{MOE_COUNTS: bufs[MOE_COUNTS] + counts})
         with jax.named_scope(f"mlp_{i}"):
-            x = x + _mlp(h, half, cfg)
-    return x + m, bufs
+            x = x + _branch(_mlp(h, half, cfg), cfg)
+    return x + _branch(m, cfg), bufs
 
 
 def _scmoe_decode_block(x, p, bufs, ai, pos, cfg, rope, window):
@@ -1363,19 +1538,23 @@ def _kinded_decode_block(x, p, bufs, li, pos, cfg, rope, window=None):
     :func:`_ring_cached_attention` (masked by offset); ``full``: K and V
     at ``pos``, read as :func:`_cached_attention` — on the chip both
     through ``tony_cached_attn``, each slot's own live blocks. Returns
-    (x, bufs)."""
+    (x, bufs). ``ssm``: no row is written and none read — the mixer
+    steps the state it owns (:func:`_ssm_decode_mixer`)."""
     attention, ai = cfg.attention_of(li)
     if T.LAYER_KINDS[cfg.layer_kinds[li]][1] == "scmoe":
         return _scmoe_decode_block(x, p, bufs, ai, pos, cfg, rope, window)
     h = _norm(x, p["attn_norm"], cfg)
     pos = jnp.asarray(pos)
+    if attention == "ssm":
+        a, bufs = _ssm_decode_mixer(h, p, bufs, ai, cfg)
+        return _kinded_ffn(x, h, a, p, cfg, bufs)
     if attention == "latent":
         a, bufs = _latent_decode_attention(h, p, bufs, ai, pos, cfg, rope,
                                            window)
         return _kinded_ffn(x, h, a, p, cfg, bufs)
     nk, nv = _KIND_KV[attention]
     with jax.named_scope("attn_" + attention):
-        q, k, v = _gqa_qkv(h, p, rope[attention], barrier=True)
+        q, k, v = _gqa_qkv(h, p, rope[attention], cfg, barrier=True)
         ring = attention == "window"
         with jax.named_scope("cache_write"):
             # a ring takes the row at pos modulo its rows, by the per-row
@@ -1405,17 +1584,22 @@ def _kinded_prompt_block(x, p, bufs, li, cfg, rope, s, live):
     and plain causal on a ``full`` kind — and rows [0, s) written
     LINEARLY to the attention's buffers (a prefill's mini cache:
     :func:`place_rows` lands a ``window`` kind's in its ring); only the
-    ``live`` positions are routed."""
+    ``live`` positions are routed. ``ssm``: the chunked scan, stopped at
+    each row's length, and the state landed whole
+    (:func:`_ssm_prompt_mixer`)."""
     attention, ai = cfg.attention_of(li)
     if T.LAYER_KINDS[cfg.layer_kinds[li]][1] == "scmoe":
         return _scmoe_prompt_block(x, p, bufs, ai, cfg, rope, s, live)
     h = _norm(x, p["attn_norm"], cfg)
+    if attention == "ssm":
+        a, bufs = _ssm_prompt_mixer(h, p, bufs, ai, cfg, live)
+        return _kinded_ffn(x, h, a, p, cfg, bufs, live)
     if attention == "latent":
         a, row = _latent_prompt(h, p, cfg, rope)
         writes = {"ckv": row}
     else:
         with jax.named_scope("attn_" + attention):
-            q, k, v = _gqa_qkv(h, p, rope[attention])
+            q, k, v = _gqa_qkv(h, p, rope[attention], cfg)
             o = T._attention(q, k, v, None, window=(
                 cfg.attn_window if attention == "window" else None))
             a = _weinsum("bshk,hkd->bsd", o, p["wo"])
@@ -1428,6 +1612,15 @@ def _kinded_prompt_block(x, p, bufs, li, cfg, rope, s, live):
             for n, t in writes.items()})
 
 
+def _embed(params: dict, tokens, cfg: T.TransformerConfig):
+    """The tokens' rows as they enter the stream, x ``embed_scale`` in
+    float32 where the model has one (1.0: the rows themselves)."""
+    x = params["embed"][tokens]
+    if cfg.embed_scale != 1.0:
+        x = x.astype(jnp.float32) * cfg.embed_scale
+    return x.astype(cfg.dtype)
+
+
 def _blocks_forward(params: dict, tokens: jax.Array, cache: dict, pos,
                     cfg: T.TransformerConfig,
                     window: int | None = None) -> tuple[jax.Array, dict]:
@@ -1436,7 +1629,7 @@ def _blocks_forward(params: dict, tokens: jax.Array, cache: dict, pos,
     shared body of :func:`extend_step` and the head-free K/V write the
     device speculative loop uses (its eager last draft step discards the
     logits, so paying the lm_head vocab projection there is pure waste)."""
-    x = params["embed"][tokens].astype(cfg.dtype)              # [B, K, D]
+    x = _embed(params, tokens, cfg)                            # [B, K, D]
     b, n_q = tokens.shape
     if (_ring_capacity(cfg) or _has_ring_bufs(cache)) and n_q > 1:
         raise ValueError(
@@ -1552,7 +1745,7 @@ def _prompt_forward(params, tokens, cfg, bufs, s, lengths=None):
     sp = _flash_safe_len(s) if _pad_prompts() else s
     if sp != s:
         tokens = jnp.pad(tokens, ((0, 0), (0, sp - s)))
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(sp), (b, sp))
     rope = _rope_tables(positions, cfg)                 # once, not per layer
     live = (positions < (s if lengths is None else lengths[:, None])
@@ -1668,7 +1861,10 @@ def place_rows(cache: dict, mini: dict, rows: jax.Array,
     takes, per row, the last ``min(length, C)`` rows by position modulo
     its row count (:func:`_ring_rows_of`), the whole slot in one
     scatter; a bucket that fits the ring lands as any linear buffer
-    (no position has wrapped yet)."""
+    (no position has wrapped yet). An ``ssm`` kind's state buffers
+    (``_STATE_BUFS``) land WHOLE, each slot's over whatever the slot held
+    — the one thing that stands between a reused slot's residue, or an
+    idle slot's garbage, and its next occupant."""
     s_b = cache_rows(mini)
     placed = {}
     for n, m in _kv_bufs(mini).items():
@@ -1680,6 +1876,10 @@ def place_rows(cache: dict, mini: dict, rows: jax.Array,
         else:
             placed[n] = cache[n].at[:, rows, :s_b].set(
                 m, mode="drop", unique_indices=True)
+    for n in _STATE_BUFS:
+        if n in mini:       # no positions: a slot's state lands WHOLE
+            placed[n] = cache[n].at[:, rows].set(
+                mini[n], mode="drop", unique_indices=True)
     return dict(cache, **placed, length=cache["length"].at[rows].set(
         lengths.astype(jnp.int32), mode="drop", unique_indices=True))
 

@@ -111,6 +111,7 @@ _BLOCK_AXES = {
 _KINDED_AXES = {
     "wq_a": (1,), "wq_b": (1,), "wkv_a": (1,), "wo": (1, 2),
     "wq": (1,), "wk": (1,), "wv": (1,),     # the window / full kinds'
+    "w_in": (1,), "w_out": (1,),            # a state-space mixer's
     "shared_gate": (1,), "shared_up": (1,), "shared_down": (1,),
 }
 #: the same for SEVERAL shared experts, stacked [L, N, d, f] / [L, N, f,

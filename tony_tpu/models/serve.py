@@ -1003,6 +1003,19 @@ class ContinuousBatcher:
                                    np.zeros((batch, 0), np.int64))
         self.cache_rows_read = dict.fromkeys(kinds, 0)
         self.cache_rows_live = dict.fromkeys(kinds, 0)
+        #: a model with state-space mixers (``cfg.ssm``; {} without):
+        #: host arithmetic, as the rows above. ``state_bytes``: bytes of
+        #: the recurrence the decode chunks' steps read AND wrote, every
+        #: slot (the device steps idle ones too); ``state_live_bytes``:
+        #: the occupied slots' part — live / all is what idle slots
+        #: cost. ``scan_positions``: positions the admissions' prompt
+        #: scans ran over, a mixer a position; ``scan_live_positions``:
+        #: those below their rows' lengths — the rest was padding.
+        self._ssm_mixers = (cfg.attention_layers().get("ssm", 0)
+                            if cfg.kinded else 0)
+        self.ssm_counts = dict.fromkeys(
+            ("state_bytes", "state_live_bytes", "scan_positions",
+             "scan_live_positions"), 0) if self._ssm_mixers else {}
         self._device_stats: collections.deque = collections.deque()
         self.phase_times = PhaseTimes(ENGINE_PHASES)
         #: () -> (occupied slots, requests waiting) as the loop driving
@@ -1490,15 +1503,20 @@ class ContinuousBatcher:
                 grp = whole[i:i + w]
                 toks, lens = self._pad_prompts_to(grp, prompts,
                                                   bucket, w)
+                n_tok = sum(len(self._seq_of(prompts[req]))
+                            for _, req in grp)
                 with pt.phase("admit_dispatch", seq=self.seq,
-                              bucket=bucket, rows=w, tokens=sum(
-                                  len(self._seq_of(prompts[req]))
-                                  for _, req in grp)):
+                              bucket=bucket, rows=w, tokens=n_tok):
                     rows, keys = self._marshal_wave(grp, w)
                     self._admit_rows(rows, toks, lens, keys,
                                      entry=entry)
                     self._enqueued(row for row, _ in grp)
                 self.prefill_padded_tokens += w * bucket
+                if self._ssm_mixers:
+                    self.ssm_counts["scan_positions"] += (
+                        self._ssm_mixers * w * bucket)
+                    self.ssm_counts["scan_live_positions"] += (
+                        self._ssm_mixers * n_tok)
                 self._rebind_streams(grp, rows, keys)
                 self._count_admission(grp, prompts)
 
@@ -1573,6 +1591,14 @@ class ContinuousBatcher:
             for kind, (read, live) in visited.items():
                 self.cache_rows_read[kind] += read
                 self.cache_rows_live[kind] += live
+            if self._ssm_mixers:
+                # a step reads and writes every slot's state once; a row
+                # is occupied where its frontier is past 0 (every idle
+                # one was retired to 0 before this issue)
+                moved = 2 * self.chunk * self.cache_bytes["ssm"]
+                self.ssm_counts["state_bytes"] += moved
+                self.ssm_counts["state_live_bytes"] += (
+                    moved * int((self._row_len > 0).sum()) // self.batch)
         self._row_len += self.chunk
         self.steps_executed += self.chunk
         for r in range(self.batch):
@@ -2204,11 +2230,11 @@ class ServeEngine:
             "zero_assignments": batcher.moe_zero_assignments}
         for kind, n in batcher.cache_bytes.items():
             reg.gauge("tony_cache_bytes", kind=kind,
-                      help="bytes of the KV cache's position buffers, by "
-                           "the kind of state that owns them (a window "
-                           "kind's ring, a full kind's max_len rows, a "
-                           "latent row, or the dense decoder's linear / "
-                           "ring cache)").set(n)
+                      help="bytes of the cache's buffers, by the kind of "
+                           "state that owns them (a window kind's ring, "
+                           "a full kind's max_len rows, a latent row, a "
+                           "state-space mixer's ssm / conv state, or the "
+                           "dense decoder's linear / ring cache)").set(n)
         self._ring_over_c = reg.counter(
             "tony_ring_rows_overwritten_total",
             help="ring rows finished requests overwrote: a layer a "
@@ -2227,6 +2253,22 @@ class ServeEngine:
                          "over rows read, how far the cached read "
                          "follows each row's own length"))}
         self._rows_seen = {k: 0 for k in self._rows_c}
+        # a model with state-space mixers: what their state and their
+        # prompt scans moved (the batcher's ``ssm_counts``)
+        self._ssm_c = {
+            what: reg.counter(f"tony_ssm_{what}_total", help=text)
+            for what, text in (
+                ("state_bytes", "bytes of recurrent state the decode "
+                                "steps read and wrote, every slot"),
+                ("state_live_bytes", "the occupied slots' part of those "
+                                     "bytes: over all, what idle slots "
+                                     "cost"),
+                ("scan_positions", "positions the admissions' prompt "
+                                   "scans ran over, a mixer a position"),
+                ("scan_live_positions", "those below their rows' "
+                                        "lengths: the rest was padding"))
+            if what in batcher.ssm_counts}
+        self._ssm_seen = dict.fromkeys(self._ssm_c, 0)
         self._qdepth_g.set(0)
         for g in self._qdepth_by_cls.values():
             g.set(0)
@@ -2463,6 +2505,10 @@ class ServeEngine:
                 # the read follows each row's own length)
                 "cache_rows_read": dict(self.b.cache_rows_read),
                 "cache_rows_live": dict(self.b.cache_rows_live),
+                # a model with state-space mixers: the state its decode
+                # steps moved and the positions its prompt scans ran
+                # ({} without)
+                "ssm": dict(self.b.ssm_counts),
                 "steps_executed": self.b.steps_executed,
                 # requests whose first token left ahead of its chunk
                 "first_tokens_early":
@@ -2768,6 +2814,10 @@ class ServeEngine:
                          else self.b.cache_rows_live)[kind]
                 c.inc(total - self._rows_seen[(what, kind)])
                 self._rows_seen[(what, kind)] = total
+            for what, c in self._ssm_c.items():
+                total = self.b.ssm_counts[what]
+                c.inc(total - self._ssm_seen[what])
+                self._ssm_seen[what] = total
 
     def _free_slot(self, row: int, t: float) -> None:
         self._occupant[row] = None

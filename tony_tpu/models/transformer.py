@@ -155,15 +155,56 @@ class SparseExperts:
         return self.total + self.n_zero
 
 
+@dataclasses.dataclass(frozen=True)
+class StateSpace:
+    """A state-space mixer (Mamba-2's selective scan, SSD): the normed
+    stream goes through ONE projection to ``[z | c | dt]`` — a gate
+    (``d_inner`` wide), the conv channels (``conv_dim``) and a step a
+    head —; ``c`` through a depthwise causal convolution of ``d_conv``
+    taps and a silu, then split into the heads' inputs ``x`` (``n_heads``
+    of ``head_dim``) and ``B``, ``C`` (``d_state`` a group, shared by a
+    group's heads); the recurrence of :mod:`tony_tpu.ops.ssm` per head;
+    ``RMSNorm(y * silu(z))`` over all ``d_inner`` and the output
+    projection. Its slot state is a FIXED-SIZE recurrence — ``[d_state,
+    d_inner]`` and the conv's last ``d_conv - 1`` inputs — not rows by
+    position (models/decode.py, ``state_layout``). ``chunk``: positions a
+    chunk of the prompt scan. ``state_dtype``: what the recurrence is
+    STORED in between steps (None: the model's ``dtype``); every step's
+    arithmetic is float32."""
+    n_heads: int
+    head_dim: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk: int = 256
+    state_dtype: Any = None
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, then B and C a group."""
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_dim(self) -> int:
+        """Outputs of the input projection: ``[z | c | dt]``."""
+        return self.d_inner + self.conv_dim + self.n_heads
+
+
 #: layer kinds of a model with a ``layer_kinds`` list: name -> (the
-#: attention it runs, its feed-forward). Attention: ``latent`` (one
+#: mixer it runs, its feed-forward). Attention: ``latent`` (one
 #: compressed row a token, :class:`LatentAttention`); ``window`` (GQA
 #: K/V of ``head_dim``, RoPE over the whole head, query i sees keys j
 #: with ``0 <= i - j < attn_window``: its state is a RING of about
 #: ``attn_window`` rows a slot); ``full`` (the same K/V, plain causal
 #: over the whole context and NO positional rotation — the interleaved
 #: local/global convention, where the window layers carry the
-#: positions: its state is ``max_len`` rows a slot). Feed-forward:
+#: positions: its state is ``max_len`` rows a slot). ``ssm`` is no
+#: attention: a state-space mixer (:class:`StateSpace`) whose state is
+#: the same size at every length. Feed-forward:
 #: ``dense`` (a SwiGLU of ``d_ff``), ``moe`` (:class:`SparseExperts`), or
 #: ``scmoe``: the DOUBLE layer with a shortcut-connected expert block —
 #: two (attention, dense SwiGLU) halves in sequence, each attention
@@ -177,6 +218,7 @@ LAYER_KINDS = {
     "window_dense": ("window", "dense"), "window_moe": ("window", "moe"),
     "full_dense": ("full", "dense"), "full_moe": ("full", "moe"),
     "latent2_scmoe": ("latent", "scmoe"),
+    "ssm_dense": ("ssm", "dense"),
 }
 #: feed-forwards that route through :class:`SparseExperts`
 _ROUTED_FFNS = ("moe", "scmoe")
@@ -301,6 +343,15 @@ class TransformerConfig:
     # logits x ``logit_scale``.
     tie_embeddings: bool = False
     logit_scale: float = 1.0
+    # its ``ssm`` kinds mix through ``ssm``
+    ssm: StateSpace | None = None
+    # Three scalars of the kinded block: the embedding's rows x
+    # ``embed_scale`` as they enter the stream; every branch (mixer,
+    # feed-forward) x ``residual_scale`` as it joins it; the softmax of
+    # q.k x ``attn_scale`` (None: the usual ``head_dim ** -0.5``).
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float | None = None
 
     def __post_init__(self):
         # fail where the config was written, not at first trace
@@ -360,8 +411,12 @@ class TransformerConfig:
             if "window" in attns and not self.attn_window:
                 raise ValueError("a 'window' kind attends inside "
                                  "`attn_window`: set it")
+            if "ssm" in attns and self.ssm is None:
+                raise ValueError("an 'ssm' kind mixes through `ssm` "
+                                 "(StateSpace): set it")
             for what, on, need in (
                     ("latent", self.latent is not None, "latent" in attns),
+                    ("ssm", self.ssm is not None, "ssm" in attns),
                     ("experts", self.experts is not None, routed),
                     ("attn_window", self.attn_window, "window" in attns)):
                 if on and not need:
@@ -370,8 +425,22 @@ class TransformerConfig:
                         f"{sorted(set(self.layer_kinds))} uses it")
             if self.latent is not None and self.latent.rope_dim % 2:
                 raise ValueError("latent.rope_dim must be even")
-            if attns - {"latent"} and self.head_dim % 2:
+            if attns - {"latent", "ssm"} and self.head_dim % 2:
                 raise ValueError("head_dim must be even (rotary halves)")
+            m = self.ssm
+            if m is not None and not (
+                    min(m.n_heads, m.head_dim, m.d_state, m.chunk) > 0
+                    and m.d_conv > 1 and m.n_groups > 0
+                    and m.n_heads % m.n_groups == 0):
+                raise ValueError(
+                    f"ssm needs positive n_heads, head_dim, d_state and "
+                    f"chunk, a convolution of d_conv > 1 taps, and "
+                    f"n_groups dividing n_heads; got {m}")
+            if not attns - {"ssm"}:
+                raise ValueError(
+                    "a model of 'ssm' kinds alone holds no rows by "
+                    "position, and the serving path sizes a slot by "
+                    "them: keep an attention kind among layer_kinds")
             e = self.experts
             if e is not None and not (0 <= e.first and 0 < e.n_held
                                       and e.first + e.n_held <= e.total
@@ -407,10 +476,15 @@ class TransformerConfig:
                         f"model's dtype — a latent row, a ring of "
                         f"attn_window rows, or max_len rows a slot")
         elif (self.latent is not None or self.experts is not None
+              or self.ssm is not None
               or self.norm != "rms" or self.parallel_block
-              or self.tie_embeddings or self.logit_scale != 1.0):
-            raise ValueError("`latent`, `experts`, `norm`, `parallel_block`"
-                             ", `tie_embeddings` and `logit_scale` describe "
+              or self.tie_embeddings or self.logit_scale != 1.0
+              or self.embed_scale != 1.0 or self.residual_scale != 1.0
+              or self.attn_scale is not None):
+            raise ValueError("`latent`, `experts`, `ssm`, `norm`, "
+                             "`parallel_block`, `tie_embeddings`, "
+                             "`logit_scale`, `embed_scale`, "
+                             "`residual_scale` and `attn_scale` describe "
                              "a model with `layer_kinds`: set it")
 
     @property
@@ -425,8 +499,8 @@ class TransformerConfig:
 
     @property
     def attentions(self) -> tuple[str, ...]:
-        """The attention of each layer, in order: ``latent`` /
-        ``window`` / ``full`` (see LAYER_KINDS)."""
+        """The mixer of each layer, in order: ``latent`` / ``window`` /
+        ``full`` attention, or ``ssm`` (see LAYER_KINDS)."""
         return tuple(LAYER_KINDS[k][0] for k in self.layer_kinds)
 
     def attention_of(self, li: int) -> tuple[str, int]:
@@ -577,6 +651,20 @@ def kinded_block_shapes(cfg: TransformerConfig, kind: str) -> dict:
             "wo": ((h, la.v_dim, d), h * la.v_dim,
                    ("heads", "kv", "embed")),
         })
+    elif attention == "ssm":
+        m = cfg.ssm
+        leaves.update({
+            "w_in": ((d, m.in_dim), d, ("embed", "mlp")),
+            # taps x channels, tap j on the input d_conv - 1 - j back
+            "conv_w": ((m.d_conv, m.conv_dim), m.d_conv, (None, "mlp")),
+            "conv_b": ((m.conv_dim,), None, ("mlp",)),
+            # float32, as the router is: a step's size and decay
+            "dt_bias": ((m.n_heads,), None, (None,)),
+            "A_log": ((m.n_heads,), None, (None,)),
+            "D": ((m.n_heads,), None, (None,)),
+            "gate_norm": ((m.d_inner,), None, ("mlp",)),
+            "w_out": ((m.d_inner, d), m.d_inner, ("mlp", "embed")),
+        })
     else:
         # K/V heads replicate under tp when there are fewer than query
         # heads, as the dense decoder's (logical_axes)
@@ -644,9 +732,27 @@ def _scmoe_block_shapes(cfg: TransformerConfig) -> dict:
     return leaves
 
 
+#: float32 leaves of a state-space mixer, and how each starts (Mamba-2's
+#: own initializer): steps log-uniform in [0.001, 0.1] through
+#: ``dt_bias = softplus^-1(dt)``, ``-a`` uniform in [1, 16], ``D`` ones
+SSM_F32_LEAVES = ("dt_bias", "A_log", "D")
+
+
+def _init_ssm_leaf(key, leaf: str, shape):
+    if leaf == "D":
+        return jnp.ones(shape, jnp.float32)
+    u = jax.random.uniform(key, shape, jnp.float32)
+    if leaf == "A_log":
+        return jnp.log(1.0 + 15.0 * u)
+    dt = jnp.exp(math.log(1e-3) + u * math.log(1e2))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 def _init_kinded_blocks(rng, cfg: TransformerConfig, dense) -> dict:
     """{kind: {leaf: [layers of that kind, ...]}}: norms ones, the
-    selection bias zeros, router float32."""
+    selection bias and the convolution's zeros, router float32, a
+    state-space mixer's step and decay leaves by
+    :func:`_init_ssm_leaf`."""
     blocks = {}
     for ki, kind in enumerate(dict.fromkeys(cfg.layer_kinds)):
         n = cfg.layer_kinds.count(kind)
@@ -656,6 +762,10 @@ def _init_kinded_blocks(rng, cfg: TransformerConfig, dense) -> dict:
         for key, (leaf, (shape, fan_in, _)) in zip(keys, shapes.items()):
             if leaf == "router_bias":
                 group[leaf] = jnp.zeros((n,) + shape, jnp.float32)
+            elif leaf in SSM_F32_LEAVES:
+                group[leaf] = _init_ssm_leaf(key, leaf, (n,) + shape)
+            elif leaf == "conv_b":
+                group[leaf] = jnp.zeros((n,) + shape, cfg.dtype)
             elif fan_in is None:
                 group[leaf] = jnp.ones((n,) + shape, cfg.dtype)
             else:
