@@ -69,6 +69,9 @@ from benchmark.tests.test_timeline_readers import (  # noqa: F401 — PR 39's
     test_no_starved_enqueue_reads_zero_not_none,
     test_the_drain_moves_no_share, test_turns_by_hand,
     test_waits_by_hand_and_the_split_sums_to_the_whole)
+from benchmark.tests.test_steps_in_flight_reader import (  # noqa: F401 — PR 42's
+    test_a_program_without_the_count_reads_none,
+    test_the_mean_of_the_window)
 from benchmark.tests.test_families import (  # noqa: F401 — collected here
     test_dense_weights_are_the_parents_bit_for_bit,
     test_family_provides_the_whole_list,
@@ -96,7 +99,7 @@ def test_the_wide_decode_cells_files_are_the_issues(monkeypatch):
                 "admit_stall_share_pct.serve", "device_starved_pct.serve",
                 "first_token_queued_ms.serve", "first_token_ride_ms.serve",
                 "slot_vacant_ms.serve", "ssm_step_share_pct.serve",
-                "ssm_state_roofline.serve"]
+                "ssm_state_roofline.serve", "steps_in_flight.serve"]
             obj["per_layer"] = obj["per_layer"][:last + 1]
             cells = [w["name"] for w in obj["workloads"]]
             last = cells.index("serve-longcatflash-wide-decode")

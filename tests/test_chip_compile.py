@@ -305,13 +305,16 @@ def _held_in_one_layout(text, dims):
     assert layouts - tiled <= {next(iter(tiled)).split(":")[0] + "}"}, layouts
 
 
+@pytest.mark.parametrize("steps", [2, 8])
 @pytest.mark.parametrize("name", sorted(STEP_ROWS_CASES))
-def test_step_rows_never_copies_the_cache(chip, name):
-    """The decode chunk (``serve.step_rows``, ``n=8``, per-row frontiers)
-    holds the K/V cache in ONE layout from its arguments through the
-    ``while`` to its results: no instruction copies a cache-sized
-    buffer, and the program's temporaries are smaller than one cache
-    buffer. With a trailing [.., KV, hd] (the parent of PR 26) the
+def test_step_rows_never_copies_the_cache(chip, name, steps):
+    """The decode program (``serve.step_rows``, per-row frontiers) — at
+    the TWO steps the cells run since PR 42 (``serve._DECODE_STEPS``: a
+    copy at the program's edge would be paid every other step there) and
+    at the 8 they ran before — holds the K/V cache in ONE layout from its
+    arguments through the ``while`` to its results: no instruction
+    copies a cache-sized buffer, and the program's temporaries are
+    smaller than one cache buffer. With a trailing [.., KV, hd] (the parent of PR 26) the
     Phi-3-mini cases fail: head_dim 96 pads to 128 lanes in the loop's
     layout and not in the arguments', so the program begins and ends
     with 4 transposing copies of the whole cache (temporaries 0.51 GB
@@ -324,9 +327,10 @@ def test_step_rows_never_copies_the_cache(chip, name):
     compiled = S.step_rows.lower(
         params, cache, chip.shape((slots, vocab), cfg.logits_storage_dtype),
         chip.shape((slots, 2), jnp.uint32), chip.shape((slots,), jnp.int32),
-        n=8, cfg=cfg).compile()
+        n=steps, cfg=cfg).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_step_rows")
+    assert S._DECODE_STEPS == 2
 
     buf = cache["k"]
     dims = ",".join(str(d) for d in buf.shape)
@@ -510,7 +514,7 @@ def test_latent_step_rows_never_copies_the_cache_or_an_expert_layer(chip):
         params, cache,
         chip.shape((slots, cfg.vocab_size), cfg.logits_storage_dtype),
         chip.shape((slots, 2), jnp.uint32), chip.shape((slots,), jnp.int32),
-        n=8, cfg=cfg).compile()
+        n=S._DECODE_STEPS, cfg=cfg).compile()
     text = compiled.as_text()
     buf = cache["ckv"]
     assert buf.shape == (3, slots, rows, 640)
@@ -586,7 +590,7 @@ def test_mixed_step_rows_holds_ring_and_linear_cache_in_place(chip):
     assert cache["k"].shape == (1, 32, 16384, 1024)
     compiled = S.step_rows.lower(
         params, cache, logits, chip.shape((32, 2), jnp.uint32),
-        chip.shape((32,), jnp.int32), n=8, cfg=cfg).compile()
+        chip.shape((32,), jnp.int32), n=S._DECODE_STEPS, cfg=cfg).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_step_rows")
     assert not _copies_of_any(text, cache)
@@ -651,7 +655,7 @@ def test_double_layer_step_rows_copies_no_cache_and_no_half(chip):
     assert buf.shape == (8, 64, 4096, 640)
     compiled = S.step_rows.lower(
         params, cache, logits, chip.shape((64, 2), jnp.uint32),
-        chip.shape((64,), jnp.int32), n=8, cfg=cfg).compile()
+        chip.shape((64,), jnp.int32), n=S._DECODE_STEPS, cfg=cfg).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_step_rows")
     dims = ",".join(str(d) for d in buf.shape)
@@ -729,7 +733,7 @@ def test_latent_step_rows_reads_each_slots_own_blocks(chip, config, slots,
     assert buf.shape == (reads, slots, rows, 640)
     compiled = S.step_rows.lower(
         params, cache, logits, chip.shape((slots, 2), jnp.uint32),
-        chip.shape((slots,), jnp.int32), n=8, cfg=cfg).compile()
+        chip.shape((slots,), jnp.int32), n=S._DECODE_STEPS, cfg=cfg).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_step_rows")
     launches = _launches(text)
@@ -831,7 +835,7 @@ def test_hybrid_step_rows_updates_the_state_in_place(chip):
     assert cache["k"].shape == (4, 64, 4096, 512)
     compiled = S.step_rows.lower(
         params, cache, logits, chip.shape((64, 2), jnp.uint32),
-        chip.shape((64,), jnp.int32), n=8, cfg=cfg).compile()
+        chip.shape((64,), jnp.int32), n=S._DECODE_STEPS, cfg=cfg).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_step_rows")
     assert not _state_sized_copies(text, cache)

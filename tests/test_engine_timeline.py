@@ -246,6 +246,7 @@ def _run(monkeypatch, params, *, kind="pipelined", script=SCRIPT):
     assert [len(got[r]) for r in range(len(script))] == \
         [budget for _, budget, _ in script]
     world.script, world.tokens_a_chunk = script, b._chunk_tokens_max()
+    world.steps_a_program = b.chunk
     world.engine = engine
     return world, b, got, world.t - t0, t0
 
@@ -277,7 +278,8 @@ def _expect(world, t0, t_end):
     ret_index = None    # log index of the last fetch's return in this run
     waiting = set()     # rids submitted and not admitted
     admitted = {}       # rid -> [t_admit, t_ride (None: still behind)]
-    behind = []         # rids whose t_ride waits for the next fetch
+    behind = []         # (rid, the newest chunk enqueued ahead of it)
+    in_queue = []       # chunks enqueued, neither fetched nor dropped
     freed = {}          # row -> time its last occupant's slot was freed
     left = {}           # rid -> tokens still to come
     on_row = {}         # row -> rid
@@ -308,13 +310,20 @@ def _expect(world, t0, t_end):
             observe("wait", data - t)
             run_first = turn_open = ret_index = None
             run_started = data
+            in_queue.clear()        # what a run leaves unfetched it drops
         elif kind == "admit_batch":
             for row, rid in data:
                 waiting.discard(rid)
                 in_flight = bool(unfetched)
                 admitted[rid] = [t, None if in_flight else t]
                 if in_flight:
-                    behind.append(rid)
+                    # it stands behind every chunk in the queue: its
+                    # wait behind them ends when the newest returns
+                    behind.append((rid, unfetched[-1]))
+                if left.get(on_row.get(row), 0) > 0:
+                    # a FORESEEN handover: the row's last step is in the
+                    # queue and the slot changes hands behind it, now
+                    freed[row] = t
                 observe("queue_wait", t - t_queued[rid])
                 observe("slot_vacant",
                         t - max(freed.get(row, 0.0), t_queued[rid]))
@@ -331,7 +340,7 @@ def _expect(world, t0, t_end):
                 observe("first_token_early", t - admitted[rid][0])
                 ahead.add(rid)
                 left[rid] -= 1
-                if left[rid] <= 0:
+                if left[rid] <= 0 and on_row[row] == rid:
                     freed[row] = t
         elif kind in ("chunk", "admit"):
             seq = len(programs)
@@ -343,9 +352,13 @@ def _expect(world, t0, t_end):
                 if seq in fetches:
                     unfetched.append(seq)
                 snaps[seq] = dict(on_row)
+                in_queue.append(seq)
+                observe("steps_in_flight",
+                        len(in_queue) * world.steps_a_program)
         elif kind == "fetch":
             seq = unfetched.pop(0)
             assert seq == seq_of[data]           # fetched in issue order
+            in_queue.remove(seq)
             in_run_prev = prev is not None and prev >= run_first
             lo = prev + 1 if in_run_prev else run_first
             admits = sum(programs[s][0] == "admit" for s in range(lo, seq))
@@ -360,9 +373,10 @@ def _expect(world, t0, t_end):
             if admits or waiting:
                 observe("turn_loaded", took)
             turn_open, ret_index, prev = t, i, seq
-            for rid in behind:
-                admitted[rid][1] = t
-            behind.clear()
+            for rid, last in behind:
+                if last <= seq:
+                    admitted[rid][1] = t
+            behind = [(rid, last) for rid, last in behind if last > seq]
             for row, rid in snaps[seq].items():
                 if left.get(rid, 0) <= 0:
                     continue
@@ -374,7 +388,7 @@ def _expect(world, t0, t_end):
                 # ahead of it
                 left[rid] -= world.tokens_a_chunk - (rid in ahead)
                 ahead.discard(rid)
-                if left[rid] <= 0:
+                if left[rid] <= 0 and on_row[row] == rid:
                     freed[row] = t
     slivers += t_end - (turn_open if turn_open is not None
                         else run_started)
@@ -401,7 +415,7 @@ def _held(b, totals):
         assert (pt.total(name), pt.count(name)) == (total, count), name
     for name in ("turn", "turn_clean", "turn_admit", "turn_loaded",
                  "starved", "first_token_queued", "first_token_ride",
-                 "first_token_early", "slot_vacant"):
+                 "first_token_early", "slot_vacant", "steps_in_flight"):
         if name not in totals:
             assert pt.count(name) == 0, name
 
@@ -470,48 +484,63 @@ def test_the_pipelined_script_by_hand(monkeypatch, params):
     kinds = "".join({"chunk": "C", "admit": "A", "wait": "|"}[k]
                     for k, _, _ in world.log
                     if k in ("chunk", "admit", "wait"))
-    # run 1: a wave of one dispatch; chunk 2 enqueued behind chunk 1; the
-    # next issue DEFERRED (a budget ends in chunk 2 and a request waits),
-    # so the admission that follows finds the device idle; two more
-    # chunks, the second certainly final. Run 2: a wave of two buckets,
-    # two chunks, a deferred issue and its admission, a last chunk.
-    # Run 3: one request, joined by another while chunk 2 is in flight:
-    # that admission stands behind it.
+    # run 1: a wave of one dispatch; chunk 2 enqueued behind chunk 1. As
+    # chunk 1 is consumed the host FORESEES that chunk 2 holds row 1's
+    # last step while a request waits: the row changes hands behind it —
+    # the admission is enqueued on a busy device (before PR 42 the issue
+    # was deferred and the admission found the device idle) — and chunk 3
+    # follows; chunk 4 is certainly final. Run 2: a wave of two buckets,
+    # two chunks, the foreseen handover of row 0 behind chunk 2, a last
+    # chunk. Run 3: one request, joined by another while chunks 1 and 2
+    # are in flight: that admission stands behind chunk 2.
     assert kinds == "ACCACC|AACCAC|ACCAC|"
     pt = b.phase_times
     assert {n: (pt.total(n), pt.count(n)) for n in (
         "turn", "turn_clean", "turn_admit", "turn_loaded", "starved",
         "first_token", "first_token_queued", "first_token_ride",
-        "slot_vacant", "queue_wait", "admit_dispatch", "wait")} == {
+        "slot_vacant", "queue_wait", "admit_dispatch", "wait",
+        "steps_in_flight")} == {
         # run 1: 130 (admission 30 + chunk 100, from the first enqueue),
-        # 100, 137 (7 starved + 30 + 100), 100; run 2: 160 (two
-        # admissions), 100, 137; run 3: 130, 100, 130
-        "turn": (467 + 397 + 360, 10),
+        # 100, 130 (the handover's admission 30 + 100), 100; run 2: 160
+        # (two admissions), 100, 130; run 3: 130, 100, 130
+        "turn": (460 + 390 + 360, 10),
         "turn_clean": (400, 4),
-        "turn_admit": (130 + 137 + 160 + 137 + 130 + 130, 6),
-        # not the last turn of runs 1 and 3's middle one: nobody waited
-        "turn_loaded": (367 + 397 + 260, 8),
-        # fetch return -> consume's two callbacks 2, pad 3, call 2
-        "starved": (7 + 7, 2),
+        "turn_admit": (130 + 130 + 160 + 130 + 130 + 130, 6),
+        # the admission turns alone: at the close of each clean turn the
+        # request that had waited was already admitted
+        "turn_loaded": (810, 6),
+        # no enqueue finds the queue empty inside a run: a foreseen
+        # admission goes in behind the row's last step, not after it
+        "starved": (0, 0),
         # 35 = pad 3 + call 2 + admission 30: the draw's own call (1)
         # runs while the device runs the admission, and NO chunk is in
-        # it; run 2's wave 65 (both dispatches ahead); the joiner 129 =
-        # 99 behind the chunk in flight + 30
-        "first_token": (5 * 35 + 2 * 65 + 129, 8),
-        "first_token_queued": (99, 8),
-        "first_token_ride": (5 * 35 + 2 * 65 + 30, 8),
-        # the two admissions after a deferred issue: 2 of callbacks; the
-        # joiner is submitted as request 6's first delta leaves — a
-        # chunk (100) earlier than the token used to — and is admitted
-        # where it was: its wait, and its never-used slot's, grow by it
-        "slot_vacant": (2 + 2 + 100, 8),
-        "queue_wait": (237 + 267 + 100, 8),
+        # it; run 2's wave 65 (both dispatches ahead); the two handovers
+        # 128 = 98 behind the row's last chunk + 30; the joiner 129 = 99
+        # behind the newer of the two chunks in flight + 30
+        "first_token": (3 * 35 + 2 * 65 + 2 * 128 + 129, 8),
+        "first_token_queued": (98 + 98 + 99, 8),
+        "first_token_ride": (3 * 35 + 2 * 65 + 3 * 30, 8),
+        # a handed-over slot stood vacant for nobody; the joiner is
+        # submitted as request 6's first delta leaves and admitted when
+        # chunk 1 has been consumed: its wait, and its never-used slot's
+        "slot_vacant": (100, 8),
+        # the handovers' requests waited for chunk 1 of their run (137:
+        # the wave 5 + the draw 1 + two issues 4 ... to its return at
+        # 135, and the two callbacks; 167 with the second bucket)
+        "queue_wait": (137 + 167 + 100, 8),
         "admit_dispatch": (7 * ADMIT_CALL, 7),
-        "wait": (3 * IDLE, 3)}
+        "wait": (3 * IDLE, 3),
+        # a count, not an interval: the steps issued and unfetched at each
+        # issue, this program's two included — a run's first 2, then 4
+        # (depth 2, two steps a program)
+        "steps_in_flight": (3 * 2 + 7 * 4, 10)}
     # every first token left ahead of its chunk
     assert (pt.total("first_token_early"), pt.count("first_token_early")) \
         == (pt.total("first_token"), 8)
-    assert wall == 4247
+    # the depth never left the floor: a host turn of 2-8 against 100
+    assert world.engine.stats()["depth"] == 2
+    assert world.engine.stats()["steps_in_flight"] == 3.4
+    assert wall == 4233
 
 
 def _drained(monkeypatch, params, tail):

@@ -30,10 +30,13 @@ shared padded cache. On top of that, the device programs:
   the cache type alone (``cfg.kv_cache_capacity``, a template, a draft
   model), never by an option;
 - :func:`step_rows` — a ``lax.scan`` of ``n`` per-row decode steps over
-  the whole batch (one dispatch per chunk, not per token; greedy by
-  default, or sampled through the same top-k/temperature/nucleus stack
-  as ``decode.generate`` — from PER-REQUEST key streams, see below);
-- :func:`first_tokens` — the draw step 0 of the next chunk will make,
+  the whole batch, ONE program (greedy by default, or sampled through
+  the same top-k/temperature/nucleus stack as ``decode.generate`` — from
+  PER-REQUEST key streams, see below). ``n`` is the unit of delivery:
+  the host sees a program's ``[B, n]`` tokens when the whole of it has
+  returned. The batchers run ``n = _DECODE_STEPS`` (2) unless told
+  otherwise: a token waits for one more step at most, not for seven;
+- :func:`first_tokens` — the draw step 0 of the next program will make,
   made once a wave right behind its admissions, so that a request's
   first token leaves when its admission has run (below);
 - :func:`retire_rows` — zero the freed rows' frontiers so idle slots
@@ -53,20 +56,35 @@ reading it, so padding rows are unreachable too.
 The admission loop itself (:class:`ContinuousBatcher`) is host-driven —
 admission is inherently data-dependent control flow (which request, into
 which slot, at what length) and runs at human/request rate, while the
-token loop stays on device in ``step_rows`` chunks. The loop is
-PIPELINED (double-buffered dispatch): chunk N+1 is issued *before* chunk
-N's tokens are fetched, so the host-side EOS/budget bookkeeping and the
-device→host fetch overlap device compute instead of serializing with
-it. Nothing on the host feeds
-the device between chunks — per-request rng streams are derivable ahead
-of time — EXCEPT retirement/admission, which the loop handles two ways:
-completions the host can PREDICT (budget exhaustion with requests still
-queued) process their chunk synchronously so the admission lands before
-the next dispatch, exactly as the sequential loop would; unpredictable
-completions (an eos mid-chunk) are caught up AFTER the speculatively
-issued chunk — the freed row ran one chunk of garbage that the host
-discards exactly as idle-slot garbage is discarded, and the late
-admission overwrites the slot before anything reads it.
+token loop stays on device in ``step_rows`` programs. The loop is
+PIPELINED over a QUEUE of issued programs: ``depth`` of them stand in
+the device's queue while the host fetches the oldest, so the host-side
+EOS/budget bookkeeping, the device→host fetch and the admissions'
+marshalling overlap device compute instead of serializing with it, and
+each program's tokens leave the turn it returns. WHY SHORT PROGRAMS AND
+A QUEUE, where this file ran eight steps a program and one program
+ahead until PR 42: the eight were set when one host↔device sync cost
+70–100 ms through a remote transport (docs/performance.md), so a sync a
+token would have been the whole budget; on a machine where a small fetch
+behind a busy queue returns when its own program ends and costs the host
+well under a millisecond, the same overlap is bought by keeping several
+short programs in flight, and a token waits out ``_DECODE_STEPS − 1``
+steps at most. Why two steps and not one: a program of its own costs the
+device ~2% of a step (measured; the constant's comment), which one cell
+could not pay. ``depth`` is nobody's argument: the engine reads it off its own
+turns (:class:`_Depth` — its host time a turn over the device's time a
+step, plus one; at least 2, never more than 16 steps ahead).
+Nothing on the host feeds the device between programs — per-request rng
+streams are derivable ahead of time — EXCEPT retirement/admission, which
+the loop handles two ways (:meth:`ServeEngine._foresee`): a completion
+the host can FORESEE (budget exhaustion with requests still queued) hands
+the row over BEHIND ITS LAST STEP — the admission is enqueued right after
+the program that ends the row, on a busy device, exactly where the
+sequential loop would have placed it; unforeseeable completions (an eos,
+a cancel) are caught up as they are consumed — the freed row ran the
+steps already queued behind it as garbage that the host discards exactly
+as idle-slot garbage is discarded, and the late admission overwrites the
+slot before anything reads it.
 
 Sampling uses PER-REQUEST key streams: request ``q``'s draw at its
 ``t``-th generated token comes from ``fold_in(fold_in(seed_key, q), t)``
@@ -74,10 +92,11 @@ Sampling uses PER-REQUEST key streams: request ``q``'s draw at its
 alone. A request's sampled output is therefore independent of admission
 timing and batch composition (the pre-pipelining loop's shared stream
 made samples depend on WHEN a request was admitted), which is also what
-lets the pipelined loop shift an admission by a chunk without changing
-any output: pipelined and sequential (``pipeline=False``) serving are
-token-identical in every mode — greedy, sampled, speculative, and
-shared-prefix (test-enforced on CPU).
+lets the pipelined loop shift an admission by some steps without
+changing any output: pipelined — at any depth, at any ``chunk`` — and
+sequential (``pipeline=False``) serving are token-identical in every
+mode — greedy, sampled, speculative, and shared-prefix (test-enforced
+on CPU).
 
 :class:`SpeculativeContinuousBatcher` composes the two serving features:
 every slot runs draft-propose/target-verify rounds at its own frontier
@@ -96,8 +115,10 @@ The host loop itself is OPEN-LOOP (:class:`ServeEngine`): the
 issue/fetch/consume/settle cycle runs against a LIVE admission queue —
 requests are submitted (and cancelled) at any time, from any thread, and
 each request's newly generated tokens are emitted as a DELTA the moment
-the chunk that produced them is consumed, not when the request retires.
-THE FIRST TOKEN LEAVES WITH ITS ADMISSION: it is step 0 of the chunk
+the program that produced them is consumed, not when the request
+retires — ``_DECODE_STEPS`` tokens a live request a program at the
+default, one at ``chunk=1``.
+THE FIRST TOKEN LEAVES WITH ITS ADMISSION: it is step 0 of the program
 enqueued behind the admission — a function of the logits the admission
 seeded and of the request's own key, so it exists the instant the
 admission ends. After each admission wave the engine enqueues one
@@ -182,8 +203,13 @@ thread enqueued them, so that order is the causal chain:
 - a RUN is the feeding of the device between two ``wait`` blocks; within
   it a TURN is the interval from one fetch's return to the next (the
   run's first opens at its first enqueue). A turn holds exactly one
-  decode chunk and the admission dispatches enqueued between it and the
-  chunk before. Observed where the fetch returns
+  decode program — "chunk" below and in the readers' names:
+  ``_DECODE_STEPS`` steps at the default — and the admission dispatches enqueued between it and
+  the program before. ``steps_in_flight``, at every issue: the decode
+  steps issued and unfetched, that program's own included — a COUNT
+  beside the intervals (its "seconds" are steps); the ``dispatch`` row
+  carries it (``in_flight``, before the issue) with the ``depth`` the
+  loop holds. Observed where the fetch returns
   (:meth:`ContinuousBatcher._await`): ``turn`` every one; ``turn_clean``
   when the chunk was enqueued before the previous fetch returned and no
   admission lies between the two (device-bound, it is the chunk's own
@@ -197,14 +223,17 @@ thread enqueued them, so that order is the causal chain:
   a restart after everything retired): the time since the run's last
   fetch returned — the device's idle time the host caused, by
   construction.
-- a request's ``first_token`` splits where the chunk that was in flight
-  at its admission returned: ``first_token_queued`` (the admission stood
-  behind that chunk; 0 when none was in flight) and ``first_token_ride``
+- a request's ``first_token`` splits where the NEWEST program that was
+  in flight at its admission returned: ``first_token_queued`` (the
+  admission stood behind every step already issued; 0 when none was in
+  flight) and ``first_token_ride``
   (the admission on the device, the fetch of the draw and its
   delivery — no chunk; the speculative batcher's still rides its first
   chunk); the two sum to ``first_token``. ``slot_vacant``, at each
   admission, is ``t_admit − max(the return of the chunk whose
-  consumption freed the slot, the request's t_queued)``: how long a free
+  consumption freed the slot — or, for a FORESEEN handover, the instant
+  the loop saw the row's last step in the queue — the request's
+  t_queued)``: how long a free
   slot and a runnable request both waited for the loop to come round.
   The request spans name their causes: ``engine.queued`` ends with
   ``slot`` and ``freed_seq`` (the chunk that freed it, −1 for a slot
@@ -285,6 +314,32 @@ _ADMIT_TOKEN_BUDGET = 256
 #: host discards; any fixed stream works)
 _IDLE_STREAM = 0x7FFFFFFF
 
+#: decode steps ONE ``step_rows`` program runs unless a caller says
+#: otherwise. A program is the unit of delivery — the host sees a
+#: program's tokens when the whole of it has returned — so at 8 (the
+#: default until PR 42, set when a host<->device sync cost 70-100 ms
+#: through a remote transport, docs/performance.md) a token drawn at
+#: step 0 waited out seven more steps; here a small fetch behind a busy
+#: queue returns when its own program ends. A DERIVED constant, from the
+#: chip (PERF.md section 6, PR 42): at ONE step a program every serve
+#: cell's gap fell to a step, but a step costs the device ~2% more as a
+#: program of its own (Kimi: a clean turn of 8.549 ms against 67.014 / 8,
+#: over the 0.5% ISSUE 42 allowed it) and the cell with the least to win
+#: back from the queue paid it whole (Command A+: 966.20 -> 954.42
+#: tokens/s, -1.22% under a bound of 1%); at TWO the same cell reads
+#: 977.60 -> 973.44 as a pair (-0.43%) and 966.33 unpaired. So two: the
+#: smallest count at which every cell's rate keeps its bound, and a gap
+#: a quarter of the chunk's. Not an argument's default to tune per
+#: model: one value.
+_DECODE_STEPS = 2
+
+#: fewest decode programs the pipelined loop keeps issued and unfetched
+#: (the double buffer: one running while the host consumes the one
+#: before), and the most decode STEPS it may stand ahead of the host —
+#: the two chunks of eight the loop held at its deepest before PR 42
+_DEPTH_FLOOR = 2
+_MAX_STEPS_AHEAD = 16
+
 
 def _nobody_waiting() -> tuple[int, int]:
     """(occupied slots, requests waiting) of a batcher no engine drives."""
@@ -293,6 +348,42 @@ def _nobody_waiting() -> tuple[int, int]:
 
 def _count_trace(name: str, shape) -> None:
     TRACE_COUNTS[(name, tuple(shape))] += 1
+
+
+class _Depth:
+    """How many decode programs the pipelined loop keeps issued and
+    unfetched, from what the engine thread measures of itself at every
+    fetch's return (:meth:`ContinuousBatcher._await`): its HOST time a
+    turn — the turn less what it spent blocked on the device — against
+    the device's time a program, the clean turns'. While the host works
+    through one turn the device works through the queue, so the queue
+    has to hold the host turn's worth of programs and the one the host
+    fetches next: ``ceil(host / step) + 1``, never under
+    ``_DEPTH_FLOOR``, never more than ``_MAX_STEPS_AHEAD`` steps. The
+    host estimate rises at once and falls slowly — the turn that has to
+    be covered is the long one, an admission's marshalling, not the
+    mean — and one observation counts for no more than the cap can
+    cover, so a stall does not hold the depth up for long after it. No
+    argument and no per-model constant: 6 slots or 64, a step of 8 ms
+    or 19, each deployment reads its own."""
+
+    def __init__(self, steps_a_program: int) -> None:
+        self.cap = max(_DEPTH_FLOOR, _MAX_STEPS_AHEAD // steps_a_program)
+        self.host = 0.0      # seconds of host work a turn (rises at once)
+        self.step = 0.0      # seconds of a clean turn: a program's own
+        self.depth = _DEPTH_FLOOR
+
+    def turn(self, host: float, took: float, clean: bool) -> None:
+        """Fold one closed turn: ``took`` seconds long, ``host`` of
+        them not blocked on the device; ``clean`` as ``turn_clean``."""
+        if clean:
+            self.step += (took - self.step) / 8 if self.step else took
+        if not self.step:
+            return
+        host = min(host, self.cap * self.step)
+        self.host = max(host, self.host + (host - self.host) / 16)
+        self.depth = min(self.cap, max(
+            _DEPTH_FLOOR, 1 + int(-(-self.host // self.step))))
 
 
 def admit_width(bucket: int, slots: int) -> int:
@@ -846,10 +937,12 @@ class ContinuousBatcher:
     as ``generate`` instead, from per-request key streams (see
     ``__init__``).
 
-    The serve loop is PIPELINED (``pipeline=True``): chunk N+1 is
-    dispatched before chunk N's tokens are fetched, overlapping the
-    fetch's transport round trip and the host bookkeeping with device
-    compute. ``pipeline=False`` is the sequential
+    The serve loop is PIPELINED (``pipeline=True``): a queue of issued
+    programs — one decode step each unless ``chunk`` says otherwise — as
+    deep as the engine's own host turn needs (:class:`_Depth`),
+    overlapping the fetch and the host bookkeeping with device compute;
+    each step's tokens leave when it has run. ``pipeline=False`` is the
+    sequential
     issue→fetch→bookkeep→admit loop that the tests hold the pipelined
     one to, token for token, in the sampled and speculative modes where
     ``decode.generate`` is no oracle; nothing else selects it.
@@ -876,7 +969,7 @@ class ContinuousBatcher:
 
     def __init__(self, params, cfg: T.TransformerConfig, batch: int,
                  max_len: int, eos_id: int | None = None,
-                 chunk: int = 8, temperature: float = 0.0,
+                 chunk: int = _DECODE_STEPS, temperature: float = 0.0,
                  top_k: int = 0, top_p: float = 0.0,
                  seed: int = 0,
                  shared_prefix=None, pipeline: bool = True,
@@ -938,11 +1031,12 @@ class ContinuousBatcher:
         self.top_k = top_k
         self.top_p = top_p
         self.seed = seed
-        #: device steps per host round trip — latency/overhead trade:
-        #: a finished row idles at most chunk-1 steps before its slot
-        #: is reused
+        #: decode steps ONE ``step_rows`` program runs (``_DECODE_STEPS``
+        #: unless given): the unit of delivery — a program's tokens reach
+        #: the host together. How many programs stand in the device's
+        #: queue is the pipelined loop's (:class:`_Depth`), not this
         self.chunk = max(1, chunk)
-        #: double-buffered dispatch (see class docstring)
+        #: a queue of issued programs (see class docstring)
         self.pipeline = bool(pipeline)
         if admission_buckets is not None:
             ladder = sorted({int(b) for b in admission_buckets})
@@ -1072,6 +1166,11 @@ class ContinuousBatcher:
         self._t_turn: float | None = None
         #: seq of the admission dispatch that last landed in each row
         self._row_seq = [-1] * self.batch
+        #: the pipelined loop's queue depth, from the turns measured here
+        self._ahead = _Depth(self.chunk)
+        #: seconds the thread had spent blocked on the device (``fetch``
+        #: and ``first_fetch``) when the turn in progress opened
+        self._waited = 0.0
 
     def _enqueued(self, rows=()) -> None:
         """Count one program into the device queue, as the call that
@@ -1084,19 +1183,29 @@ class ContinuousBatcher:
         if self._t_turn is None:
             self._t_turn = now          # a run's first turn opens here
             self._seq_chunk = self.seq
+            self._waited = self._blocked()
         elif self.seq - 1 == self._seq_run:
             self.phase_times.observe("starved", now - self._t_turn)
         for row in rows:
             self._row_seq[row] = self.seq
         self.seq += 1
 
+    def _blocked(self) -> float:
+        """Seconds this thread has spent blocked on the device so far:
+        the ``fetch`` phase and the engine's ``first_fetch``."""
+        pt = self.phase_times
+        return pt.total("fetch") + pt.total("first_fetch")
+
     def _dispatch_phase(self):
-        """The ``dispatch`` phase of the chunk about to be issued; its
-        row says which program of the queue it is and how full its batch
-        was."""
+        """The ``dispatch`` phase of the program about to be issued; its
+        row says which program of the queue it is, how full its batch
+        was, how many decode steps stood issued and unfetched ahead of
+        it and the depth the loop holds the queue to."""
         live, waiting = self._load()
-        return self.phase_times.phase("dispatch", seq=self.seq, live=live,
-                                      waiting=waiting)
+        return self.phase_times.phase(
+            "dispatch", seq=self.seq, live=live, waiting=waiting,
+            in_flight=len(self._unfetched) * self.chunk,
+            depth=self._ahead.depth)
 
     def _chunk_enqueued(self) -> None:
         """File the chunk just enqueued among the unfetched, with what
@@ -1108,6 +1217,10 @@ class ContinuousBatcher:
         self._enqueued()
         self._unfetched.append((seq, seq - self._seq_chunk, clean))
         self._seq_chunk = seq + 1
+        # decode steps issued and unfetched, this program's own included:
+        # a count beside the intervals (its "seconds" are steps)
+        self.phase_times.observe("steps_in_flight",
+                                 len(self._unfetched) * self.chunk)
 
     def _await(self, handle):
         """Block on the oldest unfetched chunk (the ``fetch`` phase; its
@@ -1132,6 +1245,10 @@ class ContinuousBatcher:
             pt.observe("turn_clean", took)
         if admits or self._load()[1]:
             pt.observe("turn_loaded", took)
+        # what the loop's depth follows: the turn's host part is the turn
+        # less what the thread spent blocked on the device in it
+        waited, self._waited = self._waited, self._blocked()
+        self._ahead.turn(took - (self._waited - waited), took, clean)
         return host
 
     # --- resident prefix templates (prefix-aware serving) ---
@@ -1348,7 +1465,7 @@ class ContinuousBatcher:
         alike. Not an admission dispatch and not a chunk: it takes no
         ``seq``, and no turn's meaning moves."""
         return first_tokens(self.logits, self._row_keys,
-                            jnp.asarray(self._row_off, jnp.int32),
+                            np.asarray(self._row_off, np.int32),
                             self.temperature, self.top_k, self.top_p)
 
     def _admit_packages(self, pairs, pkgs) -> None:
@@ -1571,11 +1688,12 @@ class ContinuousBatcher:
         return self.chunk
 
     def _issue(self):
-        """Issue one device chunk WITHOUT fetching it (async dispatch —
-        returns the not-yet-materialized device tokens). The pipelined
-        loop issues chunk N+1 here before fetching chunk N."""
+        """Issue one decode program (``chunk`` steps) WITHOUT fetching it
+        (async dispatch — returns the not-yet-materialized device
+        tokens). The pipelined loop keeps ``depth`` of them issued ahead
+        of the one it fetches."""
         with self._dispatch_phase():
-            offs = jnp.asarray(self._row_off, jnp.int32)
+            offs = np.asarray(self._row_off, np.int32)
             toks, self.cache, self.logits, stats = step_rows(
                 self.params, self.cache, self.logits, self._row_keys,
                 offs, self.chunk, self.cfg, self.temperature, self.top_k,
@@ -1633,8 +1751,9 @@ class ContinuousBatcher:
                 break
 
     def _retire(self, mask) -> None:
-        self.cache = retire_rows(self.cache, jnp.asarray(mask))
-        self._row_len[np.asarray(mask, bool)] = 0
+        mask = np.asarray(mask, bool)
+        self.cache = retire_rows(self.cache, mask)
+        self._row_len[mask] = 0
 
     def count_finished(self, prompt_len: int, emitted: int) -> int:
         """Fold a finished request into the ring's accounting: it wrote
@@ -1882,7 +2001,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
 
     def _issue(self):
         with self._dispatch_phase():
-            offs = jnp.asarray(self._row_off, jnp.int32)
+            offs = np.asarray(self._row_off, np.int32)
             packed, self.cache, self.d_cache, self.pending = (
                 spec_step_rows(self.params, self.draft_params, self.cache,
                                self.d_cache, self.pending, self._row_keys,
@@ -1906,7 +2025,7 @@ class SpeculativeContinuousBatcher(ContinuousBatcher):
             for row in range(self.batch)]
 
     def _retire(self, mask) -> None:
-        m = jnp.asarray(mask)
+        m = np.asarray(mask, bool)
         self.cache = retire_rows(self.cache, m)
         self.d_cache = retire_rows(self.d_cache, m)
 
@@ -1941,7 +2060,7 @@ class _EngineRequest:
                  "emitted", "done", "reason", "t_submit", "t_last",
                  "t_queued", "t_admit", "t_ride", "admit_seq", "span",
                  "queued_span", "first_span", "cls", "history", "requeued",
-                 "sent_ahead")
+                 "sent_ahead", "flight")
 
     def __init__(self, rid, prompt, budget: int, stream: int,
                  t_submit: float, rng_skip: int = 0,
@@ -1986,6 +2105,10 @@ class _EngineRequest:
         #: (:meth:`ServeEngine._consume_first`), until that chunk is
         #: consumed: its column 0 is the same draw, and is dropped
         self.sent_ahead: int | None = None
+        #: decode programs issued with this request in their snapshot
+        #: and not consumed yet: what the pipelined loop foresees its
+        #: end from (:meth:`ServeEngine._spare`)
+        self.flight = 0
         # TTFT-decomposition spans (tracing.NOOP_SPAN when unsampled):
         # engine.request (submit→retire) with children engine.queued
         # (submit→slot admit) and engine.first_token (admit→first
@@ -2046,7 +2169,7 @@ class ServeEngine:
 
     Cancel semantics reuse the pipelined loop's proven catch-up path: a
     cancelled occupant is only MARKED done; the slot frees when the next
-    consumed chunk crosses it (its tokens are discarded exactly like
+    consumed program crosses it (its tokens are discarded exactly like
     idle-slot garbage, and the freed slot readmits from the live
     queue). CANCEL racing retirement is idempotent — unknown or
     already-done rids are no-ops.
@@ -2116,9 +2239,10 @@ class ServeEngine:
         #: that chunk's seq) — (0.0, -1) for a slot never occupied: the
         #: start of ``slot_vacant`` and the cause ``engine.queued`` names
         self._freed = [(0.0, -1)] * batcher.batch
-        #: requests admitted behind a chunk still in flight: their
-        #: ``first_token_queued`` ends when its fetch returns
-        self._behind: list[_EngineRequest] = []
+        #: requests admitted behind programs still in flight, each with
+        #: the seq of the newest of them: its ``first_token_queued`` ends
+        #: when that one's fetch returns
+        self._behind: list[tuple[_EngineRequest, int]] = []
         #: first-token draws enqueued and not fetched, oldest first:
         #: (the ``seq`` the next chunk took, the draw's device tokens,
         #: the wave's (row, request) pairs). A draw stands in the device
@@ -2176,6 +2300,11 @@ class ServeEngine:
             help="requests whose first token left with its admission, "
                  "ahead of the chunk enqueued behind it (over the "
                  "first_token phase's ops: the share that did)")
+        self._depth_g = reg.gauge(
+            "tony_serve_pipeline_depth",
+            help="decode programs the pipelined loop keeps issued and "
+                 "unfetched: its host time a turn over the device's "
+                 "time a program, plus one (serve._Depth)")
         self._preempt_c = reg.counter(
             "tony_serve_preemptions_total",
             help="batch rows evicted-to-queue for an interactive "
@@ -2513,6 +2642,14 @@ class ServeEngine:
                 # requests whose first token left ahead of its chunk
                 "first_tokens_early":
                     self.b.phase_times.count("first_token_early"),
+                # decode steps issued and unfetched, the mean over the
+                # issues so far, and the depth (in programs of
+                # ``chunk`` steps) the pipelined loop holds now
+                "steps_in_flight": round(
+                    self.b.phase_times.total("steps_in_flight")
+                    / max(1, self.b.phase_times.count("steps_in_flight")),
+                    3),
+                "depth": self.b._ahead.depth,
             }
 
     # --- the loop (one driving thread) ---
@@ -2763,7 +2900,8 @@ class ServeEngine:
         if admitted:
             tr = tracing.get_tracer()
             pt = self.b.phase_times
-            in_flight = bool(self.b._unfetched)
+            # the newest program its admission will stand behind
+            ahead = self.b._unfetched[-1][0] if self.b._unfetched else None
             now = time.perf_counter()
             for (row, _), req in zip(pairs, admitted):
                 # the wait for a slot ends here — counted (a wait, not
@@ -2776,8 +2914,8 @@ class ServeEngine:
                 t_freed, freed_seq = self._freed[row]
                 pt.observe("slot_vacant", now - max(t_freed, req.t_queued))
                 req.t_admit = req.t_ride = now
-                if in_flight:
-                    self._behind.append(req)
+                if ahead is not None:
+                    self._behind.append((req, ahead))
                 req.queued_span.end(slot=row, freed_seq=freed_seq)
                 if req.span.recording:
                     # admit → first consumed delta: the prefill+decode
@@ -2797,11 +2935,16 @@ class ServeEngine:
         landed mid-flight) carry garbage and are discarded — the same
         discard as idle-slot garbage. The whole of it is the phase
         ``consume``; the callbacks inside it are ``emit``."""
-        for req in self._behind:
-            # the chunk it stood behind has returned; its admission and
-            # the fetch of its first token are what is left
-            req.t_ride = self.b._t_turn
-        self._behind.clear()
+        if self._behind:
+            # the last program it stood behind has returned; its admission
+            # and the fetch of its first token are what is left
+            still = []
+            for req, seq in self._behind:
+                if seq <= self.b._seq_run:
+                    req.t_ride = self.b._t_turn
+                else:
+                    still.append((req, seq))
+            self._behind = still
         with self.b.phase_times.phase("consume"):
             self._consume_chunk(host_toks, snap)
         with self.b.phase_times.phase("account"):
@@ -3019,71 +3162,114 @@ class ServeEngine:
                     live = True
             return live
 
-    def _certainly_final(self) -> bool:
-        """The chunk about to be issued provably retires every live
-        request (budget exhaustion; eos and speculative acceptance only
-        finish EARLIER, and every speculative round commits >= 1 token)
-        with nothing queued — issuing past it would be a guaranteed-
-        garbage dispatch. (A submission landing during that final chunk
-        is admitted at its settle and the loop continues.)"""
-        with self._lock:
-            if self._wait_total_locked():
-                return False
-            return all(req.budget <= self.b.chunk
-                       for req in self._occupant
-                       if req is not None and not req.done)
+    def _spare(self, req: _EngineRequest, a_program: int) -> int:
+        """Tokens the programs in flight hold for ``req`` past its
+        budget, at ``a_program`` tokens each (under the lock): at 0 or
+        more its end is already in the device's queue. Column 0 of its
+        first program is the token that left ahead (``sent_ahead``,
+        until that program is consumed) and counts for nothing."""
+        return (req.flight * a_program - (req.sent_ahead is not None)
+                - req.budget)
 
-    def _defer_issue(self, snap) -> bool:
-        """Process the in-flight chunk BEFORE issuing the next one when
-        the host can PREDICT a completion with requests still queued:
-        budget exhaustion is host-visible ahead of time, and issuing
-        across it would run the freed slot idle for a whole chunk — a
-        step-utilization loss the sequential loop doesn't pay.
-        Unpredictable completions (eos mid-chunk, a cancel) are NOT
-        deferred for — the loop stays optimistic and catches up after
-        the fact. Budget-only workloads therefore pipeline LOSSLESSLY:
-        chunk count, admission timing, and utilization all match the
-        sequential loop."""
+    def _foresee(self) -> bool:
+        """Decide whether one more decode program goes into the queue
+        now, from what the host can FORESEE step by step: a budget's end
+        is host-visible ahead of time (eos and speculative acceptance
+        only finish EARLIER, and every speculative round commits >= 1
+        token), and ``flight`` says how many programs each request
+        already has in the queue.
+
+        - Nothing waits: a program past every live request's certain end
+          is a guaranteed-garbage dispatch — hold. (A submission landing
+          meanwhile is admitted at the next settle and the loop goes on.)
+        - A request waits and a row's last step is CERTAINLY in the queue:
+          the slot is the waiting request's from there. The row is
+          vacated now and the admission enqueued behind that step, on a
+          busy device, where the loop before PR 42 stopped issuing,
+          drained, and admitted on an idle one; the old occupant's
+          tokens still reach it through the snapshots of the programs
+          that carry them. Budget-only workloads pipeline LOSSLESSLY:
+          program count, admission order and utilization match the
+          sequential loop.
+        - A request waits and a row's end is POSSIBLY in the queue but
+          not certainly (a speculative round commits 1..k+1 tokens):
+          hold until the programs in flight have told.
+
+        Unforeseen ends (eos, a cancel) are caught up as they are
+        consumed: the freed row idles the steps already in the queue
+        behind it — ``depth`` at most — and the late admission
+        overwrites the slot before anything reads it."""
+        b = self.b
+        sure, most = b.chunk, b._chunk_tokens_max()
+        vacated = hold = False
         with self._lock:
-            return bool(self._wait_total_locked()) and any(
-                req is not None and not req.done
-                and req.budget <= self.b._chunk_tokens_max()
-                for req in snap)
+            live = [(row, req) for row, req in enumerate(self._occupant)
+                    if req is not None and not req.done]
+            if not self._wait_total_locked():
+                return any(self._spare(req, sure) < 0 for _, req in live)
+            now = time.perf_counter()
+            for row, req in live:
+                if self._spare(req, sure) >= 0:
+                    self._occupant[row] = None
+                    self._freed[row] = (now, b._seq_chunk - 1)
+                    vacated = True
+                elif most > sure and self._spare(req, most) >= 0:
+                    hold = True
+        if vacated:
+            self._settle()
+        return not hold
+
+    def _top_up(self, queue: collections.deque) -> None:
+        """Issue decode programs until ``depth`` of them stand issued
+        and unfetched (``queue``: (device tokens, the occupancy each was
+        issued under), oldest first) or :meth:`_foresee` holds."""
+        b = self.b
+        self._depth_g.set(b._ahead.depth)
+        while (len(queue) < b._ahead.depth and not self._stopped
+               and self._foresee()):
+            handle, snap = b._issue(), list(self._occupant)
+            for req in snap:
+                if req is not None:
+                    req.flight += 1
+            queue.append((handle, snap))
 
     def _run_pipelined(self) -> None:
-        """Double-buffered dispatch against the live queue: chunk N+1
-        enters the device queue before chunk N's fetch blocks on the
-        transport. Structure identical to the pre-engine closed loop —
-        the equivalence pin rests on it."""
+        """A queue of issued decode programs against the live queue:
+        ``depth`` of them (:class:`_Depth`) stand in the device's queue
+        while the host fetches the oldest, consumes it, settles and tops
+        the queue up — so the fetch, the bookkeeping and the admissions'
+        marshalling overlap device compute, and a program's tokens leave
+        the turn it returns. Fetched strictly in the order issued."""
         b = self.b
         while self._wait_for_work():
             self._admit_free()
             if not self._sweep_done_occupants():
                 self._settle()          # everything cancelled pre-issue
                 continue
-            inflight = (b._issue(), list(self._occupant))
-            while inflight is not None:
-                handle, snap = inflight
-                nxt = None
-                if (not self._stopped and not self._certainly_final()
-                        and not self._defer_issue(snap)):
-                    nxt = (b._issue(), list(self._occupant))
+            queue: collections.deque = collections.deque()
+            self._top_up(queue)
+            while queue:
+                handle, snap = queue.popleft()
                 self._consume(self._fetch(handle), snap)
+                for req in snap:
+                    if req is not None:
+                        req.flight -= 1
                 self._settle()
                 if self._stopped:
-                    return               # drop any in-flight chunk
+                    return               # drop the programs in flight
                 with self._lock:
                     occupied = any(r is not None for r in self._occupant)
-                if nxt is not None and not occupied:
-                    # every request retired while the speculative chunk
-                    # was in flight (eos beat the budget bound): drop it
-                    # unfetched — all its rows are garbage, and no turn
-                    # will close on it
-                    nxt = None
-                    b._unfetched.pop()
-                if nxt is None and occupied:
-                    nxt = (b._issue(), list(self._occupant))
-                inflight = nxt
+                if queue and not occupied and not any(
+                        req is not None and not req.done
+                        for _, issued in queue for req in issued):
+                    # every request retired while later programs were in
+                    # flight (eos beat the budget bound): drop them
+                    # unfetched — all their rows are garbage, and no turn
+                    # will close on them
+                    for _ in queue:
+                        b._unfetched.pop()
+                    queue.clear()
+                self._top_up(queue)
 
     def _run_sequential(self) -> None:
         """issue → fetch → bookkeep → admit (``pipeline=False``): the
